@@ -35,6 +35,7 @@ the extraction then reports every action with a ``TOP`` footprint, which
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field, replace
 from typing import Optional
 
@@ -329,7 +330,7 @@ class ModelFootprints:
 
 class _FpInterp:
     """One forward pass over a closed jaxpr with the footprint domain.
-    Mirrors ``interval.Interp``'s walking conventions (pjit inlining via
+    Mirrors ``interval.Interp``'s walking conventions (jit-call inlining via
     aliases, producer maps) with conservative TOP for anything unknown."""
 
     def __init__(self):
@@ -411,7 +412,7 @@ class _FpInterp:
         if rule is not None:
             out = rule(self, eqn, ins)
             outs = out if isinstance(out, list) else [out]
-        elif name in ("pjit", "closed_call", "core_call", "custom_jvp_call",
+        elif name in ("jit", "closed_call", "core_call", "custom_jvp_call",
                       "custom_vjp_call", "remat_call", "checkpoint"):
             outs = self._call(eqn, ins)
         else:
@@ -1050,6 +1051,47 @@ def _leaf_vars_of(closed, arity: int) -> tuple:
     return leaves, idx
 
 
+def _eval_sliced(jaxpr, consts, *args):
+    """``jax.core.eval_jaxpr`` for a kernel slice that may run INSIDE the
+    sharded engine's ``shard_map``: there the rows are varying over the
+    mesh axis while the slice's constants and literals — traced outside
+    — are not, and a raw ``bind`` refuses to mix the two (the casts
+    ``jnp`` would insert while tracing are not in the jaxpr).  Operands
+    are therefore cast up to the eqn's widest varying set before each
+    bind, and nested ``jit`` calls are evaluated inline for the same
+    reason.  Outside a ``shard_map`` nothing varies and this is
+    ``eval_jaxpr``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.extend.core import Literal
+
+    env: dict = dict(zip(jaxpr.constvars, consts))
+    env.update(zip(jaxpr.invars, args))
+    for eqn in jaxpr.eqns:
+        vals = [v.val if isinstance(v, Literal) else env[v]
+                for v in eqn.invars]
+        vmas = [getattr(jax.typeof(x), "vma", frozenset()) for x in vals]
+        want = frozenset().union(*vmas)
+        if want:
+            vals = [
+                x if vma == want else jax.lax.pcast(
+                    jnp.asarray(x), tuple(want - vma), to="varying"
+                )
+                for x, vma in zip(vals, vmas)
+            ]
+        if eqn.primitive.name == "jit":
+            inner = eqn.params["jaxpr"]
+            outs = _eval_sliced(inner.jaxpr, inner.consts, *vals)
+        else:
+            subfuns, params = eqn.primitive.get_bind_params(eqn.params)
+            outs = eqn.primitive.bind(*subfuns, *vals, **params)
+            if not eqn.primitive.multiple_results:
+                outs = [outs]
+        env.update(zip(eqn.outvars, outs))
+    return [v.val if isinstance(v, Literal) else env[v]
+            for v in jaxpr.outvars]
+
+
 def conjunct_eval_fn(tensor):
     """A batch-size-polymorphic evaluator of the guard-conjunct leaves:
     ``fn(rows[B, W]) -> [bool[B] | bool[B, cap], ...]`` — the raw leaf
@@ -1087,20 +1129,8 @@ def conjunct_eval_fn(tensor):
             if idx != expect_idx or not leaves:
                 cache[b] = False  # retrace drifted: caller falls back
                 return None
-            jaxpr = closed.jaxpr
-            try:
-                sub = jaxpr.replace(outvars=list(leaves))
-            except Exception:  # noqa: BLE001 - older jax Jaxpr API
-                import jax.core as jcore
-
-                sub = jcore.Jaxpr(
-                    jaxpr.constvars, jaxpr.invars, list(leaves),
-                    jaxpr.eqns, jaxpr.effects,
-                )
-            import jax.core as jcore
-
-            closed_sub = jcore.ClosedJaxpr(sub, closed.consts)
-            built = jcore.jaxpr_as_fun(closed_sub)
+            sub = closed.jaxpr.replace(outvars=list(leaves))
+            built = functools.partial(_eval_sliced, sub, closed.consts)
             cache[b] = built
         if built is False:
             return None

@@ -279,7 +279,7 @@ class Interp:
         self.row_domain = row_domain
         self.env: dict = {}
         self.input_var = None  # the rows var the domain seeds
-        # pjit bodies are INLINED into this flat environment; the alias map
+        # jit-call bodies are INLINED into this flat environment; the alias map
         # links an inner jaxpr's invars to the outer vars that feed them
         # (and call outvars to the body's outvars), so guard recognition
         # and refinement walk straight through jnp's where/clip wrappers.
@@ -464,7 +464,7 @@ class Interp:
         if rule is not None:
             out = rule(self, eqn, ins)
             return out if isinstance(out, list) else [out]
-        if name in ("pjit", "closed_call", "core_call", "custom_jvp_call",
+        if name in ("jit", "closed_call", "core_call", "custom_jvp_call",
                     "custom_vjp_call", "custom_vjp_call_jaxpr",
                     "remat_call", "checkpoint"):
             return self._call(eqn, ins)
@@ -485,7 +485,7 @@ class Interp:
         return out
 
     def _call(self, eqn, ins) -> list:
-        """INLINE a pjit/call body into the flat environment (alias-linked),
+        """INLINE a jit/call body into the flat environment (alias-linked),
         so guards recognized outside a ``jnp.where`` wrapper refine values
         inside it and vice versa."""
         inner = eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")

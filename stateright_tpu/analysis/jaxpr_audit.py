@@ -91,7 +91,7 @@ def _aval_bytes(v) -> int:
 
 def _walk_jaxprs(jaxpr):
     """Yield ``jaxpr`` and every sub-jaxpr reachable through eqn params
-    (pjit bodies, cond branches, while cond/body, scan, custom calls)."""
+    (jit bodies, cond branches, while cond/body, scan, custom calls)."""
     seen = []
     stack = [jaxpr]
     while stack:

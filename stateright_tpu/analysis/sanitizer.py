@@ -554,8 +554,8 @@ def error_flag(err):
     pytrees mint fresh error codes per trace, so the full Error cannot
     cross jit boundaries; per-row replay rebuilds the message.)
 
-    Reads checkify's ``Error._pred`` (private but stable on the pinned
-    jax).  If a jax upgrade renames it this RAISES at engine build time —
+    Reads checkify's ``Error._pred`` (private; present on jax 0.9.0).
+    If a jax upgrade renames it this RAISES at engine build time —
     a checked mode that silently reports all-clear would be worse than no
     checked mode at all."""
     import jax.numpy as jnp

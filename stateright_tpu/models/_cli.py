@@ -199,8 +199,19 @@ def apply_perf(builder, cfg: dict):
         builder = builder.mxu()
     if cfg.get("mesh"):
         builder = builder.mesh()
-    if cfg.get("compile_cache"):
-        builder = builder.compile_cache(cfg["compile_cache"])
+    import jax
+
+    from ..parallel.prewarm import resolve_compile_cache_dir
+
+    # on an accelerator the device verbs are chip entry points: they keep
+    # a compile cache by default (JAX_COMPILATION_CACHE_DIR, else the
+    # fixed in-checkout directory); CPU runs cache only on request
+    cache = resolve_compile_cache_dir(
+        cfg.get("compile_cache"),
+        entry_point=jax.default_backend() != "cpu",
+    )
+    if cache:
+        builder = builder.compile_cache(cache)
     return builder
 
 

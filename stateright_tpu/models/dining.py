@@ -182,8 +182,9 @@ def main(argv=None) -> None:
         )
         m = apply_encoding(dining_model(n), perf)
         if m.tensor_model() is None:
-            print("this configuration has no device twin; use `check` (CPU)")
-            return
+            raise SystemExit(
+                "this configuration has no device twin; use `check` (CPU)"
+            )
         spawn_watched(
             apply_perf(m.checker().checked(checked), perf), watch,
             lambda b: b.spawn_tpu(),

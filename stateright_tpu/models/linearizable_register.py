@@ -311,12 +311,11 @@ def main(argv=None):
         )
         m = apply_encoding(abd_model(client_count, 2, network), perf)
         if m.tensor_model() is None:
-            print(
+            raise SystemExit(
                 f"the {network.name} network has no device twin here: "
                 "redelivery makes ABD clocks unbounded (state_bound); use "
                 "`check` (CPU) or a non-duplicating/ordered network"
             )
-            return
         spawn_watched(
             apply_perf(m.checker().checked(checked), perf), watch,
             lambda b: b.spawn_tpu(),

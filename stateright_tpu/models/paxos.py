@@ -362,10 +362,9 @@ def main(argv=None):
         )
         m = apply_encoding(paxos_model(client_count, 3), perf)
         if m.tensor_model() is None:
-            print(
+            raise SystemExit(
                 "this configuration has no device twin; use `check` (CPU)"
             )
-            return
         b = apply_perf(m.checker().checked(checked), perf)
         if target:
             b = b.target_states(target)

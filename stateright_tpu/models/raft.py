@@ -259,8 +259,9 @@ def main(argv=None) -> None:
         )
         m = apply_encoding(raft_model(n, network=network), perf)
         if m.tensor_model() is None:
-            print("this configuration has no device twin; use `check-sym`")
-            return
+            raise SystemExit(
+                "this configuration has no device twin; use `check-sym`"
+            )
         spawn_watched(
             apply_perf(m.checker().checked(checked).symmetry(), perf),
             watch, lambda b: b.spawn_tpu(),
@@ -277,8 +278,9 @@ def main(argv=None) -> None:
         )
         m = apply_encoding(raft_model(n, network=network), perf)
         if m.tensor_model() is None:
-            print("this configuration has no device twin; use `check` (CPU)")
-            return
+            raise SystemExit(
+                "this configuration has no device twin; use `check` (CPU)"
+            )
         spawn_watched(
             apply_perf(m.checker().checked(checked), perf), watch,
             lambda b: b.spawn_tpu(),

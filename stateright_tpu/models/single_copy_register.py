@@ -140,8 +140,9 @@ def main(argv=None):
         )
         m = apply_encoding(single_copy_model(client_count, 1, network), perf)
         if m.tensor_model() is None:
-            print("this configuration has no device twin; use `check` (CPU)")
-            return
+            raise SystemExit(
+                "this configuration has no device twin; use `check` (CPU)"
+            )
         spawn_watched(
             apply_perf(m.checker().checked(checked), perf), watch,
             lambda b: b.spawn_tpu(),

@@ -185,8 +185,9 @@ def main(argv=None):
             wo_register_model(client_count, server_count, network), perf
         )
         if m.tensor_model() is None:
-            print("this configuration has no device twin; use `check` (CPU)")
-            return
+            raise SystemExit(
+                "this configuration has no device twin; use `check` (CPU)"
+            )
         spawn_watched(
             apply_perf(m.checker().checked(checked), perf), watch,
             lambda b: b.spawn_tpu(),

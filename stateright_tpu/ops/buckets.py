@@ -10,7 +10,7 @@ effectively index-serial, so the fix is architectural, not incremental:
 
  - The table is an array of **buckets** of ``SLOTS`` fingerprints each; a
    fingerprint's bucket is the HIGH bits of ``mix64(fp)`` (one extra
-   splitmix64 round).  The round-5 table-size anomaly (VERDICT.md) traced to
+   splitmix64 round).  An earlier table-size anomaly traced to
    the previous derivation — the fingerprint's raw low bits — clustering:
    splitmix64's final odd multiply avalanches upward only (bit ``k`` of the
    product depends on input bits ``0..k``), so the low bits of structurally
@@ -348,9 +348,9 @@ def occupancy_stats(table_fp) -> dict:
     """Bucket-occupancy counters for a visited table (numpy, JSON-safe).
 
     The engines' growth protocol keys on load factor and single-bucket
-    overflow, but the *distribution* was never observable — and VERDICT.md
-    records an open anomaly where runs grow tables earlier than the ≤25%
-    Poisson model predicts.  This is the first diagnostic handle on it:
+    overflow, but the *distribution* was never observable — and runs
+    were seen growing tables earlier than the ≤25% Poisson model
+    predicts.  This is the diagnostic handle on it:
     exposed via ``WavefrontChecker.occupancy_stats()``, the Explorer's
     ``/.status`` (``"table"``), and the audit report metrics.
 

@@ -33,7 +33,8 @@ target slots are distinct, so write order cannot matter, and exploration
 order is carried by ``sel``, which is computed in ``bucket_insert``
 before the kernel runs.
 
-Measured verdict (v5e, 8M-slot table, 8192 novel/batch): serial walk
+An earlier micro-benchmark (v5e, 8M-slot table, 8192 novel/batch; not
+re-measured on today's code or the installed Mosaic): serial walk
 54.1 ms/insert → pipelined 37.3 ms/insert → **XLA windowed scatter
 0.14 ms/insert**.  The XLA path remains the default and the recommended
 one; ``docs/pallas-insert-verdict.md`` explains why tile-granularity DMA
@@ -80,6 +81,15 @@ NBUF = 8
 RUNW = 1024
 # state_ref cells
 _R_CUR, _R_PF, _R_WIN = 0, 1, 2
+
+
+def interpret_mode() -> bool:
+    """Mosaic compiles the kernel only for a TPU; on any other backend
+    ``pallas_call`` runs it INTERPRETED — a correctness aid, orders of
+    magnitude slower.  The engine publishes this next to ``pallas`` in
+    the recorder meta and the report flags, so an interpreted run is
+    never read as a kernel run."""
+    return jax.default_backend() != "tpu"
 
 
 def _insert_kernel(
@@ -390,7 +400,6 @@ def pallas_scatter_insert(
         ngroups, GROUP_LANES
     )
 
-    interpret = jax.default_backend() != "tpu"
     out_fp, out_pl = pl.pallas_call(
         _insert_kernel,
         out_shape=[
@@ -420,7 +429,7 @@ def pallas_scatter_insert(
             pltpu.SemaphoreType.DMA((2,)),
         ],
         input_output_aliases={3: 0, 4: 1},
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(
         jnp.stack([n_new.astype(jnp.int32), n_runs]).reshape(2),
         meta,

@@ -26,8 +26,9 @@ from ..ops.hashing import row_hash
 
 
 # Spaces below this finish in one or two engine calls on hardware: the
-# measured "rate" is fixed per-run overhead, not throughput (bench r4:
-# lin-reg-2, 544 states, 927/s on a v5e vs 7.4k/s on one CPU core).
+# measured "rate" is fixed per-run overhead, not throughput.  The constant
+# itself is not measured on today's code (chip_smoke.py on a v5e only
+# shows the direction: paxos-2's 16,668 states cost seconds of set-up).
 # Shared by the engines' footgun warning, spawn_auto's rationale, and the
 # bench's per-config disclosure notes — recalibrate it in ONE place.
 SMALL_SPACE_BREAK_EVEN = 100_000
@@ -94,7 +95,8 @@ class WavefrontChecker(Checker):
         # engines); prewarm is single-device only (the sharded engine's
         # growth rebuilds are whole-mesh shard_maps — background-compiling
         # them is future work); the persistent compile cache is a global
-        # JAX setting enabled here once a dir is configured.
+        # JAX setting, switched on here when the environment or the
+        # builder names a directory (prewarm.resolve_compile_cache_dir).
         from .prewarm import (
             ENV_POR,
             ENV_PREDEDUP,
@@ -894,8 +896,8 @@ class WavefrontChecker(Checker):
         (``ops/buckets.occupancy_stats``), or None while the run is still
         in flight.  Also folded into the model's last audit report
         (``metrics["table"]``) so the perf preflight and the observed
-        table behavior travel together (the open table-size anomaly in
-        VERDICT.md is diagnosed from exactly these counters)."""
+        table behavior travel together (early table growth is
+        diagnosed from exactly these counters)."""
         if not self._results:
             return None
         # The table is immutable once _results is set, but the Explorer

@@ -4,9 +4,7 @@ engine over a named ``('host', 'chip')`` mesh.
 The old sharded engine (``sharded.py``) hand-schedules the scale-out: a
 ``shard_map`` body routes candidates to their owner with an explicit
 ``lax.all_to_all`` and marks per-device values with vma casts
-(``jax.lax.pcast``/``pvary``) the pinned jax 0.4.37 does not have — the
-ROADMAP's standing sharded-failure class.  This engine inverts the
-responsibility: the *global* wavefront program (``wavefront.py``,
+(``jax.lax.pcast``).  This engine inverts the responsibility: the *global* wavefront program (``wavefront.py``,
 unchanged — same jaxprs, same counters, same discovery rule) is handed
 to the compiler with the carry's placement expressed as
 ``NamedSharding`` partition rules (``parallel/partition.py``), and GSPMD
@@ -27,8 +25,7 @@ inserts the collectives:
 Because the program is the single-device engine's own, parity with it is
 by construction: counts, verdicts, discovery traces, and kill+resume
 snapshots are bit-identical (pinned by tests/test_mesh.py).  Zero
-``shard_map``/``pvary``/``pcast`` references — the engine compiles and
-runs on jax 0.4.37 and newer alike.
+``shard_map``/``pcast`` references: every collective is the compiler's.
 
 Host-loop mechanics are inherited unchanged: growth, checkpointing, and
 resume round-trip the carry through host numpy; re-entry as plain numpy
@@ -55,7 +52,6 @@ from jax.sharding import Mesh
 
 from ..ops.buckets import SLOTS, bucket_of
 from ..ops.hashing import EMPTY
-from .prewarm import donation_supported
 from .partition import (
     WAVEFRONT_CARRY_RULES,
     build_mesh,
@@ -139,7 +135,7 @@ class MeshTpuChecker(TpuChecker):
             run_fn,
             in_shardings=(shardings,),
             out_shardings=(shardings, rep),
-            donate_argnums=(0,) if donation_supported() else (),
+            donate_argnums=(0,),
         )
         return mesh_init, mesh_run
 

@@ -29,14 +29,6 @@ what this module decides):
    to all-to-all/all-gather instead of a hand-scheduled collective),
    queue/candidate buffers sharded along the frontier dimension, and
    every counter/flag replicated.
-
-Compat shims live here too (the satellite dedupe): the ``shard_map``
-import dance the old sharded engine needs, and the per-engine
-collectives requirement — the OLD engine's ``shard_map`` body needs the
-vma-cast collectives (``jax.lax.pcast``/``pvary``) that the pinned jax
-0.4.37 lacks; the MESH engine deliberately needs neither (its programs
-are plain jitted global programs partitioned by in/out shardings), which
-is what turns the standing sharded-test failures into runnable coverage.
 """
 
 from __future__ import annotations
@@ -50,37 +42,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 MESH_AXES = ("host", "chip")
-
-
-# -- compat shims (ONE definition; sharded.py + tests/helpers.py import) -----
-
-def has_vma_collectives() -> bool:
-    """True when this jax exposes the vma-cast collectives
-    (``jax.lax.pcast`` / ``jax.lax.pvary``) the hand-rolled ``shard_map``
-    engine marks per-device values with.  The pinned jax 0.4.37 has
-    neither — the ROADMAP's standing sharded-failure class."""
-    return hasattr(jax.lax, "pcast") or hasattr(jax.lax, "pvary")
-
-
-def engine_requires_collectives(engine: str) -> bool:
-    """Per-engine collectives requirement (skips are per-engine, not
-    blanket): only the OLD shard_map engine (``"sharded"``) needs the vma
-    casts; the mesh engine's programs are jit-partitioned global programs
-    with zero ``pvary``/``pcast``/``shard_map`` references, and the
-    single-device engine never touches a collective at all."""
-    return engine == "sharded"
-
-
-try:  # jax >= 0.6 stable API
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
 
 
 ENV_MESH = "STATERIGHT_TPU_MESH"

@@ -12,12 +12,16 @@ the same unattributed cost center — XLA engine compiles:
    costs one wasted background compile, never correctness: the prewarmed
    executable is the SAME program, compiled earlier.
 
- - :func:`enable_persistent_compile_cache` — opt-in wiring of JAX's
-   persistent compilation cache (``jax_compilation_cache_dir``), so
-   repeated CLI/bench/regress invocations skip engine compiles entirely.
-   Thresholds are zeroed: engine compiles are seconds-long on hardware,
-   but the default min-compile-time gate would skip caching the small
-   helper programs whose re-trace still costs host time.
+ - :func:`enable_persistent_compile_cache` — wiring of JAX's persistent
+   compilation cache, so repeated CLI/bench/smoke invocations skip engine
+   compiles entirely.  WHERE the cache lives is decided by
+   :func:`resolve_compile_cache_dir` alone: ``JAX_COMPILATION_CACHE_DIR``
+   when the environment sets it (JAX reads it itself; this module then
+   never re-points the cache), else an explicit request, else — for the
+   chip entry points — one fixed directory inside the checkout.
+   Thresholds are zeroed: the default min-compile-time gate would skip
+   caching the small helper programs whose re-trace still costs host
+   time.
 
  - :class:`CompileWatch` — compile-time attribution via JAX's monitoring
    events (``/jax/core/compile/backend_compile_duration`` and the
@@ -32,6 +36,7 @@ the same unattributed cost center — XLA engine compiles:
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from typing import Callable, Optional
@@ -67,17 +72,13 @@ def _tls_counts() -> dict:
     return counts
 
 
-def _install_listener() -> bool:
-    """Register the jax monitoring listeners once; False when this jax
-    build has no monitoring surface (attribution then reads 0)."""
+def _install_listener() -> None:
+    """Register the jax monitoring listeners, once."""
     global _listener_installed
     with _listener_lock:
         if _listener_installed:
-            return True
-        try:
-            from jax._src import monitoring
-        except Exception:  # noqa: BLE001 - attribution is best-effort
-            return False
+            return
+        import jax.monitoring
 
         def on_event(event, **kw):
             if event == _HIT_EVENT:
@@ -91,13 +92,9 @@ def _install_listener() -> bool:
                     float(duration), 0.0
                 )
 
-        try:
-            monitoring.register_event_listener(on_event)
-            monitoring.register_event_duration_secs_listener(on_duration)
-        except Exception:  # noqa: BLE001
-            return False
+        jax.monitoring.register_event_listener(on_event)
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
         _listener_installed = True
-        return True
 
 
 def compile_counters() -> dict:
@@ -139,26 +136,80 @@ class CompileWatch:
 
 # -- persistent compilation cache ---------------------------------------------
 
-ENV_COMPILE_CACHE = "STATERIGHT_TPU_COMPILE_CACHE"
+ENV_JAX_CACHE_DIR = "JAX_COMPILATION_CACHE_DIR"  # JAX's own variable
+# the chip entry points' cache when the environment names none: a FIXED
+# path (the directory is part of JAX's cache key story — a temp name, pid
+# or timestamp never hits), inside the checkout, git-ignored
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    ))),
+    ".jax_cache",
+)
 ENV_PREWARM = "STATERIGHT_TPU_PREWARM"
 ENV_PREDEDUP = "STATERIGHT_TPU_PREDEDUP"
 ENV_POR = "STATERIGHT_TPU_POR"
 ENV_SPILL = "STATERIGHT_TPU_SPILL"
 
 _cache_lock = threading.Lock()
-_cache_dir: Optional[str] = None
+_cache_dir: Optional[str] = None  # what enable_persistent_compile_cache turned on
 
 
-def enable_persistent_compile_cache(path: Optional[str] = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``path`` (default: the
-    ``STATERIGHT_TPU_COMPILE_CACHE`` env var; no-op returning None when
-    neither is set).  Idempotent; re-pointing at a different dir is
-    honored (last caller wins — it is one global JAX setting).  Also zeroes
-    the cache's size/compile-time admission thresholds so every engine
-    program is cached, and installs the hit/miss listener so the flight
-    recorder can tell a disk hit from a fresh compile."""
+def resolve_compile_cache_dir(
+    requested: Optional[str] = None, *, entry_point: bool = False
+) -> Optional[str]:
+    """THE answer to "where does the persistent compile cache live":
+
+     - ``JAX_COMPILATION_CACHE_DIR`` set -> that directory, always.  A
+       ``requested`` dir (``CheckerBuilder.compile_cache()`` /
+       ``--compile-cache=``) that disagrees is ignored with one stderr
+       line — whoever launched the process placed the cache.
+     - unset -> ``requested`` if given; else :data:`CHECKOUT_CACHE_DIR`
+       for the chip entry points (``entry_point=True``: chip_smoke.py,
+       bench.py's device child, the CLI device verbs on an accelerator);
+       else None — a plain library ``spawn_tpu()`` keeps no cache."""
+    env = os.environ.get(ENV_JAX_CACHE_DIR)
+    if env:
+        if requested and os.path.abspath(requested) != os.path.abspath(env):
+            print(
+                f"stateright-tpu: {ENV_JAX_CACHE_DIR}={env} is set; "
+                f"ignoring the requested compile cache dir {requested}",
+                file=sys.stderr,
+            )
+        return env
+    if requested:
+        return str(requested)
+    return CHECKOUT_CACHE_DIR if entry_point else None
+
+
+def _point_jax_cache(path: Optional[str]) -> None:
+    """The ONE place this package sets ``jax_compilation_cache_dir`` —
+    and only when the environment did not (JAX already holds the
+    ``JAX_COMPILATION_CACHE_DIR`` value then).  jax binds its cache
+    object to the first directory it initializes with, so RE-pointing
+    (or switching off) needs the cache reset; merely enabling after the
+    process's first compile does not (checked on jax 0.9.0)."""
+    if os.environ.get(ENV_JAX_CACHE_DIR):
+        return
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_compilation_cache_dir", path)
+    if _cache_dir is not None:
+        compilation_cache.reset_cache()
+
+
+def enable_persistent_compile_cache(
+    path: Optional[str] = None, *, entry_point: bool = False
+) -> Optional[str]:
+    """Turn on JAX's persistent compilation cache at the directory
+    :func:`resolve_compile_cache_dir` picks (no-op returning None when it
+    picks none).  Idempotent.  Also zeroes the cache's size/compile-time
+    admission thresholds so every engine program is cached, and installs
+    the hit/miss listener so the flight recorder can tell a disk hit
+    from a fresh compile."""
     global _cache_dir
-    path = path or os.environ.get(ENV_COMPILE_CACHE) or None
+    path = resolve_compile_cache_dir(path, entry_point=entry_point)
     if not path:
         return None
     with _cache_lock:
@@ -167,60 +218,23 @@ def enable_persistent_compile_cache(path: Optional[str] = None) -> Optional[str]
         import jax
 
         os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
+        _point_jax_cache(path)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        _reset_jax_cache_decision()
         _cache_dir = path
     _install_listener()
     return path
 
 
-def _reset_jax_cache_decision() -> None:
-    """jax caches its is-the-cache-used decision at the FIRST compile of
-    the process (``compilation_cache._cache_checked``), so enabling the
-    dir after any compile (audit preflight, another model) would be
-    silently ignored without this reset.  Private-API touch, guarded: on
-    a jax without it the cache still works when the dir is set before the
-    first compile."""
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:  # noqa: BLE001
-        pass
-
-
 def disable_persistent_compile_cache() -> None:
     """Undo :func:`enable_persistent_compile_cache` (tests restore global
-    state; a long-lived process keeps the cache on once enabled)."""
+    state; a long-lived process keeps the cache on once enabled).  Under
+    ``JAX_COMPILATION_CACHE_DIR`` the directory stays JAX's."""
     global _cache_dir
     with _cache_lock:
-        if _cache_dir is None:
-            return
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir", None)
-        _reset_jax_cache_decision()
-        _cache_dir = None
-
-
-def donation_supported() -> bool:
-    """Whether buffer donation is real on the default backend.  The CPU
-    backend ignores ``donate_argnums`` at execution time (jax warns and
-    copies), BUT jax 0.4.x's persistent-compilation-cache deserialization
-    path still applies the donation metadata to a retrieved executable —
-    which then reads input buffers jax has already marked deleted and
-    returns garbage (reproduced on the wavefront engine: correct first
-    run, corrupted counters on every cache-served run; docs/perf.md).
-    The engines therefore request donation only where it actually
-    exists."""
-    try:
-        import jax
-
-        return jax.default_backend() != "cpu"
-    except Exception:  # noqa: BLE001 - no backend: donation moot
-        return False
+        if _cache_dir is not None:
+            _point_jax_cache(None)
+            _cache_dir = None
 
 
 def resolve_flag(mode: Optional[bool], env: str) -> bool:

@@ -111,7 +111,7 @@ def unify_jaxprs(closed_list):
     and passed as-is; ``shared=False`` values are stacked ``[K, ...]``
     and gathered by instance tag at evaluation time.  Returns ``None``
     when the jaxprs do not unify (the caller splits the cohort)."""
-    from jax._src.core import Literal, Var
+    from jax.extend.core import Literal, Var
 
     k = len(closed_list)
     j0 = closed_list[0].jaxpr
@@ -161,7 +161,7 @@ def unify_jaxprs(closed_list):
                     np.array_equal(vals[0], x) for x in vals[1:]
                 ):
                     continue
-                nv = Var("", v.aval)
+                nv = Var(v.aval)
                 invars[vi] = nv
                 changed = True
                 lifted_vars.append(nv)

@@ -75,6 +75,7 @@ FLAG_CLASS = {
     "symmetry": "isomorphic",
     "prewarm": "perf",
     "pallas": "perf",
+    "pallas_interpret": "perf",
     "compile_cache": "perf",
     # the MXU recast knobs (ops/mxu.py): counts bit-identical by
     # contract, program shapes differ — a pure perf delta

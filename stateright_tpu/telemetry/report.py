@@ -181,6 +181,12 @@ def build_config(checker) -> dict:
             getattr(checker, "_compile_cache_dir", None)
         ),
     }
+    if flags["pallas"]:
+        # Mosaic compiles the kernel only on a TPU: anywhere else the run
+        # was INTERPRETED, and the report must say so next to the flag
+        from ..ops.pallas_insert import interpret_mode
+
+        flags["pallas_interpret"] = interpret_mode()
     try:
         import jax
 

@@ -5,11 +5,12 @@ devices so multi-chip sharding (mesh + all-to-all fingerprint routing) is
 exercised exactly as the driver's ``dryrun_multichip`` does.  Must run before
 jax is used anywhere.
 
-Note the env override must be unconditional: the environment may arrive with
-``JAX_PLATFORMS`` already pointing at a real accelerator plugin, and a
-``setdefault`` would silently leave the whole suite running on one real chip.
-``jax.config.update`` additionally beats any plugin that force-selected its
-platform at interpreter startup (site hooks run before this file).
+Note the env override must be unconditional: on a machine with a chip JAX
+defaults to the TPU (and ``JAX_PLATFORMS`` may say so explicitly), and a
+``setdefault`` would silently leave the whole suite running on one real
+chip — which has one device, not the eight the sharding tests need, and
+belongs to one process at a time.  ``jax.config.update`` pins the same
+choice in case jax was imported before this file.
 """
 
 import os

@@ -28,8 +28,6 @@ import pytest
 import jax
 import numpy as np
 
-from helpers import requires_sharded_collectives
-
 from stateright_tpu.models.dining import dining_model
 from stateright_tpu.models.two_phase_commit import TwoPhaseSys
 from stateright_tpu.telemetry.health import HealthTracker, phase_timeline
@@ -182,7 +180,6 @@ def test_dining_reconciles_and_fills_action_histogram():
 # -- sharded engine ----------------------------------------------------------
 
 
-@requires_sharded_collectives
 def test_sharded_cartography_counts_and_shard_extras():
     c = TwoPhaseSys(3).checker().telemetry(cartography=True).spawn_tpu(
         sync=True, devices=2, capacity=1 << 12, frontier_capacity=1 << 9
@@ -200,7 +197,6 @@ def test_sharded_cartography_counts_and_shard_extras():
     assert imb["max"] >= imb["mean"]
 
 
-@requires_sharded_collectives
 def test_sharded_resume_preserves_cartography_counters():
     """The sharded counter tail is cumulative IN-CARRY, so snapshots must
     persist it: a resumed run re-seeded with zeros pairs restarted
@@ -221,7 +217,6 @@ def test_sharded_resume_preserves_cartography_counters():
     _reconcile(r)
 
 
-@requires_sharded_collectives
 def test_sharded_cartography_off_program_unchanged():
     """Flag-off pin for the sharded engine: the whole-run program traced
     with ``cartography=False`` is bit-identical to a build that never

@@ -45,7 +45,6 @@ from stateright_tpu.telemetry.roofline import (
     classify_stages,
     device_spec,
 )
-from tests.helpers import requires_sharded_collectives
 
 _KW = dict(capacity=1 << 12, batch=64)
 _STAGES = ("property", "expand", "hash", "dedup-insert", "queue")
@@ -88,7 +87,6 @@ def test_roofline_does_not_key_the_engine_cache():
     assert c2.unique_state_count() == c1.unique_state_count()
 
 
-@requires_sharded_collectives
 def test_sharded_roofline_block_and_cache_identity():
     """The sharded engine carries the model-kernel ledger (its insert /
     all-to-all are the pod-scale round's work) under the same
@@ -166,7 +164,7 @@ def test_classify_primitive_covers_the_catalogue():
     assert classify_primitive("reduce_sum") == "reduce"
     assert classify_primitive("argmax") == "reduce"
     assert classify_primitive("while") == "control"
-    assert classify_primitive("pjit") == "control"
+    assert classify_primitive("closed_call") == "control"
     assert classify_primitive("add") == "elementwise"
     assert classify_primitive("reshape") == "elementwise"
 
@@ -239,6 +237,27 @@ def test_device_spec_env_override_and_cpu_degradation(monkeypatch, capsys):
     monkeypatch.setenv(ENV_DEVICE_SPEC, "garbage")
     assert device_spec() is None or device_spec()["src"] != "env"
     assert "malformed" in capsys.readouterr().err
+
+
+def test_device_spec_matches_the_real_v5e_kind_and_never_guesses(
+    monkeypatch, capsys
+):
+    """A v5e reports ``device_kind == "TPU v5 lite"`` (chip_smoke.py, PR
+    22) and gets the v5e row; an unlisted TPU kind gets NO spec — loudly
+    — never a neighbouring generation's peaks."""
+    from types import SimpleNamespace
+
+    monkeypatch.delenv(ENV_DEVICE_SPEC, raising=False)
+    v5e = device_spec(SimpleNamespace(platform="tpu",
+                                      device_kind="TPU v5 lite"))
+    assert v5e["name"] == "tpu-v5e" and v5e["src"] == "device"
+    assert v5e["hbm_bytes_per_sec"] == 819e9
+    assert capsys.readouterr().err == ""
+    for kind in ("TPU v5", "TPU v7x"):
+        assert device_spec(
+            SimpleNamespace(platform="tpu", device_kind=kind)
+        ) is None
+        assert "no peak table entry" in capsys.readouterr().err
 
 
 def test_classify_stages_verdicts():

@@ -249,7 +249,7 @@ def test_serve_tpu_strategy_endpoints():
 
 def test_serve_tpu_live_status_mid_run():
     """``/.status`` surfaces live counters and discovery paths while the
-    device run is still in flight (VERDICT r2 missing #5): tiny batches plus
+    device run is still in flight: tiny batches plus
     per-step host syncs keep the run pollable."""
     import time as _time
 

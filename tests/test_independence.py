@@ -28,7 +28,6 @@ import pytest
 import jax
 
 from fixtures_por import ToggleSys, WorkersSys
-from helpers import requires_sharded_collectives
 
 from stateright_tpu.analysis.footprint import (
     FieldSet,
@@ -535,11 +534,9 @@ def test_2pc7_por_counts_pinned_full_parity():
     assert st["rows_reduced"] == 0 and st["candidates_masked"] == 0
 
 
-# -- sharded engine (runs on CI's newer jax; the pinned local jax lacks
-# the vma collectives — tests/helpers.py) ------------------------------------
+# -- sharded engine -----------------------------------------------------------
 
 
-@requires_sharded_collectives
 def test_sharded_por_parity_and_reduction():
     a = TwoPhaseSys(3).checker().spawn_tpu(
         sync=True, devices=2, capacity=1 << 12, frontier_capacity=1 << 9
@@ -563,7 +560,6 @@ def test_sharded_por_parity_and_reduction():
     assert sorted(wp.discoveries()) == ["w0 done"]
 
 
-@requires_sharded_collectives
 def test_sharded_por_off_program_unchanged():
     import jax.numpy as jnp
 

@@ -3,8 +3,7 @@
 Pins the round's contracts (docs/telemetry.md "Memory ledger"):
 
  - EXACTNESS: the analytic per-buffer bytes reconcile exactly against the
-   live engine buffers' ``nbytes`` — per buffer, both engines (the
-   sharded leg behind ``requires_sharded_collectives``);
+   live engine buffers' ``nbytes`` — per buffer, both engines;
  - ZERO JAXPR IMPACT: the ledger is host arithmetic only — the run
    program is bit-identical with the ledger on or off (the
    telemetry/checked/prededup/cartography discipline, in its strongest
@@ -38,7 +37,6 @@ from stateright_tpu.telemetry.memory import (
     total_bytes,
     wavefront_specs,
 )
-from tests.helpers import requires_sharded_collectives
 
 
 # -- exactness: analytic bytes == live buffer nbytes -------------------------
@@ -73,7 +71,6 @@ def test_wavefront_analytic_bytes_reconcile_exactly():
     assert snap["buffers"] == {s.name: s.nbytes for s in specs}
 
 
-@requires_sharded_collectives
 def test_sharded_analytic_bytes_reconcile_exactly():
     """Same exactness on the mesh engine: the GLOBAL carry arrays'
     nbytes equal the sharded analytic model per buffer."""

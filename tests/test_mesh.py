@@ -16,8 +16,7 @@ The contracts pinned here, in the family's strongest form:
    ``mesh=`` kwargs) stays the old engine, sweep x mesh is fenced;
  - the partition-rule matcher's guards (scalar, divisibility, no-match,
    flag/layout drift);
- - ZERO vma-cast collectives in the mesh path: these tests RUN — never
-   take ``requires_sharded_collectives`` — on the pinned jax 0.4.37.
+ - ZERO hand-written collectives in the mesh path (GSPMD inserts them).
 """
 
 from __future__ import annotations
@@ -39,7 +38,6 @@ from stateright_tpu.parallel.partition import (
     MESH_AXES,
     WAVEFRONT_CARRY_RULES,
     build_mesh,
-    engine_requires_collectives,
     match_partition_rules,
     resolve_mesh_flag,
     wavefront_carry_names,
@@ -344,15 +342,11 @@ def test_wavefront_carry_names_flag_guards():
 
 
 def test_mesh_engine_needs_no_vma_collectives():
-    """The acceptance pin that keeps these tests RUNNING on jax 0.4.37:
-    the mesh module's code contains no ``pvary``/``pcast`` attribute
-    access and no ``shard_map`` use (AST-checked, so docstrings don't
-    count), and the per-engine skip helper knows it."""
+    """The mesh engine's defining property: the compiler, not the code,
+    inserts the collectives — the module contains no ``pvary``/``pcast``
+    attribute access and no ``shard_map`` use (AST-checked, so
+    docstrings don't count)."""
     import stateright_tpu.parallel.mesh as mesh_mod
-
-    assert engine_requires_collectives("sharded")
-    assert not engine_requires_collectives("mesh")
-    assert not engine_requires_collectives("single")
 
     tree = ast.parse(open(mesh_mod.__file__).read())
     banned = {"pvary", "pcast", "shard_map"}

@@ -209,3 +209,17 @@ def test_increment_lock_symmetry():
     sym = IncrementLock(3).checker().symmetry().spawn_dfs().join()
     # same verdicts under reduction
     assert not sym.discoveries() and not full.discoveries()
+
+
+def test_check_tpu_without_a_device_twin_exits_nonzero():
+    """A ``check-tpu`` verb that cannot run on the device must not return
+    as if it had: ABD under a duplicating network has no device twin
+    (unbounded clocks), and the verb exits non-zero saying so."""
+    from stateright_tpu.models import linearizable_register
+
+    with pytest.raises(SystemExit) as exc:
+        linearizable_register.main(
+            ["check-tpu", "2", "unordered_duplicating"]
+        )
+    assert exc.value.code not in (0, None)
+    assert "no device twin" in str(exc.value.code)

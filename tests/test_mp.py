@@ -1,6 +1,6 @@
 """Process-parallel BFS (``checker/mp.py``): parity with the thread oracle.
 
-The mp checker is the honest multi-core CPU baseline (VERDICT r3 next #3);
+The mp checker is the honest multi-core CPU baseline;
 its per-state semantics must be indistinguishable from ``spawn_bfs`` —
 pinned unique counts, same discoveries, valid reconstructed paths — while
 its plumbing (fp-ownership sharding, all-to-all rounds, double-barrier

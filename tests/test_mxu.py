@@ -30,8 +30,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from helpers import requires_sharded_collectives
-
 from stateright_tpu.models.paxos import paxos_model
 from stateright_tpu.models.two_phase_commit import TwoPhaseSys
 from stateright_tpu.ops.mxu import MxuConfig, coalesced_step_fn, resolve_mxu
@@ -694,7 +692,6 @@ def test_mxu_kill_and_resume_parity():
 
 
 @pytest.mark.medium
-@requires_sharded_collectives
 def test_mxu_parity_on_sharded_engine():
     a = TwoPhaseSys(3).checker().spawn_tpu(
         sync=True, devices=2, capacity=1 << 12, frontier_capacity=1 << 9

@@ -88,11 +88,18 @@ def test_pallas_overflow_writes_nothing():
 
 def test_engine_pinned_count_with_pallas():
     """Full device engine with the Pallas insert: pinned 2pc count parity
-    (reference ``examples/2pc.rs:133``: 288 @ 3 RMs)."""
+    (reference ``examples/2pc.rs:133``: 288 @ 3 RMs).  Off a TPU the
+    kernel runs INTERPRETED, and the run says so wherever it says
+    ``pallas``: the recorder meta and the report's config flags."""
     from stateright_tpu.models.two_phase_commit import TwoPhaseSys
+    from stateright_tpu.telemetry.report import build_config
 
-    checker = TwoPhaseSys(3).checker().spawn_tpu(
+    checker = TwoPhaseSys(3).checker().telemetry().spawn_tpu(
         sync=True, capacity=1 << 12, frontier_capacity=1 << 8, pallas=True
     )
     assert checker.unique_state_count() == 288
     assert set(checker.discoveries()) == {"abort agreement", "commit agreement"}
+    meta = checker.flight_recorder.meta_snapshot()
+    assert meta["pallas"] is True and meta["pallas_interpret"] is True
+    flags = build_config(checker)["flags"]
+    assert flags["pallas"] is True and flags["pallas_interpret"] is True

@@ -25,13 +25,16 @@ import pytest
 
 import jax
 
-from helpers import requires_sharded_collectives
-
 from stateright_tpu.models.two_phase_commit import TwoPhaseSys
+from stateright_tpu.parallel import prewarm
 from stateright_tpu.parallel.prewarm import (
+    CHECKOUT_CACHE_DIR,
+    ENV_JAX_CACHE_DIR,
     PREWARM_THREAD_NAME,
     EnginePrewarmer,
     disable_persistent_compile_cache,
+    enable_persistent_compile_cache,
+    resolve_compile_cache_dir,
 )
 
 TPC3_UNIQUE = 288
@@ -103,7 +106,6 @@ def test_prededup_parity_under_growth_and_symmetry():
 
 
 @pytest.mark.slow
-@requires_sharded_collectives
 def test_prededup_parity_on_sharded_engine():
     a = TwoPhaseSys(3).checker().spawn_tpu(
         sync=True, devices=2, capacity=1 << 12, frontier_capacity=1 << 9
@@ -305,6 +307,67 @@ def test_persistent_cache_round_trip_zero_fresh_compiles(tmp_path):
         ), ev2
     finally:
         disable_persistent_compile_cache()
+
+
+# -- where the cache lives (prewarm.resolve_compile_cache_dir) ---------------
+
+
+def test_cache_dir_unset_env_library_none_entry_points_fixed_checkout_path(
+    monkeypatch,
+):
+    """No ``JAX_COMPILATION_CACHE_DIR``: a plain library spawn keeps no
+    cache, an explicit request is honoured, and the chip entry points get
+    ONE fixed path inside the checkout — never a temp name, a pid or a
+    timestamp (the directory moves, the cache never hits)."""
+    import os
+    import tempfile
+
+    monkeypatch.delenv(ENV_JAX_CACHE_DIR, raising=False)
+    assert resolve_compile_cache_dir() is None
+    assert resolve_compile_cache_dir("/some/where") == "/some/where"
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    got = resolve_compile_cache_dir(entry_point=True)
+    assert got == CHECKOUT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    assert got == resolve_compile_cache_dir(entry_point=True)  # stable
+    assert not got.startswith(tempfile.gettempdir())
+    assert str(os.getpid()) not in got
+    # the in-checkout directory is git-ignored
+    assert ".jax_cache/" in open(os.path.join(repo, ".gitignore")).read()
+
+
+def test_cache_dir_env_wins_and_nothing_else_is_ever_configured(
+    monkeypatch, tmp_path, capsys
+):
+    """``JAX_COMPILATION_CACHE_DIR`` set: that directory is the answer for
+    every caller, a disagreeing explicit request is ignored with one
+    stderr line, and the package never touches
+    ``jax_compilation_cache_dir`` — enabling only zeroes the admission
+    thresholds and installs the listener."""
+    env_dir = str(tmp_path / "from-env")
+    monkeypatch.setenv(ENV_JAX_CACHE_DIR, env_dir)
+    updates = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda k, v: (updates.append(k), real_update(k, v))[1],
+    )
+    assert resolve_compile_cache_dir() == env_dir
+    assert resolve_compile_cache_dir(entry_point=True) == env_dir
+    assert resolve_compile_cache_dir(env_dir) == env_dir
+    assert capsys.readouterr().err == ""
+    assert resolve_compile_cache_dir(str(tmp_path / "other")) == env_dir
+    err = capsys.readouterr().err
+    assert err.count("\n") == 1 and "ignoring" in err and env_dir in err
+    try:
+        assert enable_persistent_compile_cache(
+            str(tmp_path / "other"), entry_point=True
+        ) == env_dir
+        assert "jax_compilation_cache_dir" not in updates
+        assert "jax_persistent_cache_min_compile_time_secs" in updates
+    finally:
+        disable_persistent_compile_cache()
+    assert "jax_compilation_cache_dir" not in updates
+    assert prewarm._cache_dir is None
 
 
 # -- per-stage attribution ----------------------------------------------------

@@ -1,9 +1,11 @@
 """regress.py — the perf-regression gate over bench summaries.
 
-Pins the round-6 contract: a stale artifact (the validated-fallback replay)
-NEVER validates; per-config throughput below tolerance x baseline fails
-loudly with the offending configs named; improvements are reported, not
-punished.
+Pins the contract: an artifact with no number measured on a TPU NEVER
+validates; per-config throughput below tolerance x baseline fails loudly
+with the offending configs named; improvements are reported, not punished;
+a run bench labelled as off-chip (``platform: cpu``, ``xlacpu_*`` keys) is
+compared with nothing but still answers for its own blocks; a missing
+baseline file is "no baseline recorded yet", not an error.
 """
 
 import importlib.util
@@ -91,10 +93,9 @@ def test_main_exit_codes(tmp_path, capsys):
     assert rc == 1 and v["regressed"][0]["config"] == (
         "tpu_paxos3_states_per_sec"
     )
-    # stale -> 2 (the round-5 carry-forward can never validate)
-    rc, v = run({"fresh": False, "value": 0.0,
-                 "stale": "STALE (fresh=false, carried from r04)"})
-    assert rc == 2 and v["fresh"] is False and "STALE" in v["stale"]
+    # no TPU number in the run -> 2 (it can never validate)
+    rc, v = run({"fresh": False, "value": 0.0})
+    assert rc == 2 and v["fresh"] is False
     # --allow-stale compares two stored artifacts without the fresh gate
     rc, v = run(
         {"fresh": False, "tpu_paxos3_states_per_sec": 266699.0},
@@ -123,6 +124,56 @@ def test_main_missing_files_exit_2(tmp_path, capsys):
     rc = r.main([str(tmp_path / "absent.json")])
     assert rc == 2
     assert json.loads(capsys.readouterr().out)["ok"] is False
+
+
+def test_missing_baseline_is_no_baseline_yet_and_own_gates_still_run(
+    tmp_path, capsys
+):
+    """No BENCH_VALIDATED.json (no full TPU bench run recorded yet): the
+    comparisons are skipped, the run's own-block gates still trip."""
+    r = _load()
+    none = tmp_path / "never-written.json"
+    p = tmp_path / "run.json"
+    p.write_text(json.dumps(
+        {"fresh": True, "tpu_paxos3_states_per_sec": 270000.0}
+    ))
+    rc = r.main([str(p), f"--baseline={none}"])
+    v = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rc == 0 and v["ok"] is True and v["checked"] == 0
+    assert v["baseline"] == "no baseline recorded yet"
+    # a fresh run with no stage attribution still fails --stages
+    rc = r.main([str(p), f"--baseline={none}", "--stages"])
+    v = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rc == 1 and v["stages"]["ok"] is False
+
+
+def test_off_chip_run_is_compared_with_nothing_but_gated_on_its_blocks(
+    tmp_path, capsys
+):
+    """bench's CPU-backend artifact (``platform: cpu``, keys under
+    ``xlacpu_*``): not fresh, never compared with the TPU baseline —
+    but not refused either, and a malformed own block still exits 1."""
+    r = _load()
+    base = tmp_path / "base.json"
+    base.write_text(json.dumps(BASELINE))
+    stages = {"compile_secs": 1.0, "device_secs": 2.0, "wall_secs": 3.5,
+              "host_secs": 0.5}
+    doc = {"fresh": False, "value": 0.0, "platform": "cpu",
+           "device_key_prefix": "xlacpu",
+           "xlacpu_paxos3_states_per_sec": 1000.0,  # 0.004x of the baseline
+           "xlacpu_paxos3_stages": stages}
+    p = tmp_path / "run.json"
+    p.write_text(json.dumps(doc))
+    rc = r.main([str(p), f"--baseline={base}", "--stages"])
+    v = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rc == 0 and v["ok"] is True, v
+    assert v["platform"] == "cpu" and v["fresh"] is False
+    assert v["checked"] == 0 and v["regressed"] == []
+    assert v["stages"]["ok"] is True
+    del doc["xlacpu_paxos3_stages"]
+    p.write_text(json.dumps(doc))
+    rc = r.main([str(p), f"--baseline={base}", "--stages"])
+    assert rc == 1
 
 
 def test_sanitizer_section_gates_the_verdict(tmp_path, capsys):

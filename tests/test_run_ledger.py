@@ -12,7 +12,7 @@ Pins the round's contracts:
    record, golden-schema-pinned + round-trip);
  - ZERO JAXPR IMPACT (the family's strongest contract): registry on or
    off leaves the step jaxpr bit-identical and the engine cache unkeyed,
-   both engines (sharded leg behind ``requires_sharded_collectives``);
+   both engines;
  - the CONTRACT MATRIX: observability flag deltas classify IDENTICAL,
    ``--por`` ISOMORPHIC (with the explored-count delta reported and
    reduction-direction enforced), pure perf knobs PERF-ONLY, corrupted
@@ -54,7 +54,6 @@ from stateright_tpu.telemetry.registry import (
     RunRegistry,
 )
 from stateright_tpu.telemetry.report import VOLATILE_KEYS, config_key
-from tests.helpers import requires_sharded_collectives
 
 TPC3_UNIQUE, TPC3_STATES = 288, 1146
 
@@ -241,10 +240,10 @@ def test_registry_does_not_key_the_engine_cache(tmp_path):
     assert RunRegistry(str(tmp_path)).index(), "armed spawn must archive"
 
 
-@requires_sharded_collectives
 def test_registry_sharded_archives_and_cache_unkeyed(tmp_path):
     m = TwoPhaseSys(3)
-    kw = dict(sync=True, n_devices=2, capacity=1 << 12, batch=64)
+    kw = dict(sync=True, n_devices=2, capacity=1 << 12,
+              frontier_capacity=1 << 9)
     c1 = m.checker().runs(str(tmp_path)).spawn_tpu(**kw)
     n_keys = len(c1.tensor._sharded_run_cache)
     c2 = m.checker().spawn_tpu(**kw)

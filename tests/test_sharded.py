@@ -64,7 +64,7 @@ def test_sharded_growth_preserves_work_mid_flight():
     the atomic-step + host-grow protocol must preserve all work: pinned
     counts, discovery parity with the CPU oracle, and a monotone unique
     counter across every growth boundary (the old engine restarted from
-    scratch and reset counters — VERDICT r2 missing #4)."""
+    scratch and reset counters)."""
     sys = TwoPhaseSys(5)
     checker = sys.checker().spawn_tpu(
         devices=8, sync=True, capacity=1 << 10, frontier_capacity=1 << 7,

@@ -5,8 +5,7 @@ telemetry disabled adds ZERO ops to the step jaxpr, telemetry enabled
 costs <3% wall time on the 2PC-7 wavefront run (slow tier).
 
 The 2PC-7 occupancy time series is pinned here too: it captures the
-visited-table anomaly signature VERDICT.md has carried open for two
-rounds — growth events firing on single-bucket overflow (``full_buckets
+visited-table anomaly signature — growth events firing on single-bucket overflow (``full_buckets
 >= 1``) while the Poisson model at the observed load expects essentially
 none.
 """
@@ -402,7 +401,7 @@ def test_2pc7_occupancy_time_series_pins_table_anomaly():
     run is deterministic (fixed caps, no RNG), so the series is exact.
 
     History: the pre-fix series was the first committed evidence for the
-    VERDICT.md table-size anomaly — the raw-low-bit bucket derivation
+    table-size anomaly — the raw-low-bit bucket derivation
     clustered so badly that a bucket overflowed SLOTS=16 at load 0.25
     (full_buckets=1 vs poisson_full_expect=0.17, ~6x the Poisson model),
     and max_bucket rode 14-16 from mid-run on.  The fix (bucket = high
