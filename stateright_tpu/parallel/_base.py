@@ -23,6 +23,8 @@ from ..checker.base import Checker, CheckerBuilder
 from ..checker.path import Path
 from ..fingerprint import MASK64
 from ..ops.hashing import row_hash
+from ..telemetry.spans import new_id as new_span_id
+from ..telemetry.spans import span as tel_span
 
 
 # Spaces below this finish in one or two engine calls on hardware: the
@@ -88,7 +90,6 @@ class WavefrontChecker(Checker):
         self.tensor = tensor
         self._props = list(self.model.properties())
         self._target = options.target_state_count
-        self._verify_fingerprint_bridge()
 
         # wavefront-throughput knobs (docs/perf.md): builder flags win,
         # env knobs otherwise.  Pre-dedup is a per-engine jaxpr flag (both
@@ -252,6 +253,17 @@ class WavefrontChecker(Checker):
         # spans (autosave / spill_drain / resharding).
         self._span_parent = getattr(options, "_span_ctx", None)
         self._run_span_ctx = None
+        # every span of this checker (before, in and after its run) is in
+        # one trace: the parent's, or a fresh one
+        self._trace_id = (
+            self._span_parent.trace_id if self._span_parent is not None
+            else new_span_id()
+        )
+        # host seam span: the bridge check hashes one init row with EAGER
+        # device operations (a dispatch each), before the run span opens
+        with tel_span("fingerprint_bridge", self.flight_recorder,
+                      parent=self._span_parent, trace_id=self._trace_id):
+            self._verify_fingerprint_bridge()
         # live progress heartbeat (checkpoint.ProgressHeartbeat,
         # docs/observability.md): an atomic progress.json next to the
         # autosave generations, beaten at host syncs the engine already
@@ -336,6 +348,9 @@ class WavefrontChecker(Checker):
             )
 
         self._results = None
+        # trip counts of the device calls' while_loops, summed at each
+        # host sync (the ``dsteps`` lane of the packed stats vector)
+        self._device_steps = 0
         self._parent_map: Optional[dict[int, int]] = None
         self._done = threading.Event()
         # builder timeout parity (reference: the pool checkers' deadline):
@@ -388,34 +403,30 @@ class WavefrontChecker(Checker):
          - the heartbeat lands one forced final beat with the terminal
            status (``done`` / ``failed``), so ``status <run_dir>``
            distinguishes a finished run from a SIGKILLed one."""
-        from ..telemetry.spans import start_span
-
         rec = self.flight_recorder
-        sp = None
-        if rec is not None:
-            sp = start_span("engine_run", parent=self._span_parent)
+        failed = False
+        with tel_span(
+            "engine_run", rec, parent=self._span_parent,
+            trace_id=self._trace_id, engine=self._engine_tag,
+        ) as sp:
             self._run_span_ctx = sp.ctx
-            rec.bind_span(sp.ctx.span_id)
-        error: Optional[BaseException] = None
-        try:
-            self._run()
-        except BaseException as e:  # noqa: BLE001 - re-raised below
-            error = e
-            raise
-        finally:
-            if self._profiler is not None:
-                self._profiler.stop()
-            if sp is not None:
-                sp.end(
-                    rec,
-                    engine=self._engine_tag,
-                    error=type(error).__name__ if error else None,
-                )
-                rec.bind_span(None)
-            if self._heartbeat is not None:
-                self._heartbeat.beat(
-                    rec, status="failed" if error else "done", force=True,
-                )
+            if rec is not None:
+                rec.bind_span(sp.ctx.span_id)
+            try:
+                self._run()
+            except BaseException:  # noqa: BLE001 - propagates; the span
+                failed = True  # records its type as ``error``
+                raise
+            finally:
+                if self._profiler is not None:
+                    self._profiler.stop()
+                if rec is not None:
+                    rec.bind_span(None)
+                if self._heartbeat is not None:
+                    self._heartbeat.beat(
+                        rec, status="failed" if failed else "done",
+                        force=True,
+                    )
 
     def _deadline_stop(self) -> None:
         """The builder ``timeout()`` deadline fired: flag the cut (unless
@@ -884,6 +895,12 @@ class WavefrontChecker(Checker):
     def max_depth(self) -> int:
         return self._results["depth"] if self._results else 0
 
+    def device_steps(self) -> int:
+        """Steps of the device program so far (one step pops one batch):
+        the sum of the ``dsteps`` of this run's ``step`` records.  Exact,
+        and the same for every run of one model at one set of capacities."""
+        return self._device_steps
+
     def _table_np(self):
         """(fingerprints, payloads) of the visited table as numpy arrays."""
         return (
@@ -938,13 +955,22 @@ class WavefrontChecker(Checker):
         fps.reverse()
         return fps
 
-    def _parents(self) -> dict[int, int]:
+    def _parents(self, parent=None) -> dict[int, int]:
+        """The fp -> parent map, built once a run; ``parent`` is the
+        ``reconstruct`` span the two phases are children of."""
         if self._parent_map is None:
-            self._parent_map = self._parents_from_table(*self._table_np())
+            rec = self.flight_recorder
+            with tel_span("reconstruct.pull", rec, parent=parent):
+                table = self._table_np()
+            with tel_span("reconstruct.parents", rec, parent=parent):
+                self._parent_map = self._parents_from_table(*table)
         return self._parent_map
 
-    def _trace(self, fp: int) -> list[int]:
-        return self._walk(self._parents(), fp)
+    def _trace(self, fp: int, parent=None) -> list[int]:
+        parents = self._parents(parent)
+        with tel_span("reconstruct.walk", self.flight_recorder,
+                      parent=parent):
+            return self._walk(parents, fp)
 
     def _symmetry_key(self):
         if self._symmetry is None:
@@ -964,12 +990,20 @@ class WavefrontChecker(Checker):
         disc = self._results["disc"]
         key = self._symmetry_key()
         out = {}
-        for i, prop in enumerate(self._props):
-            fp = int(disc[i])
-            if fp != 0:
-                out[prop.name] = Path.from_fingerprints(
-                    self.model, self._trace(fp), key=key
-                )
+        rec = self.flight_recorder
+        # host seam span: the table pull, the parent dict, the chain walk
+        # and the host replay.  Whoever asks does so after the run span
+        # (and a supervisor's attempt span) closed, so this is a span of
+        # the run's trace with no parent — never a child that outlives one
+        with tel_span("reconstruct", rec, trace_id=self._trace_id) as sp:
+            for i, prop in enumerate(self._props):
+                fp = int(disc[i])
+                if fp != 0:
+                    fps = self._trace(fp, parent=sp.ctx)
+                    with tel_span("reconstruct.replay", rec, parent=sp.ctx):
+                        out[prop.name] = Path.from_fingerprints(
+                            self.model, fps, key=key
+                        )
         return out
 
     def live_discoveries(

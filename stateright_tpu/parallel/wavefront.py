@@ -74,6 +74,16 @@ from ..ops.buckets import (
     window_unique,
 )
 from ..ops.hashing import EMPTY, row_hash
+from ..telemetry.spans import (
+    STAGE_APPEND,
+    STAGE_BOOKKEEP,
+    STAGE_EXPAND,
+    STAGE_HASH,
+    STAGE_INSERT,
+    STAGE_POP,
+    STAGE_PROPS,
+    STAGE_STATS,
+)
 from ..telemetry.spans import span as tel_span
 from ..testing import faults
 from ._base import WavefrontChecker
@@ -139,9 +149,12 @@ _SNAPSHOT_KEYS = (
 )
 
 # Packed stats-vector layout: [head, tail, unique, scount, maxdepth, status,
-# disc...].  Shared by the device-side ``stats_of`` and the host loop.
+# dsteps, disc...].  Shared by the device-side ``stats_of`` and the host
+# loop.  ``dsteps`` is the trip count of the device call's ``while_loop``
+# (0 from ``init_fn`` and from the host-side ``_stats_np``).
 _ST_HEAD, _ST_TAIL, _ST_UNIQUE, _ST_SCOUNT, _ST_MAXDEPTH, _ST_STATUS = range(6)
-_ST_DISC = 6
+_ST_DSTEPS = 6
+_ST_DISC = 7
 _STATS_CARRY_ORDER = (_HEAD, _TAIL, _UNIQUE, _SCOUNT, _MAXDEPTH, _STATUS)
 
 
@@ -155,7 +168,7 @@ def _stats_np(carry, cart_start: Optional[int] = None,
     section: the queue-derived depth histogram first, then the counter
     buffers (carry tail from that index on), exactly as the device
     ``stats_of`` does."""
-    vals = [np.asarray(carry[i]) for i in _STATS_CARRY_ORDER] + list(
+    vals = [np.asarray(carry[i]) for i in _STATS_CARRY_ORDER] + [0] + list(
         np.asarray(carry[_DISC])
     )
     if por_start is not None:
@@ -426,265 +439,277 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
         if checked:
             err = carry[_ERR]
         cart = carry[cart_start:]
-        n_avail = tail - head
-        rows = jax.lax.dynamic_slice(qrows, (head, jnp.int32(0)), (batch, width))
-        fps = jax.lax.dynamic_slice(qfp, (head,), (batch,))
-        ebits = jax.lax.dynamic_slice(qebits, (head,), (batch,))
-        depths = jax.lax.dynamic_slice(qdepth, (head,), (batch,))
-        live = jnp.arange(batch, dtype=jnp.int32) < n_avail
+        with jax.named_scope(STAGE_POP):
+            n_avail = tail - head
+            rows = jax.lax.dynamic_slice(qrows, (head, jnp.int32(0)), (batch, width))
+            fps = jax.lax.dynamic_slice(qfp, (head,), (batch,))
+            ebits = jax.lax.dynamic_slice(qebits, (head,), (batch,))
+            depths = jax.lax.dynamic_slice(qdepth, (head,), (batch,))
+            live = jnp.arange(batch, dtype=jnp.int32) < n_avail
 
-        if checked:
-            # both model kernels under checkify; sticky failure flag.
-            # Dead lanes (past n_avail) hold queue padding/garbage the
-            # unchecked engine discards via the live mask AFTER computing
-            # on them — checkify would check that garbage and abort on
-            # phantom rows, so substitute a known-good init row first
-            # (outputs for those lanes are discarded identically below)
-            safe_rows = jnp.where(
-                live[:, None], rows, jnp.asarray(init_rows_np[0])[None, :]
+        with jax.named_scope(STAGE_PROPS):
+            if checked:
+                # both model kernels under checkify; sticky failure flag.
+                # Dead lanes (past n_avail) hold queue padding/garbage the
+                # unchecked engine discards via the live mask AFTER computing
+                # on them — checkify would check that garbage and abort on
+                # phantom rows, so substitute a known-good init row first
+                # (outputs for those lanes are discarded identically below)
+                safe_rows = jnp.where(
+                    live[:, None], rows, jnp.asarray(init_rows_np[0])[None, :]
+                )
+                err_new, (masks, succ, valid) = checked_kernels(safe_rows)
+                err = err | error_flag(err_new)
+            else:
+                masks = tensor.property_masks(rows)  # [B, P] bool
+            ebits, disc = eval_props(masks, fps, live, ebits, disc)
+            maxdepth = jnp.maximum(
+                maxdepth, jnp.max(jnp.where(live, depths, 0)).astype(jnp.int32)
             )
-            err_new, (masks, succ, valid) = checked_kernels(safe_rows)
-            err = err | error_flag(err_new)
-        else:
-            masks = tensor.property_masks(rows)  # [B, P] bool
-        ebits, disc = eval_props(masks, fps, live, ebits, disc)
-        maxdepth = jnp.maximum(
-            maxdepth, jnp.max(jnp.where(live, depths, 0)).astype(jnp.int32)
-        )
-        # Mid-run early exit (reference ``bfs.rs:121-128``): stop expanding
-        # once every property has a discovery.
-        elive = live & ~all_discovered(disc)
+            # Mid-run early exit (reference ``bfs.rs:121-128``): stop expanding
+            # once every property has a discovery.
+            elive = live & ~all_discovered(disc)
 
-        if not checked:
-            succ, valid = step_rows_fn(rows)  # [B, A, W], [B, A]
-        if boundary_fn is not None:
-            # mirror the host checkers: out-of-boundary successors are
-            # neither counted nor enqueued, and a state whose successors
-            # all fall outside IS terminal for ebits flushing
-            valid = valid & boundary_fn(succ)
-        valid = valid & elive[:, None]
-        terminal = elive & ~jnp.any(valid, axis=-1)
-        disc = flush_terminal(terminal, fps, ebits, disc)
+        with jax.named_scope(STAGE_EXPAND):
+            if not checked:
+                succ, valid = step_rows_fn(rows)  # [B, A, W], [B, A]
+            if boundary_fn is not None:
+                # mirror the host checkers: out-of-boundary successors are
+                # neither counted nor enqueued, and a state whose successors
+                # all fall outside IS terminal for ebits flushing
+                valid = valid & boundary_fn(succ)
+            valid = valid & elive[:, None]
+            terminal = elive & ~jnp.any(valid, axis=-1)
+        with jax.named_scope(STAGE_PROPS):
+            disc = flush_terminal(terminal, fps, ebits, disc)
 
-        # Under symmetry the search still explores ORIGINAL states (queue
-        # rows) but dedups / keys the table on the canonical class member's
-        # hash — the host analogue is ``checker/dfs.py::_dedup_key``, and it
-        # preserves the reference's pinned symmetry counts (2pc.rs:138).
-        krows = tensor.representative_rows(succ) if sym else succ
-        if por is not None:
-            # ample-set selection: expand only a minimal conflict-closed
-            # subset of each row's enabled actions; the boost scalar (set
-            # by the host at growth/resume boundaries) forces one fully
-            # expanded batch, and stays armed until a batch succeeds
-            boost = carry[por_start]
-            pstats = carry[por_start + 1]
-            amp = ample_mask(valid, rows, por, conjunct_kernel)
-            amp = jnp.where(boost > 0, valid, amp)
-            v1 = amp
-            all_fp = jnp.where(valid, row_hash(krows), EMPTY)
-            cand_fp = jnp.where(v1, all_fp, EMPTY).reshape(m)
-        else:
-            # exactly the pre-POR expression: the off-path jaxpr must stay
-            # bit-identical (a nested same-predicate select would add an
-            # eqn and silently break the cross-release compile cache)
-            v1 = valid
-            cand_fp = jnp.where(valid, row_hash(krows), EMPTY).reshape(m)
-        if prededup:
-            # intra-window pre-dedup (BLEST-style): duplicate lanes become
-            # EMPTY so the compaction budget, membership gathers, and rank
-            # pipeline run at the window's UNIQUE count.  scount deliberately
-            # still sums the generated states, duplicates included.
-            cand_fp = window_unique(cand_fp)
-        if spill is not None:
-            # Bloom pre-filter (spill/bloom.py): a candidate the filter
-            # says MAY be spilled leaves the on-device insert entirely —
-            # it is appended to the pending buffer below and resolved
-            # against the host index at the next host sync.  A Bloom MISS
-            # is a proof of off-device absence (no false negatives), so
-            # the common case never leaves the chip; before the first
-            # eviction the filter is all-zero and nothing defers.
-            sp_bloom = carry[spill_start + _SP_BLOOM]
-            fp_full = cand_fp
-            maybe_spilled = (cand_fp != EMPTY) & bloom_test(
-                sp_bloom, cand_fp, spill_bits
-            )
-            cand_fp = jnp.where(maybe_spilled, EMPTY, cand_fp)
-        cand_rows = succ.reshape(m, width)
-        cand_par = jnp.broadcast_to(fps[:, None], (batch, arity)).reshape(-1)
-        cand_ebt = jnp.broadcast_to(ebits[:, None], (batch, arity)).reshape(-1)
-        cand_dep = jnp.broadcast_to(
-            depths[:, None] + jnp.uint32(1), (batch, arity)
-        ).reshape(-1)
-
-        if por is not None:
-            tfp_pre, tpl_pre = tfp, tpl  # two-phase atomic rollback
-        # window stays at ``batch`` (measured: one cand-wide loop iteration
-        # is SLOWER than 2-3 batch-wide ones — wide iterations pay for dead
-        # lanes; the compaction budget only bounds the pipeline width)
-        tfp, tpl, sel, n_new, toverflow, coverflow = bucket_insert(
-            tfp, tpl, cand_fp, cand_par, window=batch,
-            use_pallas=pallas, generation_order=sym, compact=eff_cand,
-            probe_dot=probe_dot,
-        )
-        # Append novel rows (novel-compacted ``sel`` prefix) at the queue
-        # tail.  Rows past ``n_new`` in the written window are garbage; they
-        # sit in [tail+n_new, tail+eff_cand) which later appends overwrite
-        # before ``tail`` ever reaches them.  (Slim-queue mode writes only
-        # whole batch-chunks up to n_new; see append_novel.)
-        qrows, qfp, qebits, qdepth = append_novel(
-            qrows, qfp, qebits, qdepth, tail, sel, n_new,
-            cand_rows, cand_fp, cand_ebt, cand_dep,
-        )
-
-        if por is not None:
-            # conservative cycle proviso: a reduced row whose ample
-            # successors were ALL duplicates is fully expanded — its
-            # remaining (non-ample) candidates go through a second insert
-            # in the same step, so no state can be starved around a cycle
-            novel = candidate_novelty(m, sel, n_new)
-            reduced_row = jnp.any(valid & ~amp, axis=1)
-            fresh_row = jnp.any(novel.reshape(batch, arity), axis=1)
-            need_full = reduced_row & ~fresh_row
-            v2 = valid & ~amp & need_full[:, None]
-            cand_fp2 = jnp.where(v2, all_fp, EMPTY).reshape(m)
+        with jax.named_scope(STAGE_HASH):
+            # Under symmetry the search still explores ORIGINAL states (queue
+            # rows) but dedups / keys the table on the canonical class member's
+            # hash — the host analogue is ``checker/dfs.py::_dedup_key``, and it
+            # preserves the reference's pinned symmetry counts (2pc.rs:138).
+            krows = tensor.representative_rows(succ) if sym else succ
+            if por is not None:
+                # ample-set selection: expand only a minimal conflict-closed
+                # subset of each row's enabled actions; the boost scalar (set
+                # by the host at growth/resume boundaries) forces one fully
+                # expanded batch, and stays armed until a batch succeeds
+                boost = carry[por_start]
+                pstats = carry[por_start + 1]
+                amp = ample_mask(valid, rows, por, conjunct_kernel)
+                amp = jnp.where(boost > 0, valid, amp)
+                v1 = amp
+                all_fp = jnp.where(valid, row_hash(krows), EMPTY)
+                cand_fp = jnp.where(v1, all_fp, EMPTY).reshape(m)
+            else:
+                # exactly the pre-POR expression: the off-path jaxpr must stay
+                # bit-identical (a nested same-predicate select would add an
+                # eqn and silently break the cross-release compile cache)
+                v1 = valid
+                cand_fp = jnp.where(valid, row_hash(krows), EMPTY).reshape(m)
             if prededup:
-                cand_fp2 = window_unique(cand_fp2)
-            tail1 = tail + n_new
-            tfp, tpl, sel2, n_new2, tovf2, covf2 = bucket_insert(
-                tfp, tpl, cand_fp2, cand_par, window=batch,
+                # intra-window pre-dedup (BLEST-style): duplicate lanes become
+                # EMPTY so the compaction budget, membership gathers, and rank
+                # pipeline run at the window's UNIQUE count.  scount deliberately
+                # still sums the generated states, duplicates included.
+                cand_fp = window_unique(cand_fp)
+            if spill is not None:
+                # Bloom pre-filter (spill/bloom.py): a candidate the filter
+                # says MAY be spilled leaves the on-device insert entirely —
+                # it is appended to the pending buffer below and resolved
+                # against the host index at the next host sync.  A Bloom MISS
+                # is a proof of off-device absence (no false negatives), so
+                # the common case never leaves the chip; before the first
+                # eviction the filter is all-zero and nothing defers.
+                sp_bloom = carry[spill_start + _SP_BLOOM]
+                fp_full = cand_fp
+                maybe_spilled = (cand_fp != EMPTY) & bloom_test(
+                    sp_bloom, cand_fp, spill_bits
+                )
+                cand_fp = jnp.where(maybe_spilled, EMPTY, cand_fp)
+            cand_rows = succ.reshape(m, width)
+            cand_par = jnp.broadcast_to(fps[:, None], (batch, arity)).reshape(-1)
+            cand_ebt = jnp.broadcast_to(ebits[:, None], (batch, arity)).reshape(-1)
+            cand_dep = jnp.broadcast_to(
+                depths[:, None] + jnp.uint32(1), (batch, arity)
+            ).reshape(-1)
+
+        with jax.named_scope(STAGE_INSERT):
+            if por is not None:
+                tfp_pre, tpl_pre = tfp, tpl  # two-phase atomic rollback
+            # window stays at ``batch`` (measured: one cand-wide loop iteration
+            # is SLOWER than 2-3 batch-wide ones — wide iterations pay for dead
+            # lanes; the compaction budget only bounds the pipeline width)
+            tfp, tpl, sel, n_new, toverflow, coverflow = bucket_insert(
+                tfp, tpl, cand_fp, cand_par, window=batch,
                 use_pallas=pallas, generation_order=sym, compact=eff_cand,
                 probe_dot=probe_dot,
             )
+        with jax.named_scope(STAGE_APPEND):
+            # Append novel rows (novel-compacted ``sel`` prefix) at the queue
+            # tail.  Rows past ``n_new`` in the written window are garbage; they
+            # sit in [tail+n_new, tail+eff_cand) which later appends overwrite
+            # before ``tail`` ever reaches them.  (Slim-queue mode writes only
+            # whole batch-chunks up to n_new; see append_novel.)
             qrows, qfp, qebits, qdepth = append_novel(
-                qrows, qfp, qebits, qdepth, tail1, sel2, n_new2,
-                cand_rows, cand_fp2, cand_ebt, cand_dep,
+                qrows, qfp, qebits, qdepth, tail, sel, n_new,
+                cand_rows, cand_fp, cand_ebt, cand_dep,
             )
+
+        if por is not None:
+            with jax.named_scope(STAGE_INSERT):
+                # conservative cycle proviso: a reduced row whose ample
+                # successors were ALL duplicates is fully expanded — its
+                # remaining (non-ample) candidates go through a second insert
+                # in the same step, so no state can be starved around a cycle
+                novel = candidate_novelty(m, sel, n_new)
+                reduced_row = jnp.any(valid & ~amp, axis=1)
+                fresh_row = jnp.any(novel.reshape(batch, arity), axis=1)
+                need_full = reduced_row & ~fresh_row
+                v2 = valid & ~amp & need_full[:, None]
+                cand_fp2 = jnp.where(v2, all_fp, EMPTY).reshape(m)
+                if prededup:
+                    cand_fp2 = window_unique(cand_fp2)
+                tail1 = tail + n_new
+                tfp, tpl, sel2, n_new2, tovf2, covf2 = bucket_insert(
+                    tfp, tpl, cand_fp2, cand_par, window=batch,
+                    use_pallas=pallas, generation_order=sym, compact=eff_cand,
+                    probe_dot=probe_dot,
+                )
+            with jax.named_scope(STAGE_APPEND):
+                qrows, qfp, qebits, qdepth = append_novel(
+                    qrows, qfp, qebits, qdepth, tail1, sel2, n_new2,
+                    cand_rows, cand_fp2, cand_ebt, cand_dep,
+                )
             toverflow = toverflow | tovf2
             coverflow = coverflow | covf2
             n_new_all = n_new + n_new2
         else:
             n_new_all = n_new
 
-        # Any overflow means the batch wrote nothing durable: leave the
-        # cursors and counters untouched so the batch replays after the
-        # host grows the table / candidate budget.  (The queue appends
-        # above wrote garbage past ``tail``, which the replay overwrites;
-        # with POR's two inserts the table itself rolls back so the replay
-        # sees the same novelty verdicts.)
-        overflow = toverflow | coverflow
-        if spill is not None:
-            # append the deferred lanes (compacted, order-preserving: the
-            # cumsum/searchsorted idiom bucket_insert's budget compaction
-            # uses) at the pending cursor.  The buffer writes run even on
-            # an overflowed batch — the cursor then does not advance, so
-            # the post-growth replay overwrites the same window (the
-            # counters' replay discipline).
-            pcount = carry[spill_start + _SP_PCOUNT]
-            sp_stats = carry[spill_start + _SP_STATS]
-            didx, dlive, n_def = lane_compact(maybe_spilled, m)
-            pfp_b = jax.lax.dynamic_update_slice(
-                carry[spill_start + _SP_PFP],
-                jnp.where(dlive, fp_full[didx], EMPTY), (pcount,),
-            )
-            prows_b = jax.lax.dynamic_update_slice(
-                carry[spill_start + _SP_PROWS], cand_rows[didx],
-                (pcount, jnp.int32(0)),
-            )
-            ppar_b = jax.lax.dynamic_update_slice(
-                carry[spill_start + _SP_PPAR], cand_par[didx], (pcount,)
-            )
-            pebt_b = jax.lax.dynamic_update_slice(
-                carry[spill_start + _SP_PEBT], cand_ebt[didx], (pcount,)
-            )
-            pdep_b = jax.lax.dynamic_update_slice(
-                carry[spill_start + _SP_PDEP], cand_dep[didx], (pcount,)
-            )
-            pcount = pcount + jnp.where(overflow, jnp.int32(0), n_def)
-            d_sp = jnp.stack([
-                n_def.astype(jnp.int64),
-                jnp.sum(valid, dtype=jnp.int64) - n_def.astype(jnp.int64),
-            ])
-            sp_stats = sp_stats + jnp.where(overflow, jnp.int64(0), d_sp)
-        if por is not None:
-            tfp = jnp.where(overflow, tfp_pre, tfp)
-            tpl = jnp.where(overflow, tpl_pre, tpl)
-            n_new_all = jnp.where(overflow, 0, n_new_all)
-        head = jnp.where(overflow, head, head + jnp.minimum(n_avail, batch))
-        tail = tail + n_new_all
-        unique = unique + n_new_all.astype(jnp.int64)
-        if por is not None:
-            gen_mask = v1 | v2
-            gen = jnp.sum(gen_mask, dtype=jnp.int64)
-        else:
-            gen_mask = valid
-            gen = jnp.sum(valid, dtype=jnp.int64)
-        scount = jnp.where(overflow, scount, scount + gen)
-        if por is not None:
-            zero64 = jnp.int64(0)
-            d_por = jnp.stack([
-                jnp.sum(reduced_row & ~need_full, dtype=jnp.int64),
-                jnp.sum(need_full, dtype=jnp.int64),
-                jnp.sum(valid, dtype=jnp.int64) - gen,
-            ])
-            pstats = pstats + jnp.where(overflow, zero64, d_por)
-            # a successful batch consumes the boundary boost; a replayed
-            # (overflowed) one keeps it armed
-            boost = jnp.where(overflow, boost, jnp.int32(0))
-        if cartography:
-            # same replay discipline as scount: an overflowed batch counts
-            # nothing so the post-growth replay is the only count.  (The
-            # depth histogram needs no guard at all: it is derived from the
-            # queue at sync time, and an overflowed insert appended
-            # nothing.)  Under POR the histogram counts what was actually
-            # GENERATED (ample + proviso re-expansions), which is what
-            # reconciles against scount.
-            act_hist, p_evals, p_hits = cart
-            zero = jnp.int64(0)
-            act_hist = act_hist + jnp.where(
-                overflow, zero, action_hist_delta(gen_mask)
-            )
-            d_evals, d_hits = prop_tally_delta(live, masks, n_props)
-            p_evals = p_evals + jnp.where(overflow, zero, d_evals)
-            p_hits = p_hits + jnp.where(overflow, zero, d_hits)
-            cart = (act_hist, p_evals, p_hits)
-        # Clean-boundary growth triggers: past these thresholds the host
-        # grows buffers and resumes (table target load ≤ 25%: the Poisson
-        # bucket-overflow tail stays negligible).  With the spill tier
-        # armed the trigger reads HOT occupancy — evicted uniques live
-        # off-device and must not count against the hot table's load.
-        if spill is not None:
-            hot_unique = unique - carry[spill_start + _SP_BASE]
-        else:
-            hot_unique = unique
-        status = jnp.where(
-            toverflow | (hot_unique * 4 > cap) | (eff_cand * 4 > cap),
-            jnp.int32(_STATUS_TABLE_FULL),
-            jnp.where(
-                coverflow,
-                jnp.int32(_STATUS_CAND_FULL),
-                jnp.where(tail > qcap, jnp.int32(_STATUS_QUEUE_FULL), status),
-            ),
-        )
-        if spill is not None:
-            # the pending buffer cannot take another full window: stop the
-            # block at this clean boundary so the host resolves it.  Lowest
-            # priority — a growth status wins (growth also syncs).
+        with jax.named_scope(STAGE_BOOKKEEP):
+            # Any overflow means the batch wrote nothing durable: leave the
+            # cursors and counters untouched so the batch replays after the
+            # host grows the table / candidate budget.  (The queue appends
+            # above wrote garbage past ``tail``, which the replay overwrites;
+            # with POR's two inserts the table itself rolls back so the replay
+            # sees the same novelty verdicts.)
+            overflow = toverflow | coverflow
+        with jax.named_scope(STAGE_APPEND):
+            if spill is not None:
+                # append the deferred lanes (compacted, order-preserving: the
+                # cumsum/searchsorted idiom bucket_insert's budget compaction
+                # uses) at the pending cursor.  The buffer writes run even on
+                # an overflowed batch — the cursor then does not advance, so
+                # the post-growth replay overwrites the same window (the
+                # counters' replay discipline).
+                pcount = carry[spill_start + _SP_PCOUNT]
+                sp_stats = carry[spill_start + _SP_STATS]
+                didx, dlive, n_def = lane_compact(maybe_spilled, m)
+                pfp_b = jax.lax.dynamic_update_slice(
+                    carry[spill_start + _SP_PFP],
+                    jnp.where(dlive, fp_full[didx], EMPTY), (pcount,),
+                )
+                prows_b = jax.lax.dynamic_update_slice(
+                    carry[spill_start + _SP_PROWS], cand_rows[didx],
+                    (pcount, jnp.int32(0)),
+                )
+                ppar_b = jax.lax.dynamic_update_slice(
+                    carry[spill_start + _SP_PPAR], cand_par[didx], (pcount,)
+                )
+                pebt_b = jax.lax.dynamic_update_slice(
+                    carry[spill_start + _SP_PEBT], cand_ebt[didx], (pcount,)
+                )
+                pdep_b = jax.lax.dynamic_update_slice(
+                    carry[spill_start + _SP_PDEP], cand_dep[didx], (pcount,)
+                )
+                pcount = pcount + jnp.where(overflow, jnp.int32(0), n_def)
+                d_sp = jnp.stack([
+                    n_def.astype(jnp.int64),
+                    jnp.sum(valid, dtype=jnp.int64) - n_def.astype(jnp.int64),
+                ])
+                sp_stats = sp_stats + jnp.where(overflow, jnp.int64(0), d_sp)
+        with jax.named_scope(STAGE_BOOKKEEP):
+            if por is not None:
+                tfp = jnp.where(overflow, tfp_pre, tfp)
+                tpl = jnp.where(overflow, tpl_pre, tpl)
+                n_new_all = jnp.where(overflow, 0, n_new_all)
+            head = jnp.where(overflow, head, head + jnp.minimum(n_avail, batch))
+            tail = tail + n_new_all
+            unique = unique + n_new_all.astype(jnp.int64)
+            if por is not None:
+                gen_mask = v1 | v2
+                gen = jnp.sum(gen_mask, dtype=jnp.int64)
+            else:
+                gen_mask = valid
+                gen = jnp.sum(valid, dtype=jnp.int64)
+            scount = jnp.where(overflow, scount, scount + gen)
+            if por is not None:
+                zero64 = jnp.int64(0)
+                d_por = jnp.stack([
+                    jnp.sum(reduced_row & ~need_full, dtype=jnp.int64),
+                    jnp.sum(need_full, dtype=jnp.int64),
+                    jnp.sum(valid, dtype=jnp.int64) - gen,
+                ])
+                pstats = pstats + jnp.where(overflow, zero64, d_por)
+                # a successful batch consumes the boundary boost; a replayed
+                # (overflowed) one keeps it armed
+                boost = jnp.where(overflow, boost, jnp.int32(0))
+            if cartography:
+                # same replay discipline as scount: an overflowed batch counts
+                # nothing so the post-growth replay is the only count.  (The
+                # depth histogram needs no guard at all: it is derived from the
+                # queue at sync time, and an overflowed insert appended
+                # nothing.)  Under POR the histogram counts what was actually
+                # GENERATED (ample + proviso re-expansions), which is what
+                # reconciles against scount.
+                act_hist, p_evals, p_hits = cart
+                zero = jnp.int64(0)
+                act_hist = act_hist + jnp.where(
+                    overflow, zero, action_hist_delta(gen_mask)
+                )
+                d_evals, d_hits = prop_tally_delta(live, masks, n_props)
+                p_evals = p_evals + jnp.where(overflow, zero, d_evals)
+                p_hits = p_hits + jnp.where(overflow, zero, d_hits)
+                cart = (act_hist, p_evals, p_hits)
+            # Clean-boundary growth triggers: past these thresholds the host
+            # grows buffers and resumes (table target load ≤ 25%: the Poisson
+            # bucket-overflow tail stays negligible).  With the spill tier
+            # armed the trigger reads HOT occupancy — evicted uniques live
+            # off-device and must not count against the hot table's load.
+            if spill is not None:
+                hot_unique = unique - carry[spill_start + _SP_BASE]
+            else:
+                hot_unique = unique
             status = jnp.where(
-                (status == jnp.int32(_STATUS_OK))
-                & (pcount + m > jnp.int32(pend_cap)),
-                jnp.int32(_STATUS_SPILL_SYNC),
-                status,
+                toverflow | (hot_unique * 4 > cap) | (eff_cand * 4 > cap),
+                jnp.int32(_STATUS_TABLE_FULL),
+                jnp.where(
+                    coverflow,
+                    jnp.int32(_STATUS_CAND_FULL),
+                    jnp.where(tail > qcap, jnp.int32(_STATUS_QUEUE_FULL), status),
+                ),
             )
-        if poison_fn is not None:
-            # a poisoned popped row means a compile-time bound was crossed
-            # by a REACHABLE transition — silently wrong counts otherwise;
-            # surface it as a terminal host-visible status (takes priority
-            # over growth: growing cannot fix a bound)
-            status = jnp.where(
-                jnp.any(poison_fn(rows) & live),
-                jnp.int32(_STATUS_POISON),
-                status,
-            )
+            if spill is not None:
+                # the pending buffer cannot take another full window: stop the
+                # block at this clean boundary so the host resolves it.  Lowest
+                # priority — a growth status wins (growth also syncs).
+                status = jnp.where(
+                    (status == jnp.int32(_STATUS_OK))
+                    & (pcount + m > jnp.int32(pend_cap)),
+                    jnp.int32(_STATUS_SPILL_SYNC),
+                    status,
+                )
+            if poison_fn is not None:
+                # a poisoned popped row means a compile-time bound was crossed
+                # by a REACHABLE transition — silently wrong counts otherwise;
+                # surface it as a terminal host-visible status (takes priority
+                # over growth: growing cannot fix a bound)
+                status = jnp.where(
+                    jnp.any(poison_fn(rows) & live),
+                    jnp.int32(_STATUS_POISON),
+                    status,
+                )
         out = (tfp, tpl, qrows, qfp, qebits, qdepth, head, tail,
                unique, scount, disc, maxdepth, status)
         if checked:
@@ -709,12 +734,14 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
             go = go & ~carry[_ERR]
         return go
 
-    def stats_of(carry):
+    def stats_of(carry, dsteps):
         """Pack every scalar the host loop reads into one small vector so a
-        host sync costs a single device round-trip.  Layout: ``_ST_*``."""
+        host sync costs a single device round-trip.  Layout: ``_ST_*``;
+        ``dsteps`` is the device call's ``while_loop`` trip count."""
         parts = [
             jnp.stack(
                 [carry[i].astype(jnp.uint64) for i in _STATS_CARRY_ORDER]
+                + [dsteps.astype(jnp.uint64)]
             ),
             carry[_DISC],
         ]
@@ -744,18 +771,23 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
             parts += [c.astype(jnp.uint64) for c in carry[cart_start:]]
         return jnp.concatenate(parts)
 
-    def _run_impl(carry):
-        _, carry = jax.lax.while_loop(
+    # The two programs' function names are their XLA module names
+    # (``jit_wavefront_run`` / ``jit_wavefront_init``: the profiler's
+    # ``XLA Modules`` line) AND part of the persistent compile cache's key,
+    # which otherwise ignores debug metadata: an executable cached before
+    # the stages had names must not be served in place of this one.
+    def wavefront_run(carry):
+        k, carry = jax.lax.while_loop(
             cond, lambda s: (s[0] + 1, step(s[1])), (jnp.int32(0), carry)
         )
-        return carry, stats_of(carry)
+        with jax.named_scope(STAGE_STATS):
+            return carry, stats_of(carry, k)
 
     # the carry is donated on every backend (jax 0.9.0 donates on CPU
     # too): the host loop never touches a carry after passing it in
-    run_fn = jax.jit(_run_impl, donate_argnums=(0,))
+    run_fn = jax.jit(wavefront_run, donate_argnums=(0,))
 
-    @jax.jit
-    def init_fn():
+    def wavefront_init():
         tfp = jnp.full((cap,), EMPTY, jnp.uint64)
         tpl = jnp.zeros((cap,), jnp.uint64)
         qrows = jnp.zeros((qalloc, width), jnp.uint64)
@@ -824,8 +856,10 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
                 jnp.zeros((max(n_props, 1),), jnp.int64),
                 jnp.zeros((max(n_props, 1),), jnp.int64),
             )
-        return carry, stats_of(carry)
+        with jax.named_scope(STAGE_STATS):
+            return carry, stats_of(carry, jnp.int32(0))
 
+    init_fn = jax.jit(wavefront_init)
     return init_fn, run_fn
 
 
@@ -1663,17 +1697,18 @@ class TpuChecker(WavefrontChecker):
             jnp.zeros((2,), jnp.int64),
         ]
 
-    def _parents(self) -> dict:
+    def _parents(self, parent=None) -> dict:
         """Trace reconstruction merges every tier: host/disk-resident
         parents first, then the hot table's (the sets are disjoint —
         eviction removes what it spills)."""
         if self._parent_map is None:
-            parents: dict = {}
+            hot = super()._parents(parent)
             if getattr(self, "_spill", False) and len(self._spill_store):
+                parents: dict = {}
                 for fps, pars in self._spill_store.iter_segments():
                     parents.update(zip(fps.tolist(), pars.tolist()))
-            parents.update(self._parents_from_table(*self._table_np()))
-            self._parent_map = parents
+                parents.update(hot)
+                self._parent_map = parents
         return self._parent_map
 
     def _engine(self, cap, qcap, batch, cand, kind: str = "growth"):
@@ -1697,8 +1732,30 @@ class TpuChecker(WavefrontChecker):
                 else "compile_cache_misses"
             )
         self._last_engine_key = key
-        if eng is not None:
-            return eng
+        if eng is not None and not fresh_acquire:
+            return eng  # the run loop re-fetching the engine it holds
+        # host seam span, on a (re)acquire only.  ``source`` is what is
+        # known HERE: the lazy path's compile (or persistent-cache
+        # retrieval) is paid inside the next device_call's ``dispatch``
+        with tel_span(
+            "engine_acquire", rec, parent=self._run_span_ctx,
+            rung=kind, cap=cap,
+        ) as acq:
+            if eng is not None:
+                source = "in-memory"
+            else:
+                eng, source = self._acquire_engine(
+                    cache, key, cap, qcap, batch, cand, kind
+                )
+            acq.set(source=source)
+        return eng
+
+    def _acquire_engine(self, cache, key, cap, qcap, batch, cand,
+                        kind: str) -> tuple:
+        """``(engine, source)`` for a key the in-memory cache lacks: the
+        prewarmer's finished rung, else a build (lazy; ahead of time with
+        the memory ledger on, where a persistent-cache hit shows)."""
+        rec = self.flight_recorder
         if self._prewarmer is not None:
             try:
                 taken = self._prewarmer.take(key)
@@ -1728,7 +1785,8 @@ class TpuChecker(WavefrontChecker):
                         if mem:
                             rec.amend(ev, memory=mem)
                 self._schedule_prewarm(cap, qcap, batch, cand)
-                return eng
+                return eng, "prewarm"
+        source = "fresh"
         if rec is not None:
             # duration/cache_hit are amended by the run loop after the
             # first device call actually pays the (lazy) compile
@@ -1768,15 +1826,16 @@ class TpuChecker(WavefrontChecker):
                 if rec is not None and self._pending_compile_rec is not None:
                     d = watch.delta()
                     hit = d["persistent_hits"] > 0
+                    source = "persistent" if hit else "fresh"
                     fields = dict(
                         duration=round(build, 6), cache_hit=hit,
-                        source="persistent" if hit else "fresh",
+                        source=source,
                     )
                     if mem:
                         fields["memory"] = mem
                     rec.amend(self._pending_compile_rec, **fields)
         cache[key] = eng
-        return eng
+        return eng, source
 
     def _maybe_schedule_prewarm(self, cap, qcap, batch, cand,
                                 unique: int, tail: int) -> None:
@@ -1859,9 +1918,16 @@ class TpuChecker(WavefrontChecker):
         localize_checked_failure(self.tensor, qrows[lo:hi])
 
     def _carry_to_snapshot(self, carry, cap, qcap, cand=None) -> dict:
-        snap = {
-            k: np.asarray(v) for k, v in zip(_SNAPSHOT_KEYS, carry)
-        }
+        # under the run span while it is open; after it (the final
+        # snapshot) a parentless span of the same trace
+        with tel_span(
+            "checkpoint.pull", self.flight_recorder,
+            parent=None if self._done.is_set() else self._run_span_ctx,
+            trace_id=self._trace_id,
+        ):
+            snap = {
+                k: np.asarray(v) for k, v in zip(_SNAPSHOT_KEYS, carry)
+            }
         snap["cap"], snap["qcap"], snap["batch"] = cap, qcap, self._batch
         # self-tuned budget survives resume.  The run loop passes its LIVE
         # cand: self._cand is only written back when the run ends, so a
@@ -1959,8 +2025,10 @@ class TpuChecker(WavefrontChecker):
         return cap, qcap, [jnp.asarray(c) for c in carry]
 
     def _grow(self, carry_np: list, cap: int, qcap: int, batch: int,
-              arity: int, status: int, cand: int):
+              arity: int, status: int, cand: int, parent=None):
         """Grow whatever is (near) full; returns (cap, qcap, carry).
+        ``parent`` is the ``grow`` span the two phases here
+        (``grow.rehash``, ``grow.queue``) are children of.
 
         Both conditions are always re-checked regardless of which status code
         fired: table-full and queue-full can trip in the same batch, and
@@ -1980,6 +2048,8 @@ class TpuChecker(WavefrontChecker):
         spill_base = (
             len(self._spill_store) if getattr(self, "_spill", False) else 0
         )
+        rec = self.flight_recorder
+        parent = parent or self._run_span_ctx
 
         def table_small():
             return (
@@ -1992,10 +2062,20 @@ class TpuChecker(WavefrontChecker):
                     cap *= 2
             elif status == _STATUS_TABLE_FULL:
                 cap *= 2  # a single bucket clustered past SLOTS entries
-            tfp, tpl = host_bucket_rehash(
-                carry_np[_TFP], carry_np[_TPL], cap // SLOTS
-            )
+            with tel_span("grow.rehash", rec, parent=parent, cap=cap):
+                tfp, tpl = host_bucket_rehash(
+                    carry_np[_TFP], carry_np[_TPL], cap // SLOTS
+                )
             carry_np[_TFP], carry_np[_TPL] = tfp, tpl
+        with tel_span("grow.queue", rec, parent=parent):
+            qcap = self._grow_queue(carry_np, cap, qcap, batch)
+        return cap, qcap, carry_np
+
+    def _grow_queue(self, carry_np: list, cap: int, qcap: int,
+                    batch: int) -> int:
+        """The queue half of :meth:`_grow`: reclaim the consumed prefix,
+        double (or offload) while still needed, re-pad — in place; returns
+        the new ``qcap``."""
         head, tail = int(carry_np[_HEAD]), int(carry_np[_TAIL])
         pending = tail - head
         # the compaction below drops the consumed queue prefix — bank its
@@ -2024,7 +2104,7 @@ class TpuChecker(WavefrontChecker):
             qcap *= 2
         carry_np[_STATUS] = np.int32(_STATUS_OK)
         _repad_queue(carry_np, self._qalloc(qcap, batch))
-        return cap, qcap, carry_np
+        return qcap
 
     def _run(self):
         try:
@@ -2044,9 +2124,16 @@ class TpuChecker(WavefrontChecker):
         rec = self.flight_recorder
         watch = CompileWatch() if rec is not None else None
         t0 = time.monotonic()
-        carry, stats = fn() if arg is None else fn(arg)
-        carry = list(carry)
-        stats = np.asarray(stats)
+        # host seam span: ``dispatch`` ends when the call returns (tracing,
+        # a lazy compile, the enqueue), ``wait`` is the host blocked on the
+        # device — what a growth upload left in flight shows here too
+        with tel_span("device_call", rec, parent=self._run_span_ctx) as call:
+            with tel_span("dispatch", rec, parent=call.ctx):
+                carry, stats = fn() if arg is None else fn(arg)
+            carry = list(carry)
+            with tel_span("wait", rec, parent=call.ctx):
+                stats = np.asarray(stats)
+            call.set(dsteps=int(stats[_ST_DSTEPS]))
         if rec is not None:
             dt = time.monotonic() - t0
             d = watch.delta()
@@ -2186,6 +2273,8 @@ class TpuChecker(WavefrontChecker):
                 int(stats[_ST_UNIQUE]), int(stats[_ST_SCOUNT]),
                 int(stats[_ST_MAXDEPTH]), int(stats[_ST_STATUS]),
             )
+            dsteps = int(stats[_ST_DSTEPS])
+            self._device_steps += dsteps
             disc = stats[_ST_DISC:_ST_DISC + disc_len]
             with self._live_lock:
                 self._live = (scount, unique, maxdepth)
@@ -2227,6 +2316,9 @@ class TpuChecker(WavefrontChecker):
                     states=scount, unique=unique,
                     depth=maxdepth, status=status,
                     queue=max(tail - head, 0), cap=cap, cand=cand,
+                    # device steps of the call this sync closed, and the
+                    # lanes each popped: dsteps * batch lanes were offered
+                    dsteps=dsteps, batch=batch,
                     # HOT occupancy with the spill tier armed: evicted
                     # uniques live off-device (spilled_live is 0 otherwise)
                     load_factor=round((unique - spilled_live) / cap, 6),
@@ -2305,79 +2397,99 @@ class TpuChecker(WavefrontChecker):
                 )
                 t_grow = time.monotonic()
                 self.growth_events.append((status, unique))
-                if rec is not None:
-                    rec.record(
-                        "growth",
-                        status=_STATUS_TELEMETRY_NAMES.get(
-                            status, str(status)
-                        ),
-                        unique=unique, cap=cap, qcap=qcap, cand=cand,
-                    )
-                    if status == _STATUS_CAND_FULL:
-                        rec.add("compaction_hits")
-                    if self._cartography and getattr(self, "_live_cart", None):
-                        # growth boundaries are the cartography time series:
-                        # one ring record each (plus the closing "final")
+                status_name = _STATUS_TELEMETRY_NAMES.get(status, str(status))
+                # host seam span: one ``grow`` per growth event, its phases
+                # as children (pull / rehash / queue / push) — on the
+                # recorder's clock and, as ``sr/grow*``, the profiler's
+                with tel_span(
+                    "grow", rec, parent=self._run_span_ctx,
+                    status=status_name, unique=unique, cap=cap,
+                ) as grow:
+                    if rec is not None:
                         rec.record(
-                            "cartography", at="growth", **self._live_cart
+                            "growth", status=status_name,
+                            unique=unique, cap=cap, qcap=qcap, cand=cand,
                         )
-                # the carry TAIL (checked error flag, cartography counters)
-                # is not part of the growth transform: strip it around the
-                # host-side growth and re-attach unchanged after (the error
-                # check above already passed; the counters are
-                # capacity-independent)
-                tail_extra = list(carry[_ERR:])
-                if self._por:
-                    # growth is a boundary: arm one fully expanded batch
-                    tail_extra[self._por_start - _ERR] = jnp.int32(1)
-                carry = list(carry[:_ERR])
-                if status == _STATUS_CAND_FULL:
-                    # the candidate budget is an engine parameter, not a
-                    # carry buffer: double it, clear the carry's status word
-                    # (the insert wrote nothing, so the carry is otherwise
-                    # consistent), rebuild, replay
-                    cand = min(cand * 2, batch * arity)
-                    carry[_STATUS] = jnp.int32(_STATUS_OK)
-                    while cand * 4 > cap:
+                        if status == _STATUS_CAND_FULL:
+                            rec.add("compaction_hits")
+                        if self._cartography and getattr(
+                            self, "_live_cart", None
+                        ):
+                            # growth boundaries are the cartography time
+                            # series: one ring record each (plus the
+                            # closing "final")
+                            rec.record(
+                                "cartography", at="growth", **self._live_cart
+                            )
+                    # the carry TAIL (checked error flag, cartography
+                    # counters) is not part of the growth transform: strip
+                    # it around the host-side growth and re-attach unchanged
+                    # after (the error check above already passed; the
+                    # counters are capacity-independent)
+                    tail_extra = list(carry[_ERR:])
+                    if self._por:
+                        # growth is a boundary: arm one fully expanded batch
+                        tail_extra[self._por_start - _ERR] = jnp.int32(1)
+                    carry = list(carry[:_ERR])
+                    if status == _STATUS_CAND_FULL:
+                        # the candidate budget is an engine parameter, not a
+                        # carry buffer: double it, clear the carry's status
+                        # word (the insert wrote nothing, so the carry is
+                        # otherwise consistent), rebuild, replay
+                        cand = min(cand * 2, batch * arity)
+                        carry[_STATUS] = jnp.int32(_STATUS_OK)
+                        while cand * 4 > cap:
+                            with tel_span("grow.pull", rec, parent=grow.ctx):
+                                carry_np = [np.asarray(c) for c in carry]
+                            cap, qcap, carry_np = self._grow(
+                                carry_np, cap, qcap, batch, arity,
+                                _STATUS_TABLE_FULL, cand, parent=grow.ctx,
+                            )
+                            with tel_span("grow.push", rec, parent=grow.ctx):
+                                carry = [jnp.asarray(c) for c in carry_np]
+                        carry = list(carry) + tail_extra
+                    else:
+                        with tel_span("grow.pull", rec, parent=grow.ctx):
+                            carry_np = [np.asarray(c) for c in carry]
+                        if rec is not None:
+                            # the whole carry just crossed to the host (and
+                            # goes back after growth) — price it, and take
+                            # the free occupancy sample growth boundaries
+                            # offer
+                            nbytes = sum(a.nbytes for a in carry_np if a.ndim)
+                            rec.add_bytes(d2h=nbytes)
+                            self._telemetry_occupancy(
+                                carry_np[_TFP], at="growth", transferred=False
+                            )
+                        if (
+                            self._spill
+                            and status == _STATUS_TABLE_FULL
+                            and self._spill_should_evict(cap, qcap, batch)
+                        ):
+                            # the tentpole move: the next rung's migration
+                            # transient does not fit the device budget, so
+                            # the hot table's contents spill to the host
+                            # tier at this boundary INSTEAD of growing (the
+                            # cleared table satisfies the trigger at the
+                            # same capacity)
+                            tail_extra = self._evict_hot_table(
+                                carry_np, tail_extra
+                            )
+                            status = _STATUS_OK
                         cap, qcap, carry_np = self._grow(
-                            [np.asarray(c) for c in carry], cap, qcap,
-                            batch, arity, _STATUS_TABLE_FULL, cand,
+                            carry_np, cap, qcap, batch, arity, status, cand,
+                            parent=grow.ctx,
                         )
-                        carry = [jnp.asarray(c) for c in carry_np]
-                    carry = list(carry) + tail_extra
-                    self._stage("growth", time.monotonic() - t_grow)
-                    stats = None
-                    continue
-                carry_np = [np.asarray(c) for c in carry]
-                if rec is not None:
-                    # the whole carry just crossed to the host (and goes
-                    # back after growth) — price it, and take the free
-                    # occupancy sample growth boundaries offer
-                    nbytes = sum(a.nbytes for a in carry_np if a.ndim)
-                    rec.add_bytes(d2h=nbytes)
-                    self._telemetry_occupancy(
-                        carry_np[_TFP], at="growth", transferred=False
-                    )
-                if (
-                    self._spill
-                    and status == _STATUS_TABLE_FULL
-                    and self._spill_should_evict(cap, qcap, batch)
-                ):
-                    # the tentpole move: the next rung's migration
-                    # transient does not fit the device budget, so the
-                    # hot table's contents spill to the host tier at
-                    # this boundary INSTEAD of growing (the cleared
-                    # table satisfies the trigger at the same capacity)
-                    tail_extra = self._evict_hot_table(carry_np, tail_extra)
-                    status = _STATUS_OK
-                cap, qcap, carry_np = self._grow(
-                    carry_np, cap, qcap, batch, arity, status, cand
-                )
-                if rec is not None:
-                    rec.add_bytes(
-                        h2d=sum(a.nbytes for a in carry_np if a.ndim)
-                    )
-                carry = [jnp.asarray(c) for c in carry_np] + tail_extra
+                        if rec is not None:
+                            rec.add_bytes(
+                                h2d=sum(a.nbytes for a in carry_np if a.ndim)
+                            )
+                        # no block_until_ready here: what the upload leaves
+                        # in flight shows in the next device_call's wait
+                        with tel_span("grow.push", rec, parent=grow.ctx):
+                            carry = [
+                                jnp.asarray(c) for c in carry_np
+                            ] + tail_extra
                 self._stage("growth", time.monotonic() - t_grow)
                 stats = None
                 continue

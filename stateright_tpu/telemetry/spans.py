@@ -12,8 +12,12 @@ check), and each unit of work inside it is a **span** with a fresh
             │                 │   records — the engine binds its run
             │                 │   span to the recorder, so every step
             │                 │   carries ``span=<engine span id>``)
-            │                 └── host seams: ``autosave``,
-            │                     ``spill_drain``, ``resharding``
+            │                 └── host seams: ``engine_acquire``,
+            │                     ``device_call`` (``dispatch``, ``wait``),
+            │                     ``grow`` (``grow.pull`` / ``.rehash`` /
+            │                     ``.queue`` / ``.push``), ``autosave``,
+            │                     ``checkpoint.pull``, ``spill_drain``,
+            │                     ``resharding``
             └── job ...
 
 Span ids are minted where the work is minted — the fleet scheduler
@@ -27,10 +31,20 @@ exporter (:func:`telemetry.export.to_chrome_trace`) turns the records
 into nested duration events — one Perfetto load shows the whole fleet
 timeline.
 
+The same names also exist on the PROFILER's clock.  :class:`span`
+enters a ``jax.profiler.TraceAnnotation("sr/<name>")`` for its block, so
+a ``jax.profiler`` trace shows the host seam beside the device
+operations it waited for; and the device step program wraps its stages
+in ``jax.named_scope`` under the ``sr.<stage>`` names below, which XLA
+carries into every operation's metadata (``tf_op`` in the trace's event
+metadata).  One list of names, here, for both.
+
 Overhead contract (the telemetry discipline): spans are host-side
 bookkeeping at seams that already exist — one ``uuid`` and two
-``time.monotonic()`` calls per span, one dict per close.  No recorder →
-nothing is recorded; the step jaxpr is untouched either way.
+``time.monotonic()`` calls per span, one dict per close, and one
+TraceMe (a flag test while no profiler session is active).  No recorder
+→ no ring record; the step jaxpr is untouched either way (a named scope
+is debug metadata, not an operation).
 """
 
 from __future__ import annotations
@@ -41,6 +55,25 @@ from typing import Optional
 
 # span record schema version (tests/test_telemetry_schema.py pins it)
 SPAN_V = 1
+
+# The device step program's stages, in program order: the
+# ``jax.named_scope`` names ``parallel/wavefront.py:_build_engine`` wraps
+# them in (the first ``sr.`` component of an operation's scope path names
+# its stage).  The last two are the tail of ``step`` and the packed stats
+# vector, which have a name and no benchmark metric.
+STAGE_POP = "sr.pop"
+STAGE_PROPS = "sr.props"
+STAGE_EXPAND = "sr.expand"
+STAGE_HASH = "sr.hash"
+STAGE_INSERT = "sr.insert"
+STAGE_APPEND = "sr.append"
+STAGE_BOOKKEEP = "sr.bookkeep"
+STAGE_STATS = "sr.stats"
+STAGES = (STAGE_POP, STAGE_PROPS, STAGE_EXPAND, STAGE_HASH, STAGE_INSERT,
+          STAGE_APPEND, STAGE_BOOKKEEP, STAGE_STATS)
+
+# a host span ``name`` is ``sr/<name>`` in the profiler's trace
+ANNOTATION_PREFIX = "sr/"
 
 
 def new_id() -> str:
@@ -71,10 +104,13 @@ class SpanHandle:
 
     __slots__ = ("name", "ctx", "parent_id", "_t0", "_closed")
 
-    def __init__(self, name: str, parent: Optional[SpanContext] = None):
+    def __init__(self, name: str, parent: Optional[SpanContext] = None,
+                 trace_id: Optional[str] = None):
         self.name = str(name)
+        # ``trace_id`` joins a parentless span to a trace that is already
+        # there (a seam that runs after its run span closed)
         self.ctx = SpanContext(
-            trace_id=parent.trace_id if parent is not None else None
+            trace_id=parent.trace_id if parent is not None else trace_id
         )
         self.parent_id = parent.span_id if parent is not None else None
         self._t0 = time.monotonic()
@@ -117,22 +153,41 @@ class span:
             ...write the generation...
 
     The record lands on exit — exception or not (the seam's duration is
-    real either way); the original exception always propagates."""
+    real either way); the original exception always propagates.  The
+    block is also one ``sr/<name>`` event in a ``jax.profiler`` trace,
+    recorder or not.  :meth:`set` adds an attribute that is only known
+    inside the block (it rides the ring record)."""
 
     def __init__(self, name: str, recorder, *,
-                 parent: Optional[SpanContext] = None, **attrs):
-        self._handle = SpanHandle(name, parent)
+                 parent: Optional[SpanContext] = None,
+                 trace_id: Optional[str] = None, **attrs):
+        self._handle = SpanHandle(name, parent, trace_id)
         self._recorder = recorder
         self._attrs = attrs
+        self._annotation = None
 
     @property
     def ctx(self) -> SpanContext:
         return self._handle.ctx
 
+    def set(self, **attrs) -> None:
+        self._attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
+
     def __enter__(self) -> "span":
+        # imported here: the package's host-only paths never load jax
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation(
+            ANNOTATION_PREFIX + self._handle.name,
+            **{k: v for k, v in self._attrs.items() if v is not None},
+        )
+        self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
+        self._annotation.__exit__(exc_type, exc, tb)
         attrs = dict(self._attrs)
         if exc_type is not None:
             attrs.setdefault("error", exc_type.__name__)

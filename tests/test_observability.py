@@ -359,9 +359,24 @@ def test_supervised_run_span_chain(tmp_path):
     c2 = TwoPhaseSys(3).checker().telemetry().spawn_tpu(
         sync=True, capacity=1 << 12, batch=64
     )
-    roots = c2.flight_recorder.records("span")
-    assert [s["name"] for s in roots] == ["engine_run"]
-    assert "parent_id" not in roots[0]
+    spans2 = c2.flight_recorder.records("span")
+    roots = [s for s in spans2 if "parent_id" not in s]
+    assert [s["name"] for s in roots] == ["fingerprint_bridge", "engine_run"]
+    # ... whose host seams (engine_acquire, device_call) are its children
+    assert {s["trace_id"] for s in spans2} == {roots[1]["trace_id"]}
+    assert {"engine_acquire", "device_call"} <= {
+        s["name"] for s in spans2
+        if s.get("parent_id") == roots[1]["span_id"]
+    }
+    # ... and the path reconstruction AFTER the run is one more root of
+    # the same trace, not a child of the span that has already closed
+    c2.discoveries()
+    after = [s for s in c2.flight_recorder.records("span")
+             if "parent_id" not in s]
+    assert [s["name"] for s in after] == [
+        "fingerprint_bridge", "engine_run", "reconstruct"
+    ]
+    assert {s["trace_id"] for s in after} == {roots[1]["trace_id"]}
 
 
 def test_two_job_fleet_chrome_trace_nests(tmp_path):

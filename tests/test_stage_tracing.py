@@ -1,0 +1,290 @@
+"""The step program's stage names, the host seams on both clocks, and the
+device-step counter (``telemetry/spans.py`` + ``parallel/wavefront.py``).
+
+What the benchmark's per-stage and per-phase metrics rest on: the names are
+in the lowered program, a span is one ring record AND one profiler event,
+and ``device_steps`` is an exact count.
+"""
+
+import glob
+import os
+
+import jax
+import pytest
+
+from stateright_tpu.models.two_phase_commit import TwoPhaseSys
+from stateright_tpu.telemetry import spans
+from stateright_tpu.telemetry.recorder import FlightRecorder
+
+_KW = dict(capacity=1 << 12, batch=64)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """One telemetered 2pc-3 check and its two programs' lowered text."""
+    c = TwoPhaseSys(3).checker().telemetry().spawn_tpu(sync=True, **_KW)
+    c.join()
+    init_fn, run_fn = c._engine(c._cap, c._qcap, c._batch, c._cand)
+    carry, _ = init_fn()
+    return {
+        "checker": c,
+        "run": run_fn.lower(tuple(carry)).as_text(debug_info=True),
+        "init": init_fn.lower().as_text(debug_info=True),
+    }
+
+
+# -- the device program ---------------------------------------------------------
+
+
+@pytest.mark.parametrize("stage", spans.STAGES)
+def test_the_lowered_run_program_names_every_stage(tiny, stage):
+    assert stage.startswith("sr.")
+    assert f"/{stage}/" in tiny["run"], stage
+
+
+def test_the_two_programs_have_stable_module_names(tiny):
+    assert "module @jit_wavefront_run" in tiny["run"]
+    assert "module @jit_wavefront_init" in tiny["init"]
+    # the init program packs its stats under the same scope
+    assert f"/{spans.STAGE_STATS}/" in tiny["init"]
+
+
+def test_scopes_are_metadata_the_jaxpr_does_not_change(tiny, monkeypatch):
+    """A named scope adds no equation: the run program traced with every
+    scope turned into a no-op prints the same jaxpr."""
+    import contextlib
+
+    c = tiny["checker"]
+
+    def run_jaxpr():
+        init_fn, run_fn = c._build(c._cap, c._qcap, c._batch, c._cand)
+        carry, _ = init_fn()
+        return str(jax.make_jaxpr(lambda cr: run_fn(cr))(tuple(carry)))
+
+    named = run_jaxpr()
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    assert run_jaxpr() == named
+
+
+# -- device_steps ----------------------------------------------------------------
+
+
+def _steps(checker):
+    return [r for r in checker.flight_recorder.records() if r["kind"] == "step"]
+
+
+def test_device_steps_is_the_sum_of_dsteps_and_repeats_exactly(tiny):
+    c = tiny["checker"]
+    steps = _steps(c)
+    assert [r["dsteps"] for r in steps][0] == 0  # the init call runs no step
+    assert all(r["batch"] == 64 for r in steps)
+    assert c.device_steps() == sum(r["dsteps"] for r in steps) > 0
+    # every unique state is popped once, a step pops at most one batch
+    assert c.device_steps() * 64 >= c.unique_state_count() == 288
+    again = TwoPhaseSys(3).checker().telemetry().spawn_tpu(sync=True, **_KW)
+    assert again.device_steps() == c.device_steps()
+    assert [r["dsteps"] for r in _steps(again)] == [r["dsteps"] for r in steps]
+    # counted with the recorder off too (one lane of the vector the host
+    # loop reads anyway)
+    plain = TwoPhaseSys(3).checker().spawn_tpu(sync=True, **_KW)
+    assert plain.flight_recorder is None
+    assert plain.device_steps() == c.device_steps()
+
+
+def test_device_steps_counts_replayed_batches_under_growth():
+    """Through the growth ladder the count still equals the records' sum,
+    and the device_call spans carry the same numbers."""
+    c = TwoPhaseSys(5).checker().telemetry().spawn_tpu(
+        sync=True, capacity=1 << 10, queue_capacity=1 << 8, batch=64
+    )
+    c.join()
+    assert len(c.growth_events) >= 2
+    steps = _steps(c)
+    assert c.device_steps() == sum(r["dsteps"] for r in steps)
+    assert c.device_steps() * 64 >= c.unique_state_count() == 8832
+    calls = [r for r in c.flight_recorder.records("span")
+             if r["name"] == "device_call"]
+    assert sum(r["dsteps"] for r in calls) == c.device_steps()
+
+
+# -- spans on both clocks --------------------------------------------------------
+
+
+def _host_events(logdir, prefix="sr/"):
+    from jax.profiler import ProfileData
+
+    path = glob.glob(os.path.join(logdir, "plugins", "profile", "*", "*.xplane.pb"))
+    assert len(path) == 1
+    out = []
+    for plane in ProfileData.from_file(path[0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(prefix):
+                        out.append((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                                    dict(e.stats)))
+    return out
+
+
+def test_a_span_is_one_ring_record_and_one_profiler_event(tmp_path):
+    rec = FlightRecorder(capacity=64)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with spans.span("outer", rec, gen=3) as outer:
+            with spans.span("outer.inner", rec, parent=outer.ctx) as inner:
+                inner.set(pending=7)
+        with spans.span("no_recorder", None):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    records = {r["name"]: r for r in rec.records("span")}
+    assert set(records) == {"outer", "outer.inner"}  # no recorder, no record
+    o, i = records["outer"], records["outer.inner"]
+    assert i["parent_id"] == o["span_id"] and i["trace_id"] == o["trace_id"]
+    assert o["gen"] == 3 and i["pending"] == 7
+    # the child inside the parent on the recorder's clock (t is the close)
+    assert o["t"] - o["dur"] <= i["t"] - i["dur"] + 1e-6
+    assert i["t"] <= o["t"] + 1e-6
+    events = {n: (s, e, st) for n, s, e, st in _host_events(str(tmp_path))}
+    assert set(events) == {"sr/outer", "sr/outer.inner", "sr/no_recorder"}
+    (os_, oe, ostats), (is_, ie, istats) = events["sr/outer"], events["sr/outer.inner"]
+    # ... and on the profiler's
+    assert os_ <= is_ and ie <= oe
+    assert ostats.get("gen") in (3, "3") and istats.get("pending") in (7, "7")
+
+
+def test_outside_a_profiler_session_a_span_still_records():
+    rec = FlightRecorder(capacity=8)
+    with pytest.raises(ValueError):
+        with spans.span("boom", rec):
+            raise ValueError("x")
+    (r,) = rec.records("span")
+    assert r["name"] == "boom" and r["error"] == "ValueError"
+
+
+def test_a_parentless_span_can_join_a_trace():
+    rec = FlightRecorder(capacity=8)
+    with spans.span("late", rec, trace_id="abc123"):
+        pass
+    (r,) = rec.records("span")
+    assert r["trace_id"] == "abc123" and "parent_id" not in r
+
+
+# -- the host seams ---------------------------------------------------------------
+
+
+def _spans_by_name(checker):
+    out = {}
+    for r in checker.flight_recorder.records("span"):
+        out.setdefault(r["name"], []).append(r)
+    return out
+
+
+def _inside(parent, child, eps=1e-5):
+    return (parent["t"] - parent["dur"] <= child["t"] - child["dur"] + eps
+            and child["t"] <= parent["t"] + eps)
+
+
+def test_device_call_and_engine_acquire_seams(tiny):
+    by = _spans_by_name(tiny["checker"])
+    (run,) = by["engine_run"]
+    assert [r["source"] for r in by["engine_acquire"]] in (["fresh"], ["in-memory"])
+    assert by["engine_acquire"][0]["rung"] == "init"
+    # init + one run call, each with its dispatch and wait inside it
+    assert len(by["device_call"]) == len(by["dispatch"]) == len(by["wait"]) == 2
+    for call, dispatch, wait in zip(by["device_call"], by["dispatch"], by["wait"]):
+        assert call["parent_id"] == run["span_id"]
+        assert dispatch["parent_id"] == wait["parent_id"] == call["span_id"]
+        assert _inside(call, dispatch) and _inside(call, wait)
+        assert dispatch["dur"] + wait["dur"] <= call["dur"] + 1e-5
+    # the existing stage counters are untouched by the spans
+    stages = tiny["checker"].flight_recorder.stages()
+    assert stages["device_secs"] + stages["compile_secs"] <= sum(
+        r["dur"] for r in by["device_call"]
+    ) + 1e-3
+
+
+def test_growth_seams_cover_growth_secs():
+    c = TwoPhaseSys(5).checker().telemetry().spawn_tpu(
+        sync=True, capacity=1 << 10, queue_capacity=1 << 8, batch=64
+    )
+    c.join()
+    by = _spans_by_name(c)
+    grows = by["grow"]
+    assert len(grows) == len(c.growth_events) >= 2
+    assert {g["status"] for g in grows} <= {"table_full", "queue_full", "cand_full"}
+    children = [r for n in ("grow.pull", "grow.rehash", "grow.queue", "grow.push")
+                for r in by.get(n, [])]
+    ids = {g["span_id"]: g for g in grows}
+    assert children and all(_inside(ids[r["parent_id"]], r) for r in children)
+    assert len(by["grow.pull"]) == len(by["grow.push"]) == len(by["grow.queue"])
+    assert 1 <= len(by["grow.rehash"]) <= len(grows)  # table growths only
+    # the grow spans ARE the growth stage (same start, closed just before it)
+    growth_secs = c.flight_recorder.stages()["growth_secs"]
+    assert sum(g["dur"] for g in grows) == pytest.approx(growth_secs, abs=5e-3)
+    assert sum(r["dur"] for r in children) <= growth_secs + 1e-4
+    # a rung switch re-acquires an engine under its own span
+    assert len(by["engine_acquire"]) >= 2
+
+
+def test_reconstruct_seams_and_checkpoint_pull(tiny):
+    c = tiny["checker"]
+    before = len(c.flight_recorder.records("span"))
+    found = c.discoveries()
+    new = c.flight_recorder.records("span")[before:]
+    by = {}
+    for r in new:
+        by.setdefault(r["name"], []).append(r)
+    (root,) = by["reconstruct"]
+    run = _spans_by_name(c)["engine_run"][0]
+    (bridge,) = _spans_by_name(c)["fingerprint_bridge"]
+    assert "parent_id" not in root and "parent_id" not in bridge
+    assert root["trace_id"] == run["trace_id"] == bridge["trace_id"]
+    assert len(by["reconstruct.walk"]) == len(by["reconstruct.replay"]) == len(found) == 2
+    for name in ("reconstruct.pull", "reconstruct.parents", "reconstruct.walk",
+                 "reconstruct.replay"):
+        assert all(r["parent_id"] == root["span_id"] and _inside(root, r)
+                   for r in by[name]), name
+    # the parent map is built once a run: a second call pulls nothing
+    mark = len(c.flight_recorder.records("span"))
+    c.discovery("abort agreement")
+    again = {r["name"] for r in c.flight_recorder.records("span")[mark:]}
+    assert again == {"reconstruct", "reconstruct.walk", "reconstruct.replay"}
+    mark = len(c.flight_recorder.records("span"))
+    c.checkpoint()
+    (pull,) = c.flight_recorder.records("span")[mark:]
+    # after the run: in its trace, not under the span that has closed
+    assert pull["name"] == "checkpoint.pull" and "parent_id" not in pull
+    assert pull["trace_id"] == run["trace_id"]
+
+
+def test_a_whole_check_under_the_profiler_shows_every_host_seam(tmp_path):
+    """The seams of one check, as the benchmark's traced run sees them:
+    on the profiler's clock, the finer ones inside ``sr/engine_run``."""
+    model = TwoPhaseSys(3)
+    model.checker().spawn_tpu(sync=True, **_KW).join()  # compile outside
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        c = model.checker().telemetry().spawn_tpu(sync=True, **_KW)
+        c.join()
+        c.discoveries()
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(str(tmp_path))
+    names = [n for n, _, _, _ in events]
+    for want in ("sr/fingerprint_bridge", "sr/engine_run", "sr/engine_acquire",
+                 "sr/device_call", "sr/dispatch", "sr/wait", "sr/reconstruct",
+                 "sr/reconstruct.pull", "sr/reconstruct.parents",
+                 "sr/reconstruct.walk", "sr/reconstruct.replay"):
+        assert want in names, want
+    (run,) = [e for e in events if e[0] == "sr/engine_run"]
+    inside = [e for e in events if e[0] in ("sr/engine_acquire", "sr/device_call",
+                                            "sr/dispatch", "sr/wait")]
+    assert inside and all(run[1] <= s and e <= run[2] for _, s, e, _ in inside)
+    (bridge,) = [e for e in events if e[0] == "sr/fingerprint_bridge"]
+    assert bridge[2] <= run[1]  # before the run opens
+    calls = [e for e in events if e[0] == "sr/device_call"]
+    assert sum(int(st["dsteps"]) for _, _, _, st in calls) == c.device_steps()
+    # every ring span has its twin on the profiler's clock
+    ring = sorted(r["name"] for r in c.flight_recorder.records("span"))
+    assert sorted(n[len("sr/"):] for n in names) == ring

@@ -43,6 +43,9 @@ SCHEMA = {
             # engine-run span binding (telemetry/spans.py): steps of a
             # traced run carry their engine_run span id
             "span": str,
+            # the device call this sync closed: its while_loop's trip
+            # count, and the lanes a step pops (dsteps * batch offered)
+            "dsteps": int, "batch": int,
         },
     ),
     "growth": (
@@ -79,13 +82,15 @@ SCHEMA = {
         # close time (``t - dur`` is the start).  The optional set is
         # the union of per-span attrs: engine/error (engine_run,
         # attempt), attempt ordinal, gen (autosave), pending
-        # (spill_drain), cap/unique (resharding), key/slot (fleet job),
-        # jobs/slots (fleet root)
+        # (spill_drain), cap/unique (resharding, grow), key/slot (fleet
+        # job), jobs/slots (fleet root), rung/source (engine_acquire),
+        # dsteps (device_call), status (grow)
         {"v": int, "name": str, "trace_id": str, "span_id": str,
          "dur": _REAL},
         {"parent_id": str, "engine": str, "error": str, "attempt": int,
          "gen": int, "pending": int, "cap": int, "unique": int,
-         "key": str, "slot": int, "jobs": int, "slots": int},
+         "key": str, "slot": int, "jobs": int, "slots": int,
+         "rung": str, "source": str, "dsteps": int, "status": str},
     ),
     "health": (
         {"v": int, "event": str},
