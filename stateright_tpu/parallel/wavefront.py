@@ -597,9 +597,9 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
             overflow = toverflow | coverflow
         with jax.named_scope(STAGE_APPEND):
             if spill is not None:
-                # append the deferred lanes (compacted, order-preserving: the
-                # cumsum/searchsorted idiom bucket_insert's budget compaction
-                # uses) at the pending cursor.  The buffer writes run even on
+                # append the deferred lanes (compacted, order-preserving:
+                # lane_compact, as in bucket_insert's budget compaction) at
+                # the pending cursor.  The buffer writes run even on
                 # an overflowed batch — the cursor then does not advance, so
                 # the post-growth replay overwrites the same window (the
                 # counters' replay discipline).

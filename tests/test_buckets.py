@@ -5,13 +5,17 @@ in-batch, duplicates vs the table, EMPTY lanes, bucket collisions)."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
+from stateright_tpu.analysis.jaxpr_audit import _iter_eqns
+from stateright_tpu.ops import buckets
 from stateright_tpu.ops.buckets import (
     SLOTS,
     bucket_insert,
     bucket_of,
     host_bucket_rehash,
+    lane_compact,
 )
 from stateright_tpu.ops.hashing import EMPTY, mix64_np
 
@@ -434,3 +438,165 @@ def test_blest_probe_unit_matches_reduction_pair():
     assert np.array_equal(b, exp_b) and b.tolist() == [
         0, 1, 1, SLOTS, SLOTS
     ]
+
+
+# --- lane_compact: one packed-key sort (PR 25) -------------------------------
+#
+# The compaction used to be cumsum + searchsorted(running count, 1..width):
+# `width` binary searches, ~17 dependent gather rounds on the TPU.  The old
+# formulation lives on here, as the reference the new one is held to.
+
+
+def ref_lane_compact(mask, width):
+    """The pre-PR-25 compaction, verbatim."""
+    m = mask.shape[0]
+    csum = jnp.cumsum(mask.astype(jnp.int32))
+    count = csum[m - 1]
+    idx = jnp.searchsorted(
+        csum, jnp.arange(1, width + 1, dtype=jnp.int32), side="left"
+    ).astype(jnp.int32)
+    idx = jnp.minimum(idx, jnp.int32(m - 1))
+    live = jnp.arange(width, dtype=jnp.int32) < count
+    return idx, live, count
+
+
+def share_mask(m, share, seed=0):
+    rng = np.random.default_rng(seed)
+    if share == "none":
+        return np.zeros(m, bool)
+    if share == "one":
+        mask = np.zeros(m, bool)
+        mask[int(rng.integers(m))] = True
+        return mask
+    if share == "all":
+        return np.ones(m, bool)
+    return rng.random(m) < share
+
+
+SMALL_M = 257  # not a multiple of anything: no padding luck
+CELL_SHAPES = {  # (batch * actions, candidate budget) of the benchmark's cells
+    "twopc8-presized": (86016, 32768),
+    "paxos3-presized": (122880, 16384),
+    "paxos3-defaults": (61440, 8192),
+}
+
+
+@pytest.mark.parametrize(
+    "m,width,share",
+    [
+        (SMALL_M, width, share)
+        for width in (1, SMALL_M // 4, SMALL_M - 1, SMALL_M)
+        for share in ("none", "one", 0.1, 0.5, "all")
+    ]
+    + [
+        (m, width, share)
+        for (m, width) in CELL_SHAPES.values()
+        for share in (0.1, 0.5)  # 0.5 is over every cell's budget
+    ],
+)
+def test_lane_compact_matches_cumsum_searchsorted(m, width, share):
+    """Same ``(idx, live, count)`` as the old formulation on the live
+    lanes; dead lanes stay in range (they are gathered, then masked)."""
+    mask = jnp.asarray(share_mask(m, share, seed=m + width))
+    idx, live, count = lane_compact(mask, width)
+    ridx, rlive, rcount = ref_lane_compact(mask, width)
+    assert idx.dtype == jnp.int32 and idx.shape == (width,)
+    assert int(count) == int(rcount) == int(np.sum(np.asarray(mask)))
+    live, rlive = np.asarray(live), np.asarray(rlive)
+    assert np.array_equal(live, rlive)
+    idx, ridx = np.asarray(idx), np.asarray(ridx)
+    assert np.array_equal(idx[live], ridx[rlive])
+    assert np.array_equal(idx[live], np.flatnonzero(np.asarray(mask))[:width])
+    assert np.all((idx >= 0) & (idx < m))
+
+
+@pytest.mark.parametrize("generation_order", [False, True])
+@pytest.mark.parametrize("n_valid", ["under", "exact", "over"])
+def test_compacted_insert_is_bit_identical_to_reference_pipeline(
+    n_valid, generation_order
+):
+    """``bucket_insert(compact=CB)`` against the same insert fed by the OLD
+    compaction by hand: tables, ``sel[:n_new]``, ``n_new`` and both overflow
+    flags bit-identical; over the budget, nothing written."""
+    cb, m = 32, 96
+    rng = np.random.default_rng(7)
+    k = {"under": 20, "exact": cb, "over": cb + 9}[n_valid]
+    fps = np.full(m, EMPTY, np.uint64)
+    lanes = np.sort(rng.choice(m, k, replace=False))
+    fps[lanes] = rng.integers(1, 1 << 40, k).astype(np.uint64)
+    fps[lanes[3]] = fps[lanes[0]]  # an in-batch duplicate
+    pls = np.arange(1000, 1000 + m, dtype=np.uint64)
+    tfp0, tpl0 = fresh(64)
+    # something already in the table, one of them a candidate again
+    tfp0, tpl0, *_ = bucket_insert(
+        tfp0, tpl0, jnp.asarray(np_u64([5, 6, int(fps[lanes[1]])])),
+        jnp.asarray(np_u64([1, 2, 3])), window=8,
+    )
+    fps_j, pls_j = jnp.asarray(fps), jnp.asarray(pls)
+
+    tfp, tpl, sel, n_new, ovf, covf = bucket_insert(
+        tfp0, tpl0, fps_j, pls_j, window=8,
+        generation_order=generation_order, compact=cb,
+    )
+
+    cidx, live, count = ref_lane_compact(fps_j != EMPTY, cb)
+    assert bool(covf) == (int(count) > cb) == (n_valid == "over")
+    if n_valid == "over":
+        assert int(n_new) == 0
+        assert np.array_equal(np.asarray(tfp), np.asarray(tfp0))
+        assert np.array_equal(np.asarray(tpl), np.asarray(tpl0))
+        return
+    rtfp, rtpl, rsel, rn_new, rovf, _ = bucket_insert(
+        tfp0, tpl0, jnp.where(live, fps_j[cidx], EMPTY), pls_j[cidx],
+        window=8, generation_order=generation_order,
+    )
+    rsel = cidx[rsel]
+    n = int(n_new)
+    assert n == int(rn_new) and n > 0
+    assert bool(ovf) == bool(rovf) is False
+    assert np.array_equal(np.asarray(sel)[:n], np.asarray(rsel)[:n])
+    assert np.array_equal(np.asarray(tfp), np.asarray(rtfp))
+    assert np.array_equal(np.asarray(tpl), np.asarray(rtpl))
+    if generation_order:
+        assert np.all(np.diff(np.asarray(sel)[:n]) > 0)
+
+
+def primitive_names(fn, *args):
+    """Every primitive of ``fn``'s traced program, sub-jaxprs included."""
+    return [e.primitive.name for e in _iter_eqns(jax.make_jaxpr(fn)(*args))]
+
+
+def test_insert_compaction_is_lane_compact_and_holds_no_search(monkeypatch):
+    """One copy: ``bucket_insert(compact=CB)`` compacts through
+    ``lane_compact``, and its traced program holds no search (a
+    ``searchsorted`` traces to a ``while``/``scan``): just the membership
+    and the chunk-write ``while`` of the plain insert, and one ``sort`` more."""
+    calls = []
+    real = buckets.lane_compact
+
+    def spy(mask, width):
+        calls.append((mask.shape[0], width))
+        return real(mask, width)
+
+    monkeypatch.setattr(buckets, "lane_compact", spy)
+    tfp, tpl = fresh(64)
+    fps = jnp.full((96,), EMPTY, jnp.uint64)
+
+    def trace(compact):
+        return primitive_names(
+            lambda a, b, c, d: bucket_insert(a, b, c, d, window=8, compact=compact),
+            tfp, tpl, fps, fps,
+        )
+
+    plain = trace(None)
+    assert calls == []  # no budget, no compaction
+    compacted = trace(32)
+    assert calls == [(96, 32)]
+    loops = ("while", "scan")
+    assert sorted(p for p in plain if p in loops) == ["while", "while"]
+    assert sorted(p for p in compacted if p in loops) == ["while", "while"]
+    assert compacted.count("sort") == plain.count("sort") + 1
+    assert compacted.count("cumsum") == plain.count("cumsum")
+    # the same trace of the old formulation does hold a search loop
+    old = primitive_names(lambda x: ref_lane_compact(x, 32), fps != EMPTY)
+    assert any(p in loops for p in old)
