@@ -275,6 +275,13 @@ def abd_model(
     return m
 
 
+def abd_ordered(client_count: int, server_count: int = 2) -> ActorModel:
+    """``abd_model`` on ordered per-pair FIFO links (reference ``bench.sh``'s
+    ``check N ordered``), from plain JSON arguments: a configuration file
+    cannot hold a ``Network`` object."""
+    return abd_model(client_count, server_count, Network.new_ordered())
+
+
 def _audit_models(rest=()):
     """Default configurations for the static auditor (``audit`` verb and
     the fleet runner, ``_cli.fleet_audit``)."""

@@ -259,6 +259,15 @@ class WavefrontChecker(Checker):
             self._span_parent.trace_id if self._span_parent is not None
             else new_span_id()
         )
+        # a compiled actor twin closed its ``twin_compile`` span before any
+        # recorder existed (parallel/actor_compiler.py): the first checker
+        # that adopts the twin records it, in this checker's trace
+        pending = getattr(tensor, "compile_span", None)
+        if pending is not None and self.flight_recorder is not None:
+            tensor.compile_span = None
+            self.flight_recorder.record(
+                "span", **{**pending, "trace_id": self._trace_id}
+            )
         # host seam span: the bridge check hashes one init row with EAGER
         # device operations (a dispatch each), before the run span opens
         with tel_span("fingerprint_bridge", self.flight_recorder,

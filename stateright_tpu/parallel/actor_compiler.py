@@ -82,6 +82,7 @@ from .history_tensor import (
     LinHistoryCodec,
     MultiOpLinHistoryCodec,
 )
+from ..telemetry.spans import TWIN_HISTORY, TWIN_NET, TWIN_TABLE, span
 from .tensor_model import BitPacker, FieldWriter, TensorModel
 
 #: envelope-kind codes for the history/property tables
@@ -148,17 +149,25 @@ def compile_actor_model(
     exceeded — never silently diverging — and unordered regions ignore the
     knob (their capacity is already exact).
     """
-    return CompiledActorTensor(
-        model,
-        state_bound=state_bound,
-        env_bound=env_bound,
-        n_slots=n_slots,
-        max_states_per_actor=max_states_per_actor,
-        max_envelopes=max_envelopes,
-        max_history_states=max_history_states,
-        per_channel=per_channel,
-        per_channel_depth=per_channel_depth,
-    )
+    with span("twin_compile", None) as compile_span:
+        twin = CompiledActorTensor(
+            model,
+            state_bound=state_bound,
+            env_bound=env_bound,
+            n_slots=n_slots,
+            max_states_per_actor=max_states_per_actor,
+            max_envelopes=max_envelopes,
+            max_history_states=max_history_states,
+            per_channel=per_channel,
+            per_channel_depth=per_channel_depth,
+        )
+        compile_span.set(**twin.compile_attrs())
+    # whoever asks for the twin first (the preflight audit, a fingerprint,
+    # a checker's constructor) does so before any flight recorder exists:
+    # the first checker that adopts the twin records the closed span
+    # (parallel/_base.py:_init_common)
+    twin.compile_span = compile_span.fields
+    return twin
 
 
 class CompiledActorTensor(TensorModel):
@@ -325,6 +334,40 @@ class CompiledActorTensor(TensorModel):
             lambda code: self._envs[code],
         )
         self._device_consts = None
+
+    def compile_attrs(self) -> dict:
+        """What the closure and tabulation came to (the ``twin_compile``
+        span's attributes): per-actor state universes, the envelope
+        universe, the row, and the bytes of the look-up tables the step
+        program holds on the device (what :meth:`_consts` uploads, plus
+        the history verdict table where the codec needs one)."""
+        tables = [
+            *self._trans_np, *self._sends_np, *self._poison_np,
+            self._env_dst, self._env_pair, self._env_kind, self._env_val,
+            self._env_chosen,
+        ]
+        if self._has_timers:
+            tables += [
+                *self._teff_np, *self._ttrans_np, *self._tsends_np,
+                *self._tpoison_np, *self._tbit_np,
+            ]
+        if self._boundary_np is not None:
+            tables += self._boundary_np
+        if self.per_channel:
+            tables.append(self._chan_of)
+        for entry in self._prop_tables:
+            if entry is not None:
+                t = entry[1]
+                tables += t if isinstance(t, list) else list(t.values())
+        if self.hist is not None and self.hist._table_built:
+            tables += [self.hist.table_keys, self.hist.table_ok]
+        return {
+            "actor_states": ",".join(str(len(s)) for s in self._states),
+            "envelopes": len(self._envs),
+            "n_slots": int(self.n_slots),
+            "row_width": int(self.width),
+            "table_bytes": int(sum(np.asarray(t).nbytes for t in tables)),
+        }
 
     # -- fragment check ------------------------------------------------------
 
@@ -1484,6 +1527,7 @@ class CompiledActorTensor(TensorModel):
         return self._step_rows_multiset(rows, coalesce=True)
 
     def _step_rows_multiset(self, rows, coalesce=False):
+        import jax
         import jax.numpy as jnp
 
         cst = self._consts()
@@ -1497,74 +1541,76 @@ class CompiledActorTensor(TensorModel):
 
         slots = rows[:, self.pw :]  # [B, NS]
         occupied = slots != u64(SLOT_EMPTY)
-        ecode = jnp.where(
-            occupied, (slots >> u64(COUNT_BITS)).astype(i32), 0
-        )  # [B, NS]
-        dst = cst["env_dst"][ecode]  # [B, NS]
-        if self.ordered:
-            # count bits hold the 1-based rank within the directed flow;
-            # only the head (rank 1) of each flow is deliverable
-            # (reference ``model.rs:224-227``)
-            rank1 = (slots & u64(COUNT_MASK)).astype(i32)  # [B, NS]
-            pair = jnp.where(occupied, cst["env_pair"][ecode], -1)
-            at_head = occupied & (rank1 == 1)
+        with jax.named_scope(TWIN_TABLE):
+            ecode = jnp.where(
+                occupied, (slots >> u64(COUNT_BITS)).astype(i32), 0
+            )  # [B, NS]
+            dst = cst["env_dst"][ecode]  # [B, NS]
+            if self.ordered:
+                # count bits hold the 1-based rank within the directed flow;
+                # only the head (rank 1) of each flow is deliverable
+                # (reference ``model.rs:224-227``)
+                rank1 = (slots & u64(COUNT_MASK)).astype(i32)  # [B, NS]
+                pair = jnp.where(occupied, cst["env_pair"][ecode], -1)
+                at_head = occupied & (rank1 == 1)
 
-        # -- deliver actions (slot a delivers envelope in slot a) -----------
-        new_scode = jnp.zeros((B, NS), i32)
-        valid = jnp.zeros((B, NS), bool)
-        poison = jnp.zeros((B, NS), bool)
-        send_codes = jnp.full((B, NS, max(self.K, 1)), -1, i32)
-        for i in range(self.n_actors):
-            mask = occupied & (dst == i)
-            sc = pk.get(rows, f"a{i}").astype(i32)[:, None]  # [B, 1]
-            flat = sc * ne + ecode  # [B, NS]
-            nc = cst["trans"][i].reshape(-1)[flat]
-            pi = cst["poison"][i].reshape(-1)[flat]
-            ks = cst["sends"][i].reshape(-1, max(self.K, 1))[flat]
-            new_scode = jnp.where(mask, nc, new_scode)
-            valid = valid | (mask & (nc >= 0))
-            poison = poison | (mask & pi)
-            send_codes = jnp.where(mask[..., None], ks, send_codes)
-        if self.ordered:
-            valid = valid & at_head
+            # -- deliver actions (slot a delivers envelope in slot a) -------
+            new_scode = jnp.zeros((B, NS), i32)
+            valid = jnp.zeros((B, NS), bool)
+            poison = jnp.zeros((B, NS), bool)
+            send_codes = jnp.full((B, NS, max(self.K, 1)), -1, i32)
+            for i in range(self.n_actors):
+                mask = occupied & (dst == i)
+                sc = pk.get(rows, f"a{i}").astype(i32)[:, None]  # [B, 1]
+                flat = sc * ne + ecode  # [B, NS]
+                nc = cst["trans"][i].reshape(-1)[flat]
+                pi = cst["poison"][i].reshape(-1)[flat]
+                ks = cst["sends"][i].reshape(-1, max(self.K, 1))[flat]
+                new_scode = jnp.where(mask, nc, new_scode)
+                valid = valid | (mask & (nc >= 0))
+                poison = poison | (mask & pi)
+                send_codes = jnp.where(mask[..., None], ks, send_codes)
+            if self.ordered:
+                valid = valid & at_head
 
         # -- successor slot arrays ------------------------------------------
-        slots_b = jnp.broadcast_to(slots[:, None, :], (B, NS, NS))
-        diag = jnp.eye(NS, dtype=bool)[None]
-        if self.ordered:
-            # delivering the head removes it and advances the rest of its
-            # flow by one rank (empty flows vanish with their last slot)
-            pair_a = pair[:, :, None]  # flow of the delivered envelope
-            pair_s = pair[:, None, :]  # flow of each slot
-            same_flow = (pair_a >= 0) & (pair_a == pair_s)
-            slots_d = jnp.where(same_flow, slots_b - u64(1), slots_b)
-            slots_d = jnp.where(diag, u64(SLOT_EMPTY), slots_d)
-        else:
-            if self.dup:
-                # duplicating network: delivery leaves the envelope in
-                # flight (reference ``network.rs:203-205``); only drops
-                # remove it
-                delivered = slots
-            else:
-                count = (slots & u64(COUNT_MASK)).astype(i32)
-                delivered = jnp.where(
-                    count <= 1, u64(SLOT_EMPTY), slots - u64(1)
-                )  # [B, NS]
-            slots_d = jnp.where(diag, delivered[:, :, None], slots_b)
-        for k in range(self.K):
-            sk = send_codes[..., k]
+        with jax.named_scope(TWIN_NET):
+            slots_b = jnp.broadcast_to(slots[:, None, :], (B, NS, NS))
+            diag = jnp.eye(NS, dtype=bool)[None]
             if self.ordered:
-                slots_d, of = slot_send_ordered(
-                    slots_d, sk.astype(u64), cst["env_pair"],
-                    valid & (sk >= 0),
-                )
+                # delivering the head removes it and advances the rest of its
+                # flow by one rank (empty flows vanish with their last slot)
+                pair_a = pair[:, :, None]  # flow of the delivered envelope
+                pair_s = pair[:, None, :]  # flow of each slot
+                same_flow = (pair_a >= 0) & (pair_a == pair_s)
+                slots_d = jnp.where(same_flow, slots_b - u64(1), slots_b)
+                slots_d = jnp.where(diag, u64(SLOT_EMPTY), slots_d)
             else:
-                slots_d, of = slot_send(
-                    slots_d, sk.astype(u64), valid & (sk >= 0),
-                    set_semantics=self.dup,
-                )
-            poison = poison | of
-        slots_d = slot_canonicalize(slots_d)
+                if self.dup:
+                    # duplicating network: delivery leaves the envelope in
+                    # flight (reference ``network.rs:203-205``); only drops
+                    # remove it
+                    delivered = slots
+                else:
+                    count = (slots & u64(COUNT_MASK)).astype(i32)
+                    delivered = jnp.where(
+                        count <= 1, u64(SLOT_EMPTY), slots - u64(1)
+                    )  # [B, NS]
+                slots_d = jnp.where(diag, delivered[:, :, None], slots_b)
+            for k in range(self.K):
+                sk = send_codes[..., k]
+                if self.ordered:
+                    slots_d, of = slot_send_ordered(
+                        slots_d, sk.astype(u64), cst["env_pair"],
+                        valid & (sk >= 0),
+                    )
+                else:
+                    slots_d, of = slot_send(
+                        slots_d, sk.astype(u64), valid & (sk >= 0),
+                        set_semantics=self.dup,
+                    )
+                poison = poison | of
+            slots_d = slot_canonicalize(slots_d)
 
         # -- successor packed words -----------------------------------------
         # every value below reads from `rows`, never from the written
@@ -1598,116 +1644,117 @@ class CompiledActorTensor(TensorModel):
             fw.set("timers", tnew.astype(u64))
 
         # -- history updates -------------------------------------------------
-        if self.C and self._multi:
-            # multi-op workload (put_count >= 2): phase = 2*completed +
-            # in_flight.  A put_ok return invokes the next op in the same
-            # transition (+2); the final get_ok return just completes (+1).
-            # The newly-invoked op's snapshot (peers' completed counts) is
-            # scattered into the snap field of the op it belongs to —
-            # writes 2..K and the read all carry real-time snapshots here,
-            # unlike the K=1 layout where only the read's is non-trivial.
-            K = self.hist.K
-            eb = self.hist.snap_entry_bits
-            kind = cst["env_kind"][ecode]  # [B, NS]
-            ci = self._client_of_dev()[jnp.clip(dst, 0, self.n_actors - 1)]
-            is_ret_w = valid & (kind == _K_PUT_OK) & (ci >= 0)
-            is_ret_r = valid & (kind == _K_GET_OK) & (ci >= 0)
-            rv = cst["env_val"][ecode]
-            phases = jnp.stack(
-                [
-                    pk.get(rows, f"h{c}_phase").astype(i32)
-                    for c in range(self.C)
-                ],
-                -1,
-            )  # [B, C]
-            comp = phases >> 1  # completed ops per thread (stored states)
-            for c in range(self.C):
-                m_w = is_ret_w & (ci == c)
-                m_r = is_ret_r & (ci == c)
-                cur_ph = pk.get(rows, f"h{c}_phase").astype(i32)[:, None]
-                new_ph = jnp.where(
-                    m_w, cur_ph + 2, jnp.where(m_r, cur_ph + 1, cur_ph)
-                )
-                fw.set(f"h{c}_phase", new_ph.astype(u64))
-                cur_comp = cur_ph >> 1  # [B, 1]
-                snap = jnp.zeros((B, NS), i32)
-                for j in range(self.C):
-                    if j == c:
-                        continue
-                    slot = self.hist._snap_slot(c, j)
-                    snap = snap | (comp[:, j : j + 1] << (eb * slot))
-                for m in range(K):
-                    sel = m_w & (cur_comp == m)
-                    cur_snap = pk.get(rows, f"h{c}_snap{m}").astype(i32)[
-                        :, None
-                    ]
-                    fw.set(
-                        f"h{c}_snap{m}",
-                        jnp.where(sel, snap, cur_snap).astype(u64),
+        with jax.named_scope(TWIN_HISTORY):
+            if self.C and self._multi:
+                # multi-op workload (put_count >= 2): phase = 2*completed +
+                # in_flight.  A put_ok return invokes the next op in the same
+                # transition (+2); the final get_ok return just completes (+1).
+                # The newly-invoked op's snapshot (peers' completed counts) is
+                # scattered into the snap field of the op it belongs to —
+                # writes 2..K and the read all carry real-time snapshots here,
+                # unlike the K=1 layout where only the read's is non-trivial.
+                K = self.hist.K
+                eb = self.hist.snap_entry_bits
+                kind = cst["env_kind"][ecode]  # [B, NS]
+                ci = self._client_of_dev()[jnp.clip(dst, 0, self.n_actors - 1)]
+                is_ret_w = valid & (kind == _K_PUT_OK) & (ci >= 0)
+                is_ret_r = valid & (kind == _K_GET_OK) & (ci >= 0)
+                rv = cst["env_val"][ecode]
+                phases = jnp.stack(
+                    [
+                        pk.get(rows, f"h{c}_phase").astype(i32)
+                        for c in range(self.C)
+                    ],
+                    -1,
+                )  # [B, C]
+                comp = phases >> 1  # completed ops per thread (stored states)
+                for c in range(self.C):
+                    m_w = is_ret_w & (ci == c)
+                    m_r = is_ret_r & (ci == c)
+                    cur_ph = pk.get(rows, f"h{c}_phase").astype(i32)[:, None]
+                    new_ph = jnp.where(
+                        m_w, cur_ph + 2, jnp.where(m_r, cur_ph + 1, cur_ph)
                     )
-                cur_rv = pk.get(rows, f"h{c}_rval").astype(i32)[:, None]
-                fw.set(
-                    f"h{c}_rval",
-                    jnp.where(m_r, rv, cur_rv).astype(u64),
-                )
-        elif self.C:
-            kind = cst["env_kind"][ecode]  # [B, NS]
-            ci = self._client_of_dev()[jnp.clip(dst, 0, self.n_actors - 1)]
-            is_ret_w = (
-                valid
-                & ((kind == _K_PUT_OK) | (kind == _K_PUT_FAIL))
-                & (ci >= 0)
-            )
-            is_ret_r = valid & (kind == _K_GET_OK) & (ci >= 0)
-            rv = cst["env_val"][ecode]
-            phases = jnp.stack(
-                [
-                    pk.get(rows, f"h{c}_phase").astype(i32)
-                    for c in range(self.C)
-                ],
-                -1,
-            )  # [B, C]
-            # completed-op count per thread, derived from its phase
-            comp = jnp.where(
-                phases == PHASE_W_INFLIGHT,
-                0,
-                jnp.where(phases == PHASE_DONE, 2, 1),
-            )  # [B, C]
-            for c in range(self.C):
-                m_w = is_ret_w & (ci == c)  # write returned + read invoked
-                m_r = is_ret_r & (ci == c)
-                cur_ph = pk.get(rows, f"h{c}_phase").astype(i32)[:, None]
-                new_ph = jnp.where(
-                    m_w,
-                    PHASE_R_INFLIGHT,
-                    jnp.where(m_r, PHASE_DONE, cur_ph),
-                )
-                fw.set(f"h{c}_phase", new_ph.astype(u64))
-                # read-invocation snapshot: other threads' completed counts
-                if self.C > 1:
+                    fw.set(f"h{c}_phase", new_ph.astype(u64))
+                    cur_comp = cur_ph >> 1  # [B, 1]
                     snap = jnp.zeros((B, NS), i32)
                     for j in range(self.C):
                         if j == c:
                             continue
                         slot = self.hist._snap_slot(c, j)
-                        snap = snap | (comp[:, j : j + 1] << (2 * slot))
-                    cur_snap = pk.get(rows, f"h{c}_snap").astype(i32)[:, None]
+                        snap = snap | (comp[:, j : j + 1] << (eb * slot))
+                    for m in range(K):
+                        sel = m_w & (cur_comp == m)
+                        cur_snap = pk.get(rows, f"h{c}_snap{m}").astype(i32)[
+                            :, None
+                        ]
+                        fw.set(
+                            f"h{c}_snap{m}",
+                            jnp.where(sel, snap, cur_snap).astype(u64),
+                        )
+                    cur_rv = pk.get(rows, f"h{c}_rval").astype(i32)[:, None]
                     fw.set(
-                        f"h{c}_snap",
-                        jnp.where(m_w, snap, cur_snap).astype(u64),
+                        f"h{c}_rval",
+                        jnp.where(m_r, rv, cur_rv).astype(u64),
                     )
-                cur_rv = pk.get(rows, f"h{c}_rval").astype(i32)[:, None]
-                fw.set(
-                    f"h{c}_rval",
-                    jnp.where(m_r, rv, cur_rv).astype(u64),
+            elif self.C:
+                kind = cst["env_kind"][ecode]  # [B, NS]
+                ci = self._client_of_dev()[jnp.clip(dst, 0, self.n_actors - 1)]
+                is_ret_w = (
+                    valid
+                    & ((kind == _K_PUT_OK) | (kind == _K_PUT_FAIL))
+                    & (ci >= 0)
                 )
-                if self.hist.wfail_bits:
-                    m_wf = m_w & (kind == _K_PUT_FAIL)
-                    cur_wf = pk.get(rows, f"h{c}_wfail").astype(i32)[:, None]
-                    fw.set(
-                        f"h{c}_wfail",
-                        jnp.where(m_wf, 1, cur_wf).astype(u64),
+                is_ret_r = valid & (kind == _K_GET_OK) & (ci >= 0)
+                rv = cst["env_val"][ecode]
+                phases = jnp.stack(
+                    [
+                        pk.get(rows, f"h{c}_phase").astype(i32)
+                        for c in range(self.C)
+                    ],
+                    -1,
+                )  # [B, C]
+                # completed-op count per thread, derived from its phase
+                comp = jnp.where(
+                    phases == PHASE_W_INFLIGHT,
+                    0,
+                    jnp.where(phases == PHASE_DONE, 2, 1),
+                )  # [B, C]
+                for c in range(self.C):
+                    m_w = is_ret_w & (ci == c)  # write returned + read invoked
+                    m_r = is_ret_r & (ci == c)
+                    cur_ph = pk.get(rows, f"h{c}_phase").astype(i32)[:, None]
+                    new_ph = jnp.where(
+                        m_w,
+                        PHASE_R_INFLIGHT,
+                        jnp.where(m_r, PHASE_DONE, cur_ph),
                     )
+                    fw.set(f"h{c}_phase", new_ph.astype(u64))
+                    # read-invocation snapshot: other threads' completed counts
+                    if self.C > 1:
+                        snap = jnp.zeros((B, NS), i32)
+                        for j in range(self.C):
+                            if j == c:
+                                continue
+                            slot = self.hist._snap_slot(c, j)
+                            snap = snap | (comp[:, j : j + 1] << (2 * slot))
+                        cur_snap = pk.get(rows, f"h{c}_snap").astype(i32)[:, None]
+                        fw.set(
+                            f"h{c}_snap",
+                            jnp.where(m_w, snap, cur_snap).astype(u64),
+                        )
+                    cur_rv = pk.get(rows, f"h{c}_rval").astype(i32)[:, None]
+                    fw.set(
+                        f"h{c}_rval",
+                        jnp.where(m_r, rv, cur_rv).astype(u64),
+                    )
+                    if self.hist.wfail_bits:
+                        m_wf = m_w & (kind == _K_PUT_FAIL)
+                        cur_wf = pk.get(rows, f"h{c}_wfail").astype(i32)[:, None]
+                        fw.set(
+                            f"h{c}_wfail",
+                            jnp.where(m_wf, 1, cur_wf).astype(u64),
+                        )
 
         cur_poison = pk.get(rows, "poison").astype(i32)[:, None]
         fw.set(
@@ -1769,6 +1816,7 @@ class CompiledActorTensor(TensorModel):
         handler re-armed it)."""
         if not self._has_timers:
             return succ, valid
+        import jax
         import jax.numpy as jnp
 
         i32, u64 = jnp.int32, jnp.uint64
@@ -1792,10 +1840,11 @@ class CompiledActorTensor(TensorModel):
         send_cols = []
         for i in range(n):
             sc = pk.get(rows, f"a{i}").astype(i32)  # [B]
-            nc = cst["ttrans"][i][sc]
-            pi = cst["tpoison"][i][sc]
-            nb = cst["tbit"][i][sc]
-            send_cols.append(cst["tsends"][i][sc])  # [B, Kt]
+            with jax.named_scope(TWIN_TABLE):
+                nc = cst["ttrans"][i][sc]
+                pi = cst["tpoison"][i][sc]
+                nb = cst["tbit"][i][sc]
+                send_cols.append(cst["tsends"][i][sc])  # [B, Kt]
             fw_t.set(
                 f"a{i}",
                 jnp.where(col == i, nc[:, None], sc[:, None]).astype(u64),
@@ -1991,6 +2040,7 @@ class CompiledActorTensor(TensorModel):
         footprint pass decomposes it per action and the conflict matrix
         stops being all-dependent (no ``JX302``; docs/analysis.md
         "Per-channel encoding")."""
+        import jax
         import jax.numpy as jnp
 
         cst = self._consts()
@@ -2026,38 +2076,44 @@ class CompiledActorTensor(TensorModel):
             """[B, cap(action), cap(word)] region after consuming slot
             ``a`` (one copy / the flow head) — the non-duplicating
             deliver/drop effect; dup deliveries skip this entirely."""
-            reg_b = jnp.broadcast_to(reg[:, None, :], (B, cap, cap))
-            diag = jnp.eye(cap, dtype=bool)[None]
-            if self.ordered:
-                occ_b = jnp.broadcast_to(occ[:, None, :], (B, cap, cap))
-                return jnp.where(
-                    diag, EMPTYW,
-                    jnp.where(occ_b, reg_b - u64(1), reg_b),
-                )
-            count = reg & u64(COUNT_MASK)
-            gone = jnp.where(count <= u64(1), EMPTYW, reg - u64(1))
-            return jnp.where(diag, gone[:, :, None], reg_b)
+            with jax.named_scope(TWIN_NET):
+                reg_b = jnp.broadcast_to(reg[:, None, :], (B, cap, cap))
+                diag = jnp.eye(cap, dtype=bool)[None]
+                if self.ordered:
+                    occ_b = jnp.broadcast_to(
+                        occ[:, None, :], (B, cap, cap)
+                    )
+                    return jnp.where(
+                        diag, EMPTYW,
+                        jnp.where(occ_b, reg_b - u64(1), reg_b),
+                    )
+                count = reg & u64(COUNT_MASK)
+                gone = jnp.where(count <= u64(1), EMPTYW, reg - u64(1))
+                return jnp.where(diag, gone[:, :, None], reg_b)
 
         # -- deliver actions: one per (channel, slot) -----------------------
         for ci, (_s, d) in enumerate(self._channels):
             if d >= n:
                 continue
-            cap, reg, occ, ecode = region_view(ci)
-            sc = pk.get(rows, f"a{d}").astype(i32)[:, None]  # [B, 1]
-            flat = sc * ne + ecode  # [B, cap]
-            nc = cst["trans"][d].reshape(-1)[flat]
-            valid = occ & (nc >= 0)
-            if self.ordered:
-                valid = valid & ((reg & u64(COUNT_MASK)).astype(i32) == 1)
-            poison = None
-            if self._ch_poison_any[ci]:
-                poison = occ & cst["poison"][d].reshape(-1)[flat]
+            with jax.named_scope(TWIN_TABLE):
+                cap, reg, occ, ecode = region_view(ci)
+                sc = pk.get(rows, f"a{d}").astype(i32)[:, None]  # [B, 1]
+                flat = sc * ne + ecode  # [B, cap]
+                nc = cst["trans"][d].reshape(-1)[flat]
+                valid = occ & (nc >= 0)
+                if self.ordered:
+                    valid = valid & (
+                        (reg & u64(COUNT_MASK)).astype(i32) == 1
+                    )
+                poison = None
+                if self._ch_poison_any[ci]:
+                    poison = occ & cst["poison"][d].reshape(-1)[flat]
+                ks = cst["sends"][d].reshape(-1, max(self.K, 1))[flat]
 
             if self.dup:
                 work = {}
             else:
                 work = {ci: consumed(ci, cap, reg, occ)}
-            ks = cst["sends"][d].reshape(-1, max(self.K, 1))[flat]
             of = self._apply_sends(
                 work, rows, valid, ks, self._ch_targets[ci], cst, cap
             )
@@ -2068,10 +2124,11 @@ class CompiledActorTensor(TensorModel):
                              coalesce=coalesce)
             fw.set(f"a{d}", jnp.where(valid, nc, sc).astype(u64))
             if self._ch_ret_kind[ci] and self.C:
-                self._channel_history(
-                    fw, valid, ecode, int(self._client_of[d]), cst, B,
-                    cap,
-                )
+                with jax.named_scope(TWIN_HISTORY):
+                    self._channel_history(
+                        fw, valid, ecode, int(self._client_of[d]), cst,
+                        B, cap,
+                    )
             if self._has_timers and self._ch_timer[ci]:
                 eff = cst["teff"][d].reshape(-1)[flat]  # [B, cap]
                 tcur = pk.get(rows, "timers").astype(i32)[:, None]
@@ -2117,8 +2174,9 @@ class CompiledActorTensor(TensorModel):
             tcur_all = pk.get(rows, "timers").astype(i32)  # [B]
             for i in range(n):
                 sc = pk.get(rows, f"a{i}").astype(i32)  # [B]
-                nc = cst["ttrans"][i][sc]
-                nb = cst["tbit"][i][sc]
+                with jax.named_scope(TWIN_TABLE):
+                    nc = cst["ttrans"][i][sc]
+                    nb = cst["tbit"][i][sc]
                 valid_i = (((tcur_all >> i) & 1) == 1)[:, None]  # [B, 1]
                 fw = FieldWriter(pk, packed_broadcast(1),
                                  coalesce=coalesce)

@@ -38,6 +38,10 @@ Device ops (all pure, jittable, batched over leading axes):
    slot (the caller re-sorts once per step via :func:`slot_canonicalize`).
  - :func:`slot_canonicalize` — re-sort so EMPTY slots sink to the end.
 
+Each device op runs under the ``twin.net`` named scope
+(``telemetry/spans.py``), so a profile splits ``sr.expand`` into the
+network encoding and the rest, for compiled and hand-written twins alike.
+
 Host-side, :class:`SlotCodec` mirrors the packing for ``encode_state`` /
 ``decode_state`` bridges.
 """
@@ -46,9 +50,11 @@ from __future__ import annotations
 
 from typing import Callable, Iterable
 
+import jax
 import jax.numpy as jnp
 
 from ..fingerprint import MASK64
+from ..telemetry.spans import TWIN_NET
 
 COUNT_BITS = 6
 COUNT_MASK = (1 << COUNT_BITS) - 1
@@ -106,6 +112,7 @@ def slot_occupied(slots):
     return slots != jnp.uint64(SLOT_EMPTY)
 
 
+@jax.named_scope(TWIN_NET)
 def slot_deliver(slots, index: int):
     """Consume one instance of the envelope in slot ``index`` (static index;
     batched over leading axes).  Caller must ensure the slot is occupied.
@@ -118,6 +125,7 @@ def slot_deliver(slots, index: int):
     return slots.at[..., index].set(neww)
 
 
+@jax.named_scope(TWIN_NET)
 def slot_send(slots, code, enable, set_semantics: bool = False):
     """Add one instance of ``code`` (uint64[...]) where ``enable`` (bool[...]).
 
@@ -160,6 +168,7 @@ def slot_send(slots, code, enable, set_semantics: bool = False):
     return claimed, overflow
 
 
+@jax.named_scope(TWIN_NET)
 def slot_send_ordered(slots, code, pair_lookup, enable):
     """Append ``code`` at the TAIL of its directed flow (ordered networks):
     the claimed slot's count bits get rank ``1 + |in-flight same-flow
@@ -186,11 +195,13 @@ def slot_send_ordered(slots, code, pair_lookup, enable):
     return claimed, overflow
 
 
+@jax.named_scope(TWIN_NET)
 def slot_canonicalize(slots):
     """Sort slots ascending; EMPTY (all-ones) sinks to the end."""
     return jnp.sort(slots, axis=-1)
 
 
+@jax.named_scope(TWIN_NET)
 def region_send_ordered(reg, code, enable):
     """Ordered append for the PER-CHANNEL packing: ``reg`` is one directed
     channel's slot region, which under the per-channel layout IS a single

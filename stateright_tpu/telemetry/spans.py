@@ -20,6 +20,13 @@ check), and each unit of work inside it is a **span** with a fresh
             │                     ``resharding``
             └── job ...
 
+(Two seams run before an engine's run span opens and are parentless in
+its trace: ``fingerprint_bridge`` and ``twin_compile``, the actor
+compiler's closure + tabulation, which runs before any checker — and so
+any recorder — exists: it closes with no recorder and keeps its
+:attr:`span.fields` on the twin, and the first checker that adopts the
+twin records them.)
+
 Span ids are minted where the work is minted — the fleet scheduler
 roots the trace, ``supervise()`` opens one span per attempt, the
 engines one per run — and the context propagates DOWN via the builder
@@ -72,6 +79,16 @@ STAGE_STATS = "sr.stats"
 STAGES = (STAGE_POP, STAGE_PROPS, STAGE_EXPAND, STAGE_HASH, STAGE_INSERT,
           STAGE_APPEND, STAGE_BOOKKEEP, STAGE_STATS)
 
+# Sub-scopes of ``sr.expand`` that a compiled actor twin's ``step_rows``
+# opens (``parallel/actor_compiler.py``; ``twin.net`` lives in
+# ``parallel/actor_tensor.py``'s slot kernels, which hand-written twins
+# share).  They do NOT start with ``sr.``: an operation's stage stays the
+# first ``sr.<stage>`` of its scope path, and these split that stage.
+TWIN_TABLE = "twin.table"  # (state, envelope) look-ups, effect decoding
+TWIN_NET = "twin.net"  # slot deliver / send / canonicalise
+TWIN_HISTORY = "twin.history"  # the linearizability history fields
+TWIN_SCOPES = (TWIN_TABLE, TWIN_NET, TWIN_HISTORY)
+
 # a host span ``name`` is ``sr/<name>`` in the profiler's trace
 ANNOTATION_PREFIX = "sr/"
 
@@ -102,7 +119,7 @@ class SpanHandle:
     children parent under.  ``end`` is idempotent — a double close
     records nothing twice."""
 
-    __slots__ = ("name", "ctx", "parent_id", "_t0", "_closed")
+    __slots__ = ("name", "ctx", "parent_id", "fields", "_t0", "_closed")
 
     def __init__(self, name: str, parent: Optional[SpanContext] = None,
                  trace_id: Optional[str] = None):
@@ -113,20 +130,21 @@ class SpanHandle:
             trace_id=parent.trace_id if parent is not None else trace_id
         )
         self.parent_id = parent.span_id if parent is not None else None
+        self.fields: Optional[dict] = None  # the closed record's fields
         self._t0 = time.monotonic()
         self._closed = False
 
     def end(self, recorder, **attrs) -> Optional[dict]:
         """Close the span and record it into ``recorder`` (None → the
-        span is dropped, by the no-recorder-no-telemetry rule).  Extra
-        ``attrs`` ride the record (they must stay within the golden
-        schema's optional set).  Returns the stored record (or None)."""
+        span is dropped, by the no-recorder-no-telemetry rule; its
+        ``fields`` stay on the handle for a seam that closes before its
+        recorder exists).  Extra ``attrs`` ride the record (they must stay
+        within the golden schema's optional set).  Returns the stored
+        record (or None)."""
         if self._closed:
             return None
         self._closed = True
         dur = round(time.monotonic() - self._t0, 6)
-        if recorder is None:
-            return None
         fields = {
             "v": SPAN_V,
             "name": self.name,
@@ -137,6 +155,9 @@ class SpanHandle:
         if self.parent_id is not None:
             fields["parent_id"] = self.parent_id
         fields.update({k: v for k, v in attrs.items() if v is not None})
+        self.fields = fields
+        if recorder is None:
+            return None
         return recorder.record("span", **fields)
 
 
@@ -169,6 +190,11 @@ class span:
     @property
     def ctx(self) -> SpanContext:
         return self._handle.ctx
+
+    @property
+    def fields(self) -> Optional[dict]:
+        """The closed span's record fields (None while open)."""
+        return self._handle.fields
 
     def set(self, **attrs) -> None:
         self._attrs.update(attrs)

@@ -288,3 +288,69 @@ def test_a_whole_check_under_the_profiler_shows_every_host_seam(tmp_path):
     # every ring span has its twin on the profiler's clock
     ring = sorted(r["name"] for r in c.flight_recorder.records("span"))
     assert sorted(n[len("sr/"):] for n in names) == ring
+
+
+# -- a compiled actor twin: sub-scopes of sr.expand, the twin_compile span --------
+
+
+@pytest.fixture(scope="module")
+def compiled_twin():
+    """One telemetered check of the compiled ABD twin (2 clients, 2 servers,
+    ordered links: 564 states) and its run program's lowered text."""
+    from stateright_tpu.models.linearizable_register import abd_ordered
+
+    model = abd_ordered(2, 2)
+    c = model.checker().telemetry().spawn_tpu(sync=True, capacity=1 << 13, batch=256)
+    c.join()
+    init_fn, run_fn = c._engine(c._cap, c._qcap, c._batch, c._cand)
+    carry, _ = init_fn()
+    return {"model": model, "checker": c,
+            "run": run_fn.lower(tuple(carry)).as_text(debug_info=True)}
+
+
+@pytest.mark.parametrize("scope", spans.TWIN_SCOPES)
+def test_a_compiled_twins_step_names_its_parts_inside_expand(compiled_twin, scope):
+    assert not scope.startswith("sr.")  # a part is never a stage of its own
+    assert f"/{spans.STAGE_EXPAND}/{scope}/" in compiled_twin["run"], scope
+
+
+def test_the_slot_kernels_carry_twin_net_for_hand_twins_too():
+    import jax.numpy as jnp
+
+    from stateright_tpu.parallel import actor_tensor as at
+
+    slots = jnp.full((4, 6), at.SLOT_EMPTY, jnp.uint64)
+    code = jnp.arange(4, dtype=jnp.uint64)
+    text = jax.jit(
+        lambda s: at.slot_canonicalize(at.slot_send(s, code, code < 9)[0])
+    ).lower(slots).as_text(debug_info=True)
+    assert f"/{spans.TWIN_NET}/" in text
+
+
+def test_twin_compile_is_adopted_once_by_the_first_recorder(compiled_twin):
+    c, model = compiled_twin["checker"], compiled_twin["model"]
+    by = _spans_by_name(c)
+    (rec,) = by["twin_compile"]
+    twin = model._tensor_cached()
+    assert {k: rec[k] for k in twin.compile_attrs()} == twin.compile_attrs()
+    assert rec["row_width"] == twin.width == 17 and rec["n_slots"] == twin.n_slots
+    assert rec["actor_states"] == "64,50,3,3" and rec["envelopes"] == 56
+    assert rec["table_bytes"] == 61432 and rec["dur"] > 0
+    # the attribute counts exactly what the step program uploads
+    on_device = jax.tree_util.tree_leaves(twin._consts())
+    assert rec["table_bytes"] == sum(int(a.nbytes) for a in on_device)
+    # in the checker's trace, parentless (it closed before the run span opened)
+    (run,) = by["engine_run"]
+    assert rec["trace_id"] == run["trace_id"] and "parent_id" not in rec
+    assert twin.compile_span is None  # handed over: a second checker finds none
+    again = model.checker().telemetry().spawn_tpu(sync=True, capacity=1 << 13, batch=256)
+    again.join()
+    assert "twin_compile" not in _spans_by_name(again)
+
+
+def test_a_span_without_a_recorder_keeps_its_fields():
+    with spans.span("early", None, cap=3) as sp:
+        assert sp.fields is None
+        sp.set(unique=7)
+    assert sp.fields["name"] == "early" and sp.fields["dur"] >= 0
+    assert (sp.fields["cap"], sp.fields["unique"]) == (3, 7)

@@ -84,13 +84,16 @@ SCHEMA = {
         # attempt), attempt ordinal, gen (autosave), pending
         # (spill_drain), cap/unique (resharding, grow), key/slot (fleet
         # job), jobs/slots (fleet root), rung/source (engine_acquire),
-        # dsteps (device_call), status (grow)
+        # dsteps (device_call), status (grow), the universes, row and
+        # table bytes of a compiled actor twin (twin_compile)
         {"v": int, "name": str, "trace_id": str, "span_id": str,
          "dur": _REAL},
         {"parent_id": str, "engine": str, "error": str, "attempt": int,
          "gen": int, "pending": int, "cap": int, "unique": int,
          "key": str, "slot": int, "jobs": int, "slots": int,
-         "rung": str, "source": str, "dsteps": int, "status": str},
+         "rung": str, "source": str, "dsteps": int, "status": str,
+         "actor_states": str, "envelopes": int, "n_slots": int,
+         "row_width": int, "table_bytes": int},
     ),
     "health": (
         {"v": int, "event": str},
