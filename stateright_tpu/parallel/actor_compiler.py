@@ -1546,6 +1546,7 @@ class CompiledActorTensor(TensorModel):
                 occupied, (slots >> u64(COUNT_BITS)).astype(i32), 0
             )  # [B, NS]
             dst = cst["env_dst"][ecode]  # [B, NS]
+            pair = None  # flow id of each slot: ordered networks only
             if self.ordered:
                 # count bits hold the 1-based rank within the directed flow;
                 # only the head (rank 1) of each flow is deliverable
@@ -1572,6 +1573,12 @@ class CompiledActorTensor(TensorModel):
                 send_codes = jnp.where(mask[..., None], ks, send_codes)
             if self.ordered:
                 valid = valid & at_head
+                # flow id of each send column's code: with `pair`, all the
+                # look-ups slot_send_ordered's carried ids ever need
+                send_pairs = [
+                    cst["env_pair"][send_codes[..., k]]
+                    for k in range(self.K)
+                ]
 
         # -- successor slot arrays ------------------------------------------
         with jax.named_scope(TWIN_NET):
@@ -1585,6 +1592,9 @@ class CompiledActorTensor(TensorModel):
                 same_flow = (pair_a >= 0) & (pair_a == pair_s)
                 slots_d = jnp.where(same_flow, slots_b - u64(1), slots_b)
                 slots_d = jnp.where(diag, u64(SLOT_EMPTY), slots_d)
+                # a delivery changes no slot's code, so the successors'
+                # flow ids are the row's own with the delivered slot freed
+                pair_d = jnp.where(diag, -1, pair_s)  # [B, NS, NS]
             else:
                 if self.dup:
                     # duplicating network: delivery leaves the envelope in
@@ -1600,8 +1610,8 @@ class CompiledActorTensor(TensorModel):
             for k in range(self.K):
                 sk = send_codes[..., k]
                 if self.ordered:
-                    slots_d, of = slot_send_ordered(
-                        slots_d, sk.astype(u64), cst["env_pair"],
+                    slots_d, pair_d, of = slot_send_ordered(
+                        slots_d, pair_d, sk.astype(u64), send_pairs[k],
                         valid & (sk >= 0),
                     )
                 else:
@@ -1766,7 +1776,7 @@ class CompiledActorTensor(TensorModel):
 
         if not self.model.lossy:
             return self._append_timeouts(
-                rows, slots, cst, succ, valid, coalesce=coalesce
+                rows, slots, pair, cst, succ, valid, coalesce=coalesce
             )
 
         # -- drop actions (lossy networks): consume without delivering ------
@@ -1804,16 +1814,18 @@ class CompiledActorTensor(TensorModel):
         droppable = at_head if self.ordered else occupied
         valid = jnp.concatenate([valid, droppable], axis=1)
         return self._append_timeouts(
-            rows, slots, cst, succ, valid, coalesce=coalesce
+            rows, slots, pair, cst, succ, valid, coalesce=coalesce
         )
 
-    def _append_timeouts(self, rows, slots, cst, succ, valid,
+    def _append_timeouts(self, rows, slots, pair, cst, succ, valid,
                          coalesce=False):
         """Append one Timeout action column per actor (reference
         ``model.rs:234-238,288-306``): valid iff the actor's timer bit is
         set; the tabulated ``on_timeout`` effect updates the actor state,
         appends its sends, and rewrites the timer bit (cleared unless the
-        handler re-armed it)."""
+        handler re-armed it).  ``pair`` is the flow id of each slot of
+        ``slots`` on an ordered network (``slot_send_ordered``'s carried
+        ids), else None."""
         if not self._has_timers:
             return succ, valid
         import jax
@@ -1854,11 +1866,17 @@ class CompiledActorTensor(TensorModel):
         fw_t.set("timers", jnp.stack(tvals, 1).astype(u64))
         slots_t = jnp.broadcast_to(slots[:, None, :], (B, n, NS))
         sk_all = jnp.stack(send_cols, axis=1)  # [B, n, Kt]
+        if self.ordered:
+            pair_t = jnp.broadcast_to(pair[:, None, :], (B, n, NS))
+            with jax.named_scope(TWIN_TABLE):
+                send_pairs = [
+                    cst["env_pair"][sk_all[..., k]] for k in range(self.Kt)
+                ]
         for k in range(self.Kt):
             sk = sk_all[..., k]
             if self.ordered:
-                slots_t, of = slot_send_ordered(
-                    slots_t, sk.astype(u64), cst["env_pair"],
+                slots_t, pair_t, of = slot_send_ordered(
+                    slots_t, pair_t, sk.astype(u64), send_pairs[k],
                     valid_t & (sk >= 0),
                 )
             else:

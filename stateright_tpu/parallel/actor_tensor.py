@@ -37,6 +37,13 @@ Device ops (all pure, jittable, batched over leading axes):
  - :func:`slot_send` — increment an existing code's count or claim a free
    slot (the caller re-sorts once per step via :func:`slot_canonicalize`).
  - :func:`slot_canonicalize` — re-sort so EMPTY slots sink to the end.
+ - :func:`slot_send_ordered` — ordered networks, slot-multiset layout: claim
+   a free slot at the TAIL of the code's directed flow (count bits = 1-based
+   rank in the flow).  The caller carries each slot's flow id beside the
+   slot word (``slot_pair``) and passes the sent code's (``code_pair``); the
+   kernel returns the updated ids, so K sends in a row cost no look-up.
+ - :func:`region_send_ordered` — ordered networks, per-channel layout: the
+   region IS one flow, so the rank is its occupancy; no flow ids.
 
 Each device op runs under the ``twin.net`` named scope
 (``telemetry/spans.py``), so a profile splits ``sr.expand`` into the
@@ -169,18 +176,26 @@ def slot_send(slots, code, enable, set_semantics: bool = False):
 
 
 @jax.named_scope(TWIN_NET)
-def slot_send_ordered(slots, code, pair_lookup, enable):
+def slot_send_ordered(slots, slot_pair, code, code_pair, enable):
     """Append ``code`` at the TAIL of its directed flow (ordered networks):
     the claimed slot's count bits get rank ``1 + |in-flight same-flow
     envelopes|``.  No dedup — ordered flows hold duplicates at distinct
-    ranks.  ``pair_lookup`` maps envelope codes to flow ids.  Returns
-    ``(slots, overflow)``; overflow = no free slot, or the flow is already
-    ``COUNT_MASK`` deep (rank would corrupt the code bits)."""
+    ranks.
+
+    The flow ids are CARRIED beside the slot words, not derived from them:
+    ``slot_pair`` (int32, the shape of ``slots``) holds each slot's flow id
+    and ``code_pair`` (int32, the shape of ``code``) the sent code's.  The
+    contract, which the caller establishes once and every call keeps (with
+    ``env_pair`` the caller's code -> flow table)::
+
+        slot_pair == where(slot_occupied(slots), env_pair[slot_codes(slots)], -1)
+
+    Returns ``(slots, slot_pair, overflow)``; overflow = no free slot, or
+    the flow is already ``COUNT_MASK`` deep (rank would corrupt the code
+    bits)."""
     n = slots.shape[-1]
     occ = slot_occupied(slots)
-    pair_s = jnp.where(occ, pair_lookup[slot_codes(slots).astype(jnp.int32)], -1)
-    pair_c = pair_lookup[code.astype(jnp.int32)]
-    in_flow = occ & (pair_s == pair_c[..., None])
+    in_flow = occ & (slot_pair == code_pair[..., None])
     depth = jnp.sum(in_flow, axis=-1).astype(jnp.uint64)
 
     free = ~occ
@@ -191,8 +206,9 @@ def slot_send_ordered(slots, code, pair_lookup, enable):
     onehot = (jnp.arange(n) == first_free[..., None]) & claim[..., None]
     neww = (code << jnp.uint64(COUNT_BITS)) | (depth + jnp.uint64(1))
     claimed = jnp.where(onehot, neww[..., None], slots)
+    claimed_pair = jnp.where(onehot, code_pair[..., None], slot_pair)
     overflow = enable & (~any_free | too_deep)
-    return claimed, overflow
+    return claimed, claimed_pair, overflow
 
 
 @jax.named_scope(TWIN_NET)
@@ -205,9 +221,9 @@ def slot_canonicalize(slots):
 def region_send_ordered(reg, code, enable):
     """Ordered append for the PER-CHANNEL packing: ``reg`` is one directed
     channel's slot region, which under the per-channel layout IS a single
-    FIFO flow — no ``pair_lookup`` needed (contrast
-    :func:`slot_send_ordered`, which disambiguates flows inside the global
-    slot multiset).  Appends ``code`` at the tail: the claimed slot's
+    FIFO flow — no flow ids needed (contrast :func:`slot_send_ordered`,
+    which tells flows apart inside the global slot multiset by the ids it
+    carries).  Appends ``code`` at the tail: the claimed slot's
     count bits get rank ``1 + |occupied slots in the region|``.  Returns
     ``(reg, overflow)``; overflow = no free slot, or the flow is already
     ``COUNT_MASK`` deep (the rank would corrupt the code bits)."""
