@@ -4,9 +4,9 @@ A brand-new framework with the capabilities of the Stateright model checker
 (reference mounted at ``/root/reference``; see ``SURVEY.md``), re-designed
 TPU-first: states serialize to fixed-width ``uint64`` rows, frontier expansion
 runs as a jit-compiled batched transition function, visited-set deduplication
-and property evaluation run on-device, and multi-chip scaling shards the
-wavefront over a ``jax.sharding.Mesh`` with fingerprints routed all-to-all
-over ICI.
+and property evaluation run on-device, and multi-chip scaling places the
+same program's table and queue over a ``jax.sharding.Mesh``, the compiler
+inserting the collectives.
 
 Layers (bottom-up, mirroring the reference's layer map in SURVEY.md §1):
 

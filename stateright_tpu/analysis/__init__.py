@@ -29,7 +29,7 @@ and the Explorer's ``/.status``.  Rule catalogue: ``docs/analysis.md``.
 """
 
 from .audit import audit_model, config_signature
-from .costmodel import CostReport, sharded_costs, wavefront_costs
+from .costmodel import CostReport, wavefront_costs
 from .footprint import extract_footprints
 from .independence import (
     IndependenceReport,
@@ -62,6 +62,5 @@ __all__ = [
     "por_plan",
     "run_independence",
     "run_sanitizer",
-    "sharded_costs",
     "wavefront_costs",
 ]

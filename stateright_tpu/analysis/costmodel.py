@@ -852,8 +852,8 @@ def _stage_fns(tensor, cap: int, qcap: int, batch: int, cand: int,
 
     The insert/queue wiring here MIRRORS ``wavefront._build_engine``'s
     default path (window=batch, compact=eff_cand, qalloc=qcap+m) by
-    hand — the ``telemetry/memory.sharded_specs`` discipline, not the
-    ``_carry_avals``-derived one: the engine's step is one fused jaxpr,
+    hand, not derived from ``_carry_avals`` as the memory ledger's
+    specs are: the engine's step is one fused jaxpr,
     and standalone stage kernels are the whole point of per-stage
     attribution.  The XLA reconciliation checks each stage against its
     OWN compile, so a drift against the engine would NOT trip it —
@@ -1070,74 +1070,6 @@ def wavefront_costs(
         engine="wavefront",
         shapes={"batch": batch, "capacity": cap, "queue_capacity": qcap,
                 "cand": min(cand, batch * arity)},
-        stages=stages, reconciliation=recon, actions=actions,
-        candidates=candidates,
-        findings=mxu_findings(candidates, stages),
-    )
-    if cache is not None:
-        cache[key] = out
-    return out
-
-
-def sharded_costs(
-    tensor, cap_local: int, fcap_local: int, ndev: int,
-    *, sym: bool = False, reconcile: bool = True, mxu=None,
-) -> Optional[CostReport]:
-    """The sharded engine's MODEL-kernel ledger (property/expand/hash at
-    the per-device frontier width).  The engine-side insert and
-    all-to-all are mesh collectives the single-kernel walk cannot price
-    honestly — they land with the pod-scale mesh round (ROADMAP); the
-    block says so via the ``engine`` tag."""
-    width = getattr(tensor, "width", None)
-    arity = getattr(tensor, "max_actions", None)
-    if not isinstance(width, int) or not isinstance(arity, int):
-        return None
-    key = ("sharded", cap_local, fcap_local, ndev, bool(sym),
-           bool(reconcile))
-    if mxu is not None:
-        key = key + (tuple(mxu),)
-    cache = _cost_cache(tensor)
-    if cache is not None and key in cache:
-        return cache[key]
-    try:
-        np.asarray(tensor.init_rows())
-        fns = _stage_fns(
-            tensor, cap_local, max(cap_local // 2, 1), fcap_local,
-            4 * fcap_local, sym, mxu=mxu,
-        )
-    except Exception:  # noqa: BLE001
-        return None
-    stages: dict = {}
-    recon: dict = {}
-    expand_closed = None
-    for name in ("property", "expand", "hash"):
-        fn, avals = fns[name]
-        try:
-            closed = _trace(fn, avals)
-        except Exception:  # noqa: BLE001
-            continue
-        if name == "expand":
-            expand_closed = closed
-        stages[name] = walk_jaxpr(closed, name)
-        if reconcile:
-            recon[name] = reconcile_stage(
-                stages[name], xla_cost(fn, avals)
-            )
-    if not stages:
-        return None
-    actions = None
-    if expand_closed is not None:
-        try:
-            actions = action_costs(expand_closed, arity)
-        except Exception:  # noqa: BLE001
-            actions = None
-    from ..ops.mxu import effective_mxu
-
-    candidates = mxu_candidates(stages, mxu=effective_mxu(tensor, mxu))
-    out = CostReport(
-        engine="sharded",
-        shapes={"batch": fcap_local, "capacity": cap_local * ndev,
-                "devices": ndev},
         stages=stages, reconciliation=recon, actions=actions,
         candidates=candidates,
         findings=mxu_findings(candidates, stages),

@@ -1051,47 +1051,6 @@ def _leaf_vars_of(closed, arity: int) -> tuple:
     return leaves, idx
 
 
-def _eval_sliced(jaxpr, consts, *args):
-    """``jax.core.eval_jaxpr`` for a kernel slice that may run INSIDE the
-    sharded engine's ``shard_map``: there the rows are varying over the
-    mesh axis while the slice's constants and literals — traced outside
-    — are not, and a raw ``bind`` refuses to mix the two (the casts
-    ``jnp`` would insert while tracing are not in the jaxpr).  Operands
-    are therefore cast up to the eqn's widest varying set before each
-    bind, and nested ``jit`` calls are evaluated inline for the same
-    reason.  Outside a ``shard_map`` nothing varies and this is
-    ``eval_jaxpr``."""
-    import jax
-    import jax.numpy as jnp
-    from jax.extend.core import Literal
-
-    env: dict = dict(zip(jaxpr.constvars, consts))
-    env.update(zip(jaxpr.invars, args))
-    for eqn in jaxpr.eqns:
-        vals = [v.val if isinstance(v, Literal) else env[v]
-                for v in eqn.invars]
-        vmas = [getattr(jax.typeof(x), "vma", frozenset()) for x in vals]
-        want = frozenset().union(*vmas)
-        if want:
-            vals = [
-                x if vma == want else jax.lax.pcast(
-                    jnp.asarray(x), tuple(want - vma), to="varying"
-                )
-                for x, vma in zip(vals, vmas)
-            ]
-        if eqn.primitive.name == "jit":
-            inner = eqn.params["jaxpr"]
-            outs = _eval_sliced(inner.jaxpr, inner.consts, *vals)
-        else:
-            subfuns, params = eqn.primitive.get_bind_params(eqn.params)
-            outs = eqn.primitive.bind(*subfuns, *vals, **params)
-            if not eqn.primitive.multiple_results:
-                outs = [outs]
-        env.update(zip(eqn.outvars, outs))
-    return [v.val if isinstance(v, Literal) else env[v]
-            for v in jaxpr.outvars]
-
-
 def conjunct_eval_fn(tensor):
     """A batch-size-polymorphic evaluator of the guard-conjunct leaves:
     ``fn(rows[B, W]) -> [bool[B] | bool[B, cap], ...]`` — the raw leaf
@@ -1130,7 +1089,9 @@ def conjunct_eval_fn(tensor):
                 cache[b] = False  # retrace drifted: caller falls back
                 return None
             sub = closed.jaxpr.replace(outvars=list(leaves))
-            built = functools.partial(_eval_sliced, sub, closed.consts)
+            built = functools.partial(
+                jax.core.eval_jaxpr, sub, closed.consts
+            )
             cache[b] = built
         if built is False:
             return None

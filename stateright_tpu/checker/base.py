@@ -58,7 +58,7 @@ class CheckerBuilder:
         # docs/sweep.md); None = env default (STATERIGHT_TPU_SWEEP on
         # models that define sweep_family)
         self.sweep_spec = None
-        # mesh-native sharded engine (parallel/mesh.py, docs/mesh.md);
+        # the multi-device mesh engine (parallel/mesh.py, docs/mesh.md);
         # None = env default (STATERIGHT_TPU_MESH, off when unset)
         self.mesh_mode: Optional[bool] = None
         self.mesh_devices: Optional[int] = None
@@ -127,11 +127,9 @@ class CheckerBuilder:
         bucket-occupancy distribution every N host syncs on the device
         engines, plus a closing ``final`` sample — each a D2H table pull,
         priced in the recorder's transfer counters.  Growth boundaries are
-        always sampled for free (the table is host-side there anyway), as
-        is the sharded engine's run end (it materializes the table
-        host-side regardless); the single-device engine keeps its final
-        table on device, so its run-end sample happens only under
-        ``occupancy_every``.
+        always sampled for free (the table is host-side there anyway);
+        the final table stays on the device, so the run-end sample
+        happens only under ``occupancy_every``.
 
         ``profile_steps=N`` arms a scoped ``jax.profiler`` trace of the
         first N hot steps into ``profile_dir`` (device engines only).
@@ -175,9 +173,10 @@ class CheckerBuilder:
         ``cartography=True`` additionally folds the search-cartography
         counters into the device step (``ops/cartography.py``,
         docs/telemetry.md): per-depth frontier sizes, the per-action
-        successor histogram, per-property evaluation tallies — and on the
-        sharded engine per-shard table loads plus the routed-candidate
-        matrix.  This is the one telemetry option that changes the step
+        successor histogram, per-property evaluation tallies (the mesh
+        engine adds per-shard table loads and the parent-owner ->
+        child-owner routing matrix, read off its final table on the
+        host).  This is the one telemetry option that changes the step
         program (small integer reductions riding the existing packed
         stats vector; measured ≤5% on 2pc-7, pinned in the slow tier);
         off, the step jaxpr stays bit-identical.  The counters surface as
@@ -434,13 +433,13 @@ class CheckerBuilder:
     def mesh(
         self, enabled: bool = True, *, devices: Optional[int] = None
     ) -> "CheckerBuilder":
-        """Run ``spawn_tpu`` on the mesh-native sharded engine
-        (``stateright_tpu/parallel/mesh.py``; docs/mesh.md): the
-        single-device wavefront program partitioned over a named
-        ``('host', 'chip')`` device mesh with ``NamedSharding`` rules —
-        visited table sharded by bucket owner, queue buffers sharded,
-        counters replicated — so the compiler inserts the cross-shard
-        collectives instead of a hand-scheduled ``shard_map`` body.
+        """Run ``spawn_tpu`` on the mesh engine
+        (``stateright_tpu/parallel/mesh.py``; docs/mesh.md), the one
+        multi-device engine: the single-device wavefront program
+        partitioned over a named ``('host', 'chip')`` device mesh with
+        ``NamedSharding`` rules — visited table sharded by bucket owner,
+        queue buffers sharded, counters replicated — so the compiler
+        inserts the cross-shard collectives.
 
         Parity contract, pinned by tests/test_mesh.py: unique/total
         counts, property verdicts, discovery traces, and kill+resume
@@ -449,9 +448,9 @@ class CheckerBuilder:
         differs).  ``devices=N`` bounds the mesh to the first N local
         devices (default: all of them).  Env override
         ``STATERIGHT_TPU_MESH=1`` (or ``=N`` for a device bound).  The
-        OLD hand-rolled engine keeps its spelling — the
-        ``devices=``/``n_devices=``/``mesh=`` spawn kwargs — and wins
-        when both are given explicitly."""
+        ``devices=`` / ``n_devices=`` / ``mesh=`` arguments of
+        ``spawn_tpu`` ask for the same engine (:meth:`spawn_tpu`); every
+        width that is named must be the same one."""
         self.mesh_mode = bool(enabled)
         self.mesh_devices = int(devices) if devices is not None else None
         return self
@@ -477,8 +476,8 @@ class CheckerBuilder:
         verdicts bit-identical to an unconstrained run, with the
         cartography block reconciling exactly.  The snapshot manifest
         carries the host/disk tier contents, so kill+resume works
-        mid-spill.  Env override ``STATERIGHT_TPU_SPILL=1``; wavefront
-        engine only (the sharded engine rejects it with guidance), and
+        mid-spill.  Env override ``STATERIGHT_TPU_SPILL=1``; one device
+        only (the mesh engine rejects it with guidance), and
         mutually exclusive with ``por()`` for now.  Spawn knobs:
         ``spill_bloom_bits``, ``spill_dir``, ``spill_host_bytes``
         (host-tier budget before the disk tier takes over; env
@@ -563,7 +562,7 @@ class CheckerBuilder:
         feature (pinned by test); ``checked=True`` pays the checkify
         instrumentation cost and is a debugging mode, not a bench
         configuration.  Host checkers ignore the flag (Python raises
-        eagerly there); the sharded engine rejects it for now."""
+        eagerly there)."""
         self.checked_mode = bool(enabled)
         return self
 
@@ -652,8 +651,8 @@ class CheckerBuilder:
     def spawn_mp_bfs(self, processes: Optional[int] = None) -> "Checker":
         """Process-parallel BFS: real multi-core checking (the thread pool
         above is GIL-bound).  Fingerprint-ownership sharding over forked
-        workers — the CPU analogue of the device engines' all-to-all
-        routing; see ``checker/mp.py``.  ``processes`` defaults to
+        workers — the CPU analogue of the mesh engine's table sharding;
+        see ``checker/mp.py``.  ``processes`` defaults to
         ``threads(N)`` if set above 1, else all cores."""
         from .mp import MpBfsChecker
 
@@ -751,42 +750,70 @@ class CheckerBuilder:
             return cpu_spawn()
         return probe_then(lambda: self.spawn_tpu(**tpu_kw))
 
+    def _mesh_request(self, kw: dict) -> Optional[dict]:
+        """The one rule for "more than one device": ``None`` when the run
+        is a one-device run, else the ``mesh=`` / ``n_devices=`` arguments
+        of :class:`~stateright_tpu.parallel.mesh.MeshTpuChecker`.
+
+        Every spelling asks for the same engine: ``devices=N`` (N > 1),
+        ``n_devices=N`` and ``mesh=<Mesh>`` among ``kw`` (read, never
+        popped), :meth:`mesh`, ``--mesh`` and ``STATERIGHT_TPU_MESH``.
+        They cannot disagree on the engine; the widths they name must be
+        one width."""
+        from ..parallel.partition import resolve_mesh_flag
+
+        on, flag_n = resolve_mesh_flag(self.mesh_mode, self.mesh_devices)
+        mesh = kw.get("mesh")
+        named = {
+            "devices=": kw.get("devices") or None,
+            "n_devices=": kw.get("n_devices"),
+            "mesh=": None if mesh is None else mesh.size,
+            ".mesh(devices=) / STATERIGHT_TPU_MESH": flag_n if on else None,
+        }
+        named = {k: int(v) for k, v in named.items() if v is not None}
+        if len(set(named.values())) > 1:
+            raise ValueError(
+                "spawn_tpu was asked for different numbers of devices: "
+                + ", ".join(f"{k} {v}" for k, v in named.items())
+                + " (name one width; docs/mesh.md)"
+            )
+        n = next(iter(named.values()), None)
+        if not (on or mesh is not None or (n is not None and n > 1)):
+            return None
+        return {"mesh": mesh, "n_devices": n}
+
     def spawn_tpu(self, **kw) -> "Checker":
         """The point of this framework: wavefront BFS on TPU (no reference
         counterpart; see ``stateright_tpu/parallel/wavefront.py``).
 
-        Pass ``devices=N`` (or ``mesh=...``) to shard the wavefront over a
-        device mesh with all-to-all fingerprint routing
-        (``stateright_tpu/parallel/sharded.py``).  The mesh-NATIVE engine
-        (``stateright_tpu/parallel/mesh.py``, docs/mesh.md) is spelled
-        :meth:`mesh` / ``--mesh`` / ``STATERIGHT_TPU_MESH=1`` instead;
-        an explicit ``devices``/``n_devices``/``mesh=`` argument keeps
-        selecting the old engine.
+        One rule picks the engine.  A sweep spec runs the
+        :class:`~stateright_tpu.sweep.engine.SweepChecker`.  More than
+        one device, asked for by ``devices=N`` (N > 1), ``n_devices=N``,
+        ``mesh=<Mesh>``, :meth:`mesh`, ``--mesh`` or
+        ``STATERIGHT_TPU_MESH``, runs the mesh engine
+        (``stateright_tpu/parallel/mesh.py``, docs/mesh.md): the same
+        program with its carry placed over the devices, so counts,
+        verdicts and paths equal the one-device run's.  Anything else
+        runs :class:`~stateright_tpu.parallel.wavefront.TpuChecker`.
 
         A static preflight audit runs first (``docs/analysis.md``): audit
         errors abort here, before any device work; silence deliberately
         with :meth:`skip_audit`."""
-        from ..parallel.partition import resolve_mesh_flag
         from ..sweep import resolve_sweep_spec
 
-        mesh_on, mesh_n = resolve_mesh_flag(
-            getattr(self, "mesh_mode", None),
-            getattr(self, "mesh_devices", None),
-        )
+        mesh_kw = self._mesh_request(kw)
+        for name in ("devices", "n_devices", "mesh"):
+            kw.pop(name, None)
         spec = resolve_sweep_spec(
             getattr(self, "sweep_spec", None), self.model
         )
         if spec is not None:
-            if "n_devices" in kw or "mesh" in kw or kw.get("devices"):
-                raise NotImplementedError(
-                    "sweeps run on the single-device engine for now — "
-                    "drop the devices/mesh argument (docs/sweep.md)"
-                )
-            if mesh_on:
+            if mesh_kw is not None:
                 raise NotImplementedError(
                     "sweep x mesh is a queued unlock (ROADMAP): sweeps "
-                    "run on the single-device engine for now — drop "
-                    ".mesh()/--mesh/STATERIGHT_TPU_MESH (docs/sweep.md)"
+                    "run on the single-device engine for now — drop the "
+                    "devices/mesh argument, .mesh(), --mesh and "
+                    "STATERIGHT_TPU_MESH (docs/sweep.md)"
                 )
             # audit once per distinct SHAPE of the family (the cohort
             # grouping key: twin class + row layout + properties) —
@@ -815,20 +842,10 @@ class CheckerBuilder:
 
             return SweepChecker(self, spec, **kw)
         self._preflight_audit()
-        devices = kw.pop("devices", None)
-        if devices is not None and devices != 1:
-            kw.setdefault("n_devices", devices)
-        if "n_devices" in kw or "mesh" in kw:
-            # the old engine's spelling stays the old engine — even with
-            # the mesh flag armed, an explicit devices/mesh argument is
-            # an explicit choice (the A/B harness relies on this)
-            from ..parallel.sharded import ShardedTpuChecker
-
-            return ShardedTpuChecker(self, **kw)
-        if mesh_on:
+        if mesh_kw is not None:
             from ..parallel.mesh import MeshTpuChecker
 
-            return MeshTpuChecker(self, n_devices=mesh_n, **kw)
+            return MeshTpuChecker(self, **mesh_kw, **kw)
         from ..parallel.wavefront import TpuChecker
 
         return TpuChecker(self, **kw)

@@ -4,10 +4,11 @@ The thread pool (``pool.py``) mirrors the reference's work-stealing job
 market (``bfs.rs:70-151``) faithfully, but under the CPython GIL its
 ``threads(N)`` is effectively single-core.  This strategy provides real
 multi-core checking: ``fork``-ed worker processes running a bulk-synchronous
-wavefront with **fingerprint-ownership sharding** — the same decomposition
-the device engines use (``parallel/sharded.py`` routes fingerprints to their
-owner shard by ``fp % D`` over ICI; here the "devices" are processes and the
-"all-to-all" is a pair of multiprocessing queues per worker).
+wavefront with **fingerprint-ownership sharding** — the decomposition the
+mesh engine's partition rules state for devices (``parallel/partition.py``:
+a visited-table shard owns a bucket range; here the "devices" are processes,
+a fingerprint's owner is ``fp % N``, and the "all-to-all" is a pair of
+multiprocessing queues per worker).
 
 Per round, each worker:
 
@@ -39,8 +40,8 @@ The search continues with the *original* state (the ``dfs.py`` subtlety),
 and parent pointers link original fingerprints, so discovery paths are
 genuine action sequences needing no class-matching walk.  Per-round
 arrival batches are folded in worker order, making the reduced counts
-deterministic for a fixed worker count (like the device engines, whose
-counts are pinned per mesh width).
+deterministic for a fixed worker count (the device engine's are the same
+at every mesh width: one program, one visit order).
 
 **Visitors** work here too (closing the reference's multi-core-or-visitor
 tradeoff): callbacks cannot cross process boundaries, so workers record

@@ -203,17 +203,6 @@ def raft_model(
     return m
 
 
-# Sharded-engine symmetry-reduced unique counts for raft_model(3), pinned
-# EXACTLY per mesh width (the schedule is deterministic for a fixed
-# width; representative-based reduction is visit-order-sensitive, so the
-# numbers differ per width).  Width 1 equals the host FIFO oracle
-# (tests/test_tensor_models.py::host_fifo_sym_oracle).  Measured round 5;
-# re-measure when the canonicalizer or routing changes — this table is
-# the single source for tests/test_raft.py AND __graft_entry__.py's
-# multichip dryrun gate.
-RAFT3_SYM_SHARDED_BY_WIDTH = {1: 2926, 2: 2960, 4: 3010, 8: 3015}
-
-
 def _audit_models(rest=()):
     """Default configurations for the static auditor (``audit`` verb and
     the fleet runner, ``_cli.fleet_audit``)."""

@@ -3,7 +3,7 @@ search is going* (docs/telemetry.md "Search cartography").
 
 The flight recorder (telemetry/) answers *where time goes*; nothing
 answered which actions dominate the frontier, how deep the wave is,
-whether properties are being exercised, or whether shards are balanced.
+whether properties are being exercised.
 These helpers fold those answers into the engines' step programs as small
 integer reductions over masks the step already computes (the enabled-action
 mask, the live mask, the property masks, the insert selection) — the
@@ -46,31 +46,15 @@ DEPTH_BINS = 128
 CARTOGRAPHY_V = 1
 
 
-def cart_shapes(arity: int, n_props: int) -> tuple:
-    """Carry-buffer shapes, in carry order: depth histogram, per-action
-    successor counts, per-property evaluation / condition-hit tallies.
-    Property arrays keep at least one lane so the carry stays non-empty
-    (same convention as the engines' ``disc`` vector)."""
-    p = max(n_props, 1)
-    return ((DEPTH_BINS,), (max(arity, 1),), (p,), (p,))
-
-
-def cart_zero_np(arity: int, n_props: int) -> list:
-    """Fresh host-side zero counters for every :func:`cart_shapes` buffer
-    (sharded-engine seed; the wavefront resume re-seed zeroes only the
-    :func:`cart_carry_shapes` subset — its depth histogram is
-    queue-derived and so survives a resume complete)."""
-    return [np.zeros(s, np.int64) for s in cart_shapes(arity, n_props)]
-
-
 def cart_carry_shapes(arity: int, n_props: int) -> tuple:
-    """The wavefront engine's carry-tail shapes: :func:`cart_shapes`
-    WITHOUT the depth histogram — the wavefront derives depths from its
-    queue at sync time (:func:`queue_depth_hist`) instead of paying a
-    per-step counter.  The sharded engine still carries all four (its
-    frontier is one BFS level, so its depth update is a scalar-index
-    add, not a scatter)."""
-    return cart_shapes(arity, n_props)[1:]
+    """The carry-tail shapes, in carry order: per-action successor
+    counts, per-property evaluation / condition-hit tallies.  Property
+    arrays keep at least one lane so the carry stays non-empty (same
+    convention as the engines' ``disc`` vector).  No depth histogram:
+    the engine derives depths from its queue at sync time
+    (:func:`queue_depth_hist`) instead of paying a per-step counter."""
+    p = max(n_props, 1)
+    return ((max(arity, 1),), (p,), (p,))
 
 
 def queue_depth_hist(qdepth, tail):
@@ -123,7 +107,7 @@ def action_hist_delta(valid):
 def prop_tally_delta(live, masks, n_props: int):
     """(d_evals, d_hits) for one batch: rows evaluated (the live count,
     identical for every property) and rows whose condition mask held, per
-    property.  Shapes follow :func:`cart_shapes`."""
+    property.  Shapes follow :func:`cart_carry_shapes`."""
     import jax.numpy as jnp
 
     p = max(n_props, 1)
@@ -150,7 +134,8 @@ def trim_hist(values) -> list:
 def shard_imbalance(loads) -> dict:
     """Imbalance summary over per-shard table loads: max/mean plus their
     ratio (1.0 = perfectly balanced; fingerprint uniformity should keep
-    this near 1 — routing skew shows up here first on multi-chip runs)."""
+    this near 1 — routing skew shows up here first on multi-chip runs).
+    The mesh engine's ``mesh_stats`` reads it off the final table."""
     arr = np.asarray(loads, dtype=np.float64).reshape(-1)
     if arr.size == 0:
         return {"max": 0, "mean": 0.0, "ratio": 1.0}
@@ -172,8 +157,6 @@ def snapshot(
     prop_names,
     states: int,
     unique: int,
-    shard_load=None,
-    route_matrix=None,
     por=None,
 ) -> dict:
     """Assemble the host-facing cartography block (JSON-safe) from raw
@@ -196,16 +179,6 @@ def snapshot(
         "fresh_inserts": int(unique),
         "duplicate_hits": max(int(states) - int(unique), 0),
     }
-    if shard_load is not None:
-        loads = [int(v) for v in np.asarray(shard_load).reshape(-1).tolist()]
-        out["shard_load"] = loads
-        out["shard_imbalance"] = shard_imbalance(loads)
-    if route_matrix is not None:
-        mat = np.asarray(route_matrix)
-        out["route_matrix"] = [
-            [int(v) for v in row] for row in mat.reshape(mat.shape[-2], -1)
-        ] if mat.ndim >= 2 else [[int(v) for v in mat.reshape(-1)]]
-        out["routed_candidates"] = int(mat.sum())
     if por is not None:
         # partial-order reduction: the reduced-vs-full split (ops/por.py)
         # — rows expanded with a reduced ample set, proviso-forced full

@@ -10,6 +10,8 @@ boolean kernels per wavefront (see ``SURVEY.md`` §7).
 Public surface:
  - :class:`TensorModel` / :class:`BitPacker` (``tensor_model.py``)
  - :class:`TpuChecker` (``wavefront.py``) via ``model.checker().spawn_tpu()``
+ - :class:`MeshTpuChecker` (``mesh.py``) via ``spawn_tpu(devices=N)``: the
+   same program over a device mesh built by :func:`build_mesh`
 """
 
 import jax
@@ -18,4 +20,5 @@ jax.config.update("jax_enable_x64", True)
 
 from .tensor_model import BitPacker, TensorBackedModel, TensorModel  # noqa: E402,F401
 from .wavefront import TpuChecker  # noqa: E402,F401
-from .sharded import ShardedTpuChecker, default_mesh  # noqa: E402,F401
+from .mesh import MeshTpuChecker  # noqa: E402,F401
+from .partition import build_mesh  # noqa: E402,F401

@@ -1,10 +1,10 @@
 """Shared result surface + host-side plumbing for the wavefront engines.
 
-Both the single-device (``wavefront.py``) and mesh-sharded (``sharded.py``)
-engines produce the same artifacts — a fingerprint→parent table, discovery
-fingerprints, and counters — and reconstruct traces identically (reference
-analogue ``src/checker/bfs.rs:314-342``).  This base class holds everything
-that is engine-independent so semantics fixes land once.
+The device engines (``wavefront.py``, its mesh placement ``mesh.py``, and the
+sweep engine) produce the same artifacts — a fingerprint→parent table,
+discovery fingerprints, and counters — and reconstruct traces identically
+(reference analogue ``src/checker/bfs.rs:314-342``).  This base class holds
+everything that is engine-independent so semantics fixes land once.
 """
 
 from __future__ import annotations
@@ -92,10 +92,10 @@ class WavefrontChecker(Checker):
         self._target = options.target_state_count
 
         # wavefront-throughput knobs (docs/perf.md): builder flags win,
-        # env knobs otherwise.  Pre-dedup is a per-engine jaxpr flag (both
-        # engines); prewarm is single-device only (the sharded engine's
-        # growth rebuilds are whole-mesh shard_maps — background-compiling
-        # them is future work); the persistent compile cache is a global
+        # env knobs otherwise.  Pre-dedup is a jaxpr flag of the step
+        # program; prewarm is single-device only (the mesh engine's
+        # growth rebuilds are not compiled in the background yet); the
+        # persistent compile cache is a global
         # JAX setting, switched on here when the environment or the
         # builder names a directory (prewarm.resolve_compile_cache_dir).
         from .prewarm import (
@@ -147,9 +147,9 @@ class WavefrontChecker(Checker):
                     )
         # billion-state spill tier (stateright_tpu/spill/, docs/spill.md):
         # host-backed visited overflow with a device-side Bloom
-        # pre-filter.  Wavefront engine only (the sharded engine's table
-        # is mesh-distributed — spilling it is the pod-scale round's
-        # work), and mutually exclusive with POR for now (the two-phase
+        # pre-filter.  One device only (the mesh engine's table is
+        # distributed over the mesh — spilling it is the pod-scale
+        # round's work), and mutually exclusive with POR for now (the two-phase
         # ample insert and the Bloom deferral do not compose).
         self._spill = resolve_flag(
             getattr(options, "spill_mode", None), ENV_SPILL
@@ -158,10 +158,11 @@ class WavefrontChecker(Checker):
             if self._engine_tag != "single":
                 raise NotImplementedError(
                     "spill mode (CheckerBuilder.spill()) is single-device "
-                    "only for now: the sharded engine's visited table is "
-                    "mesh-distributed and spills with the pod-scale mesh "
-                    "round (ROADMAP).  Drop the devices/mesh argument, or "
-                    "drop .spill()/--spill/STATERIGHT_TPU_SPILL."
+                    "only for now: the mesh engine's visited table is "
+                    "distributed over the mesh and spills with the "
+                    "pod-scale mesh round (ROADMAP).  Drop the devices/"
+                    "mesh argument (.mesh()/--mesh/STATERIGHT_TPU_MESH), "
+                    "or drop .spill()/--spill/STATERIGHT_TPU_SPILL."
                 )
             if self._por:
                 raise NotImplementedError(
@@ -173,7 +174,7 @@ class WavefrontChecker(Checker):
             self._init_spill()
         # MXU recast round (ops/mxu.py, docs/roofline.md): the three
         # bytes-moved reductions executing the JX4xx hot-spot ranking.
-        # Resolved ONCE here for both engines; None (off) keeps the step
+        # Resolved ONCE here; None (off) keeps the step
         # jaxpr bit-identical and the engine cache unkeyed (pinned).
         # The POR plan above deliberately footprints the PLAIN step
         # kernel either way: the coalesced kernel computes the same
@@ -250,7 +251,7 @@ class WavefrontChecker(Checker):
         # supervisor parents the engine_run span under the job/attempt
         # span via builder._span_ctx; None roots a fresh trace.  The run
         # span's own ctx (set by _run_traced) parents the host-seam
-        # spans (autosave / spill_drain / resharding).
+        # spans (autosave / spill_drain).
         self._span_parent = getattr(options, "_span_ctx", None)
         self._run_span_ctx = None
         # every span of this checker (before, in and after its run) is in
@@ -454,19 +455,7 @@ class WavefrontChecker(Checker):
     def _pre_run_validate(self) -> None:  # engine-specific, optional
         pass
 
-    # -- memory ledger hooks (telemetry/memory.py) ---------------------------
-
-    def _memory_spec_fn(self):
-        """``caps -> [BufferSpec]`` analytic model; engine-specific."""
-        raise NotImplementedError
-
-    def _memory_caps(self) -> dict:
-        """The engine's CONFIGURED capacities as a spec-fn caps dict."""
-        raise NotImplementedError
-
-    def _memory_extra(self) -> dict:
-        """Engine-shape annotations for the ledger snapshot."""
-        return {}
+    # -- memory ledger (telemetry/memory.py; the engine gives the specs) -----
 
     def _analytic_footprint_bytes(self, caps: Optional[dict] = None):
         """Total analytic bytes of the device-resident carry at ``caps``
@@ -491,11 +480,6 @@ class WavefrontChecker(Checker):
             total,
             warn_once_obj=self.model,
         )
-
-    def _roofline_cost_fn(self):
-        """Zero-arg ``() -> CostReport | None`` analytic cost model at
-        this engine's capacities; engine-specific."""
-        raise NotImplementedError
 
     def roofline(self, live: bool = True) -> Optional[dict]:
         """Latest roofline-ledger block (``telemetry/roofline.py``), or
@@ -543,7 +527,7 @@ class WavefrontChecker(Checker):
             np.uint64,
         )
 
-    _engine_tag = "single"  # overridden by the sharded engine
+    _engine_tag = "single"  # "mesh" and "sweep" override it
 
     def _check_snapshot_sig(self, snap: dict) -> None:
         tag = str(snap.get("engine", "single"))
@@ -909,13 +893,6 @@ class WavefrontChecker(Checker):
         the sum of the ``dsteps`` of this run's ``step`` records.  Exact,
         and the same for every run of one model at one set of capacities."""
         return self._device_steps
-
-    def _table_np(self):
-        """(fingerprints, payloads) of the visited table as numpy arrays."""
-        return (
-            np.asarray(self._results["table_fp"]),
-            np.asarray(self._results["table_parent"]),
-        )
 
     def occupancy_stats(self) -> Optional[dict]:
         """Bucket-occupancy counters of the visited table

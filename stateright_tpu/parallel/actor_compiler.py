@@ -1405,7 +1405,7 @@ class CompiledActorTensor(TensorModel):
         )
 
     def init_rows(self) -> np.ndarray:
-        # Both engines call init_rows() host-side while BUILDING a run, so
+        # The engines call init_rows() host-side while BUILDING a run, so
         # this is the last guaranteed outside-any-trace moment: populate the
         # device-constant cache here.  A lazy first touch from inside a
         # traced step would memoize trace-local tracers, and any later trace

@@ -1,14 +1,10 @@
-"""Mesh-native sharded wavefront — GSPMD partitioning of the wavefront
-engine over a named ``('host', 'chip')`` mesh.
+"""The multi-device engine — GSPMD partitioning of the wavefront engine
+over a named ``('host', 'chip')`` mesh.
 
-The old sharded engine (``sharded.py``) hand-schedules the scale-out: a
-``shard_map`` body routes candidates to their owner with an explicit
-``lax.all_to_all`` and marks per-device values with vma casts
-(``jax.lax.pcast``).  This engine inverts the responsibility: the *global* wavefront program (``wavefront.py``,
-unchanged — same jaxprs, same counters, same discovery rule) is handed
-to the compiler with the carry's placement expressed as
-``NamedSharding`` partition rules (``parallel/partition.py``), and GSPMD
-inserts the collectives:
+The *global* wavefront program (``wavefront.py``, unchanged — same
+jaxprs, same counters, same discovery rule) is handed to the compiler
+with the carry's placement expressed as ``NamedSharding`` partition
+rules (``parallel/partition.py``), and GSPMD inserts the collectives:
 
  - The visited table shards by bucket owner.  Table positions are
    ``bucket * SLOTS + slot`` and a ``P(('host','chip'))`` sharding of
@@ -24,17 +20,18 @@ inserts the collectives:
 
 Because the program is the single-device engine's own, parity with it is
 by construction: counts, verdicts, discovery traces, and kill+resume
-snapshots are bit-identical (pinned by tests/test_mesh.py).  Zero
-``shard_map``/``pcast`` references: every collective is the compiler's.
+snapshots are bit-identical (pinned by tests/test_mesh.py).  No
+collective is written by hand anywhere in the package (held by
+``test_no_second_engine``): every one is the compiler's.
 
 Host-loop mechanics are inherited unchanged: growth, checkpointing, and
 resume round-trip the carry through host numpy; re-entry as plain numpy
 is fine because ``jax.jit``'s ``in_shardings`` re-shards inputs on the
-way in.  Multi-host (``jax.distributed``) runs share the axis names —
-each process contributes one ``host`` row — but the single-controller
-host loop can only pull *replicated* values there, so growth,
-checkpoint, and trace reconstruction require a fully addressable mesh
-today (pre-size ``capacity=`` on multi-host; docs/mesh.md).
+way in.  The host loop is one controller's: it pulls the carry for
+growth, checkpoints and trace reconstruction, so the mesh must be fully
+addressable from this process (``_pre_run_validate`` refuses one that is
+not; a ``jax.distributed`` run would need a process-spanning host loop,
+docs/mesh.md "Multi-host").
 
 The spill tier stays single-device (the inherited ``_init_common``
 rejection), and ``pallas=True`` is rejected — the Pallas insert kernel
@@ -65,10 +62,10 @@ from .wavefront import TpuChecker, _carry_avals
 class MeshTpuChecker(TpuChecker):
     """Wavefront BFS partitioned over a named device mesh.
 
-    Spelled ``CheckerBuilder.mesh()`` / ``--mesh`` /
-    ``STATERIGHT_TPU_MESH=1`` (the old engine keeps the
-    ``devices=``/``n_devices=``/``mesh=`` spawn kwargs).  Everything but
-    placement is the single-device engine."""
+    What ``spawn_tpu`` returns whenever more than one device is asked
+    for (``CheckerBuilder._mesh_request``: ``devices=N``, ``n_devices=``,
+    ``mesh=``, ``.mesh()``, ``--mesh``, ``STATERIGHT_TPU_MESH``).
+    Everything but placement is the single-device engine."""
 
     _engine_tag = "mesh"
 
@@ -108,12 +105,9 @@ class MeshTpuChecker(TpuChecker):
             ("mesh",) + tuple(d.id for d in self._mesh.devices.flat),
         )
 
-    def _carry_shardings(self, cap, qcap, batch):
-        avals = _carry_avals(
-            self.tensor, len(self._props), cap, qcap, batch,
-            self._checked, self._cartography, self._por,
-            self._spill_cfg if self._spill else None,
-        )
+    def _place(self, avals):
+        """One ``NamedSharding`` per carry buffer, by the partition rules
+        (``avals``: anything with the carry's shapes, in carry order)."""
         names = wavefront_carry_names(
             len(avals), checked=self._checked, por=self._por,
             spill=bool(self._spill),
@@ -121,6 +115,36 @@ class MeshTpuChecker(TpuChecker):
         return match_partition_rules(
             WAVEFRONT_CARRY_RULES, names, avals, self._mesh
         )
+
+    def _carry_shardings(self, cap, qcap, batch):
+        return self._place(_carry_avals(
+            self.tensor, len(self._props), cap, qcap, batch,
+            self._checked, self._cartography, self._por,
+            self._spill_cfg if self._spill else None,
+        ))
+
+    def _memory_spec_fn(self):
+        """The wavefront carry's own specs, each with the sharding the
+        rules give it: ``per_device_bytes`` is read off the shard shapes,
+        so it cannot drift from ``partition.py`` (telemetry/memory.py)."""
+        from ..telemetry.memory import BufferSpec
+
+        base = super()._memory_spec_fn()
+
+        def spec_fn(caps):
+            specs = base(caps)
+            placed = self._place(
+                [jax.ShapeDtypeStruct(s.shape, s.dtype) for s in specs]
+            )
+            return [
+                BufferSpec(s.name, s.shape, s.dtype, sh)
+                for s, sh in zip(specs, placed)
+            ]
+
+        return spec_fn
+
+    def _memory_extra(self) -> dict:
+        return {**super()._memory_extra(), "devices": self.n_devices}
 
     def _build(self, cap, qcap, batch, cand):
         """The single-device engine's own programs, re-jitted with the
@@ -144,19 +168,19 @@ class MeshTpuChecker(TpuChecker):
         local = {d.id for d in jax.local_devices()}
         if not all(d.id in local for d in self._mesh.devices.flat):
             raise NotImplementedError(
-                "the mesh spans processes this controller cannot address: "
-                "multi-host growth/checkpointing needs a process-spanning "
-                "host loop (docs/mesh.md 'Multi-host'); pre-size "
-                "capacity= and run one controller per pod slice for now"
+                "the mesh holds devices this process cannot address: the "
+                "host loop pulls the carry for growth, checkpoints and "
+                "paths, so the mesh engine runs one process's devices "
+                "(docs/mesh.md 'Multi-host')"
             )
 
-    # -- per-shard load / routing imbalance (the A/B readout) ----------------
+    # -- per-shard load / routing imbalance ----------------------------------
 
     def mesh_stats(self) -> Optional[dict]:
         """Per-shard visited-table load, the parent-owner -> child-owner
         routing matrix, and the imbalance summary
-        (``ops/cartography.shard_imbalance``) — the measurable A/B
-        against the old engine.  None while the run is in flight.
+        (``ops/cartography.shard_imbalance``).  None while the run is
+        in flight.
 
         Ownership is derived from the final table exactly as the
         partition rules place it: position ``p`` belongs to shard
@@ -202,8 +226,7 @@ class MeshTpuChecker(TpuChecker):
     def _run_impl(self):
         super()._run_impl()
         # the imbalance readout rides the results + the cartography block
-        # (ops/cartography.snapshot key names: shard_load/shard_imbalance/
-        # route_matrix — same keys the old engine emits there)
+        # (keys shard_load / shard_imbalance / route_matrix)
         try:
             stats = self.mesh_stats() if self._results is not None else None
         except Exception:  # noqa: BLE001 - a readout must never fail a run

@@ -4,12 +4,10 @@ One place for everything the GSPMD-partitioned wavefront needs to say
 about *placement* (``parallel/mesh.py`` says nothing — it only applies
 what this module decides):
 
- - :data:`MESH_AXES` — the ``('host', 'chip')`` axis pair.  Today a
-   single process builds a ``1 x N`` mesh over its local devices;
-   launched under ``jax.distributed`` each process contributes its local
-   devices as one row, so the same axis names scale to DCN x ICI without
-   touching the partition rules (everything below shards over the
-   *flattened* pair).
+ - :data:`MESH_AXES` — the ``('host', 'chip')`` axis pair.  One process
+   builds a ``1 x N`` mesh over its local devices; the ``host`` axis is
+   there so that the same names scale to DCN x ICI without touching the
+   partition rules (everything below shards over the *flattened* pair).
  - :func:`build_mesh` — the one constructor both the checker and the
    tests use.
  - :func:`match_partition_rules` — the regex-rule matcher (the
@@ -81,29 +79,11 @@ def resolve_mesh_flag(mode, devices):
 
 # -- mesh construction -------------------------------------------------------
 
-def build_mesh(n_devices: Optional[int] = None, devices=None) -> Mesh:
-    """The named ``('host', 'chip')`` mesh the engine partitions over.
-
-    Single process (the default): ``1 x N`` over the first ``n_devices``
-    local devices (all of them when unset).  Under ``jax.distributed``
-    (``jax.process_count() > 1``) every process contributes its local
-    devices as one ``host`` row — ``n_devices`` then bounds the per-host
-    chip count.  An explicit ``devices`` sequence wins outright (tests
-    build deliberate sub-meshes with it)."""
-    if devices is not None:
-        devs = list(devices)
-        return Mesh(np.asarray(devs).reshape(1, len(devs)), MESH_AXES)
-    procs = jax.process_count()
-    if procs > 1:
-        all_devs = jax.devices()
-        per_host = len(all_devs) // procs
-        if n_devices is not None:
-            per_host = min(per_host, int(n_devices))
-        grid = np.asarray(all_devs[: procs * per_host]).reshape(
-            procs, per_host
-        )
-        return Mesh(grid, MESH_AXES)
-    devs = jax.devices()
+def build_mesh(n_devices: Optional[int] = None) -> Mesh:
+    """The named ``('host', 'chip')`` mesh the engine partitions over:
+    ``1 x N`` over the first ``n_devices`` local devices (all of them
+    when unset)."""
+    devs = jax.local_devices()
     if n_devices is not None:
         if int(n_devices) > len(devs):
             raise ValueError(

@@ -98,8 +98,7 @@ _STATUS_SPILL_SYNC = 5  # spill tier: pending buffer near-full, the host
 #                         must resolve it against the host index
 
 # growth-record names for the flight recorder, keyed on THIS engine's
-# status words (telemetry.STATUS_NAMES is the cross-engine vocabulary;
-# the sharded engine numbers its codes differently and keeps its own map)
+# status words (telemetry.STATUS_NAMES is the recorder's vocabulary)
 _STATUS_TELEMETRY_NAMES = {
     _STATUS_OK: "ok",
     _STATUS_QUEUE_FULL: "queue_full",
@@ -1027,8 +1026,7 @@ class TpuChecker(WavefrontChecker):
     tile-granularity DMA read-modify-write must lose at
     ~1-candidate-per-block density).  The bench A/B measures both on
     every run and reports whichever path wins (``bench.py``).
-    Single-device engine only: the sharded engine has its own insert and
-    rejects ``pallas=True``.
+    Single-device engine only: the mesh engine rejects ``pallas=True``.
     """
 
     def __init__(

@@ -118,10 +118,9 @@ class SupervisedRun:
 
 
 def _spill_applicable(builder, spawn_kw: dict) -> bool:
-    """Can the PR 8 spill tier be armed for this run?  Wavefront engine
-    only (no devices/mesh), and mutually exclusive with POR."""
-    if spawn_kw.get("devices") or spawn_kw.get("n_devices") or \
-            spawn_kw.get("mesh") is not None:
+    """Can the PR 8 spill tier be armed for this run?  One device only
+    (``spawn_tpu``'s own rule), and mutually exclusive with POR."""
+    if builder._mesh_request(spawn_kw) is not None:
         return False
     if getattr(builder, "por_mode", None):
         return False
@@ -389,7 +388,7 @@ def _degrade_for_oom(
             f"spill_armed(budget={pinned[0]})" if pinned else "spill_armed"
         )
         return event, None, pinned
-    # spill cannot apply (sharded / POR / already armed): shrink the
+    # spill cannot apply (mesh / POR / already armed): shrink the
     # expansion batch once — halving it halves the per-step candidate
     # windows and queue slack (the per-batch share of the transient)
     cur = None

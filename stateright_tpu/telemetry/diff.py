@@ -23,7 +23,7 @@ table is the single place the diff engine encodes them):
  - *observability* (``telemetry``/``cartography``/``memory``/
    ``roofline``): bit-identical counts; blocks may appear/disappear.
  - *identical* (``checked``/``prededup``/``spill``, and an engine
-   delta — wavefront/sharded/host parity is pinned): bit-identical
+   delta — wavefront/mesh/host parity is pinned): bit-identical
    counts and verdicts.
  - *isomorphic* (``por``/``symmetry``, and an ``encoding`` delta):
    identical verdicts, explored counts may shrink (a reduction that

@@ -133,8 +133,7 @@ class HealthTracker:
         phase = self._classify(d_states, d_unique)
 
         # -- stall detection ------------------------------------------------
-        # engines without a cheap frontier *count* (sharded: only a
-        # replicated keep-going flag crosses to the host) send ``busy``
+        # an engine without a cheap frontier *count* sends ``busy``
         # explicitly; otherwise an empty queue is completion-shaped
         flag = rec.get("busy")
         if flag is not None:

@@ -19,7 +19,7 @@ Two reconciling views, deliberately separated:
    derived from the engine's own ``_carry_avals`` (the same signature the
    prewarm AOT path compiles against, so agreement is already pinned),
    and ``tests/test_memory.py`` pins analytic bytes == the live engine
-   buffers' ``nbytes`` EXACTLY on both engines.
+   buffers' ``nbytes`` EXACTLY, on one device and on a mesh.
  - **Live device readings** — ``device.memory_stats()`` bytes/peak where
    the backend supports them (TPU; CPU returns nothing and every
    consumer degrades to the analytic path), and
@@ -80,18 +80,26 @@ GROWTH_LOAD_DENOM = 4
 
 
 class BufferSpec:
-    """One device-resident buffer: name, shape, dtype, exact bytes."""
+    """One device-resident buffer: name, shape, dtype, exact bytes — and
+    the bytes ONE device holds of it, which differ from ``nbytes`` only
+    where ``sharding`` (the buffer's own ``NamedSharding`` on the mesh
+    engine) splits it."""
 
-    __slots__ = ("name", "shape", "dtype", "nbytes")
+    __slots__ = ("name", "shape", "dtype", "nbytes", "per_device_nbytes")
 
-    def __init__(self, name: str, shape: tuple, dtype) -> None:
+    def __init__(self, name: str, shape: tuple, dtype, sharding=None) -> None:
         self.name = name
         self.shape = tuple(int(d) for d in shape)
         self.dtype = np.dtype(dtype)
-        n = 1
-        for d in self.shape:
-            n *= d
-        self.nbytes = int(n * self.dtype.itemsize)
+
+        def nbytes(shape) -> int:
+            return int(np.prod(shape, dtype=np.int64)) * self.dtype.itemsize
+
+        self.nbytes = nbytes(self.shape)
+        self.per_device_nbytes = (
+            self.nbytes if sharding is None
+            else nbytes(sharding.shard_shape(self.shape))
+        )
 
     def __repr__(self) -> str:  # debugging ergonomics only
         return (
@@ -125,7 +133,7 @@ def wavefront_specs(
     *, checked: bool = False, cartography: bool = False, por: bool = False,
     spill=None,
 ) -> list:
-    """Per-buffer specs of the single-device wavefront carry at these
+    """Per-buffer specs of the wavefront carry at these
     capacities — derived from the engine's own abstract carry signature
     (``wavefront._carry_avals``, the prewarm-AOT contract), so the
     analytic bytes reconcile EXACTLY against the live buffers' nbytes.
@@ -133,7 +141,8 @@ def wavefront_specs(
     the tier is armed: the Bloom filter and pending buffers are
     device-resident and count against the budget like any carry buffer
     (the HOST/DISK tier contents deliberately do not — they are what the
-    budget is being traded against)."""
+    budget is being traded against).  The mesh engine places the same
+    specs (``MeshTpuChecker._memory_spec_fn``)."""
     from ..parallel.wavefront import _carry_avals
 
     avals = _carry_avals(
@@ -155,64 +164,6 @@ def wavefront_specs(
     return [
         BufferSpec(n, a.shape, a.dtype) for n, a in zip(names, avals)
     ]
-
-
-def sharded_specs(
-    width: int, arity: int, n_props: int, ndev: int,
-    cap_local: int, fcap_local: int,
-    *, cartography: bool = False, por: bool = False,
-) -> list:
-    """Per-buffer specs of the sharded engine's GLOBAL carry (logical
-    array shapes — what ``np.asarray(carry[i]).nbytes`` reports; the
-    per-device planning view divides the sharded buffers by ``ndev`` and
-    counts replicated ones in full, see :func:`sharded_per_device_bytes`).
-    Must mirror ``sharded.device_init``'s output exactly (pinned by the
-    exactness test)."""
-    p = max(n_props, 1)
-    specs = [
-        BufferSpec("table_fp", (ndev * cap_local,), np.uint64),
-        BufferSpec("table_parent", (ndev * cap_local,), np.uint64),
-        BufferSpec("rows", (ndev * fcap_local, width), np.uint64),
-        BufferSpec("fps", (ndev * fcap_local,), np.uint64),
-        BufferSpec("ebits", (ndev * fcap_local,), np.uint32),
-        BufferSpec("unique", (), np.int64),
-        BufferSpec("scount", (), np.int64),
-        BufferSpec("disc", (p,), np.uint64),
-        BufferSpec("depth", (), np.int32),
-        BufferSpec("status", (), np.int32),
-    ]
-    if por:
-        specs += [
-            BufferSpec("por_boost", (), np.int32),
-            BufferSpec("por_stats", (3,), np.int64),
-        ]
-    if cartography:
-        from ..ops.cartography import DEPTH_BINS
-
-        specs += [
-            BufferSpec("cart_depth_hist", (DEPTH_BINS,), np.int64),
-            BufferSpec("cart_action_hist", (max(arity, 1),), np.int64),
-            BufferSpec("cart_prop_evals", (p,), np.int64),
-            BufferSpec("cart_prop_hits", (p,), np.int64),
-            BufferSpec("cart_shard_load", (ndev,), np.int64),
-            BufferSpec("cart_route_matrix", (ndev, ndev), np.int64),
-        ]
-    return specs
-
-
-_SHARDED_LOCAL = frozenset(
-    {"table_fp", "table_parent", "rows", "fps", "ebits", "cart_shard_load",
-     "cart_route_matrix"}
-)
-
-
-def sharded_per_device_bytes(specs: list, ndev: int) -> int:
-    """HBM-per-chip view of a sharded footprint: sharded buffers divide
-    over the mesh, replicated ones are resident in full on every chip."""
-    out = 0
-    for s in specs:
-        out += s.nbytes // ndev if s.name in _SHARDED_LOCAL else s.nbytes
-    return int(out)
 
 
 # -- live device readings ----------------------------------------------------
@@ -416,7 +367,7 @@ class MemoryLedger:
         self.recorder = recorder
         self.every = int(every)
         # engine-shape annotations for the snapshot (queue_capacity /
-        # frontier_capacity / devices), refreshed per observe
+        # devices), refreshed per observe
         self.extra = dict(extra or {})
         self._caps: Optional[dict] = None
         self._snap: Optional[dict] = None
@@ -493,7 +444,7 @@ class MemoryLedger:
         return {
             k: snap[k]
             for k in ("v", "engine", "capacity", "queue_capacity",
-                      "frontier_capacity", "devices", "buffers",
+                      "devices", "buffers",
                       "total_bytes", "per_device_bytes", "next_rung")
             if k in snap
         }
@@ -511,9 +462,10 @@ class MemoryLedger:
             "total_bytes": total_bytes(specs),
             "next_rung": next_rung_block(self.spec_fn, caps),
         }
-        ndev = self.extra.get("devices")
-        if ndev:
-            snap["per_device_bytes"] = sharded_per_device_bytes(specs, ndev)
+        if self.extra.get("devices"):
+            snap["per_device_bytes"] = int(
+                sum(s.per_device_nbytes for s in specs)
+            )
         if self._budget is not None:
             snap["budget_bytes"] = self._budget
             snap["budget_src"] = self._budget_src

@@ -34,13 +34,11 @@ from typing import Callable, Optional
 
 from .health import HealthTracker
 
-# Growth-record status vocabulary across engines.  Each engine maps its
-# own numeric status words onto these names NEXT TO its constant
-# definitions (``parallel/wavefront.py`` and ``parallel/sharded.py`` number
-# their codes differently; the integers are never shared, only the names).
+# Growth-record status vocabulary.  An engine maps its own numeric status
+# words onto these names NEXT TO its constant definitions
+# (``parallel/wavefront.py``; the integers are never shared, only the names).
 STATUS_NAMES = frozenset({
-    "ok", "queue_full", "table_full", "cand_full", "poison",
-    "frontier_full", "bucket_full", "spill_sync",
+    "ok", "queue_full", "table_full", "cand_full", "poison", "spill_sync",
 })
 
 
@@ -416,8 +414,7 @@ class FlightRecorder:
         (``stateright_tpu/checkpoint.py`` autosave status + supervised
         restart count; docs/robustness.md) — the outside-the-ring
         discipline of the other feature blocks.  ``None`` clears it
-        (autosave disarmed after arming, e.g. the sharded engine's
-        multi-controller fence)."""
+        (autosave disarmed after arming)."""
         with self._lock:
             self._durability = dict(snap) if snap else None
 

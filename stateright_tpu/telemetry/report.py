@@ -426,11 +426,6 @@ def render_markdown(report: dict, rec=None, roofline_live=None) -> str:
                 f"- shard imbalance: max={imb['max']} mean={imb['mean']} "
                 f"ratio={imb['ratio']} (1.0 = balanced)"
             )
-        if cart.get("routed_candidates") is not None:
-            lines.append(
-                f"- all-to-all routed candidates: "
-                f"{cart['routed_candidates']}"
-            )
     mem = report.get("memory")
     if mem:
         from .memory import fmt_bytes

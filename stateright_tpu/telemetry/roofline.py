@@ -190,12 +190,9 @@ def achieved_block(
 ) -> Optional[dict]:
     """Achieved-vs-ceiling estimate from the PR-4 wall-clock attribution:
     per-step analytic bytes/FLOPs x the estimated device-step count
-    over the attributed device seconds.  The static costs price ONE
-    device's kernels per lockstep step, so the whole block is the
-    PER-CHIP view: a sharded run pops ``batch x devices`` rows per
-    lockstep step (``devices`` from the static block; 1 on the
-    wavefront engine), and the resulting per-chip bytes/s compares
-    against one chip's HBM ceiling.  An estimate by construction
+    over the attributed device seconds.  The static costs price the
+    whole step program (a mesh run's step is the same program, its
+    ``batch`` rows spread over the devices).  An estimate by construction
     (growth replays and property-hit early exits shift it a few
     percent), which is why it lives in the live/markdown surfaces,
     never the deterministic report body."""
@@ -204,8 +201,7 @@ def achieved_block(
     dev_secs = stages_secs.get("device_secs")
     if not dev_secs or dev_secs <= 0 or batch <= 0 or unique <= 0:
         return None
-    rows_per_step = int(batch) * max(int(static.get("devices", 1) or 1), 1)
-    steps = max((int(unique) + rows_per_step - 1) // rows_per_step, 1)
+    steps = max((int(unique) + int(batch) - 1) // int(batch), 1)
     totals = static.get("totals") or {}
     bts, fls = totals.get("bytes"), totals.get("flops")
     if not bts:
@@ -230,8 +226,8 @@ class RooflineLedger:
     """Host-side roofline accounting for one engine run.
 
     ``cost_fn() -> CostReport | None`` is the engine's analytic model
-    (``costmodel.wavefront_costs`` / ``sharded_costs`` at the run's
-    capacities, cached on the twin).  Built once at spawn — re-tracing
+    (``costmodel.wavefront_costs`` at the run's capacities, cached on
+    the twin); ``engine`` names the engine that runs it in the block.  Built once at spawn — re-tracing
     the pipeline kernels plus one small XLA compile per stage for the
     reconciliation — and pushed into the flight recorder as the
     versioned ``roofline`` ring record + live snapshot.  Zero device
@@ -249,7 +245,9 @@ class RooflineLedger:
         except Exception:  # noqa: BLE001 - accounting must never break
             self._report = None  # a run (the memory-ledger discipline)
         if self._report is not None:
-            self._static = self._report.static_block()
+            self._static = {
+                **self._report.static_block(), "engine": engine,
+            }
             self._recon = self._report.recon_block()
             if recorder is not None:
                 recorder.set_roofline(self.snapshot())
@@ -300,7 +298,7 @@ class RooflineLedger:
         """snapshot() + the achieved-vs-ceiling estimate once wall-clock
         attribution exists (``checker.roofline()``'s default view).
         ``batch`` defaults to the static block's own (the engine's
-        expansion width — the sharded engine's per-device frontier)."""
+        expansion width)."""
         snap = self.snapshot()
         if snap is None:
             return None

@@ -16,8 +16,7 @@ check), and each unit of work inside it is a **span** with a fresh
             │                     ``device_call`` (``dispatch``, ``wait``),
             │                     ``grow`` (``grow.pull`` / ``.rehash`` /
             │                     ``.queue`` / ``.push``), ``autosave``,
-            │                     ``checkpoint.pull``, ``spill_drain``,
-            │                     ``resharding``
+            │                     ``checkpoint.pull``, ``spill_drain``
             └── job ...
 
 (Two seams run before an engine's run span opens and are parentless in

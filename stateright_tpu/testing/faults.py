@@ -11,7 +11,7 @@ decides, deterministically, whether this occurrence fails.
 
 Sites (the seams wired in this package):
 
- - ``host_sync``       — every device-engine host sync (wavefront + sharded)
+ - ``host_sync``       — every device-engine host sync
  - ``growth``          — every growth boundary (device engines)
  - ``spill_flush``     — a :class:`~stateright_tpu.spill.SpillStore` disk
    segment flush

@@ -136,7 +136,7 @@ def test_single_copy_two_servers_tpu_finds_violation():
     assert not final.history.is_consistent()
 
 
-def test_single_copy_sharded_matches():
+def test_single_copy_mesh_matches():
     m = single_copy_model(2, 1)
     t = m.checker().spawn_tpu(
         devices=8, sync=True, capacity=1 << 10, frontier_capacity=1 << 7
@@ -242,7 +242,7 @@ def test_wo_rejects_put2():
         compile_actor_model(m)
 
 
-def test_abd_sharded_matches():
+def test_abd_mesh_matches():
     m = abd_model(2, 2)
     t = m.checker().spawn_tpu(
         devices=8, sync=True, capacity=1 << 12, frontier_capacity=1 << 9

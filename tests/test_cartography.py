@@ -177,74 +177,48 @@ def test_dining_reconciles_and_fills_action_histogram():
     assert fired < len(cart["action_hist"])
 
 
-# -- sharded engine ----------------------------------------------------------
+# -- mesh engine --------------------------------------------------------------
 
 
-def test_sharded_cartography_counts_and_shard_extras():
+def test_mesh_cartography_counts_and_shard_extras():
     c = TwoPhaseSys(3).checker().telemetry(cartography=True).spawn_tpu(
         sync=True, devices=2, capacity=1 << 12, frontier_capacity=1 << 9
     )
     cart = _reconcile(c)
-    # shard-local extras: per-shard fresh inserts sum to unique, the
-    # routed-candidate matrix is 2x2 and covers at least the non-init
-    # unique states (every fresh insert arrived through the all-to-all)
+    # shard extras, read off the final table: per-shard loads sum to
+    # unique, the parent-owner -> child-owner matrix is 2x2 and covers
+    # every non-init unique state
     assert sum(cart["shard_load"]) == TPC3_UNIQUE
     assert len(cart["route_matrix"]) == 2
     assert all(len(row) == 2 for row in cart["route_matrix"])
-    assert cart["routed_candidates"] >= TPC3_UNIQUE - 1
+    assert sum(map(sum, cart["route_matrix"])) == TPC3_UNIQUE - 1
     imb = cart["shard_imbalance"]
     assert imb["ratio"] >= 1.0
     assert imb["max"] >= imb["mean"]
 
 
-def test_sharded_resume_preserves_cartography_counters():
-    """The sharded counter tail is cumulative IN-CARRY, so snapshots must
-    persist it: a resumed run re-seeded with zeros pairs restarted
-    histograms with total-derived fresh_inserts and breaks
-    ``sum(depth_hist) == unique`` (regression)."""
-    c = TwoPhaseSys(3).checker().telemetry(cartography=True).spawn_tpu(
-        sync=True, devices=2, capacity=1 << 12, frontier_capacity=1 << 9
-    )
+def test_mesh_resume_keeps_depth_histogram_and_shard_extras():
+    """Resume on the mesh keeps what the one-chip engine keeps: the
+    depth histogram is queue-derived and comes back COMPLETE (the
+    snapshot kept the queue), the banked lanes of a pre-snapshot growth
+    included; the shard extras are read off the final table, so they
+    cover every state whichever run inserted it.  (The per-step tallies
+    restart at zero on a resume, by the engine's own rule.)"""
+    kw = dict(sync=True, devices=2, batch=32, queue_capacity=64,
+              capacity=1 << 12)
+    c = TwoPhaseSys(3).checker().telemetry(cartography=True).spawn_tpu(**kw)
     assert c.unique_state_count() == TPC3_UNIQUE
+    assert c.flight_recorder.records("growth"), "qcap=64 must grow"
     snap = c.checkpoint()
-    assert any(k.startswith("cart") for k in snap), (
-        "snapshot must carry the cartography counter tail"
-    )
+    assert int(np.asarray(snap["cart_depth_base"]).sum()) > 0
     r = TwoPhaseSys(3).checker().telemetry(cartography=True).spawn_tpu(
         sync=True, devices=2, resume=snap
     )
     assert r.unique_state_count() == TPC3_UNIQUE
-    _reconcile(r)
-
-
-def test_sharded_cartography_off_program_unchanged():
-    """Flag-off pin for the sharded engine: the whole-run program traced
-    with ``cartography=False`` is bit-identical to a build that never
-    mentions the flag (the default path every pre-cartography caller
-    takes), and the flag ON actually changes the program."""
-    import jax.numpy as jnp
-
-    from stateright_tpu.parallel.sharded import (
-        _build_sharded_run,
-        default_mesh,
-    )
-
-    m = TwoPhaseSys(3)
-    tensor = m._tensor_cached()
-    props = list(m.properties())
-    mesh = default_mesh(2)
-
-    def step_jaxpr(cartography):
-        kw = {} if cartography is None else {"cartography": cartography}
-        init_fn, step_fn = _build_sharded_run(
-            tensor, props, mesh, 1 << 11, 1 << 9, 1 << 10, None, **kw
-        )
-        out = init_fn()
-        carry = tuple(jnp.asarray(x) for x in out[:-1])
-        return str(jax.make_jaxpr(lambda *cr: step_fn(*cr))(*carry))
-
-    assert step_jaxpr(None) == step_jaxpr(False)
-    assert step_jaxpr(None) != step_jaxpr(True)
+    cart = r.cartography()
+    assert sum(cart["depth_hist"]) == TPC3_UNIQUE
+    assert sum(cart["shard_load"]) == TPC3_UNIQUE
+    assert cart["route_matrix"] == c.cartography()["route_matrix"]
 
 
 # -- health model ------------------------------------------------------------
@@ -324,8 +298,8 @@ def test_health_mark_done_closes_open_stall():
 
 
 def test_health_busy_flag_overrides_missing_queue():
-    """The sharded engine has no cheap frontier count (only the replicated
-    keep-going flag crosses to the host) and sends ``busy`` explicitly;
+    """An engine with no queue count to send (the thread pool) sends
+    ``busy`` explicitly;
     ``busy=False`` is completion-shaped even with no queue field, and
     ``busy=True`` arms the zero-novelty stall guard."""
     t = HealthTracker(stall_after=2)

@@ -12,7 +12,7 @@ factored properties — and requires, for every seed:
    fingerprint agreement, successor-set equality, property-mask
    agreement) via the same crawl used for the examples;
  - unique-count and discovery parity across spawn_bfs / spawn_dfs /
-   spawn_mp_bfs / spawn_tpu / the 8-device sharded engine.
+   spawn_mp_bfs / spawn_tpu / the 8-device mesh engine.
 
 Seeds are fixed, so failures reproduce exactly.
 """

@@ -87,18 +87,28 @@ def test_roofline_does_not_key_the_engine_cache():
     assert c2.unique_state_count() == c1.unique_state_count()
 
 
-def test_sharded_roofline_block_and_cache_identity():
-    """The sharded engine carries the model-kernel ledger (its insert /
-    all-to-all are the pod-scale round's work) under the same
-    cache-identity contract."""
+def test_mesh_roofline_block_and_cache_identity():
+    """A two-device run carries the wavefront program's ledger (it runs
+    that program; the collectives the compiler adds are not priced) and
+    names the mesh engine, under the same cache-identity contract."""
     m = TwoPhaseSys(3)
     c1 = (
         m.checker().telemetry(roofline=True)
         .spawn_tpu(sync=True, devices=2, capacity=1 << 12)
     )
     roof = c1.roofline()
-    assert roof is not None and roof["engine"] == "sharded"
-    assert set(roof["stages"]) == {"property", "expand", "hash"}
+    assert roof is not None and roof["engine"] == "mesh"
+    assert set(roof["stages"]) == set(
+        m.checker().telemetry(roofline=True)
+        .spawn_tpu(sync=True, capacity=1 << 12).roofline()["stages"]
+    )
+    n_keys = len(c1.tensor._run_cache)
+    c2 = m.checker().telemetry().spawn_tpu(
+        sync=True, devices=2, capacity=1 << 12
+    )
+    assert len(c2.tensor._run_cache) == n_keys
+    assert c2.roofline() is None
+    assert c2.unique_state_count() == c1.unique_state_count()
 
 
 # -- reconciliation (the acceptance-criteria pin) ----------------------------
@@ -286,16 +296,13 @@ def test_achieved_block_math():
     assert ach["est_device_steps"] == 3  # ceil(25 / 10)
     assert ach["bytes_per_sec"] == 1500.0
     assert ach["frac_of_hbm_ceiling"] == pytest.approx(0.0015)
-    # sharded: the static costs price ONE chip's kernels, and a mesh
-    # pops batch x devices rows per lockstep step — the per-chip view
-    # must divide the step estimate by the mesh, not inflate the
-    # achieved fraction ndev-fold
+    # a mesh run's step is the same program: its batch rows are spread
+    # over the devices, the step estimate does not change with the mesh
     ach = achieved_block(
-        {**static, "devices": 4}, spec, {"device_secs": 2.0},
-        unique=100, batch=10,
+        static, spec, {"device_secs": 2.0}, unique=100, batch=10,
     )
-    assert ach["est_device_steps"] == 3  # ceil(100 / (10 * 4))
-    assert ach["bytes_per_sec"] == 1500.0
+    assert ach["est_device_steps"] == 10
+    assert ach["bytes_per_sec"] == 5000.0
     # no attribution yet / no bytes: no achieved block, never a crash
     assert achieved_block(static, spec, None, 25, 10) is None
     assert achieved_block({"totals": {}}, spec,

@@ -534,10 +534,10 @@ def test_2pc7_por_counts_pinned_full_parity():
     assert st["rows_reduced"] == 0 and st["candidates_masked"] == 0
 
 
-# -- sharded engine -----------------------------------------------------------
+# -- mesh engine --------------------------------------------------------------
 
 
-def test_sharded_por_parity_and_reduction():
+def test_mesh_por_parity_and_reduction():
     a = TwoPhaseSys(3).checker().spawn_tpu(
         sync=True, devices=2, capacity=1 << 12, frontier_capacity=1 << 9
     )
@@ -558,30 +558,3 @@ def test_sharded_por_parity_and_reduction():
     assert wp.unique_state_count() < wf.unique_state_count()
     assert wp.state_count() < wf.state_count()
     assert sorted(wp.discoveries()) == ["w0 done"]
-
-
-def test_sharded_por_off_program_unchanged():
-    import jax.numpy as jnp
-
-    from stateright_tpu.parallel.sharded import (
-        _build_sharded_run,
-        default_mesh,
-    )
-
-    m = TwoPhaseSys(3)
-    tensor = m._tensor_cached()
-    props = list(m.properties())
-    mesh = default_mesh(2)
-
-    def step_jaxpr(por_plan_arg):
-        kw = {} if por_plan_arg == "absent" else {"por": por_plan_arg}
-        init_fn, step_fn = _build_sharded_run(
-            tensor, props, mesh, 1 << 11, 1 << 9, 1 << 10, None, **kw
-        )
-        out = init_fn()
-        carry = tuple(jnp.asarray(x) for x in out[:-1])
-        return str(jax.make_jaxpr(lambda *cr: step_fn(*cr))(*carry))
-
-    assert step_jaxpr("absent") == step_jaxpr(None)
-    plan = por_plan(tensor, props)
-    assert step_jaxpr("absent") != step_jaxpr(plan)

@@ -71,9 +71,10 @@ def test_wavefront_analytic_bytes_reconcile_exactly():
     assert snap["buffers"] == {s.name: s.nbytes for s in specs}
 
 
-def test_sharded_analytic_bytes_reconcile_exactly():
+def test_mesh_analytic_bytes_reconcile_exactly():
     """Same exactness on the mesh engine: the GLOBAL carry arrays'
-    nbytes equal the sharded analytic model per buffer."""
+    nbytes equal the analytic model per buffer, and the bytes one device
+    holds are read off each buffer's own sharding."""
     c = (
         TwoPhaseSys(3)
         .checker()
@@ -81,14 +82,21 @@ def test_sharded_analytic_bytes_reconcile_exactly():
         .spawn_tpu(sync=True, devices=2, capacity=1 << 12)
     )
     specs = c._memory_spec_fn()(c._memory_caps())
-    carry = c._final_state[0]
+    carry = c._final_carry
     assert len(specs) == len(carry)
     for s, arr in zip(specs, carry):
-        a = np.asarray(arr)
-        assert a.nbytes == s.nbytes, (s.name, a.nbytes, s.nbytes)
+        assert arr.nbytes == s.nbytes, (s.name, arr.nbytes, s.nbytes)
+        local = arr.addressable_shards[0].data.nbytes
+        assert local == s.per_device_nbytes, (s.name, local)
     snap = c.memory()
-    assert snap["devices"] == 2
-    assert snap["per_device_bytes"] <= snap["total_bytes"]
+    assert snap["engine"] == "mesh" and snap["devices"] == 2
+    assert snap["total_bytes"] == sum(s.nbytes for s in specs)
+    assert snap["per_device_bytes"] == sum(
+        s.per_device_nbytes for s in specs
+    )
+    # the table and the queue are split in two, the counters are not
+    assert snap["total_bytes"] // 2 < snap["per_device_bytes"]
+    assert snap["per_device_bytes"] < snap["total_bytes"]
 
 
 def test_exec_memory_analysis_agrees_with_the_analytic_carry():

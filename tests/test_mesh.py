@@ -1,5 +1,5 @@
-"""Mesh-native sharded engine (``parallel/mesh.py`` +
-``parallel/partition.py``; docs/mesh.md) — ISSUE 19 acceptance.
+"""The mesh engine (``parallel/mesh.py`` + ``parallel/partition.py``;
+docs/mesh.md): the one multi-device engine.
 
 The contracts pinned here, in the family's strongest form:
 
@@ -11,17 +11,25 @@ The contracts pinned here, in the family's strongest form:
  - growth preserves both the work AND the sharded placement;
  - the per-shard load / routing-matrix readout is well-formed and rides
    the results;
- - engine selection: ``.mesh()`` / ``--mesh`` / ``STATERIGHT_TPU_MESH``
-   arm THIS engine, the old spelling (``devices=``/``n_devices=``/
-   ``mesh=`` kwargs) stays the old engine, sweep x mesh is fenced;
+ - engine selection: every spelling of "more than one device"
+   (``devices=`` / ``n_devices=`` / ``mesh=`` / ``.mesh()`` / ``--mesh``
+   / ``STATERIGHT_TPU_MESH``) selects THIS engine, spellings that name
+   different widths are an error, sweep x mesh is fenced;
+ - the pinned 2pc spaces, shortest paths, growth, target counts, live
+   counters and resume refusals on the suite's 8-device CPU mesh (the
+   cases the deleted ``shard_map`` engine was held to);
  - the partition-rule matcher's guards (scalar, divisibility, no-match,
    flag/layout drift);
- - ZERO hand-written collectives in the mesh path (GSPMD inserts them).
+ - ZERO hand-written collectives anywhere in the package (GSPMD
+   inserts them), and no second engine to write them in.
 """
 
 from __future__ import annotations
 
 import ast
+import io
+import pathlib
+import time
 
 import numpy as np
 import pytest
@@ -45,6 +53,7 @@ from stateright_tpu.parallel.partition import (
 from stateright_tpu.parallel.wavefront import TpuChecker
 
 TPC3_UNIQUE, TPC3_TOTAL = 288, 1146
+TPC5_UNIQUE = 8832  # examples/2pc.rs:133
 PAXOS1_TOTAL, PAXOS1_UNIQUE = 482, 265
 
 
@@ -157,6 +166,9 @@ def test_mesh_growth_preserves_work_and_sharding():
     m = TwoPhaseSys(4)
     mesh = _mesh_spawn(m, capacity=1 << 9, batch=128)
     assert len(mesh.growth_events) >= 1
+    # growth never loses progress: unique is monotone across boundaries
+    uniq = [u for _, u in mesh.growth_events]
+    assert uniq == sorted(uniq) and all(u > 0 for u in uniq)
     ref = _solo_spawn(m, capacity=1 << 12, batch=128)
     assert mesh.unique_state_count() == ref.unique_state_count()
     assert mesh.state_count() == ref.state_count()
@@ -166,7 +178,161 @@ def test_mesh_growth_preserves_work_and_sharding():
     assert len(table.addressable_shards) == 8
 
 
-# -- the A/B readout ----------------------------------------------------------
+@pytest.mark.medium
+def test_mesh_growth_boundary_and_npz_resume():
+    """A snapshot carrying a growth-boundary flag (status != OK) must grow
+    on resume, on the mesh too (the grown carry re-enters sharded), and a
+    snapshot survives a real savez/load round trip.  The boundary
+    statuses are forced so the test is deterministic."""
+    kw = dict(devices=2, capacity=1 << 13, batch=128, steps_per_call=1)
+    running = TwoPhaseSys(5).checker().spawn_tpu(**kw)
+    snap = running.checkpoint(timeout=120.0)
+    running.stop().join()
+    assert 0 < int(snap["unique"]) < TPC5_UNIQUE, "checkpoint was not mid-run"
+    buf = io.BytesIO()
+    np.savez(buf, **snap)
+    buf.seek(0)
+    loaded = dict(np.load(buf, allow_pickle=False))
+    for status in (0, 2, 1):  # as taken, _STATUS_TABLE_FULL, _QUEUE_FULL
+        s = dict(loaded)
+        s["status"] = np.int32(status)
+        resumed = TwoPhaseSys(5).checker().spawn_tpu(
+            sync=True, resume=s, **kw
+        )
+        assert resumed.unique_state_count() == TPC5_UNIQUE
+        resumed.assert_properties()
+
+
+# -- the cases the deleted shard_map engine was held to -----------------------
+
+
+def test_build_mesh_takes_all_devices():
+    mesh = build_mesh()
+    assert mesh.size == len(jax.devices()) == 8
+    assert dict(mesh.shape) == {"host": 1, "chip": 8}
+
+
+@pytest.mark.parametrize("n,expected", [(3, 288), (5, TPC5_UNIQUE)])
+def test_mesh_2pc_pinned_counts(n, expected):
+    sys = TwoPhaseSys(n)
+    checker = sys.checker().spawn_tpu(devices=8, sync=True)
+    assert isinstance(checker, MeshTpuChecker)
+    assert checker.unique_state_count() == expected
+    cpu = sys.checker().spawn_bfs().join()
+    assert cpu.unique_state_count() == expected
+    assert checker.state_count() == cpu.state_count()
+    assert set(checker.discoveries()) == set(cpu.discoveries()) == {
+        "abort agreement",
+        "commit agreement",
+    }
+    checker.assert_properties()
+
+
+def test_mesh_discovery_paths_are_valid_and_shortest():
+    sys = TwoPhaseSys(3)
+    checker = sys.checker().spawn_tpu(devices=8, sync=True)
+    cpu = sys.checker().spawn_bfs().join()  # single-thread BFS: shortest paths
+    for name in ("abort agreement", "commit agreement"):
+        path = checker.discovery(name)
+        cond = sys.property_by_name(name).condition
+        assert cond(sys, path.final_state())
+        # level-synchronous wavefront => shortest witness, like 1-thread BFS
+        assert len(path) == len(cpu.discovery(name))
+
+
+def test_mesh_capacity_overflow_grows():
+    sys = TwoPhaseSys(3)
+    checker = sys.checker().spawn_tpu(
+        devices=8, sync=True, capacity=1 << 8, frontier_capacity=1 << 5
+    )
+    assert checker.growth_events, "capacities were too generous to grow"
+    assert checker.unique_state_count() == 288
+    checker.assert_properties()
+
+
+def test_mesh_target_state_count():
+    sys = TwoPhaseSys(5)
+    checker = sys.checker().target_states(1000).spawn_tpu(
+        devices=8, sync=True, frontier_capacity=1 << 7
+    )
+    assert 1000 <= checker.unique_state_count() < TPC5_UNIQUE
+
+
+def test_mesh_matches_single_device_table_contents():
+    """Every fingerprint the single-device engine visits is in the mesh
+    engine's table with the SAME parent (one program: no tie-break can
+    differ), and every parent is itself visited or the init marker."""
+    sys = TwoPhaseSys(3)
+    single = sys.checker().spawn_tpu(sync=True)
+    mesh = sys.checker().spawn_tpu(devices=8, sync=True)
+    assert single._parents() == mesh._parents()
+    visited = set(mesh._parents())
+    for parent in mesh._parents().values():
+        assert parent == 0 or parent in visited
+
+
+def test_mesh_on_two_devices():
+    checker = TwoPhaseSys(3).checker().spawn_tpu(devices=2, sync=True)
+    assert checker.n_devices == 2
+    assert checker.unique_state_count() == 288
+
+
+def test_mesh_live_progress_counters():
+    """The chunked host loop surfaces live counters mid-run."""
+    checker = TwoPhaseSys(5).checker().spawn_tpu(
+        devices=8, capacity=1 << 17, frontier_capacity=1 << 7,
+        steps_per_call=1,
+    )
+    samples = []
+    while not checker.is_done():
+        samples.append(checker.unique_state_count())
+        time.sleep(0.05)
+    checker.join()
+    assert checker.unique_state_count() == TPC5_UNIQUE
+    # monotone live counters (no overflow restart at these capacities)
+    assert samples == sorted(samples)
+
+
+def test_mesh_resume_rejects_other_model_or_engine():
+    kw = dict(devices=8, capacity=1 << 13, frontier_capacity=1 << 9)
+    c = TwoPhaseSys(3).checker().spawn_tpu(sync=True, **kw)
+    snap = c.checkpoint()
+    with pytest.raises(ValueError, match="different model"):
+        TwoPhaseSys(4).checker().spawn_tpu(sync=True, resume=snap, **kw)
+    # cross-engine confusion is caught, both directions
+    with pytest.raises(ValueError, match="'mesh' engine; this is the 'single'"):
+        TwoPhaseSys(3).checker().spawn_tpu(sync=True, resume=snap)
+    single_snap = TwoPhaseSys(3).checker().spawn_tpu(sync=True).checkpoint()
+    with pytest.raises(ValueError, match="'single' engine; this is the 'mesh'"):
+        TwoPhaseSys(3).checker().spawn_tpu(sync=True, resume=single_snap, **kw)
+    # a snapshot the deleted shard_map engine took is refused by the same
+    # message (its carry had another layout)
+    old = dict(snap, engine=np.asarray("sharded"))
+    with pytest.raises(ValueError, match="'sharded' engine; this is the 'mesh'"):
+        TwoPhaseSys(3).checker().spawn_tpu(sync=True, resume=old, **kw)
+    # the carry is the GLOBAL one, so another mesh width is no other
+    # engine: the snapshot finishes on two devices at the same totals
+    r = TwoPhaseSys(3).checker().spawn_tpu(sync=True, devices=2, resume=snap)
+    assert r.n_devices == 2
+    assert r.unique_state_count() == c.unique_state_count() == 288
+    assert r.state_count() == c.state_count()
+
+
+def test_async_run_thread_error_surfaces_at_join(monkeypatch):
+    """A failure on the engine's run thread (here: the mesh engine's
+    build) must not vanish with the daemon thread: ``join()`` raises."""
+    def boom(self, *a, **k):
+        raise RuntimeError("build exploded")
+
+    monkeypatch.setattr(MeshTpuChecker, "_build", boom)
+    checker = TwoPhaseSys(3).checker().spawn_tpu(
+        sync=False, devices=8, capacity=1 << 13, frontier_capacity=1 << 9
+    )
+    with pytest.raises(RuntimeError, match="build exploded"):
+        checker.join()
+
+
+# -- per-shard load and routing -----------------------------------------------
 
 
 def test_mesh_stats_well_formed_and_in_results():
@@ -241,24 +407,70 @@ def test_env_knob_spawns_mesh_engine(monkeypatch):
     assert c.unique_state_count() == TPC3_UNIQUE
 
 
-def test_old_spelling_stays_old_engine(monkeypatch):
-    """``devices=``/``n_devices=`` keep routing to the OLD shard_map
-    engine even with the mesh flag armed — the A/B harness depends on
-    the two spellings staying distinct."""
-    import stateright_tpu.parallel.sharded as sharded_mod
+_SPELLINGS = {
+    "devices": lambda b: b.spawn_tpu(sync=True, devices=2),
+    "n_devices": lambda b: b.spawn_tpu(sync=True, n_devices=2),
+    "mesh": lambda b: b.spawn_tpu(sync=True, mesh=build_mesh(2)),
+    "builder": lambda b: b.mesh(devices=2).spawn_tpu(sync=True),
+}
 
-    calls = []
 
-    class Sentinel:
-        def __init__(self, options, **kw):
-            calls.append(kw)
-            raise RuntimeError("sentinel-constructed")
+@pytest.fixture(scope="module")
+def solo_runs():
+    """One-device reference runs, one per model (shared by the four
+    spellings): counts, discoveries, paths."""
+    from stateright_tpu.models.raft import raft_model
 
-    monkeypatch.setattr(sharded_mod, "ShardedTpuChecker", Sentinel)
-    monkeypatch.setenv(ENV_MESH, "1")
-    with pytest.raises(RuntimeError, match="sentinel"):
-        TwoPhaseSys(3).checker().spawn_tpu(sync=True, devices=2)
-    assert calls and calls[0].get("n_devices") == 2
+    models = {
+        "2pc3": lambda: TwoPhaseSys(3).checker(),
+        "2pc5": lambda: TwoPhaseSys(5).checker(),
+        "raft3-sym": lambda: raft_model(3).checker().symmetry(),
+    }
+    return {
+        k: (mk, mk().spawn_tpu(sync=True)) for k, mk in models.items()
+    }
+
+
+@pytest.mark.parametrize("spelling", sorted(_SPELLINGS))
+def test_every_spelling_selects_the_mesh_engine(
+    spelling, solo_runs, monkeypatch
+):
+    """``devices=2``, ``n_devices=2``, ``mesh=build_mesh(2)`` and
+    ``.mesh(devices=2)`` are one request: a two-device MeshTpuChecker
+    whose counts, discoveries and paths equal TpuChecker's — on raft-3
+    under symmetry too, where the visit order decides the count."""
+    monkeypatch.delenv(ENV_MESH, raising=False)
+    for name, (mk, solo) in solo_runs.items():
+        assert type(solo) is TpuChecker
+        c = _SPELLINGS[spelling](mk())
+        assert type(c) is MeshTpuChecker, (spelling, name)
+        assert c.n_devices == 2
+        assert c.unique_state_count() == solo.unique_state_count(), name
+        assert c.state_count() == solo.state_count(), name
+        assert c.max_depth() == solo.max_depth(), name
+        _assert_trace_parity(solo, c)
+
+
+def test_conflicting_widths_are_an_error(monkeypatch):
+    monkeypatch.delenv(ENV_MESH, raising=False)
+    b = TwoPhaseSys(3).checker
+    with pytest.raises(ValueError, match="different numbers of devices"):
+        b().mesh(devices=4).spawn_tpu(sync=True, devices=2)
+    with pytest.raises(ValueError, match="different numbers of devices"):
+        b().spawn_tpu(sync=True, devices=2, n_devices=4)
+    with pytest.raises(ValueError, match="different numbers of devices"):
+        b().spawn_tpu(sync=True, devices=4, mesh=build_mesh(2))
+    monkeypatch.setenv(ENV_MESH, "4")
+    with pytest.raises(ValueError, match="different numbers of devices"):
+        b().spawn_tpu(sync=True, devices=2)
+    # the same width said twice is one request; one device is no request
+    c = b().mesh(devices=2).spawn_tpu(
+        sync=True, devices=2, mesh=build_mesh(2)
+    )
+    assert type(c) is MeshTpuChecker and c.n_devices == 2
+    monkeypatch.delenv(ENV_MESH)
+    assert type(b().spawn_tpu(sync=True, devices=1)) is TpuChecker
+    assert b()._mesh_request({}) is None
 
 
 def test_sweep_x_mesh_is_fenced():
@@ -338,34 +550,58 @@ def test_wavefront_carry_names_flag_guards():
         wavefront_carry_names(13, checked=True, por=True)
 
 
-# -- no vma collectives in the mesh path --------------------------------------
+# -- one engine, no hand-written collectives ----------------------------------
 
 
-def test_mesh_engine_needs_no_vma_collectives():
-    """The mesh engine's defining property: the compiler, not the code,
-    inserts the collectives — the module contains no ``pvary``/``pcast``
-    attribute access and no ``shard_map`` use (AST-checked, so
-    docstrings don't count)."""
-    import stateright_tpu.parallel.mesh as mesh_mod
+def test_no_second_engine():
+    """The compiler, not the code, inserts the collectives — in every
+    module of the package: no ``shard_map``, ``all_to_all``, ``pcast`` or
+    ``pvary`` name, attribute or import, and no import of the deleted
+    ``parallel.sharded`` (AST-checked, so docstrings don't count)."""
+    import stateright_tpu
 
-    tree = ast.parse(open(mesh_mod.__file__).read())
-    banned = {"pvary", "pcast", "shard_map"}
-    hits = [
-        node.attr
-        for node in ast.walk(tree)
-        if isinstance(node, ast.Attribute) and node.attr in banned
-    ] + [
-        node.id
-        for node in ast.walk(tree)
-        if isinstance(node, ast.Name) and node.id in banned
-    ] + [
-        alias.name
-        for node in ast.walk(tree)
-        if isinstance(node, (ast.Import, ast.ImportFrom))
-        for alias in node.names
-        if alias.name in banned
-    ]
+    banned = {"pvary", "pcast", "shard_map", "all_to_all"}
+    root = pathlib.Path(stateright_tpu.__file__).parent
+    files = sorted(root.rglob("*.py"))
+    assert len(files) > 50 and root / "parallel" / "mesh.py" in files
+    assert not (root / "parallel" / "sharded.py").exists()
+    hits = []
+    for path in files:
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Attribute):
+                names = [node.attr]
+            elif isinstance(node, ast.Name):
+                names = [node.id]
+            elif isinstance(node, (ast.Import, ast.ImportFrom)):
+                names = (getattr(node, "module", None) or "").split(".")
+                names += [p for a in node.names for p in a.name.split(".")]
+                names = ["parallel.sharded" if n == "sharded" else n
+                         for n in names]
+            else:
+                continue
+            hits += [
+                f"{path.relative_to(root)}:{node.lineno}: {n}"
+                for n in names if n in banned | {"parallel.sharded"}
+            ]
     assert not hits, hits
+
+
+def test_dryrun_multichip_runs_the_mesh_engine(monkeypatch, capsys):
+    """The driver's multi-chip dry run (``__graft_entry__.py``): pinned
+    2pc counts with growth, kill + resume, the compiled twin, symmetry
+    equal to the one-device count — on the mesh engine."""
+    import os
+
+    import __graft_entry__ as graft
+
+    # the dry run pins the platform in os.environ; put it back after
+    for name in ("JAX_PLATFORMS", "XLA_FLAGS"):
+        monkeypatch.setenv(name, os.environ.get(name, ""))
+    graft.dryrun_multichip(2)
+    out = capsys.readouterr().out
+    assert "dryrun_multichip OK: 2-device mesh engine" in out
+    assert "2pc5 unique=8832" in out and "kill+resume exact" in out
+    assert "raft3-sym unique=2926 (= one device" in out
 
 
 # -- regress --mesh gate (injectable artifacts) -------------------------------

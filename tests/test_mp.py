@@ -4,7 +4,7 @@ The mp checker is the honest multi-core CPU baseline;
 its per-state semantics must be indistinguishable from ``spawn_bfs`` —
 pinned unique counts, same discoveries, valid reconstructed paths — while
 its plumbing (fp-ownership sharding, all-to-all rounds, double-barrier
-termination) is the CPU analogue of ``parallel/sharded.py``.
+termination) is the CPU analogue of the mesh engine's table sharding.
 """
 
 import pytest

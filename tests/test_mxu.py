@@ -692,7 +692,7 @@ def test_mxu_kill_and_resume_parity():
 
 
 @pytest.mark.medium
-def test_mxu_parity_on_sharded_engine():
+def test_mxu_parity_on_mesh_engine():
     a = TwoPhaseSys(3).checker().spawn_tpu(
         sync=True, devices=2, capacity=1 << 12, frontier_capacity=1 << 9
     )
@@ -702,26 +702,16 @@ def test_mxu_parity_on_sharded_engine():
     assert a.unique_state_count() == b.unique_state_count() == TPC3_UNIQUE
     assert a.state_count() == b.state_count()
     assert sorted(a.discoveries()) == sorted(b.discoveries())
-    # cache-key pin: the unflagged sharded key carries no mxu element;
-    # the flagged one ends with the components the sharded program
-    # actually reads (coalesce, probe — slim_queue has no sharded
-    # analogue, so keying on it would recompile an identical shard_map)
+    # cache-key pin: the unflagged key carries no mxu element (MXU off
+    # leaves the cache unkeyed), the flagged one carries the effective
+    # config, each before the mesh engine's own device tail
+    assert a._last_engine_key[-1] == ("mesh", 0, 1)
     assert not any(
         isinstance(e, tuple) and e and e[0] == "mxu"
         for e in a._last_engine_key
     )
-    # (the 2pc hand twin gained a real coalesced kernel: keyed on)
-    assert b._last_engine_key[-1] == ("mxu", True, True)
-    c = TwoPhaseSys(3).checker().mxu(
-        coalesce=False, slim_queue=True, probe=False
-    ).spawn_tpu(
-        sync=True, devices=2, capacity=1 << 12, frontier_capacity=1 << 9
-    )
-    assert c.unique_state_count() == TPC3_UNIQUE
-    assert not any(
-        isinstance(e, tuple) and e and e[0] == "mxu"
-        for e in c._last_engine_key
-    ), "slim-only mxu must leave the sharded key pre-MXU (same program)"
+    assert b._last_engine_key[-1] == ("mesh", 0, 1)
+    assert b._last_engine_key[-2][0] == "mxu"
 
 
 @pytest.mark.slow

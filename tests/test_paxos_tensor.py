@@ -91,7 +91,7 @@ def test_paxos2_tpu_checker_pinned_count():
 
 
 @pytest.mark.medium
-def test_paxos2_sharded_matches():
+def test_paxos2_mesh_matches():
     m = paxos_model(2, 3)
     checker = m.checker().spawn_tpu(
         devices=8, sync=True, capacity=1 << 16, frontier_capacity=1 << 12

@@ -106,7 +106,7 @@ def test_prededup_parity_under_growth_and_symmetry():
 
 
 @pytest.mark.slow
-def test_prededup_parity_on_sharded_engine():
+def test_prededup_parity_on_mesh_engine():
     a = TwoPhaseSys(3).checker().spawn_tpu(
         sync=True, devices=2, capacity=1 << 12, frontier_capacity=1 << 9
     )

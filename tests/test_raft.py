@@ -174,28 +174,20 @@ def test_mechanical_symmetry_engine_matches_fifo_oracle():
     assert len(path.actions()) >= 3  # timeout + vote round trip
 
 
-from stateright_tpu.models.raft import (  # single source of the pin table
-    RAFT3_SYM_SHARDED_BY_WIDTH as RAFT3_SYM_SHARDED,
-)
-
-
-def test_mechanical_symmetry_sharded_engine_pinned_per_mesh_width():
-    """Sharded-engine symmetry on the compiled twin: reduced counts are
+@pytest.mark.parametrize("width", [2, 8])
+def test_mechanical_symmetry_mesh_equals_the_one_chip_count(width):
+    """Symmetry on the compiled twin over a mesh: reduced counts are
     visit-order-dependent when the representative is not class-invariant,
-    but for a FIXED mesh width the schedule is deterministic — so the
-    count is pinned EXACTLY per width (a canonicalization tie-break or
-    routing regression cannot hide inside a range).  Width 1 equals the
-    host FIFO oracle (2,926)."""
-    m = raft_model(3)
-    c = m.checker().symmetry().spawn_tpu(
-        sync=True, devices=8, capacity=1 << 14, frontier_capacity=1 << 9
+    and the mesh engine runs the one-device program — so at EVERY width
+    the count is the one-device count, which is the host FIFO oracle's
+    (2,926).  (The deleted ``shard_map`` engine visited in another order
+    at each width: 2,960 at 2, 3,015 at 8.)"""
+    c = raft_model(3).checker().symmetry().spawn_tpu(
+        sync=True, devices=width, capacity=1 << 14, frontier_capacity=1 << 9
     )
-    assert c.unique_state_count() == RAFT3_SYM_SHARDED[8]
+    assert c.n_devices == width
+    assert c.unique_state_count() == RAFT3_SYM_FIFO
     assert sorted(c.discoveries()) == ["a leader is elected"]
-    c2 = m.checker().symmetry().spawn_tpu(
-        sync=True, devices=2, capacity=1 << 14, frontier_capacity=1 << 9
-    )
-    assert c2.unique_state_count() == RAFT3_SYM_SHARDED[2]
 
 
 def test_eventually_property_parity_general_fragment():

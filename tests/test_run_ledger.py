@@ -240,16 +240,16 @@ def test_registry_does_not_key_the_engine_cache(tmp_path):
     assert RunRegistry(str(tmp_path)).index(), "armed spawn must archive"
 
 
-def test_registry_sharded_archives_and_cache_unkeyed(tmp_path):
+def test_registry_mesh_archives_and_cache_unkeyed(tmp_path):
     m = TwoPhaseSys(3)
     kw = dict(sync=True, n_devices=2, capacity=1 << 12,
               frontier_capacity=1 << 9)
     c1 = m.checker().runs(str(tmp_path)).spawn_tpu(**kw)
-    n_keys = len(c1.tensor._sharded_run_cache)
+    n_keys = len(c1.tensor._run_cache)
     c2 = m.checker().spawn_tpu(**kw)
-    assert len(c2.tensor._sharded_run_cache) == n_keys
+    assert len(c2.tensor._run_cache) == n_keys
     recs = RunRegistry(str(tmp_path)).index()
-    assert recs and recs[0]["engine"] == "sharded"
+    assert recs and recs[0]["engine"] == "mesh"
     assert recs[0]["headline"]["unique"] == TPC3_UNIQUE
 
 

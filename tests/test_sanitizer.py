@@ -395,9 +395,16 @@ def test_checked_false_leaves_run_jaxpr_bit_identical():
     assert baseline != run_jaxpr(True)  # instrumentation is really there
 
 
-def test_sharded_engine_rejects_checked():
-    with pytest.raises(NotImplementedError, match="single-device"):
-        TwoPhaseSys(3).checker().checked().spawn_tpu(devices=2)
+def test_mesh_engine_runs_checked_at_parity():
+    """``checked`` on two devices: the mesh engine runs the one-device
+    program, instrumentation included (the deleted ``shard_map`` engine
+    refused the flag)."""
+    solo = TwoPhaseSys(3).checker().checked().spawn_tpu(sync=True)
+    c = TwoPhaseSys(3).checker().checked().spawn_tpu(sync=True, devices=2)
+    assert c.n_devices == 2 and c._checked
+    assert c.unique_state_count() == solo.unique_state_count() == 288
+    assert c.state_count() == solo.state_count()
+    assert sorted(c.discoveries()) == sorted(solo.discoveries())
 
 
 # ---------------------------------------------------------------------------

@@ -19,7 +19,7 @@ Pins the round's contracts:
  - the tiers themselves: HostIndex/SpillStore units incl. the mmap'd
    disk tier, the spill-aware ``capacity_plan`` column, the health
    model's growth_oom_risk -> spill_forecast downgrade, and the
-   sharded/POR rejection guards.
+   mesh/POR rejection guards.
 """
 
 import io
@@ -556,7 +556,7 @@ def test_spill_resolution_skips_when_nothing_spilled():
 # -- rejection guards --------------------------------------------------------
 
 
-def test_sharded_engine_rejects_spill_with_guidance():
+def test_mesh_engine_rejects_spill_with_guidance():
     with pytest.raises(NotImplementedError, match="single-device"):
         TwoPhaseSys(3).checker().spill().spawn_tpu(devices=2)
 

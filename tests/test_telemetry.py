@@ -66,7 +66,7 @@ def test_counters_and_status_names():
     rec.add_bytes(d2h=100)
     assert rec.counters()["d2h_bytes"] == 200
     assert rec.counters()["h2d_bytes"] == 7
-    assert "table_full" in STATUS_NAMES and "frontier_full" in STATUS_NAMES
+    assert "table_full" in STATUS_NAMES and "queue_full" in STATUS_NAMES
 
 
 def test_jsonl_round_trip(tmp_path):

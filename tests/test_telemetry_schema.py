@@ -31,14 +31,14 @@ SCHEMA = {
             "d_states": int, "d_unique": int, "dedup": _REAL,
         },
         {
-            # engine-specific annotations: wavefront/sharded add device
+            # engine-specific annotations: the device engines add
             # capacities + table load, mp adds round/frontier, pool adds
             # its work-queue length
             "depth": int, "status": _REAL, "queue": int, "cap": int,
             "cand": int, "load_factor": _REAL, "frontier": int,
             "round": int,
-            # sharded: explicit liveness for the health model's stall
-            # guard (no frontier count crosses to the host there)
+            # explicit liveness for the health model's stall guard (an
+            # engine with no queue count to send: the thread pool)
             "busy": bool,
             # engine-run span binding (telemetry/spans.py): steps of a
             # traced run carry their engine_run span id
@@ -82,7 +82,7 @@ SCHEMA = {
         # close time (``t - dur`` is the start).  The optional set is
         # the union of per-span attrs: engine/error (engine_run,
         # attempt), attempt ordinal, gen (autosave), pending
-        # (spill_drain), cap/unique (resharding, grow), key/slot (fleet
+        # (spill_drain), cap/unique (grow), key/slot (fleet
         # job), jobs/slots (fleet root), rung/source (engine_acquire),
         # dsteps (device_call), status (grow), the universes, row and
         # table bytes of a compiled actor twin (twin_compile)
@@ -105,7 +105,7 @@ SCHEMA = {
             "props": list, "fresh_inserts": int, "duplicate_hits": int,
         },
         {"shard_load": list, "shard_imbalance": dict,
-         "route_matrix": list, "routed_candidates": int},
+         "route_matrix": list},
     ),
     "spill": (
         # spill-tier events (stateright_tpu/spill/, docs/spill.md):

@@ -209,10 +209,10 @@ def test_2pc_tpu_symmetry_matches_host_oracle():
 
 
 @pytest.mark.medium
-def test_2pc_sharded_symmetry_reduces_and_discovers():
-    """The mesh engine's symmetry reduction: all-to-all routing scrambles
-    enqueue order across shards, so only reduction + discovery validity are
-    asserted (counts are deterministic per mesh but order-sensitive)."""
+def test_2pc_mesh_symmetry_reduces_and_discovers():
+    """Symmetry reduction on eight devices: the reduced count is the
+    one-device engine's (the mesh engine runs its program), below the
+    full space, with valid discoveries."""
     from stateright_tpu.models.two_phase_commit import TwoPhaseSys
 
     checker = TwoPhaseSys(4).checker().symmetry().spawn_tpu(
@@ -220,6 +220,10 @@ def test_2pc_sharded_symmetry_reduces_and_discovers():
     )
     full = TwoPhaseSys(4).checker().spawn_tpu(sync=True, capacity=1 << 13)
     assert checker.unique_state_count() < full.unique_state_count()
+    solo = TwoPhaseSys(4).checker().symmetry().spawn_tpu(
+        sync=True, capacity=1 << 13
+    )
+    assert checker.unique_state_count() == solo.unique_state_count()
     assert set(checker.discoveries()) == {"abort agreement", "commit agreement"}
 
 

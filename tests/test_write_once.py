@@ -136,7 +136,7 @@ def test_compiled_twin_parity_single_device():
     tpu.assert_properties()
 
 
-def test_compiled_twin_parity_sharded():
+def test_compiled_twin_parity_mesh():
     tpu = wo_register_model(2, 1).checker().spawn_tpu(
         devices=8, sync=True, capacity=1 << 12, frontier_capacity=1 << 7
     )
