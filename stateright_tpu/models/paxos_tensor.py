@@ -57,7 +57,12 @@ from ..parallel.actor_tensor import (
     slot_canonicalize,
     slot_send,
 )
-from ..parallel.tensor_model import BitPacker, FieldWriter, TensorModel
+from ..parallel.tensor_model import (
+    BitPacker,
+    FieldWriter,
+    TensorModel,
+    select_along_axis,
+)
 from ..semantics.linearizability import LinearizabilityTester
 from ..semantics.register import READ, Register, write
 
@@ -73,7 +78,14 @@ MAX_CLIENTS = 7  # 3-bit read-value code + the closure strategy's own cap
 class PaxosTensor(TensorModel):
     """Device twin of ``paxos_model(client_count, 3)`` on an unordered
     non-duplicating network (the reference benchmark configuration,
-    ``examples/paxos.rs:323-338``)."""
+    ``examples/paxos.rs:323-338``).
+
+    A look-up over a per-actor axis of a few entries (a server field or a
+    client phase at the envelope's ``dst``) is written as a select over the
+    columns (``tensor_model.select_along_axis``), not as an element gather:
+    a gather costs ~8 ns a lane on a v5e whatever it gathers from, ten of
+    them at ``[batch, actions]`` were 3.07 s of a 6.55 s busy paxos-3 check
+    (ledger, PR 30)."""
 
     #: this hand-tuned twin packs the network as ONE sorted slot multiset
     #: too, so the independence analysis's JX305 escape-hatch pointer
@@ -441,7 +453,8 @@ class PaxosTensor(TensorModel):
         def gi(name):  # packed field as [B, 1] int32 (broadcasts over A)
             return pk.get(rows, name).astype(i32)[:, None]
 
-        # server fields stacked [B, S]; then gathered at dst -> [B, A]
+        # server fields stacked [B, S]; then read at dst -> [B, A] (a select
+        # over the S columns, not an element gather: class docstring)
         srv = {
             f: jnp.concatenate([gi(f"s{s}_{f}") for s in range(S)], axis=1)
             for f in (
@@ -452,7 +465,7 @@ class PaxosTensor(TensorModel):
         dstc = jnp.clip(dst, 0, S - 1)
 
         def at_dst(f):  # [B, A]
-            return jnp.take_along_axis(srv[f], dstc, axis=1)
+            return select_along_axis(srv[f], dstc)
 
         srnd, sldr = at_dst("rnd"), at_dst("ldr")
         sprop, sacc, saccd, sdec = (
@@ -467,7 +480,7 @@ class PaxosTensor(TensorModel):
         if C > 0:
             cph = jnp.concatenate([gi(f"c{c}_phase") for c in range(C)], axis=1)
             clic = jnp.clip(dst - S, 0, C - 1)
-            cphase = jnp.take_along_axis(cph, clic, axis=1)
+            cphase = select_along_axis(cph, clic)
             # peer phases for the read-invocation snapshot: snap bits over all
             # threads (self slot left 0)
             allph = cph  # [B, C]

@@ -425,3 +425,29 @@ class BitPacker:
         if off:
             v = v << jnp.uint64(off)
         return rows.at[..., word].set(cleared | (v & mask))
+
+
+def select_along_axis(cols, idx):
+    """``cols[..., idx]`` along the LAST axis, for an axis of a few entries:
+    ``jnp.take_along_axis(cols, idx, axis=-1)`` on in-range indices, written
+    as a chain of ``n - 1`` selects instead of an element gather.
+
+    ``cols`` is ``[..., n]`` (one column per actor: a server field, a client
+    phase, a small table), ``idx`` is ``[..., A]`` with every entry in
+    ``[0, n)``; the result is ``[..., A]`` of ``cols``' dtype.  An element
+    gather costs ~8 ns a lane on a v5e whatever it gathers from (0.3065 s /
+    302 steps / 122,880 lanes in ``paxos3-presized``; ledger, PR 30); the
+    selects are vector work that fuses into their consumers (alone on the
+    chip 0.002 ns a lane at n = 3 and 0.15-0.23 at n = 106, where a one-hot
+    sum reads 0.088: PERF.md section 6, PR 31).  Indices outside ``[0, n)``
+    are the caller's to clip: they read column ``n - 1`` here, where
+    ``take_along_axis`` wraps or fills."""
+    import jax.numpy as jnp
+
+    n = cols.shape[-1]
+    out = jnp.broadcast_to(
+        cols[..., n - 1 :], jnp.broadcast_shapes(cols.shape[:-1] + (1,), idx.shape)
+    )
+    for k in range(n - 2, -1, -1):
+        out = jnp.where(idx == k, cols[..., k : k + 1], out)
+    return out

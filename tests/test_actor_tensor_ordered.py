@@ -185,7 +185,13 @@ def test_the_deep_flow_case_refuses_only_the_full_flow():
 
 
 def gather_shapes(fn, *args) -> collections.Counter:
-    """Result shapes of every gather in ``fn``'s jaxpr, sub-jaxprs included."""
+    """Result shapes of every gather in ``fn``'s jaxpr, sub-jaxprs included.
+
+    Counts DISTINCT sub-jaxprs, not call sites: ``_walk_jaxprs`` visits each
+    jaxpr object once, and ``jnp.take_along_axis`` is a cached jit, so ten
+    calls of it at one shape read as 1.  The pins below are plain ``x[idx]``
+    look-ups (one gather equation each) and stand as they are; to count what
+    a program RUNS use ``tests/test_paxos_tensor.py:gather_call_sites``."""
     return collections.Counter(
         tuple(eqn.outvars[0].aval.shape)
         for eqn in _iter_eqns(jax.make_jaxpr(fn)(*args))

@@ -9,10 +9,12 @@ states @ 2 clients / 3 servers (reference ``examples/paxos.rs:291,311``).
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from stateright_tpu.fingerprint import hash_words
 from stateright_tpu.models.paxos import paxos_model
+from stateright_tpu.parallel.tensor_model import select_along_axis
 
 
 def crawl_and_check(m, tm, max_levels=None):
@@ -107,6 +109,62 @@ def test_paxos2_cpu_bfs_agrees():
     cpu = m.checker().spawn_bfs().join()
     assert cpu.unique_state_count() == 16668
     assert set(cpu.discoveries()) == {"value chosen"}
+
+
+@pytest.mark.parametrize("dtype", [np.int32, np.uint64], ids=["s32", "u64"])
+@pytest.mark.parametrize("n", [1, 2, 3, 5])
+def test_select_along_axis_is_take_along_axis_in_range(n, dtype):
+    """The select chain reproduces the gather on in-range indices, bit for
+    bit: every column is read by some lane, high u64 bits included."""
+    rng = np.random.default_rng(1000 * n + np.dtype(dtype).itemsize)
+    B, A = 13, 30
+    cols = rng.integers(0, np.iinfo(dtype).max, (B, n), dtype=dtype, endpoint=True)
+    idx = rng.integers(0, n, (B, A), dtype=np.int32)
+    idx[:n, 0] = np.arange(n)  # each column read at least once
+    cols, idx = jnp.asarray(cols), jnp.asarray(idx)
+    want = np.asarray(jnp.take_along_axis(cols, idx, axis=1))
+    for got in (select_along_axis(cols, idx), jax.jit(select_along_axis)(cols, idx)):
+        assert got.dtype == dtype and got.shape == (B, A)
+        assert np.array_equal(np.asarray(got), want)
+
+
+def gather_call_sites(jaxpr) -> list:
+    """Result shape of every ``gather`` the program RUNS: a sub-jaxpr is
+    walked once per equation that calls it (``jnp.take_along_axis`` is a
+    cached jit, so ten calls share one jaxpr object and
+    ``analysis/jaxpr_audit._walk_jaxprs`` reports them as one)."""
+    shapes = []
+    for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
+        if eqn.primitive.name == "gather":
+            shapes.append(tuple(eqn.outvars[0].aval.shape))
+        for p in eqn.params.values():
+            for sub in p if isinstance(p, (list, tuple)) else (p,):
+                if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                    shapes += gather_call_sites(sub)
+    return shapes
+
+
+def test_gather_call_sites_counts_each_call_of_a_shared_jaxpr():
+    cols, idx = jnp.zeros((7, 3), jnp.int32), jnp.zeros((7, 30), jnp.int32)
+
+    def ten(cols, idx):
+        return sum(jnp.take_along_axis(cols + k, idx, axis=1) for k in range(10))
+
+    assert gather_call_sites(jax.make_jaxpr(ten)(cols, idx)) == [(7, 30)] * 10
+
+
+@pytest.mark.parametrize("clients", [2, 3])
+def test_step_rows_gathers_nothing_at_batch_by_actions(clients):
+    """The per-server fields and the client phase at ``dst`` were ten element
+    gathers at ``[B, A]`` lanes, 3.07 s of a 6.55 s busy paxos-3 check
+    (ledger, PR 30); they are selects over the 3 columns now, and no other
+    look-up at those lanes has come in."""
+    tm = paxos_model(clients, 3).tensor_model()
+    B, A = 7, tm.max_actions
+    rows = jnp.zeros((B, tm.width), jnp.uint64)
+    for step in (tm.step_rows, tm.step_rows_coalesced):
+        shapes = gather_call_sites(jax.make_jaxpr(step)(rows))
+        assert not [s for s in shapes if int(np.prod(s)) >= B * A], shapes
 
 
 def test_paxos_tensor_eligibility():
