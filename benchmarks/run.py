@@ -7,10 +7,18 @@ cell's model, makes one untimed warm-up check that walks every engine rung
 the cell will use (set-up), then runs whole checks back to back — a closed
 loop, one client — until ``--seconds`` have passed; the check in flight is
 finished and counted.  Every check is held to the configuration's pins.
+The workload file's ``loop.kind`` names the unit of work: ``closed`` (the
+default) re-checks one model object, whose engines stay resident, and the
+window may not ask the compiler for anything; ``cold`` makes every check —
+warm-up, window, profiled — on a model object built inside the check's
+timed span, so each pays the twin's compilation and the engines'
+acquisition, every program served from the persistent cache.
 
 The LAST stdout line is the result object (``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` and, traced, ``breakdown``); everything
-else is on earlier lines.  ``--trace 0`` reports the cell's end-to-end
+``failed``, ``metrics``, ``device``, traced ``breakdown``, and last
+``compared``: every number the run was held to beside its limit, which are
+also the last lines on stderr); everything else is on earlier lines.
+``--trace 0`` reports the cell's end-to-end
 metrics from a plain builder; ``--trace 1`` turns the flight recorder on,
 profiles one whole warm check with ``jax.profiler`` and reports the cell's
 per-layer metrics, each through its own reader under ``layer_metrics/``.
@@ -44,6 +52,7 @@ from srbench.manifest import Manifest  # noqa: E402
 
 ANNOTATION = "srbench_traced_check"
 WALKS = 256  # random walks of the seeded exactness sample
+KEPT = 16384  # states of the exactness sample under .symmetry()
 
 _TAG = ""
 
@@ -103,9 +112,12 @@ def memory_peak_bytes(chips: int):
 
 def drop(result: dict) -> None:
     """Let go of a check's checker (and its device buffers) before the
-    next spawn: peak memory is one check's, not two."""
+    next spawn — and of its model object, which in the ``cold`` loop is the
+    check's own, twin and engines with it: peak memory is one check's, not
+    two."""
     result.pop("checker", None)
     result.pop("paths", None)
+    result.pop("model", None)
     gc.collect()
 
 
@@ -142,18 +154,24 @@ class HostNoise:
                 f"gc={d[6]:.3f}s/{d[7]}")
 
 
-def exactness_sample(model, checker, seed: int) -> str:
-    """Seeded random walks of the host object model: every state on them
-    must be in the warm-up checker's visited set."""
-    visited = chk.visited_fingerprints(checker)
-    if visited is None:
-        say("exactness sample skipped: checkpoint() exposes no table")
-        return "skipped"
-    fps = reference.random_walk_fingerprints(model, seed, WALKS)
+def exactness_sample(model, visited, seed: int, symmetric: bool) -> int:
+    """How many states of the seeded sample the warm-up checker's visited
+    set (``visited``) lacks.  The sample: every state on seeded random
+    walks of the host object model; under ``.symmetry()``, where the set
+    holds one representative a class as the search met them, a seeded draw
+    from the states the plain reference's FIFO representative search keeps
+    (``reference.kept_fingerprints``: a whole host search, so the caller
+    takes that one once the window has closed, outside ``setup_s``)."""
+    if symmetric:
+        fps = reference.kept_fingerprints(model, seed, KEPT)
+        drawn = f"symmetry kept<={KEPT}"
+    else:
+        fps = reference.random_walk_fingerprints(model, seed, WALKS)
+        drawn = f"walks={WALKS}"
     missing = chk.missing_from(visited, fps)
-    say(f"exactness sample: seed={seed} walks={WALKS} states={len(fps)} "
+    say(f"exactness sample: seed={seed} {drawn} states={len(fps)} "
         f"visited={len(visited)} missing={missing}")
-    return "ok" if missing == 0 else f"{missing} reachable states not visited"
+    return missing
 
 
 def label_gaps(gaps_ns, to_monotonic, markers, limit: int = 10) -> list:
@@ -185,7 +203,7 @@ def markers_of(result: dict) -> list:
     return out
 
 
-def profiled_check(model, workload, trace_dir: str) -> dict:
+def profiled_check(make_model, workload, trace_dir: str) -> dict:
     """One whole warm check under ``jax.profiler``, bracketed by one
     TraceAnnotation stamped with ``time.monotonic`` to align the clocks."""
     import jax
@@ -198,7 +216,7 @@ def profiled_check(model, workload, trace_dir: str) -> dict:
     jax.profiler.start_trace(trace_dir, profiler_options=options)
     try:
         with jax.profiler.TraceAnnotation(ANNOTATION):
-            result = chk.run_check(model, workload, telemetry=True)
+            result = chk.run_check(make_model, workload, telemetry=True)
     finally:
         jax.profiler.stop_trace()
     return result
@@ -235,7 +253,8 @@ def main(argv=None) -> int:
         cell = manifest.cell(args.workload)
         config = manifest.config(cell["config"])
         workload = manifest.workload(cell["name"])
-    except (KeyError, OSError) as e:
+        kind = chk.loop_kind(workload)
+    except (KeyError, OSError, ValueError) as e:
         die(str(e))
     chips = int(cell["chips"])
     dev = device_gate(chips, args.rehearse_cpu)
@@ -244,7 +263,8 @@ def main(argv=None) -> int:
     except ImportError as e:
         die(f"the system under test is not importable from {CHECKOUT}: {e}")
     say(f"cell {cell['name']}: config={cell['config']} traffic={cell['traffic']} "
-        f"chips={chips} seed={args.seed} seconds={args.seconds} trace={args.trace}")
+        f"chips={chips} seed={args.seed} seconds={args.seconds} trace={args.trace}"
+        + ("" if kind == "closed" else f" loop={kind}"))
     say(f"device: {json.dumps(dev)}")
 
     compiles.install()
@@ -252,18 +272,48 @@ def main(argv=None) -> int:
     say(f"compile cache: {cache_dir} ({len(os.listdir(cache_dir))} entries at start)")
 
     traced = bool(args.trace)
-    model = chk.build_model(config)
+    compared: dict = {}  # name -> [the worst number of the run, its limit]
+    failures: list = []
+
+    def hold(where: str, rows: list) -> int:
+        """Keep each compared number's worst, its messages where it is
+        over its limit; returns how many are over."""
+        over = 0
+        for name, number, limit, messages in rows:
+            worst = compared.setdefault(name, [number, limit])
+            worst[0] = max(worst[0], number)
+            if number > limit:
+                over += 1
+                failures.extend(f"{where}: {m}" for m in messages)
+        return over
+
+    # ``closed``: the one object every check is made on; ``cold``: each
+    # check builds its own inside its timed span
+    if kind == "closed":
+        model = chk.build_model(config)
+        make_model = lambda: model  # noqa: E731
+    else:
+        make_model = lambda: chk.build_model(config)  # noqa: E731
+    symmetric = any(v["verb"] == "symmetry" for v in workload.get("builder", []))
+
+    def hold_sample(model, visited) -> None:
+        missing = exactness_sample(model, visited, args.seed, symmetric)
+        hold("exactness sample", [("sample_missing", missing, 0,
+                                   [f"{missing} reachable states not visited"])])
+
     # -- set-up: one untimed warm-up check walks every rung the cell uses ----
     t_w = time.monotonic()
-    warm = chk.run_check(model, workload, telemetry=traced)
+    warm = chk.run_check(make_model, workload, telemetry=traced)
     say(f"warm-up check: {warm['check_s']:.3f}s unique={warm['unique']} "
         f"generated={warm['generated']} depth={warm['max_depth']} "
         f"discoveries={warm['discoveries']} growth_events={warm['growth_events']} "
         f"compiles={compiles.snapshot()}")
-    failures = [f"warm-up: {m}" for m in chk.pin_failures(model, config, workload, warm)]
-    sample = exactness_sample(model, warm["checker"], args.seed)
-    if sample not in ("ok", "skipped"):
-        failures.append(f"exactness sample: {sample}")
+    hold("warm-up", chk.compare(warm["model"], config, workload, warm))
+    visited = chk.visited_fingerprints(warm["checker"])
+    if visited is None:
+        say("exactness sample skipped: checkpoint() exposes no table")
+    elif not symmetric:
+        hold_sample(warm["model"], visited)
     warm_records = warm.get("records", [])
     drop(warm)
     setup_compiles = compiles.snapshot()
@@ -274,36 +324,56 @@ def main(argv=None) -> int:
     trace_dir = os.path.join(manifest.root, ".bench_trace", cell["name"])
     checks, attempted, failed = [], 0, 0
     noise = HostNoise()
+    asked = setup_compiles  # the compile counters as the last check left them
     t_first = time.monotonic()
     setup_s = t_first - T_PROCESS_START
     while True:
         attempted += 1
         before = noise.snapshot()
         try:
-            res = chk.run_check(model, workload, telemetry=traced)
-            bad = chk.pin_failures(model, config, workload, res)
+            res = chk.run_check(make_model, workload, telemetry=traced)
+            bad = hold(f"check {attempted}",
+                       chk.compare(res["model"], config, workload, res))
         except Exception as e:  # noqa: BLE001 - a check that raises is a failed check
             failed += 1
             failures.append(f"check {attempted} raised {type(e).__name__}: {e}")
         else:
             if bad:
                 failed += 1
-                failures += [f"check {attempted}: {m}" for m in bad]
             t_last = res["t1"]
             drop(res)
             checks.append(res)
             spans = {name: b - a for name, a, b in res["spans"]}
             res["search_s"] = spans["spawn_join"]
+            now = compiles.snapshot()
+            res["compiles"], asked = compiles.delta(asked, now), now
+            cold = "" if kind == "closed" else (
+                f"build={spans['build_model']:.4f}s compile_requests="
+                f"{res['compiles']['compile_requests']} (persistent misses "
+                f"{res['compiles']['persistent_misses']}) ")
             say(f"check {attempted}: start=+{res['t0'] - t_first:.4f}s "
                 f"{res['check_s']:.4f}s search={res['search_s']:.4f}s "
-                f"reconstruct={spans['reconstruct']:.4f}s "
+                f"reconstruct={spans['reconstruct']:.4f}s {cold}"
                 f"drop={time.monotonic() - t_last:.4f}s; "
                 f"{HostNoise.line(before, noise.snapshot())}")
         if time.monotonic() - t_first >= args.seconds:
             break
     window = compiles.delta(setup_compiles, compiles.snapshot())
-    if window["persistent_misses"] or window["compile_requests"]:
-        failures.append(f"the measured window compiled: {window}")
+    # no fresh compile in any window; a closed window asks the compiler for
+    # nothing at all, a cold one for the same programs in every check
+    asks = sorted({c["compiles"]["compile_requests"] for c in checks})
+    rows = [("window_persistent_misses", window["persistent_misses"], 0,
+             [f"the persistent cache did not hold what the window asked for: {window}"])]
+    if kind == "closed":
+        rows.append(("window_compile_requests", window["compile_requests"], 0,
+                     [f"the measured window compiled: {window}"]))
+    elif checks:
+        rows.append(("compile_requests_spread", asks[-1] - asks[0], 0,
+                     [f"the checks asked for different numbers of programs: {asks}"]))
+        rows.append(("checks_without_compile_requests", int(asks[0] == 0), 0,
+                     ["a cold check asked the compiler for nothing: its "
+                      "engines were not its own"]))
+    hold("window", rows)
     if not checks:
         die("no check completed in the window: " + "; ".join(failures))
 
@@ -335,10 +405,11 @@ def main(argv=None) -> int:
     else:
         say(f"end-to-end numbers of this TRACED run (recorder on; compare "
             f"with a --trace 0 run for the tracing overhead): {json.dumps(measured)}")
-        # one more whole warm check, after the window, under the profiler
-        profiled = profiled_check(model, workload, trace_dir)
-        bad = chk.pin_failures(model, config, workload, profiled)
-        failures += [f"profiled check: {m}" for m in bad]
+        # one more whole check, after the window, under the profiler
+        profiled = profiled_check(make_model, workload, trace_dir)
+        hold("profiled check",
+             chk.compare(profiled["model"], config, workload, profiled))
+        tensor = profiled["model"].tensor_model()
         drop(profiled)
         say(f"profiled check: {profiled['check_s']:.4f}s vs recorder-only "
             f"median {check_s:.4f}s")
@@ -349,7 +420,6 @@ def main(argv=None) -> int:
             "device_ops": reduced["device_ops"],
             "idle_gaps": reduced["idle_gaps"],
         }
-        tensor = model.tensor_model()
         ctx = {
             "cell": cell, "config": config, "workload": workload,
             "pins": config["pins"],
@@ -369,12 +439,21 @@ def main(argv=None) -> int:
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
         shutil.rmtree(trace_dir, ignore_errors=True)
+    if symmetric and visited is not None:
+        # the reference's whole search: after the window, not in setup_s
+        hold_sample(make_model(), visited)
     for f in failures:
         say(f"NOT CORRECT: {f}")
     ordered = {"correct": not failures, "attempted": attempted,
                "failed": failed, "metrics": metrics, "device": dev}
     if breakdown is not None:
         ordered["breakdown"] = breakdown
+    # every number compared beside its limit: the line's last key, and the
+    # last lines on stderr
+    ordered["compared"] = {k: {"value": v, "limit": lim}
+                           for k, (v, lim) in compared.items()}
+    for k, (v, lim) in compared.items():
+        print(f"{_TAG}compared: {k}={v} limit={lim}", file=sys.stderr, flush=True)
     if args.rehearse_cpu:
         say(f"rehearsal complete (no result line): {json.dumps(ordered)}")
         return 2

@@ -5,6 +5,10 @@ counts -> ``discoveries()`` and ``discovery(name)`` for each discovered
 property (the device's parent chain replayed on the host object model).
 The benchmark times it on the host clock and holds the answers to the
 configuration's pins; nothing here reads the program's internals.
+
+The workload file's ``loop.kind`` says on what a check is made: ``closed``
+re-checks ONE model object (its engines stay resident), ``cold`` builds
+the model object anew INSIDE every check's timed span.
 """
 
 from __future__ import annotations
@@ -14,6 +18,18 @@ import time
 from typing import Optional
 
 RECORDER_CAPACITY = 8192  # ring size in traced runs: every record is kept
+LOOP_KINDS = ("closed", "cold")
+
+
+def loop_kind(workload: dict) -> str:
+    """The workload's ``loop.kind``; an absent ``loop`` or ``kind`` is
+    ``closed``.  ValueError, naming the kinds, for one the loop has not."""
+    kind = (workload.get("loop") or {}).get("kind", "closed")
+    if kind not in LOOP_KINDS:
+        raise ValueError(
+            f"unknown loop.kind {kind!r}: the kinds are {', '.join(LOOP_KINDS)}"
+        )
+    return kind
 
 
 def build_model(config: dict):
@@ -37,11 +53,17 @@ def builder_for(model, workload: dict, telemetry: bool):
     return b
 
 
-def run_check(model, workload: dict, telemetry: bool) -> dict:
-    """One timed check.  Returns the answers, the host-clock spans and —
-    in a traced run — the flight recorder's records."""
+def run_check(make_model, workload: dict, telemetry: bool) -> dict:
+    """One timed check, on the model object ``make_model()`` returns INSIDE
+    the timed span: the ``closed`` loop hands back its one object, the
+    ``cold`` loop builds one from the configuration, so twin, engines and
+    checker are all this check's.  Returns the answers, the host-clock
+    spans and — in a traced run — the flight recorder's records."""
     spans = []
     t0 = time.monotonic()
+    model = make_model()
+    t_spawn = time.monotonic()
+    spans.append(("build_model", t0, t_spawn))
     checker = builder_for(model, workload, telemetry).spawn_tpu(
         sync=True, **workload.get("spawn", {})
     )
@@ -50,7 +72,7 @@ def run_check(model, workload: dict, telemetry: bool) -> dict:
     generated = checker.state_count()
     depth = checker.max_depth()
     t_join = time.monotonic()
-    spans.append(("spawn_join", t0, t_join))
+    spans.append(("spawn_join", t_spawn, t_join))
     found = checker.discoveries()
     paths = {name: checker.discovery(name) for name in found}
     t1 = time.monotonic()
@@ -67,6 +89,7 @@ def run_check(model, workload: dict, telemetry: bool) -> dict:
         "growth_events": len(getattr(checker, "growth_events", ())),
         "spans": spans,
         "checker": checker,
+        "model": model,
     }
     rec = getattr(checker, "flight_recorder", None)
     if telemetry and rec is not None:
@@ -77,38 +100,46 @@ def run_check(model, workload: dict, telemetry: bool) -> dict:
     return out
 
 
-def pin_failures(model, config: dict, workload: dict, result: dict) -> list:
-    """Why this check is NOT correct (empty when it is): every pinned
-    count, the discovery set, each discovery path's last state judged by
-    its property on the host model, and the cell's growth expectation."""
+def compare(model, config: dict, workload: dict, result: dict) -> list:
+    """Every number this check is held to, as ``(name, number, limit,
+    messages)``: each pinned count's distance from its pin, the discovery
+    set's, the discovery paths whose last state its property does not
+    single out on the host model, and the cell's growth expectation.  All
+    are exact: the limit is 0, and ``messages`` say why a number is over."""
     pins = config["pins"]
-    bad = []
+    rows = []
     for key in ("unique", "generated", "max_depth"):
-        if result[key] != pins[key]:
-            bad.append(f"{key} {result[key]} != pinned {pins[key]}")
-    if result["discoveries"] != sorted(pins["discoveries"]):
-        bad.append(
-            f"discoveries {result['discoveries']} != pinned "
-            f"{sorted(pins['discoveries'])}"
-        )
+        rows.append((
+            f"{key}_off", abs(result[key] - pins[key]), 0,
+            [f"{key} {result[key]} != pinned {pins[key]}"],
+        ))
+    wanted = sorted(pins["discoveries"])
+    rows.append((
+        "discoveries_off", len(set(result["discoveries"]) ^ set(wanted)), 0,
+        [f"discoveries {result['discoveries']} != pinned {wanted}"],
+    ))
+    bad_paths = []
     for name, path in result["paths"].items():
         if path is None:
-            bad.append(f"discovery {name!r} has no path")
+            bad_paths.append(f"discovery {name!r} has no path")
             continue
         prop = model.property_by_name(name)
         holds = bool(prop.condition(model, path.last_state()))
-        wanted = prop.expectation.name == "SOMETIMES"
-        if holds != wanted:
-            bad.append(
+        if holds != (prop.expectation.name == "SOMETIMES"):
+            bad_paths.append(
                 f"the replayed path of {name!r} ends in a state its "
                 "property does not single out"
             )
+    rows.append(("paths_off", len(bad_paths), 0, bad_paths))
     growth = workload.get("expect_growth")
-    if growth == "none" and result["growth_events"] != 0:
-        bad.append(f"{result['growth_events']} growth events in a presized cell")
-    if growth == "some" and result["growth_events"] == 0:
-        bad.append("no growth event in a cell that starts from the defaults")
-    return bad
+    events = result["growth_events"]
+    if growth == "none":
+        rows.append(("growth_off", events, 0,
+                     [f"{events} growth events in a presized cell"]))
+    elif growth == "some":
+        rows.append(("growth_off", int(events == 0), 0,
+                     ["no growth event in a cell that starts from the defaults"]))
+    return rows
 
 
 def visited_fingerprints(checker) -> Optional["np.ndarray"]:  # noqa: F821

@@ -15,12 +15,21 @@ themselves.  It defines what the pins mean (the reference semantics,
    satisfies it, an ``always`` property by the first that violates it;
    the search stops once every property has a discovery
 
+Under ``.symmetry()`` (``symmetric=True``) the same search runs over the
+ORIGINAL states in FIFO order and deduplicates on each state's
+``representative()``: the visited set holds one representative a class.
+Where the representative is not class-invariant that count depends on the
+visit order, so a symmetric configuration's pins are THIS search's, the
+order a BFS engine visits in — not the depth-first count upstream prints
+(2pc with 5 resource managers: 508 here, 665 there).
+
 It also draws the seeded random walks of the exactness sample.
 """
 
 from __future__ import annotations
 
 import random
+from typing import Optional
 
 
 def _discovers(prop, model, state) -> bool:
@@ -44,8 +53,13 @@ def successors(model, state) -> list:
     return out
 
 
-def reference_bfs(model) -> dict:
-    """Exhaust ``model`` on the host; returns the four pinned quantities."""
+def reference_bfs(model, symmetric: bool = False,
+                  kept: Optional[list] = None) -> dict:
+    """Exhaust ``model`` on the host; returns the four pinned quantities.
+    ``symmetric``: deduplicate on ``state.representative()`` (the states
+    themselves are searched on, in FIFO order).  Every state that enters
+    the visited set is appended to ``kept``, in that order."""
+    key = (lambda s: s.representative()) if symmetric else (lambda s: s)
     props = list(model.properties())
     found: set = set()
     seen: set = set()
@@ -55,9 +69,12 @@ def reference_bfs(model) -> dict:
         if not model.within_boundary(s):
             continue
         generated += 1
-        if s not in seen:
-            seen.add(s)
+        k = key(s)
+        if k not in seen:
+            seen.add(k)
             frontier.append(s)
+            if kept is not None:
+                kept.append(s)
     depth = -1
     done = False
     while frontier and not done:
@@ -72,9 +89,12 @@ def reference_bfs(model) -> dict:
                 break
             for n in successors(model, s):
                 generated += 1
-                if n not in seen:
-                    seen.add(n)
+                k = key(n)
+                if k not in seen:
+                    seen.add(k)
                     nxt_frontier.append(n)
+                    if kept is not None:
+                        kept.append(n)
         frontier = nxt_frontier
     return {
         "unique": len(seen),
@@ -82,6 +102,25 @@ def reference_bfs(model) -> dict:
         "max_depth": max(depth, 0),
         "discoveries": sorted(found),
     }
+
+
+def kept_fingerprints(model, seed: int, count: int) -> list:
+    """The exactness sample under ``.symmetry()``: ``count`` states drawn
+    with ``seed`` from ALL the states the FIFO representative search keeps
+    (every depth alike; all of them where it keeps fewer), each named as a
+    checker's visited set names it there — the fingerprint of its class's
+    representative, worked out on the HOST objects alone
+    (``model.fingerprint_state(state.representative())``; nothing of the
+    twin's own canonicaliser is asked).  Random walks do NOT serve there: a
+    walk leaves the kept members after one step, and where the
+    representative is not class-invariant (2pc's sorts by one field) the
+    representative of a reachable state that no kept member generated is in
+    nobody's set."""
+    kept: list = []
+    reference_bfs(model, symmetric=True, kept=kept)
+    if len(kept) > count:
+        kept = random.Random(seed).sample(kept, count)
+    return [model.fingerprint_state(s.representative()) for s in kept]
 
 
 def random_walk_fingerprints(model, seed: int, walks: int,

@@ -362,6 +362,14 @@ def _result(twopc3, **over):
     return base
 
 
+def _pin_failures(model, config, workload, result):
+    """Why a check is not correct: the messages of every compared number
+    that is over its limit."""
+    return [m for _, number, limit, messages
+            in chk.compare(model, config, workload, result)
+            if number > limit for m in messages]
+
+
 @pytest.mark.parametrize("over, workload, needle", [
     ({}, {"expect_growth": "none"}, None),
     ({"unique": 287}, {}, "unique 287 != pinned 288"),
@@ -373,7 +381,7 @@ def _result(twopc3, **over):
 ])
 def test_pin_failures(twopc3, over, workload, needle):
     config = json.load(open(os.path.join(DATA, "twopc3.json")))
-    bad = chk.pin_failures(twopc3, config, workload, _result(twopc3, **over))
+    bad = _pin_failures(twopc3, config, workload, _result(twopc3, **over))
     if needle is None:
         assert bad == []
     else:
@@ -386,7 +394,7 @@ def test_a_path_that_ends_in_the_wrong_state_is_not_correct(twopc3):
     # swap the two discoveries' paths: each now ends in the other's state
     a, c = "abort agreement", "commit agreement"
     res["paths"] = {a: res["paths"][c], c: res["paths"][a]}
-    bad = chk.pin_failures(twopc3, config, {}, res)
+    bad = _pin_failures(twopc3, config, {}, res)
     assert len([b for b in bad if "replayed path" in b]) == 2
 
 
@@ -480,15 +488,21 @@ def test_rehearsal_runs_the_added_cell_and_prints_no_result(
     assert "exactness sample: seed=7 walks=256" in p.stdout
     assert "missing=0" in p.stdout
     # one line a check (phases, rusage, collector passes) before the window's
-    assert "check 1: start=+0.0000s " in p.stdout
+    assert "check 1: start=+0.00" in p.stdout  # under 10 ms after the window opens
     assert "mean over the wall, not a metric" in p.stdout
     if trace == 0:
         # the CPU backend reports no memory statistics: peak_hbm is left out
         assert set(out["metrics"]) == {"check_s", "gen_rate", "setup_s"}
         assert "breakdown" not in out
     else:
-        want = {m["name"] for m in doc["per_layer"]} - {"step_roofline"}
-        assert set(out["metrics"]) == want  # no peaks on a CPU: no roofline
+        # what the manifest says this cell reports (a hand twin: none of the
+        # compiled twin's readers, none of the cold loop's)
+        m = Manifest(str(root / "BENCHMARK.json"), str(root / "benchmarks"))
+        want = {e["name"] for e in m.metrics_for("per_layer", "twopc3-tiny")}
+        assert "twin_compile_s" not in want and "acquire_check_s" not in want
+        assert {"depth_levels", "fingerprint_bridge_s", "dispatch_s"} <= want
+        # no peaks on a CPU: no roofline
+        assert set(out["metrics"]) == want - {"step_roofline"}
         assert out["metrics"]["depth_levels"]["value"] == 10.0
         assert out["metrics"]["growth_s"]["value"] == 0.0
         assert out["metrics"]["cache_misses"]["unit"] == "count"

@@ -212,9 +212,18 @@ def test_the_cell_is_presized_for_the_pinned_space(manifest):
     assert wl["builder"] == [] and wl["expect_growth"] == "none"
     assert wl["spawn"]["queue_capacity"] >= pins["unique"]  # every unique row fits
     assert pins["unique"] / wl["spawn"]["capacity"] < 0.14  # the table's load
-    # it reports every per-layer metric the manifest has, the six new ones too
+    # it reports every per-layer metric the manifest has, the six of PR 28
+    # too, but for the cold loop's own (its checks acquire nothing)
     names = {m["name"] for m in manifest.metrics_for("per_layer", cell["name"])}
-    assert names == {m["name"] for m in manifest.doc["per_layer"]} >= set(TWIN_METRICS)
+    assert names == {m["name"] for m in manifest.doc["per_layer"]} - {
+        "acquire_check_s", "twin_compile_check_s"}
+    assert names >= set(TWIN_METRICS)
+    # four of the six read nothing on a hand twin, and say so in the manifest
+    listed = {m["name"]: m["workloads"] for m in manifest.doc["per_layer"]
+              if "workloads" in m and m["name"] in TWIN_METRICS}
+    assert listed == dict.fromkeys(
+        ("stage_expand_table_s", "stage_expand_history_s", "twin_compile_s",
+         "twin_table_bytes"), ["linreg2x3o-presized", "linreg2x3o-cold"])
     # device-bound (gen_rate spread 0.04% over 6 runs on a v5e): all four
     assert {m["name"] for m in manifest.metrics_for("end_to_end", cell["name"])} == {
         "check_s", "gen_rate", "peak_hbm", "setup_s"}
@@ -243,6 +252,9 @@ def tiny_linreg(tmp_path_factory):
         "name": "linreg2x2o-tiny", "config": "linreg2x2o", "traffic": "tiny",
         "chips": 1, "why": "rehearsal of the compiled twin's readers on the CPU",
     })
+    for m in doc["per_layer"]:  # a compiled twin: join its readers' lists
+        if "linreg2x3o-presized" in m.get("workloads", []):
+            m["workloads"].append("linreg2x2o-tiny")
     (root / "BENCHMARK.json").write_text(json.dumps(doc))
     assert Manifest(str(root / "BENCHMARK.json"), str(bench)).problems() == []
     return root, doc
