@@ -24,10 +24,12 @@ BENCH = os.path.join(REPO, "benchmarks")
 DATA = os.path.join(HERE, "data")
 RUN = os.path.join(BENCH, "run.py")
 sys.path.insert(0, BENCH)
+sys.path.insert(0, HERE)
 
 from srbench import check as chk  # noqa: E402
 from srbench import reference  # noqa: E402
 from srbench.manifest import Manifest  # noqa: E402
+from test_benchmark_own import assert_a_rehearsal_prints  # noqa: E402
 
 TAG = "[CPU REHEARSAL - not a chip result] "
 # a reader added as a file: in how many of the window's checks the ring
@@ -150,12 +152,18 @@ def test_an_unknown_kind_is_an_error_that_names_the_kinds():
         chk.loop_kind({"loop": {"kind": "bursty"}})
 
 
-def test_every_committed_workload_names_a_known_kind():
-    manifest = Manifest(os.path.join(REPO, "BENCHMARK.json"), BENCH)
+@pytest.fixture(scope="module")
+def manifest():
+    return Manifest(os.path.join(REPO, "BENCHMARK.json"), BENCH)
+
+
+def test_every_committed_workload_names_a_known_kind(manifest):
+    """Asks the manifest, does not pin it: no count of cells, no claim
+    about the kind of a cell this test does not name."""
     kinds = {w["name"]: chk.loop_kind(manifest.workload(w["name"]))
              for w in manifest.doc["workloads"]}
-    assert kinds.pop("linreg2x3o-cold") == "cold"
-    assert set(kinds.values()) == {"closed"} and len(kinds) == 4
+    assert set(kinds.values()) <= set(chk.LOOP_KINDS)
+    assert kinds["linreg2x3o-cold"] == "cold"
 
 
 # -- the cold loop, rehearsed ---------------------------------------------------
@@ -225,7 +233,7 @@ def test_cold_traced_line_has_the_cells_metrics_and_the_compared_numbers_last(
         "correct", "attempted", "failed", "metrics", "device"]
     manifest = Manifest(os.path.join(REPO, "BENCHMARK.json"), BENCH)
     want = {m["name"] for m in manifest.metrics_for("per_layer", "linreg2x3o-cold")}
-    assert set(out["metrics"]) == (want | {"own_object_checks"}) - {"step_roofline"}
+    assert_a_rehearsal_prints(want | {"own_object_checks"}, out["metrics"])
     compared = out["compared"]
     assert set(compared) == {
         "unique_off", "generated_off", "max_depth_off", "discoveries_off",

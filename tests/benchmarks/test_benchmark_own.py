@@ -41,6 +41,17 @@ def manifest():
     return Manifest(os.path.join(REPO, "BENCHMARK.json"), BENCH)
 
 
+def assert_a_rehearsal_prints(want, printed):
+    """A traced rehearsal prints every per-layer metric the manifest gives
+    its cell (``want``) and no other — but a share of a roofline
+    (``<kernel>_roofline``) may be left out: it needs a published peak, and
+    no CPU has one.  ``step_roofline`` is left out for that reason."""
+    assert set(printed) <= set(want), set(printed) - set(want)
+    left_out = set(want) - set(printed)
+    assert all(name.endswith("_roofline") for name in left_out), left_out
+    assert "step_roofline" in left_out
+
+
 # -- median arithmetic --------------------------------------------------------
 
 
@@ -501,8 +512,7 @@ def test_rehearsal_runs_the_added_cell_and_prints_no_result(
         want = {e["name"] for e in m.metrics_for("per_layer", "twopc3-tiny")}
         assert "twin_compile_s" not in want and "acquire_check_s" not in want
         assert {"depth_levels", "fingerprint_bridge_s", "dispatch_s"} <= want
-        # no peaks on a CPU: no roofline
-        assert set(out["metrics"]) == want - {"step_roofline"}
+        assert_a_rehearsal_prints(want, out["metrics"])
         assert out["metrics"]["depth_levels"]["value"] == 10.0
         assert out["metrics"]["growth_s"]["value"] == 0.0
         assert out["metrics"]["cache_misses"]["unit"] == "count"

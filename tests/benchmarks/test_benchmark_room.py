@@ -1,0 +1,154 @@
+"""The proof that a cell is data: the harness's own tests ask the manifest,
+they do not pin its contents.
+
+``benchmarks/README.md`` promises that a configuration, a cell and a
+per-layer metric are added by ADDING files and manifest entries.  A test of
+the harness that counts the cells, or fixes a reader's ``workloads`` list,
+breaks that promise for every later PR: it may not edit the test (the test
+is under the benchmark's ``paths``), and it may not leave it red.  So here a
+copy of the committed manifest gains what such a PR brings — one closed
+cell, one cold cell, one configuration, one reader listed for a new cell
+alone — and EVERY test under ``tests/benchmarks/`` that reads the manifest
+(each ``test_*`` function whose one argument is the ``manifest`` fixture,
+found by that signature, so a test added later is held to this too) is run
+on the copy.  CPU-only, unit-cheap.
+"""
+
+import inspect
+import json
+import os
+import shutil
+import sys
+
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(os.path.dirname(HERE))
+BENCH = os.path.join(REPO, "benchmarks")
+DATA = os.path.join(HERE, "data")
+sys.path.insert(0, BENCH)
+sys.path.insert(0, HERE)
+
+import test_benchmark_loops as loops  # noqa: E402
+import test_benchmark_own as own  # noqa: E402
+import test_benchmark_stages as stages  # noqa: E402
+import test_benchmark_sym as sym  # noqa: E402
+import test_benchmark_twin as twin  # noqa: E402
+from srbench import check as chk  # noqa: E402
+from srbench.manifest import Manifest  # noqa: E402
+
+CLOSED, COLD, CONFIG, READER = ("linreg2x2o-tiny", "linreg2x2o-cold", "linreg2x2o",
+                                "checks_in_window")
+MANIFEST_READERS = sorted(
+    (fn for module in (loops, own, stages, sym, twin)
+     for name, fn in vars(module).items()
+     if name.startswith("test_") and inspect.isfunction(fn)
+     and list(inspect.signature(fn).parameters) == ["manifest"]),
+    key=lambda fn: (fn.__module__, fn.__name__),
+)
+
+
+@pytest.fixture(scope="module")
+def roomier(tmp_path_factory):
+    """The committed benchmark plus what a later ``model_config`` PR adds,
+    as files and manifest entries only."""
+    root = tmp_path_factory.mktemp("bench_room")
+    bench = root / "benchmarks"
+    for sub in ("workloads", "layer_metrics", "configs"):
+        shutil.copytree(os.path.join(BENCH, sub), bench / sub)
+    before = {str(p.relative_to(root)): p.read_bytes()
+              for p in bench.rglob("*") if p.is_file()}
+    # one configuration, stated in full as a committed one is
+    cfg = json.load(open(os.path.join(DATA, f"{CONFIG}.json")))
+    cfg.update({
+        "client_count": 2,
+        "reduced_from": {"client_count": {"source": 3, "here": 2, "why": "tiny"}},
+        "deployment": {"servers": 2, "clients": 2},
+        "assumed": {"device_twin": "compiled by actor_compiler"},
+        "guarantees": ["exact unique-state count over the whole reachable space"],
+    })
+    (bench / "configs" / f"{CONFIG}.json").write_text(json.dumps(cfg))
+    # one closed cell and one cold cell on it
+    for cell in (CLOSED, COLD):
+        shutil.copy(os.path.join(DATA, f"{cell}.json"), bench / "workloads")
+    # one reader, listed for the new cold cell alone
+    (bench / "layer_metrics" / f"{READER}.py").write_text(
+        'UNIT = "count"\nLAYER = "host run loop"\nMOVES = "check_s"\n'
+        'SOURCE = "program_counter"\n\n\n'
+        "def read(ctx):\n"
+        '    return float(len(ctx["checks"]))\n'
+    )
+    doc = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+    doc["configs"].append({
+        "name": CONFIG, "source": "stateright examples/linearizable-register.rs",
+        "file": f"benchmarks/configs/{CONFIG}.json", "reduced": ["client_count"],
+        "why": "tiny",
+    })
+    for cell in (CLOSED, COLD):
+        wl = json.load(open(os.path.join(DATA, f"{cell}.json")))
+        doc["workloads"].append({
+            "name": cell, "config": CONFIG, "traffic": wl["traffic"], "chips": 1,
+            "why": "a tiny cell of the benchmark's own tests",
+        })
+    for m in doc["per_layer"]:
+        # compiled twins both: they join those readers' lists; the cold one
+        # the cold loop's own two as well
+        if "linreg2x3o-presized" in m.get("workloads", []):
+            m["workloads"].append(CLOSED)
+        if "linreg2x3o-cold" in m.get("workloads", []):
+            m["workloads"].append(COLD)
+    doc["per_layer"].append({
+        "name": READER, "unit": "count", "better": "higher",
+        "source": "program_counter", "layer": "host run loop",
+        "moves": "check_s", "workloads": [COLD],
+    })
+    (root / "BENCHMARK.json").write_text(json.dumps(doc))
+    assert {k: (root / k).read_bytes() for k in before} == before
+    return root, Manifest(str(root / "BENCHMARK.json"), str(bench))
+
+
+def test_the_copy_has_more_of_everything_and_is_sound(roomier):
+    _, more = roomier
+    committed = Manifest(os.path.join(REPO, "BENCHMARK.json"), BENCH).doc
+    assert more.problems() == []
+    for key, added in (("workloads", 2), ("configs", 1), ("per_layer", 1)):
+        assert len(more.doc[key]) == len(committed[key]) + added
+    kinds = {w["name"]: chk.loop_kind(more.workload(w["name"]))
+             for w in more.doc["workloads"]}
+    assert (kinds[CLOSED], kinds[COLD]) == ("closed", "cold")
+    # the new reader is the new cold cell's alone
+    assert [w["name"] for w in more.doc["workloads"]
+            if READER in {m["name"] for m in more.metrics_for("per_layer", w["name"])}
+            ] == [COLD]
+
+
+def test_the_tests_that_read_the_manifest_are_found():
+    names = {fn.__name__ for fn in MANIFEST_READERS}
+    assert {"test_every_committed_workload_names_a_known_kind",
+            "test_the_cell_is_presized_for_the_pinned_space",
+            "test_manifest_and_files_agree",
+            "test_manifest_has_exactly_the_contract_keys",
+            "test_every_reader_file_repeats_its_manifest_entry",
+            "test_config_files_state_source_cut_guarantees_and_pins",
+            "test_the_symmetric_cell_is_presized_for_the_pinned_space"} <= names
+
+
+@pytest.mark.parametrize("held", MANIFEST_READERS,
+                         ids=lambda fn: f"{fn.__module__}.{fn.__name__}")
+def test_a_test_that_reads_the_manifest_holds_with_more_in_it(roomier, held):
+    _, more = roomier
+    held(more)
+
+
+def test_a_rehearsal_of_an_added_cell_prints_what_the_manifest_gives_it(roomier):
+    """The third repaired predicate, on the copy: a traced rehearsal of the
+    added cold cell prints every per-layer metric the copy's manifest gives
+    it — the reader of its own among them — and leaves out only shares of a
+    roofline (no peak on a CPU), however many of those there are."""
+    root, more = roomier
+    out = loops._result(loops._rehearse(root, COLD, trace=1))
+    assert out["correct"] is True and out["failed"] == 0
+    want = {m["name"] for m in more.metrics_for("per_layer", COLD)}
+    assert READER in want and "acquire_check_s" in want
+    own.assert_a_rehearsal_prints(want, out["metrics"])
+    assert out["metrics"][READER]["value"] == out["attempted"]
