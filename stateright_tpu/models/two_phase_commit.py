@@ -27,6 +27,8 @@ from ..parallel.tensor_model import (
     FieldWriter,
     TensorBackedModel,
     TensorModel,
+    pack_by_rank,
+    stable_rank,
 )
 from ..symmetry import RewritePlan
 from ._cli import (
@@ -275,39 +277,33 @@ class TwoPhaseTensor(TensorModel):
         same permutation).  Must replicate the object form *exactly* — the
         host sorts the RM state **strings** ("aborted" < "committed" <
         "prepared" < "working"), which is the reverse of the 2-bit codes, so
-        the device sort key is ``3 - code``; stable argsort then yields the
+        the device sort key is ``3 - code``; its stable rank then is the
         identical permutation, preserving the pinned symmetry counts
-        (665 @ 5 RMs, reference ``2pc.rs:138``)."""
+        (665 @ 5 RMs, reference ``2pc.rs:138``).
+
+        No sort and no gather: each RM's rank comes from compares over the
+        candidate lanes (``stable_rank``) and its three fields are shifted
+        straight to that rank in the packed words (``pack_by_rank``).  The
+        ``argsort`` + three ``take_along_axis`` this replaces were 5.9 s of a
+        7.35 s 2pc-13 check (12 ns a gathered lane; PERF.md section 6,
+        PR 34)."""
         import jax.numpy as jnp
 
         n, pk = self.n, self.packer
         u64 = jnp.uint64
-        rm = pk.get(rows, "rm")
-        tp = pk.get(rows, "tm_prepared")
-        mp = pk.get(rows, "msg_prepared")
-        rmv = jnp.stack(
-            [((rm >> u64(2 * i)) & u64(3)).astype(jnp.int32) for i in range(n)],
-            -1,
-        )  # [..., n]
-        tpv = jnp.stack(
-            [((tp >> u64(i)) & u64(1)).astype(jnp.int32) for i in range(n)], -1
-        )
-        mpv = jnp.stack(
-            [((mp >> u64(i)) & u64(1)).astype(jnp.int32) for i in range(n)], -1
-        )
-        order = jnp.argsort(3 - rmv, axis=-1, stable=True)  # new -> old
-        rms = jnp.take_along_axis(rmv, order, axis=-1)
-        tps = jnp.take_along_axis(tpv, order, axis=-1)
-        mps = jnp.take_along_axis(mpv, order, axis=-1)
-        zero = jnp.zeros_like(rm)
-        rm_new, tp_new, mp_new = zero, zero, zero
-        for i in range(n):
-            rm_new = rm_new | (rms[..., i].astype(u64) << u64(2 * i))
-            tp_new = tp_new | (tps[..., i].astype(u64) << u64(i))
-            mp_new = mp_new | (mps[..., i].astype(u64) << u64(i))
-        rows = pk.set(rows, "rm", rm_new)
-        rows = pk.set(rows, "tm_prepared", tp_new)
-        rows = pk.set(rows, "msg_prepared", mp_new)
+
+        def columns(name, bits):
+            field = pk.get(rows, name)
+            return [
+                ((field >> u64(bits * i)) & u64((1 << bits) - 1)).astype(jnp.int32)
+                for i in range(n)
+            ]
+
+        fields = {"rm": 2, "tm_prepared": 1, "msg_prepared": 1}
+        cols = {name: columns(name, bits) for name, bits in fields.items()}
+        ranks = stable_rank([3 - code for code in cols["rm"]])  # old -> new
+        for name, bits in fields.items():
+            rows = pk.set(rows, name, pack_by_rank(cols[name], ranks, bits))
         return rows
 
     # -- device --------------------------------------------------------------

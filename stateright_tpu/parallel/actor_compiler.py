@@ -83,7 +83,14 @@ from .history_tensor import (
     MultiOpLinHistoryCodec,
 )
 from ..telemetry.spans import TWIN_HISTORY, TWIN_NET, TWIN_TABLE, span
-from .tensor_model import BitPacker, FieldWriter, TensorModel
+from .tensor_model import (
+    BitPacker,
+    FieldWriter,
+    TensorModel,
+    pack_by_rank,
+    place_by_rank,
+    stable_rank,
+)
 
 #: envelope-kind codes for the history/property tables
 _K_OTHER, _K_PUT_OK, _K_GET_OK, _K_PUT_FAIL = 0, 1, 2, 3
@@ -1221,35 +1228,27 @@ class CompiledActorTensor(TensorModel):
         pk = self.pk
         n = self.n_actors
         fact = self._sym_tables["fact"]
-        ar = jnp.arange(n, dtype=i32)
-
-        ucodes = jnp.stack(
-            [
-                cst["umaps"][i][pk.get(rows, f"a{i}").astype(i32)]
-                for i in range(n)
-            ],
-            axis=-1,
-        )  # [..., n]
-        keys = cst["keys"][ucodes]
-        order = jnp.argsort(keys, axis=-1, stable=True)  # new -> old
-        mapping = jnp.argsort(order, axis=-1)  # old -> new (plan.mapping)
+        ucols = [
+            cst["umaps"][i][pk.get(rows, f"a{i}").astype(i32)] for i in range(n)
+        ]
+        keys = cst["keys"][jnp.stack(ucols, axis=-1)]  # [..., n]
+        # old -> new (plan.mapping): the stable sort's rank, from compares
+        mapping = stable_rank([keys[..., i] for i in range(n)])
         # lexicographic rank of the mapping tuple = table permutation index
-        lead = ucodes.shape[:-1]
+        lead = ucols[0].shape
         perm_id = jnp.zeros(lead, i32)
         for k in range(n):
             c = jnp.zeros(lead, i32)
             for j in range(k + 1, n):
-                c = c + (mapping[..., j] < mapping[..., k]).astype(i32)
+                c = c + (mapping[j] < mapping[k]).astype(i32)
             perm_id = perm_id + c * jnp.int32(fact[k])
 
-        usorted = jnp.take_along_axis(ucodes, order, axis=-1)  # [..., n]
+        usorted = jnp.stack(place_by_rank(ucols, mapping), axis=-1)  # [..., n]
         codes2 = cst["rw"][perm_id[..., None], usorted]  # [..., n]
 
         if self._has_timers:
             tb = pk.get(rows, "timers").astype(i32)  # [...]
-            bits = (tb[..., None] >> ar) & 1
-            bits = jnp.take_along_axis(bits, order, axis=-1)
-            tword = jnp.sum(bits << ar, axis=-1)
+            tword = pack_by_rank([(tb >> i) & 1 for i in range(n)], mapping, 1)
         else:
             tword = jnp.zeros(lead, i32)
 

@@ -451,3 +451,64 @@ def select_along_axis(cols, idx):
     for k in range(n - 2, -1, -1):
         out = jnp.where(idx == k, cols[..., k : k + 1], out)
     return out
+
+
+def stable_rank(keys):
+    """Where each of ``n`` key columns lands in their stable ascending sort:
+    ``rank[i] = #{j : keys[j] < keys[i]} + #{j < i : keys[j] == keys[i]}``,
+    the old -> new mapping ``argsort(argsort(stack(keys, -1), stable=True))``
+    (``jnp.argsort(..., stable=True)`` itself is the inverse, new -> old).
+
+    ``keys`` is a LIST of ``n`` equally shaped arrays — one per actor, the
+    candidates in the lanes — never one ``[..., n]`` array: a minor axis of
+    13 pads to 128 lanes on a v5e.  One compare a pair, ``n (n - 1) / 2`` in
+    all, elementwise over the lanes; no ``sort`` and no ``gather``.  Returns
+    ``n`` int32 arrays of the keys' shape, a permutation of ``0 .. n - 1`` in
+    every lane."""
+    import jax.numpy as jnp
+
+    n = len(keys)
+    ranks = [jnp.full(keys[0].shape, i, jnp.int32) for i in range(n)]
+    for i in range(n):
+        for j in range(i + 1, n):
+            # the later column sorts first only on a strictly smaller key
+            swap = (keys[j] < keys[i]).astype(jnp.int32)
+            ranks[i] = ranks[i] + swap
+            ranks[j] = ranks[j] - swap
+    return ranks
+
+
+def place_by_rank(cols, ranks):
+    """Permute ``n`` columns to their ranks: ``out[p]`` is the ``cols[i]``
+    whose ``ranks[i] == p`` — ``take_along_axis(stack(cols, -1),
+    argsort(keys, stable=True), -1)[..., p]`` for ``ranks =
+    stable_rank(keys)`` — as ``n`` select chains over the lanes instead of
+    an element gather (which costs 9-16 ns a lane on a v5e whatever it
+    gathers from; PERF.md section 6, PR 31).  ``ranks`` must be a
+    permutation in every lane.  Returns a list of ``n`` arrays."""
+    import jax.numpy as jnp
+
+    n = len(cols)
+    out = []
+    for p in range(n):
+        placed = cols[n - 1]
+        for i in range(n - 2, -1, -1):
+            placed = jnp.where(ranks[i] == p, cols[i], placed)
+        out.append(placed)
+    return out
+
+
+def pack_by_rank(cols, ranks, bits):
+    """``place_by_rank`` for columns that are ``bits``-wide fields of one
+    packed word: ``OR_i cols[i] << (bits * ranks[i])`` — each field shifted
+    straight to its rank's position, ``n`` variable shifts and no selects.
+    ``cols`` hold values below ``2 ** bits``.  The word is a ``uint32`` where
+    ``n * bits`` fits one and a ``uint64`` beyond: a variable shift of a u64
+    costs more than one of a u32 (PERF.md section 6, PR 34)."""
+    import jax.numpy as jnp
+
+    dtype = jnp.uint32 if len(cols) * bits <= 32 else jnp.uint64
+    word = jnp.zeros(cols[0].shape, dtype)
+    for c, r in zip(cols, ranks):
+        word = word | (c.astype(dtype) << (r * bits).astype(dtype))
+    return word

@@ -83,6 +83,7 @@ from ..telemetry.spans import (
     STAGE_POP,
     STAGE_PROPS,
     STAGE_STATS,
+    SYM_CANON,
 )
 from ..telemetry.spans import span as tel_span
 from ..testing import faults
@@ -487,7 +488,18 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
             # rows) but dedups / keys the table on the canonical class member's
             # hash — the host analogue is ``checker/dfs.py::_dedup_key``, and it
             # preserves the reference's pinned symmetry counts (2pc.rs:138).
-            krows = tensor.representative_rows(succ) if sym else succ
+            if sym:
+                with jax.named_scope(SYM_CANON):
+                    # the barrier keeps the canonicaliser in the successor
+                    # block's layout: fused into row_hash's flat candidate
+                    # layout XLA splits it in two and copies + reshapes every
+                    # per-actor column between them (2pc-13: ~100 a step,
+                    # 0.017 of the stage's 0.020 s; PERF.md section 6, PR 34)
+                    krows = jax.lax.optimization_barrier(
+                        tensor.representative_rows(succ)
+                    )
+            else:
+                krows = succ
             if por is not None:
                 # ample-set selection: expand only a minimal conflict-closed
                 # subset of each row's enabled actions; the boost scalar (set
@@ -795,7 +807,11 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
         qdepth = jnp.zeros((qalloc,), jnp.uint32)
 
         irows = jnp.asarray(init_rows_np)
-        ifp = row_hash(tensor.representative_rows(irows) if sym else irows)
+        ikrows = irows
+        if sym:
+            with jax.named_scope(STAGE_HASH), jax.named_scope(SYM_CANON):
+                ikrows = tensor.representative_rows(irows)
+        ifp = row_hash(ikrows)
         tfp, tpl, sel, n_new, overflow, _ = bucket_insert(
             tfp, tpl, ifp,
             jnp.zeros((n_init,), jnp.uint64),  # parent 0 = "is an init state"

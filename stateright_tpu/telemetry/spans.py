@@ -88,6 +88,11 @@ TWIN_NET = "twin.net"  # slot deliver / send / canonicalise
 TWIN_HISTORY = "twin.history"  # the linearizability history fields
 TWIN_SCOPES = (TWIN_TABLE, TWIN_NET, TWIN_HISTORY)
 
+# Sub-scope of ``sr.hash`` around the twin's ``representative_rows``, opened
+# only by a step program built under ``.symmetry()``: the canonicaliser's
+# operations carry ``sr.hash/sym.canon`` (their stage stays ``sr.hash``).
+SYM_CANON = "sym.canon"
+
 # a host span ``name`` is ``sr/<name>`` in the profiler's trace
 ANNOTATION_PREFIX = "sr/"
 

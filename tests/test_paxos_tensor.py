@@ -128,19 +128,20 @@ def test_select_along_axis_is_take_along_axis_in_range(n, dtype):
         assert np.array_equal(np.asarray(got), want)
 
 
-def gather_call_sites(jaxpr) -> list:
-    """Result shape of every ``gather`` the program RUNS: a sub-jaxpr is
-    walked once per equation that calls it (``jnp.take_along_axis`` is a
-    cached jit, so ten calls share one jaxpr object and
-    ``analysis/jaxpr_audit._walk_jaxprs`` reports them as one)."""
+def gather_call_sites(jaxpr, primitive="gather") -> list:
+    """Result shape of every ``gather`` (or other ``primitive``, ``sort``
+    say) the program RUNS: a sub-jaxpr is walked once per equation that
+    calls it (``jnp.take_along_axis`` is a cached jit, so ten calls share
+    one jaxpr object and ``analysis/jaxpr_audit._walk_jaxprs`` reports them
+    as one)."""
     shapes = []
     for eqn in getattr(jaxpr, "jaxpr", jaxpr).eqns:
-        if eqn.primitive.name == "gather":
+        if eqn.primitive.name == primitive:
             shapes.append(tuple(eqn.outvars[0].aval.shape))
         for p in eqn.params.values():
             for sub in p if isinstance(p, (list, tuple)) else (p,):
                 if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
-                    shapes += gather_call_sites(sub)
+                    shapes += gather_call_sites(sub, primitive)
     return shapes
 
 

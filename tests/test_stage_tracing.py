@@ -66,6 +66,21 @@ def test_scopes_are_metadata_the_jaxpr_does_not_change(tiny, monkeypatch):
     assert run_jaxpr() == named
 
 
+def test_symmetry_names_the_canonicaliser_inside_sr_hash_and_only_then(tiny):
+    """``sym.canon`` wraps ``representative_rows`` in both programs of a
+    check built under ``.symmetry()``; a plain check opens no such scope (its
+    step program is the one the compile cache already holds)."""
+    assert not spans.SYM_CANON.startswith("sr.")  # never a stage of its own
+    assert spans.SYM_CANON not in tiny["run"] + tiny["init"]
+    c = TwoPhaseSys(3).checker().symmetry().spawn_tpu(sync=True, **_KW)
+    c.join()
+    init_fn, run_fn = c._engine(c._cap, c._qcap, c._batch, c._cand)
+    carry, _ = init_fn()
+    canon = f"/{spans.STAGE_HASH}/{spans.SYM_CANON}/"
+    assert canon in run_fn.lower(tuple(carry)).as_text(debug_info=True)
+    assert canon in init_fn.lower().as_text(debug_info=True)
+
+
 # -- device_steps ----------------------------------------------------------------
 
 
