@@ -65,6 +65,7 @@ from ..parallel.tensor_model import (
 )
 from ..semantics.linearizability import LinearizabilityTester
 from ..semantics.register import READ, Register, write
+from ..telemetry.spans import PROPS_LIN
 
 S = 3  # servers (the benchmark configuration is fixed at 3)
 
@@ -704,6 +705,7 @@ class PaxosTensor(TensorModel):
         return succ, valid
 
     def property_masks(self, rows):
+        import jax
         import jax.numpy as jnp
 
         from ..parallel.history_tensor import closure_verdict
@@ -712,27 +714,28 @@ class PaxosTensor(TensorModel):
         i32 = jnp.int32
         B = rows.shape[0]
 
-        phase = jnp.stack(
-            [pk.get(rows, f"c{c}_phase").astype(i32) for c in range(C)], -1
-        )  # [B, C]
-        rval = jnp.stack(
-            [pk.get(rows, f"c{c}_rval").astype(i32) for c in range(C)], -1
-        )
-        snap = jnp.stack(
-            [pk.get(rows, f"c{c}_snap").astype(i32) for c in range(C)], -1
-        )
-        hvalid = pk.get(rows, "hvalid") == jnp.uint64(1)
+        with jax.named_scope(PROPS_LIN):
+            phase = jnp.stack(
+                [pk.get(rows, f"c{c}_phase").astype(i32) for c in range(C)], -1
+            )  # [B, C]
+            rval = jnp.stack(
+                [pk.get(rows, f"c{c}_rval").astype(i32) for c in range(C)], -1
+            )
+            snap = jnp.stack(
+                [pk.get(rows, f"c{c}_snap").astype(i32) for c in range(C)], -1
+            )
+            hvalid = pk.get(rows, "hvalid") == jnp.uint64(1)
 
-        # s[b, i, t] = ops thread t had completed when thread i's read was
-        # invoked (the snapshot recorded at get-invocation; self slot 0)
-        done = phase == 2
-        s = jnp.zeros((B, C, C), i32)
-        for i in range(C):
-            for t in range(C):
-                if t == i:
-                    continue
-                s = s.at[:, i, t].set((snap[:, i] >> (2 * t)) & 3)
-        linearizable = closure_verdict(done, s, rval) & hvalid
+            # s[b, i, t] = ops thread t had completed when thread i's read was
+            # invoked (the snapshot recorded at get-invocation; self slot 0)
+            done = phase == 2
+            s = jnp.zeros((B, C, C), i32)
+            for i in range(C):
+                for t in range(C):
+                    if t == i:
+                        continue
+                    s = s.at[:, i, t].set((snap[:, i] >> (2 * t)) & 3)
+            linearizable = closure_verdict(done, s, rval) & hvalid
 
         # "value chosen": some get_ok with a non-null value is in flight
         slots = rows[:, self.pw :]

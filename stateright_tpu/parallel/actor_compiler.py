@@ -82,7 +82,9 @@ from .history_tensor import (
     LinHistoryCodec,
     MultiOpLinHistoryCodec,
 )
-from ..telemetry.spans import TWIN_HISTORY, TWIN_NET, TWIN_TABLE, span
+from ..telemetry.spans import (
+    PROPS_LIN, TWIN_HISTORY, TWIN_NET, TWIN_TABLE, span,
+)
 from .tensor_model import (
     BitPacker,
     FieldWriter,
@@ -345,9 +347,12 @@ class CompiledActorTensor(TensorModel):
     def compile_attrs(self) -> dict:
         """What the closure and tabulation came to (the ``twin_compile``
         span's attributes): per-actor state universes, the envelope
-        universe, the row, and the bytes of the look-up tables the step
+        universe, the row, the bytes of the look-up tables the step
         program holds on the device (what :meth:`_consts` uploads, plus
-        the history verdict table where the codec needs one)."""
+        the history verdict table where the codec needs one), and the
+        linearizability history the packed word carries: the codec's
+        verdict strategy (``closure`` / ``table``; ``none`` for a model
+        without a history), its client threads and their bits."""
         tables = [
             *self._trans_np, *self._sends_np, *self._poison_np,
             self._env_dst, self._env_pair, self._env_kind, self._env_val,
@@ -366,14 +371,18 @@ class CompiledActorTensor(TensorModel):
             if entry is not None:
                 t = entry[1]
                 tables += t if isinstance(t, list) else list(t.values())
-        if self.hist is not None and self.hist._table_built:
-            tables += [self.hist.table_keys, self.hist.table_ok]
+        hist = self.hist
+        if hist is not None and hist._table_built:
+            tables += [hist.table_keys, hist.table_ok]
         return {
             "actor_states": ",".join(str(len(s)) for s in self._states),
             "envelopes": len(self._envs),
             "n_slots": int(self.n_slots),
             "row_width": int(self.width),
             "table_bytes": int(sum(np.asarray(t).nbytes for t in tables)),
+            "hist_strategy": "none" if hist is None else hist.strategy,
+            "hist_threads": 0 if hist is None else int(hist.C),
+            "hist_bits": 0 if hist is None else int(hist.C * hist.thread_bits),
         }
 
     # -- fragment check ------------------------------------------------------
@@ -2269,6 +2278,7 @@ class CompiledActorTensor(TensorModel):
         return jnp.asarray(self._client_of)
 
     def property_masks(self, rows):
+        import jax
         import jax.numpy as jnp
 
         cst = self._consts()
@@ -2302,52 +2312,8 @@ class CompiledActorTensor(TensorModel):
                 [eval_factored(e) for e in cst["props"]], axis=-1
             )
 
-        phases = jnp.stack(
-            [pk.get(rows, f"h{c}_phase").astype(i32) for c in range(self.C)],
-            -1,
-        )
-        rvals = jnp.stack(
-            [pk.get(rows, f"h{c}_rval").astype(i32) for c in range(self.C)],
-            -1,
-        )
-        if self._multi:
-            snaps = jnp.stack(
-                [
-                    jnp.stack(
-                        [
-                            pk.get(rows, f"h{c}_snap{m}").astype(i32)
-                            for m in range(self.hist.K)
-                        ],
-                        -1,
-                    )
-                    for c in range(self.C)
-                ],
-                -2,
-            )  # [B, C, K]
-            keys = self.hist.device_key(phases, snaps, rvals)
-            linearizable = self.hist.device_lookup(keys)
-        else:
-            snaps = jnp.stack(
-                [
-                    pk.get(rows, f"h{c}_snap").astype(i32)
-                    for c in range(self.C)
-                ],
-                -1,
-            )
-            wfails = None
-            if self.hist.wfail_bits:
-                wfails = jnp.stack(
-                    [
-                        pk.get(rows, f"h{c}_wfail").astype(i32)
-                        for c in range(self.C)
-                    ],
-                    -1,
-                )
-            if self.hist.strategy == "closure":
-                linearizable = self.hist.device_verdict(phases, snaps, rvals)
-            else:
-                keys = self.hist.device_key(phases, snaps, rvals, wfails)
-                linearizable = self.hist.device_lookup(keys)
+        with jax.named_scope(PROPS_LIN):
+            linearizable = self._linearizable_mask(rows)
 
         if self.per_channel:
             # read ONLY the chosen-capable channels' regions: get_ok
@@ -2385,3 +2351,55 @@ class CompiledActorTensor(TensorModel):
             ],
             axis=-1,
         )
+
+    def _linearizable_mask(self, rows):
+        """The history fields of ``rows`` decoded and held to the codec's
+        verdict (closure or table): ``[batch]`` bool."""
+        import jax.numpy as jnp
+
+        i32 = jnp.int32
+        pk = self.pk
+        phases = jnp.stack(
+            [pk.get(rows, f"h{c}_phase").astype(i32) for c in range(self.C)],
+            -1,
+        )
+        rvals = jnp.stack(
+            [pk.get(rows, f"h{c}_rval").astype(i32) for c in range(self.C)],
+            -1,
+        )
+        if self._multi:
+            snaps = jnp.stack(
+                [
+                    jnp.stack(
+                        [
+                            pk.get(rows, f"h{c}_snap{m}").astype(i32)
+                            for m in range(self.hist.K)
+                        ],
+                        -1,
+                    )
+                    for c in range(self.C)
+                ],
+                -2,
+            )  # [B, C, K]
+            keys = self.hist.device_key(phases, snaps, rvals)
+            return self.hist.device_lookup(keys)
+        snaps = jnp.stack(
+            [
+                pk.get(rows, f"h{c}_snap").astype(i32)
+                for c in range(self.C)
+            ],
+            -1,
+        )
+        wfails = None
+        if self.hist.wfail_bits:
+            wfails = jnp.stack(
+                [
+                    pk.get(rows, f"h{c}_wfail").astype(i32)
+                    for c in range(self.C)
+                ],
+                -1,
+            )
+        if self.hist.strategy == "closure":
+            return self.hist.device_verdict(phases, snaps, rvals)
+        keys = self.hist.device_key(phases, snaps, rvals, wfails)
+        return self.hist.device_lookup(keys)

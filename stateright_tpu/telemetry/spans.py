@@ -93,6 +93,13 @@ TWIN_SCOPES = (TWIN_TABLE, TWIN_NET, TWIN_HISTORY)
 # operations carry ``sr.hash/sym.canon`` (their stage stays ``sr.hash``).
 SYM_CANON = "sym.canon"
 
+# Sub-scope of ``sr.props`` around the linearizability verdict of a twin
+# whose state holds a history (the compiled actor twin's and
+# ``PaxosTensor``'s ``property_masks``): the history fields' decoding and
+# the closure / table verdict carry ``sr.props/props.lin``; the stage's
+# other properties (``value chosen``'s slot scan) do not.
+PROPS_LIN = "props.lin"
+
 # a host span ``name`` is ``sr/<name>`` in the profiler's trace
 ANNOTATION_PREFIX = "sr/"
 

@@ -84,8 +84,9 @@ SCHEMA = {
         # attempt), attempt ordinal, gen (autosave), pending
         # (spill_drain), cap/unique (grow), key/slot (fleet
         # job), jobs/slots (fleet root), rung/source (engine_acquire),
-        # dsteps (device_call), status (grow), the universes, row and
-        # table bytes of a compiled actor twin (twin_compile)
+        # dsteps (device_call), status (grow), the universes, row,
+        # table bytes and history codec of a compiled actor twin
+        # (twin_compile)
         {"v": int, "name": str, "trace_id": str, "span_id": str,
          "dur": _REAL},
         {"parent_id": str, "engine": str, "error": str, "attempt": int,
@@ -93,7 +94,8 @@ SCHEMA = {
          "key": str, "slot": int, "jobs": int, "slots": int,
          "rung": str, "source": str, "dsteps": int, "status": str,
          "actor_states": str, "envelopes": int, "n_slots": int,
-         "row_width": int, "table_bytes": int},
+         "row_width": int, "table_bytes": int, "hist_strategy": str,
+         "hist_threads": int, "hist_bits": int},
     ),
     "health": (
         {"v": int, "event": str},
