@@ -20,8 +20,9 @@ effectively index-serial, so the fix is architectural, not incremental:
    the pinned 2PC-7 occupancy series is back at the Poisson expectation
    (``tests/test_telemetry.py``), and ``tests/test_buckets.py`` pins
    avalanche + chi-square on the derivation itself.  Membership is ONE wide
-   gather (``[M, SLOTS]`` lines) + a vectorized lane compare — gathers are
-   cheap on TPU (the measured cost is scatters).
+   gather (``[M, SLOTS]`` lines) + a vectorized lane compare — LINE gathers
+   are cheap on TPU (the measured cost is scatters, and ELEMENT gathers: the
+   values that follow a sort ride through it as operands, ``bucket_insert``).
  - Batch candidates are sorted ONCE by their remixed key (bucket bits are
    the key's MSBs; EMPTY lanes pin to the maximal key), which simultaneously
    (a) groups equal fingerprints adjacently for first-occurrence dedup,
@@ -102,18 +103,33 @@ def window_unique(fps: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(keep, fps, EMPTY)
 
 
-def lane_compact(mask: jnp.ndarray, width: int):
-    """Order-preserving lane compaction: ``(idx, live, count)`` such
-    that ``x[idx]`` gathers the first ``width`` True lanes of ``mask``
-    to the front (``live`` flags which output lanes are real, ``count``
-    the total True lanes; dead lanes of ``idx`` are in range: they list
-    the False lanes, in order).  The one compaction of this module:
-    ``bucket_insert``'s candidate budget and the spill tier's
-    pending-deferral append both call it.
+def lane_compact(mask: jnp.ndarray, width: int, carry=(), pos=None):
+    """Order-preserving lane compaction: ``(idx, live, count, *carried)``
+    with the first ``width`` True lanes of ``mask`` at the front: ``idx``
+    their lane indices, each ``carry`` array's values at those lanes
+    (``live`` flags which output lanes are real, ``count`` the total True
+    lanes; dead lanes of ``idx`` are in range: they list the False lanes,
+    in order, and ``carried`` holds those lanes' values).  ``pos`` (unique
+    int32 in ``[0, 2^31)``, default the lane index) orders the lanes and
+    is what ``idx`` then lists.  The one compaction of this module:
+    ``bucket_insert``'s candidate budget, its novel compaction and the
+    spill tier's pending-deferral append all call it.
 
-    ONE one-operand sort of a packed u32 key — bit 31 = lane invalid, low
-    31 bits = lane index — so valid lanes sort first and stay in index
-    order: no stability, no payload operand, no ``argsort``, no scatter.
+    ONE sort of a packed u32 key — bit 31 = lane invalid, low 31 bits =
+    ``pos`` — so valid lanes sort first and stay in order: the keys are
+    unique, so no stability, no ``argsort``, no scatter.  A value that
+    has to follow its lane can ride through the sort as an operand
+    (``carry``) in place of ``x[idx]`` afterwards: on one v5e an element
+    gather costs 3.6 ns a lane alone in a program and 7.1 inside the step
+    program, whatever it gathers from (PR 36; ms a call alone on the
+    chip, at 86,016 -> 32,768 lanes / 68,608 -> 32,768: this sort + four
+    u32 gathers 0.526 / 0.528, the sort carrying the four words 0.118 /
+    0.115, the sort alone 0.054) — but every operand of a sort over
+    16,384 lanes adds ~10 s to the program's compile (this sandbox's TPU
+    compiler: 1 / 3 / 5 / 7 u32 operands at 32,768 lanes and beyond 3 /
+    19 / 44 / 79 s; at 8,192 lanes 3 s at most), so
+    ``bucket_insert`` carries through its CB-wide compaction and not
+    through its ``M``-wide one.
     It replaced ``cumsum`` + ``searchsorted(running count, 1..width)``:
     ``width`` binary searches are ~17 DEPENDENT random-access gather
     rounds, and gather latency is what this chip charges for, while a
@@ -129,14 +145,17 @@ def lane_compact(mask: jnp.ndarray, width: int):
     m = mask.shape[0]
     assert width <= m < (1 << 31), "lane index must fit the key's low 31 bits"
     count = jnp.sum(mask, dtype=jnp.int32)
-    key = jnp.where(mask, jnp.uint32(0), jnp.uint32(1 << 31)) | jnp.arange(
-        m, dtype=jnp.uint32
-    )
+    if pos is None:
+        pos = jnp.arange(m, dtype=jnp.uint32)
+    key = jnp.where(mask, jnp.uint32(0), jnp.uint32(1 << 31)) | pos.astype(jnp.uint32)
     # keys are unique, so an unstable sort has exactly one result
-    first = jax.lax.sort(key, is_stable=False)[:width]
+    first, *carried = (
+        x[:width]
+        for x in jax.lax.sort((key, *carry), num_keys=1, is_stable=False)
+    )
     idx = (first & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
     live = jnp.arange(width, dtype=jnp.int32) < count
-    return idx, live, count
+    return idx, live, count, *carried
 
 
 def bucket_of(fps, nbuckets: int) -> np.ndarray:
@@ -184,23 +203,60 @@ def bucket_insert(
     candidate budget and replays the batch, so no work is lost.
 
     ``compact=CB`` first compacts the valid lanes into a CB-wide buffer
-    (order-preserving: :func:`lane_compact`'s one packed-key sort + two
-    gathers — no scatters, no search) and runs the whole
-    sort/membership/rank/write pipeline at width CB.  Engine batches are
-    >90% EMPTY padding (static action arity vs ~2-9 enabled actions per
-    state), and on TPU the step's LATENCY scales with array width — u64
-    sorts, random-access table gathers, and index arithmetic all pay for
-    the padding lanes — so running at the real candidate count is a
-    multi-x step-time win on hardware.
+    (order-preserving: :func:`lane_compact` — no scatters, no search) and
+    runs the whole sort/membership/rank/write pipeline at width CB.
+    Engine batches are >90% EMPTY padding (static action arity vs ~2-9
+    enabled actions per state), and on TPU the step's LATENCY scales with
+    array width — u64 sorts, random-access table gathers, and index
+    arithmetic all pay for the padding lanes — so running at the real
+    candidate count is a multi-x step-time win on hardware.
+
+    **What is carried where, and what is still fetched** (PR 36).  A value
+    that has to follow a sort's permutation is an OPERAND of that sort,
+    never ``x[perm]`` afterwards: an element gather costs 7.1 ns a lane
+    inside the step program on one v5e (3.6 alone in a program), a u64
+    twice that, whatever it gathers from; a sort operand costs a small
+    fraction at run time — and ~10 s of COMPILE time each (this sandbox's
+    TPU compiler, sorts over 16,384 lanes), which is the other half of
+    every choice below.
+
+     1. the budget compaction (``lane_compact`` at ``M`` lanes) carries
+        nothing: ``fps[lane]`` stays a gather at CB lanes (two u32
+        gathers).  Carrying ``fps`` and ``payloads`` through it was timed
+        (alone on the chip, ms a call, 86,016 -> 32,768 lanes: sort + four
+        gathers 0.526, the sort carrying four words 0.118) and is faster
+        by 0.4 ms a step, but a five-operand sort at ``M`` lanes adds 40 s
+        to the compile of EVERY step program (5.4 -> 45 s at 61,440 ->
+        8,192), and a check from the defaults compiles one a rung.  Its
+        ``idx`` is the ORIGINAL lane, which every later step keeps, so
+        ``sel`` is never mapped back;
+     2. the key sort (stable, at CB lanes) carries ``fps`` and the
+        original lane beside the key;
+     3. the novel compaction (``lane_compact`` at CB lanes) carries the
+        target slot, the fingerprint and the original lane (under
+        ``generation_order`` the original lane is its ordering position);
+     4. the payload follows NO sort: it is needed only where something is
+        written, so the write loop fetches ``payloads[sel]`` by original
+        lane, ``window`` lanes a chunk (~n_new lanes a step, not CB).
+
+    The per-bucket rank's segment base is a running max, not a look-up.
+    The old body fetched all of these by index: eighteen u32 gathers at
+    CB lanes, 3.62 s of a 7.3 s-busy 2pc-8 check (eleven call sites; kept
+    verbatim as ``tests/test_buckets.py:ref_bucket_insert``, which this
+    body is held to bit for bit).  Alone on the chip at 86,016 -> 32,768
+    lanes, ms a call, old -> everything carried: compaction + key sort
+    1.743 -> 0.189; novel compaction 0.97 -> 0.04; segment base 0.29 ->
+    0.05.  What is left beside the two kept fetches: the membership loop's
+    ``[window, SLOTS]`` line gather, the chunked scatters and the
+    whole-table passes (ROADMAP Queue 1 2b).
     """
     m_orig = fps.shape[0]
     cand_overflow = jnp.bool_(False)
-    cidx = None
+    lane = jnp.arange(m_orig, dtype=jnp.int32)  # ORIGINAL lanes, all the way
     if compact is not None and compact < m_orig:
-        cidx, live, n_valid_orig = lane_compact(fps != EMPTY, compact)
+        lane, live, n_valid_orig = lane_compact(fps != EMPTY, compact)
         cand_overflow = n_valid_orig > jnp.int32(compact)
-        fps = jnp.where(live, fps[cidx], EMPTY)
-        payloads = payloads[cidx]  # dead lanes masked by the EMPTY fp above
+        fps = jnp.where(live, fps[lane], EMPTY)
     m = fps.shape[0]
     window = min(window, m)
     nslots = table_fp.shape[0]
@@ -208,10 +264,12 @@ def bucket_insert(
     assert nbuckets & (nbuckets - 1) == 0, "bucket count must be a power of two"
     bucket_bits = int(nbuckets).bit_length() - 1
 
-    key = bucket_key(fps)
-    order = jnp.argsort(key)
-    sfp = fps[order]
-    skey = key[order]
+    # stable: among equal fingerprints the LOWEST original lane comes first
+    # (compaction kept lane order), which decides the parent a state records
+    # and, under generation_order, which class member is explored
+    skey, sfp, order = jax.lax.sort(
+        (bucket_key(fps), fps, lane), num_keys=1, is_stable=True
+    )
     valid = sfp != EMPTY
     first = jnp.concatenate([jnp.ones((1,), bool), sfp[1:] != sfp[:-1]]) & valid
     bucket = (skey >> jnp.uint64(64 - bucket_bits)).astype(jnp.int32)
@@ -273,13 +331,13 @@ def bucket_insert(
     present, base = present[:m], base[:m]
     novel = first & ~present
 
-    # per-bucket insertion rank among this batch's novel candidates
-    idx = jnp.arange(m, dtype=jnp.int32)
+    # per-bucket insertion rank among this batch's novel candidates: the
+    # novel-count before the bucket's first row is non-decreasing along the
+    # sorted lanes, so a running max carries it down the bucket's rows
     bstart = jnp.concatenate([jnp.ones((1,), bool), bucket[1:] != bucket[:-1]])
-    seg_start = jax.lax.cummax(jnp.where(bstart, idx, 0))
     csum = jnp.cumsum(novel.astype(jnp.int32))
-    rank = jnp.where(novel, csum - 1 - (csum - novel)[seg_start], 0)
-    # (csum - novel)[seg_start] = novel-count before the bucket's first row
+    seg_base = jax.lax.cummax(jnp.where(bstart, csum - novel, 0))
+    rank = jnp.where(novel, csum - 1 - seg_base, 0)
 
     slot = base + rank
     overflow = jnp.any(novel & (slot >= SLOTS))
@@ -297,14 +355,15 @@ def bucket_insert(
     # explored — generation order makes the reduced search reproducible by
     # a host FIFO oracle (tests/test_tensor_models.py).  Windowed chunked
     # scatters write only ~n_new entries either way.
+    # One sort carries target slot, fingerprint and original lane to the
+    # front (under generation_order the original lane is the position
+    # itself); its keys are unique, so the lanes past n_new are
+    # deterministic too.
+    tgt = jnp.where(novel, bucket * SLOTS + slot, nslots)
     if generation_order:
-        keys = jnp.where(novel, order.astype(jnp.int32), jnp.int32(m))
+        sel, _, _, tgt, cfp = lane_compact(novel, m, carry=(tgt, sfp), pos=order)
     else:
-        keys = jnp.where(novel, idx, jnp.int32(m))
-    perm = jnp.argsort(keys)
-    tgt = jnp.where(novel, bucket * SLOTS + slot, nslots)[perm]
-    cfp = sfp[perm]
-    cpl = payloads[order][perm]
+        _, _, _, tgt, cfp, sel = lane_compact(novel, m, carry=(tgt, sfp, order))
 
     # Pad to a whole number of windows: ``dynamic_slice`` clamps its start
     # index, which would silently misalign the final chunk against its
@@ -320,23 +379,25 @@ def bucket_insert(
         k, *_ = state
         return k * window < n_new  # n_new is 0 on overflow: nothing written
 
+    # The payload is needed only where something is written, so it follows
+    # no sort: it is fetched by original lane, ``window`` lanes a chunk.
     if use_pallas:
         from .pallas_insert import pallas_scatter_insert
 
         table_fp, table_payload = pallas_scatter_insert(
-            table_fp, table_payload, tgt, cfp, cpl, n_new
+            table_fp, table_payload, tgt, cfp, payloads[sel], n_new
         )
     else:
         ptgt = padded(tgt, nslots)
         pcfp = padded(cfp, EMPTY)
-        pcpl = padded(cpl, 0)
+        psel = padded(sel, 0)
 
         def chunk_body(state):
             k, tfp, tpl = state
             off = k * window
             t = jax.lax.dynamic_slice(ptgt, (off,), (window,))
             f = jax.lax.dynamic_slice(pcfp, (off,), (window,))
-            p = jax.lax.dynamic_slice(pcpl, (off,), (window,))
+            p = payloads[jax.lax.dynamic_slice(psel, (off,), (window,))]
             in_range = jnp.arange(window, dtype=jnp.int32) + off < n_new
             t = jnp.where(in_range, t, nslots)
             tfp = tfp.at[t].set(f, mode="drop")
@@ -347,9 +408,6 @@ def bucket_insert(
             chunk_cond, chunk_body, (jnp.int32(0), table_fp, table_payload)
         )
 
-    sel = order[perm]
-    if cidx is not None:
-        sel = cidx[sel]  # map compacted positions back to original indices
     return table_fp, table_payload, sel, n_new, overflow, cand_overflow
 
 

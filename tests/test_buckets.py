@@ -2,6 +2,9 @@
 straightforward host-side set on arbitrary candidate streams (duplicates
 in-batch, duplicates vs the table, EMPTY lanes, bucket collisions)."""
 
+import sys
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -9,15 +12,18 @@ import jax
 import jax.numpy as jnp
 
 from stateright_tpu.analysis.jaxpr_audit import _iter_eqns
-from stateright_tpu.ops import buckets
 from stateright_tpu.ops.buckets import (
     SLOTS,
     bucket_insert,
+    bucket_key,
     bucket_of,
     host_bucket_rehash,
     lane_compact,
 )
 from stateright_tpu.ops.hashing import EMPTY, mix64_np
+
+sys.path.insert(0, str(Path(__file__).parent))
+from test_paxos_tensor import gather_call_sites  # noqa: E402
 
 
 def np_u64(x):
@@ -55,6 +61,16 @@ def table_contents(state):
     tfp, tpl = np.asarray(tfp), np.asarray(tpl)
     occ = tfp != EMPTY
     return dict(zip(tfp[occ].tolist(), tpl[occ].tolist()))
+
+
+def same_bucket_fps(count, nbuckets):
+    """``count`` distinct fingerprints the derivation places in bucket 0."""
+    fps, x = [], 1
+    while len(fps) < count:
+        if int(bucket_of(np.uint64(x), nbuckets)) == 0:
+            fps.append(x)
+        x += 1
+    return fps
 
 
 # re-tiered fast->slow (PR 2): the fast tier blew the 870s tier-1 budget
@@ -103,11 +119,7 @@ def test_payloads_stored_for_novel_entries():
 def test_bucket_overflow_is_clean():
     nbuckets = 4
     # SLOTS+1 distinct fps the mix64 derivation places in the SAME bucket
-    fps, x = [], 1
-    while len(fps) < SLOTS + 1:
-        if int(bucket_of(np.uint64(x), nbuckets)) == 0:
-            fps.append(x)
-        x += 1
+    fps = same_bucket_fps(SLOTS + 1, nbuckets=nbuckets)
     state = fresh(nbuckets)
     state, _, n_new, overflow = insert(state, fps)
     assert overflow
@@ -390,11 +402,7 @@ def test_blest_probe_full_bucket_overflow_parity():
     """A bucket driven past SLOTS must overflow identically (flag on and
     off) and leave both tables untouched."""
     nbuckets = 4
-    fps, x = [], 1
-    while len(fps) < SLOTS + 1:
-        if int(bucket_of(np.uint64(x), nbuckets)) == 0:
-            fps.append(x)
-        x += 1
+    fps = same_bucket_fps(SLOTS + 1, nbuckets=nbuckets)
     pay = list(range(1, len(fps) + 1))
     a = _insert_all(fresh(nbuckets), fps, pay, probe_dot=False)
     b = _insert_all(fresh(nbuckets), fps, pay, probe_dot=True)
@@ -561,42 +569,329 @@ def test_compacted_insert_is_bit_identical_to_reference_pipeline(
         assert np.all(np.diff(np.asarray(sel)[:n]) > 0)
 
 
+# --- bucket_insert carries its values through its sorts (PR 36) ---------------
+#
+# The insert used to fetch every value AFTER a sort by the permutation the sort
+# produced: ``x[order]``, ``x[perm]``, ``x[cidx]`` - eighteen element gathers at
+# the candidate width, 3.6 s of an 8.76 s 2pc-8 check on the chip.  The old body
+# lives on here, verbatim, as the reference the new one is held to bit for bit.
+
+
+def ref_bucket_insert(
+    table_fp, table_payload, fps, payloads, window, use_pallas=False,
+    generation_order=False, compact=None, probe_dot=False,
+):
+    """The pre-PR-36 ``bucket_insert`` body, verbatim."""
+    m_orig = fps.shape[0]
+    cand_overflow = jnp.bool_(False)
+    cidx = None
+    if compact is not None and compact < m_orig:
+        cidx, live, n_valid_orig = lane_compact(fps != EMPTY, compact)
+        cand_overflow = n_valid_orig > jnp.int32(compact)
+        fps = jnp.where(live, fps[cidx], EMPTY)
+        payloads = payloads[cidx]  # dead lanes masked by the EMPTY fp above
+    m = fps.shape[0]
+    window = min(window, m)
+    nslots = table_fp.shape[0]
+    nbuckets = nslots // SLOTS
+    assert nbuckets & (nbuckets - 1) == 0, "bucket count must be a power of two"
+    bucket_bits = int(nbuckets).bit_length() - 1
+
+    key = bucket_key(fps)
+    order = jnp.argsort(key)
+    sfp = fps[order]
+    skey = key[order]
+    valid = sfp != EMPTY
+    first = jnp.concatenate([jnp.ones((1,), bool), sfp[1:] != sfp[:-1]]) & valid
+    bucket = (skey >> jnp.uint64(64 - bucket_bits)).astype(jnp.int32)
+    n_valid = jnp.sum(valid).astype(jnp.int32)
+
+    # membership + occupancy-base gathers, windowed over the VALID PREFIX
+    # only (EMPTY rotates to all-ones and sorts last, so valid candidates
+    # are a prefix of the sorted order).  Random-access HBM gathers are the
+    # step's latency bottleneck on TPU — measured 11.4 ms for an M=61k-row
+    # gather from an 8M-slot table where only ~4k lanes were valid; padding
+    # lanes pay full price in a monolithic gather, and this read-only loop
+    # (typically 2-3 windows) makes the cost track the real candidate
+    # count.  Writes stay outside: the atomic nothing-written-on-overflow
+    # contract the engines' growth protocols rely on is untouched.
+    table_lines = table_fp.reshape(nbuckets, SLOTS)
+    mpad_w = (-m) % window
+    pbucket = bucket if mpad_w == 0 else jnp.concatenate(
+        [bucket, jnp.zeros((mpad_w,), jnp.int32)]
+    )
+    psfp = sfp if mpad_w == 0 else jnp.concatenate(
+        [sfp, jnp.full((mpad_w,), EMPTY, jnp.uint64)]
+    )
+
+    def mem_body(state):
+        k, present, base = state
+        off = k * window
+        wbkt = jax.lax.dynamic_slice(pbucket, (off,), (window,))
+        wfp = jax.lax.dynamic_slice(psfp, (off,), (window,))
+        lines = table_lines[wbkt]
+        if probe_dot:
+            # BLEST one-hot probe (ops/mxu.py): one blocked bitmapped
+            # matmul over the candidate x slot comparison tile replaces
+            # the reduce_or/reduce_sum pair — same (present, base) bits,
+            # but a genuine dot-class op for the MXU to chew on-chip
+            from .mxu import blest_probe
+
+            p, b = blest_probe(lines, wfp, EMPTY)
+        else:
+            p = jnp.any(lines == wfp[:, None], axis=-1)
+            # occupancy comes free from the same gathered line: slots fill
+            # densely from 0 and never free, so non-EMPTY count == next slot
+            b = jnp.sum(lines != EMPTY, axis=-1).astype(jnp.int32)
+        present = jax.lax.dynamic_update_slice(present, p, (off,))
+        base = jax.lax.dynamic_update_slice(base, b, (off,))
+        return k + 1, present, base
+
+    # initial carries derive from the (possibly mesh-varying) inputs so the
+    # loop types check inside shard_map: a literal zeros() is replicated-
+    # typed while the body's output varies over the mesh axis
+    _, present, base = jax.lax.while_loop(
+        lambda s: s[0] * window < n_valid,
+        mem_body,
+        (
+            jnp.int32(0),
+            jnp.zeros((m + mpad_w,), bool) | (n_valid < 0),
+            jnp.zeros((m + mpad_w,), jnp.int32) + n_valid * 0,
+        ),
+    )
+    present, base = present[:m], base[:m]
+    novel = first & ~present
+
+    # per-bucket insertion rank among this batch's novel candidates
+    idx = jnp.arange(m, dtype=jnp.int32)
+    bstart = jnp.concatenate([jnp.ones((1,), bool), bucket[1:] != bucket[:-1]])
+    seg_start = jax.lax.cummax(jnp.where(bstart, idx, 0))
+    csum = jnp.cumsum(novel.astype(jnp.int32))
+    rank = jnp.where(novel, csum - 1 - (csum - novel)[seg_start], 0)
+    # (csum - novel)[seg_start] = novel-count before the bucket's first row
+
+    slot = base + rank
+    overflow = jnp.any(novel & (slot >= SLOTS))
+    blocked = overflow | cand_overflow
+    # n_new = 0 on any overflow: the write loops below key on it, so the
+    # nothing-written atomicity holds for the candidate budget too
+    n_new = jnp.where(blocked, 0, jnp.sum(novel)).astype(jnp.int32)
+
+    # Compact novel candidates to the front.  Plain runs keep sorted-fp
+    # order (bucket-contiguous — the Pallas kernel then touches each line
+    # group once); the visited SET is order-independent there.  Symmetry
+    # runs compact in GENERATION order (original batch position): the dedup
+    # key is the canonical fp of a not-necessarily-class-invariant
+    # representative, so enqueue order decides which class member gets
+    # explored — generation order makes the reduced search reproducible by
+    # a host FIFO oracle (tests/test_tensor_models.py).  Windowed chunked
+    # scatters write only ~n_new entries either way.
+    if generation_order:
+        keys = jnp.where(novel, order.astype(jnp.int32), jnp.int32(m))
+    else:
+        keys = jnp.where(novel, idx, jnp.int32(m))
+    perm = jnp.argsort(keys)
+    tgt = jnp.where(novel, bucket * SLOTS + slot, nslots)[perm]
+    cfp = sfp[perm]
+    cpl = payloads[order][perm]
+
+    # Pad to a whole number of windows: ``dynamic_slice`` clamps its start
+    # index, which would silently misalign the final chunk against its
+    # ``in_range`` mask (dropping the last novel entries).
+    pad = (-m) % window
+
+    def padded(x, fill):
+        if pad == 0:
+            return x
+        return jnp.concatenate([x, jnp.full((pad,), fill, x.dtype)])
+
+    def chunk_cond(state):
+        k, *_ = state
+        return k * window < n_new  # n_new is 0 on overflow: nothing written
+
+    if use_pallas:
+        from .pallas_insert import pallas_scatter_insert
+
+        table_fp, table_payload = pallas_scatter_insert(
+            table_fp, table_payload, tgt, cfp, cpl, n_new
+        )
+    else:
+        ptgt = padded(tgt, nslots)
+        pcfp = padded(cfp, EMPTY)
+        pcpl = padded(cpl, 0)
+
+        def chunk_body(state):
+            k, tfp, tpl = state
+            off = k * window
+            t = jax.lax.dynamic_slice(ptgt, (off,), (window,))
+            f = jax.lax.dynamic_slice(pcfp, (off,), (window,))
+            p = jax.lax.dynamic_slice(pcpl, (off,), (window,))
+            in_range = jnp.arange(window, dtype=jnp.int32) + off < n_new
+            t = jnp.where(in_range, t, nslots)
+            tfp = tfp.at[t].set(f, mode="drop")
+            tpl = tpl.at[t].set(p, mode="drop")
+            return k + 1, tfp, tpl
+
+        _, table_fp, table_payload = jax.lax.while_loop(
+            chunk_cond, chunk_body, (jnp.int32(0), table_fp, table_payload)
+        )
+
+    sel = order[perm]
+    if cidx is not None:
+        sel = cidx[sel]  # map compacted positions back to original indices
+    return table_fp, table_payload, sel, n_new, overflow, cand_overflow
+
+
+REF_M, REF_CB, REF_WINDOW, REF_NBUCKETS = 101, 32, 7, 64  # 7 divides neither
+
+
+def jitted(fn):
+    return jax.jit(fn, static_argnames=("window", "generation_order", "compact"))
+
+
+INSERTS = {"new": jitted(bucket_insert), "ref": jitted(ref_bucket_insert)}
+
+
+def seeded_table(in_table):
+    tfp, tpl = fresh(REF_NBUCKETS)
+    fps = jnp.asarray(np_u64([5, 6] + list(in_table)))
+    tfp, tpl, *_ = bucket_insert(tfp, tpl, fps, fps + jnp.uint64(1), window=8)
+    return tfp, tpl
+
+
+def both_inserts(tfp0, tpl0, fps, pls, generation_order, compact):
+    out = {
+        name: fn(
+            tfp0, tpl0, jnp.asarray(fps), jnp.asarray(pls), window=REF_WINDOW,
+            generation_order=generation_order, compact=compact,
+        )
+        for name, fn in INSERTS.items()
+    }
+    (tfp, tpl, sel, n_new, ovf, covf), (rtfp, rtpl, rsel, rn_new, rovf, rcovf) = (
+        out["new"], out["ref"]
+    )
+    n = int(n_new)
+    assert n == int(rn_new)
+    assert bool(ovf) == bool(rovf) and bool(covf) == bool(rcovf)
+    # (the old body's ``sel`` was int64 without a budget: ``argsort``'s dtype)
+    assert sel.dtype == jnp.int32 and sel.shape == rsel.shape
+    assert np.array_equal(np.asarray(sel)[:n], np.asarray(rsel)[:n])
+    assert np.all((np.asarray(sel) >= 0) & (np.asarray(sel) < len(fps)))
+    assert np.array_equal(np.asarray(tfp), np.asarray(rtfp))
+    assert np.array_equal(np.asarray(tpl), np.asarray(rtpl))
+    if bool(ovf) or bool(covf):  # nothing written
+        assert n == 0
+        assert np.array_equal(np.asarray(tfp), np.asarray(tfp0))
+        assert np.array_equal(np.asarray(tpl), np.asarray(tpl0))
+    return out["new"]
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("budget", ["none", "under", "exact", "over"])
+@pytest.mark.parametrize("generation_order", [False, True])
+def test_insert_is_bit_identical_to_the_gathering_reference(
+    generation_order, budget, seed
+):
+    """Tables, ``sel[:n_new]``, ``n_new`` and both flags equal to the old
+    body's, with in-batch duplicates (the LOWEST lane wins: its payload is
+    the one stored), candidates already in the table, EMPTY lanes and a
+    ``window`` that divides neither ``m`` nor the budget; over the budget
+    nothing is written."""
+    rng = np.random.default_rng(100 + seed)
+    k = {"none": 60, "under": 20, "exact": REF_CB, "over": REF_CB + 9}[budget]
+    fps = np.full(REF_M, EMPTY, np.uint64)
+    lanes = np.sort(rng.choice(REF_M, k, replace=False))
+    fps[lanes] = rng.integers(1, 1 << 40, k).astype(np.uint64)
+    lo, mid, hi = lanes[2], lanes[7], lanes[k - 1]
+    fps[mid] = fps[hi] = fps[lo]  # one fingerprint on three lanes
+    fps[lanes[4]] = fps[lanes[3]]
+    pls = np.arange(1000, 1000 + REF_M, dtype=np.uint64)
+    tfp0, tpl0 = seeded_table([int(fps[lanes[1]]), int(fps[lanes[5]])])
+    compact = None if budget == "none" else REF_CB
+    tfp, tpl, sel, n_new, ovf, covf = both_inserts(
+        tfp0, tpl0, fps, pls, generation_order, compact
+    )
+    assert bool(covf) == (budget == "over") and not bool(ovf)
+    if budget == "over":
+        return
+    n = int(n_new)
+    assert n == len(set(fps[lanes].tolist())) - 2
+    chosen = np.asarray(sel)[:n].tolist()
+    assert lo in chosen and mid not in chosen and hi not in chosen
+    assert table_contents((tfp, tpl))[int(fps[lo])] == int(pls[lo])
+    if generation_order:
+        assert chosen == sorted(chosen)
+
+
+@pytest.mark.parametrize("budget", ["none", "under"])
+@pytest.mark.parametrize("generation_order", [False, True])
+def test_insert_bucket_overflow_matches_the_gathering_reference(
+    generation_order, budget
+):
+    """A bucket driven past SLOTS by the batch (some of its slots already
+    taken, one candidate a duplicate of the table): both flags as the old
+    body's, nothing written; one candidate fewer and the same batch lands."""
+    crowd = same_bucket_fps(SLOTS + 1, REF_NBUCKETS)
+    tfp0, tpl0 = seeded_table(crowd[:3])
+    rng = np.random.default_rng(9)
+    for extra, overflows in ((SLOTS + 1, True), (SLOTS, False)):
+        fps = np.full(REF_M, EMPTY, np.uint64)
+        lanes = np.sort(rng.choice(REF_M, 24, replace=False))
+        fps[lanes] = rng.integers(1 << 41, 1 << 42, 24).astype(np.uint64)
+        fps[lanes[: extra - 2]] = crowd[2:extra]  # crowd[2] is in the table
+        pls = np.arange(1, REF_M + 1, dtype=np.uint64)
+        out = both_inserts(
+            tfp0, tpl0, fps, pls, generation_order,
+            None if budget == "none" else REF_CB,
+        )
+        assert bool(out[4]) == overflows and not bool(out[5])
+        assert (int(out[3]) == 0) == overflows
+
+
 def primitive_names(fn, *args):
     """Every primitive of ``fn``'s traced program, sub-jaxprs included."""
     return [e.primitive.name for e in _iter_eqns(jax.make_jaxpr(fn)(*args))]
 
 
-def test_insert_compaction_is_lane_compact_and_holds_no_search(monkeypatch):
-    """One copy: ``bucket_insert(compact=CB)`` compacts through
-    ``lane_compact``, and its traced program holds no search (a
-    ``searchsorted`` traces to a ``while``/``scan``): just the membership
-    and the chunk-write ``while`` of the plain insert, and one ``sort`` more."""
-    calls = []
-    real = buckets.lane_compact
-
-    def spy(mask, width):
-        calls.append((mask.shape[0], width))
-        return real(mask, width)
-
-    monkeypatch.setattr(buckets, "lane_compact", spy)
+@pytest.mark.parametrize("generation_order", [False, True])
+def test_insert_fetches_nothing_a_sort_can_carry(generation_order):
+    """The static pin of PR 36: a value that follows a sort's permutation
+    rides through the sort as an operand, it is not fetched afterwards by
+    ``x[perm]``.  The traced ``bucket_insert(compact=CB)`` holds three
+    gathers - the membership loop's ``[window, SLOTS]`` LINE gather, the
+    budget compaction's ``fps[lane]`` at ``(CB,)`` (carrying it through the
+    ``m``-wide sort compiles 40 s slower a step program, PR 36) and the
+    write loop's ``payloads[sel chunk]`` at ``(window,)`` (a payload is
+    needed only where something is written) - and three sorts (budget
+    compaction at ``m``, key sort and novel compaction at ``CB``); no
+    search either (a ``searchsorted`` traces to a ``while``/``scan``):
+    just the membership and the chunk-write ``while``.  The old body,
+    traced the same way, holds its eighteen u32 gathers at ``(CB,)`` as
+    eleven call sites."""
+    m, cb, window = 96, 32, 8
     tfp, tpl = fresh(64)
-    fps = jnp.full((96,), EMPTY, jnp.uint64)
+    fps = jnp.full((m,), EMPTY, jnp.uint64)
 
-    def trace(compact):
-        return primitive_names(
-            lambda a, b, c, d: bucket_insert(a, b, c, d, window=8, compact=compact),
-            tfp, tpl, fps, fps,
-        )
+    def trace(fn, compact):
+        return jax.make_jaxpr(
+            lambda a, b, c, d: fn(
+                a, b, c, d, window=window, compact=compact,
+                generation_order=generation_order,
+            )
+        )(tfp, tpl, fps, fps)
 
-    plain = trace(None)
-    assert calls == []  # no budget, no compaction
-    compacted = trace(32)
-    assert calls == [(96, 32)]
     loops = ("while", "scan")
-    assert sorted(p for p in plain if p in loops) == ["while", "while"]
-    assert sorted(p for p in compacted if p in loops) == ["while", "while"]
-    assert compacted.count("sort") == plain.count("sort") + 1
-    assert compacted.count("cumsum") == plain.count("cumsum")
-    # the same trace of the old formulation does hold a search loop
-    old = primitive_names(lambda x: ref_lane_compact(x, 32), fps != EMPTY)
-    assert any(p in loops for p in old)
+    for compact, element, widths in (
+        (cb, [(cb,), (window,)], [m, cb, cb]),
+        (None, [(window,)], [m, m]),
+    ):
+        jaxpr = trace(bucket_insert, compact)
+        assert sorted(gather_call_sites(jaxpr)) == sorted(element + [(window, SLOTS)])
+        assert [s[0] for s in gather_call_sites(jaxpr, "sort")] == widths
+        names = [e.primitive.name for e in _iter_eqns(jaxpr)]
+        assert sorted(p for p in names if p in loops) == ["while", "while"]
+    old = gather_call_sites(trace(ref_bucket_insert, cb))
+    assert sorted(old) == [(window, SLOTS)] + [(cb,)] * 11  # seven of them u64
+    # and the old compaction's own formulation does hold a search loop
+    searched = primitive_names(lambda x: ref_lane_compact(x, 32), fps != EMPTY)
+    assert any(p in loops for p in searched)
