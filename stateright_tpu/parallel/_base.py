@@ -23,6 +23,7 @@ from ..checker.base import Checker, CheckerBuilder
 from ..checker.path import Path
 from ..fingerprint import MASK64
 from ..ops.hashing import row_hash
+from ..telemetry.spans import adopt_span
 from ..telemetry.spans import new_id as new_span_id
 from ..telemetry.spans import span as tel_span
 
@@ -266,9 +267,7 @@ class WavefrontChecker(Checker):
         pending = getattr(tensor, "compile_span", None)
         if pending is not None and self.flight_recorder is not None:
             tensor.compile_span = None
-            self.flight_recorder.record(
-                "span", **{**pending, "trace_id": self._trace_id}
-            )
+            adopt_span(self.flight_recorder, pending, self._trace_id)
         # host seam span: the bridge check hashes one init row with EAGER
         # device operations (a dispatch each), before the run span opens
         with tel_span("fingerprint_bridge", self.flight_recorder,

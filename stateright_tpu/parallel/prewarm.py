@@ -24,10 +24,12 @@ the same unattributed cost center — XLA engine compiles:
    time.
 
  - :class:`CompileWatch` — compile-time attribution via JAX's monitoring
-   events (``/jax/core/compile/backend_compile_duration`` and the
-   compilation-cache hit/miss events).  The engines' run loops snapshot
-   it around device calls to split "device step" from "XLA compile" wall
-   time without adding any ops to the compiled programs.  Counters are
+   events (``/jax/core/compile/backend_compile_duration``, which holds a
+   cache retrieval inside it, the lowering and retrieval durations beside
+   it, and the compilation-cache hit/miss events).  The engines' run
+   loops snapshot it around device calls to split lowering and the
+   backend's compile-or-load step out of the call without adding any ops
+   to the compiled programs.  Counters are
    PER-THREAD (jax fires the events on the compiling thread), so the run
    loop's watch never absorbs the prewarm worker's background compiles —
    each watcher sees exactly its own.
@@ -52,10 +54,15 @@ _listener_installed = False
 # counter attributed whoever compiled anywhere to whoever was watching).
 _tls = threading.local()
 
-_COMPILE_DURATION_EVENTS = (
-    "/jax/core/compile/backend_compile_duration",
-    "/jax/compilation_cache/cache_retrieval_time_sec",
-)
+# JAX's duration events this module reads (jax 0.9.0).  The backend event
+# WRAPS the retrieval one: ``pxla._cached_compilation`` times
+# ``compiler.compile_or_get_cached`` under it, and on a persistent-cache
+# hit that call records the retrieval from inside — a hit fires both, a
+# miss one.  So the two are never summed into one counter.
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
@@ -64,11 +71,23 @@ def _tls_counts() -> dict:
     counts = getattr(_tls, "counts", None)
     if counts is None:
         counts = {
-            "backend_compile_secs": 0.0,  # backend compiles + retrievals
+            # a program's whole compile-or-load step: cache key, then a
+            # retrieval and deserialisation or a fresh XLA compile
+            "backend_compile_secs": 0.0,
+            "retrieval_secs": 0.0,  # the retrievals inside the above
+            "lower_secs": 0.0,  # jaxpr -> MLIR module
+            # a COUNT: an inner jit's trace lies inside the outer's, so
+            # the trace durations nest and their sum is not a time
+            "jaxprs_traced": 0,
             "persistent_cache_hits": 0,
             "persistent_cache_misses": 0,
         }
         _tls.counts = counts
+        # open only between a CompileWatch() and its delta(): the
+        # (event, end on time.monotonic, duration, retrieval inside it or
+        # None) of each lower and each backend event, for the spans
+        _tls.events = None
+        _tls.retrieved = None  # a retrieval whose backend event is due
     return counts
 
 
@@ -87,10 +106,27 @@ def _install_listener() -> None:
                 _tls_counts()["persistent_cache_misses"] += 1
 
         def on_duration(event, duration, **kw):
-            if event in _COMPILE_DURATION_EVENTS:
-                _tls_counts()["backend_compile_secs"] += max(
-                    float(duration), 0.0
-                )
+            if event == _TRACE_EVENT:
+                _tls_counts()["jaxprs_traced"] += 1
+                return
+            if event not in (BACKEND_COMPILE_EVENT, RETRIEVAL_EVENT,
+                             LOWER_EVENT):
+                return
+            end = time.monotonic()
+            counts = _tls_counts()
+            secs = max(float(duration), 0.0)
+            if event == RETRIEVAL_EVENT:
+                counts["retrieval_secs"] += secs
+                _tls.retrieved = secs
+                return
+            retrieved = None
+            if event == LOWER_EVENT:
+                counts["lower_secs"] += secs
+            else:
+                counts["backend_compile_secs"] += secs
+                retrieved, _tls.retrieved = _tls.retrieved, None
+            if _tls.events is not None:
+                _tls.events.append((event, end, secs, retrieved))
 
         jax.monitoring.register_event_listener(on_event)
         jax.monitoring.register_event_duration_secs_listener(on_duration)
@@ -105,32 +141,46 @@ def compile_counters() -> dict:
 
 
 class CompileWatch:
-    """Delta view over :func:`compile_counters`: ``start()`` then
-    ``delta()`` yields the compile seconds and persistent-cache hits the
-    CURRENT THREAD performed in between (see module docstring)."""
+    """Delta view over :func:`compile_counters`: ``delta()`` yields what
+    the CURRENT THREAD did since the watch was made (or ``start()``-ed):
+    ``compile_secs`` (the backend's compile-or-load steps, a retrieval
+    counted once, inside its step), ``retrieval_secs``, ``lower_secs``,
+    ``jaxprs_traced``, the persistent-cache hits and misses, and
+    ``events`` — one ``(event, end, duration, retrieved)`` per lower and
+    per backend step, ``end`` on ``time.monotonic``, so that each can be
+    laid down as a span with a real start.  The event list is kept only
+    while a watch is open; ``delta()`` hands it over and clears it (see
+    module docstring)."""
 
     def __init__(self):
-        self._base = compile_counters()
+        self.start()
 
     def start(self) -> "CompileWatch":
         self._base = compile_counters()
+        _tls.events = []
         return self
 
     def delta(self) -> dict:
         now = compile_counters()
+        base = self._base
+        events, _tls.events = _tls.events or [], None
         return {
             "compile_secs": round(
-                now["backend_compile_secs"] - self._base["backend_compile_secs"],
-                6,
+                now["backend_compile_secs"] - base["backend_compile_secs"], 6
             ),
+            "retrieval_secs": round(
+                now["retrieval_secs"] - base["retrieval_secs"], 6
+            ),
+            "lower_secs": round(now["lower_secs"] - base["lower_secs"], 6),
+            "jaxprs_traced": now["jaxprs_traced"] - base["jaxprs_traced"],
             "persistent_hits": (
-                now["persistent_cache_hits"]
-                - self._base["persistent_cache_hits"]
+                now["persistent_cache_hits"] - base["persistent_cache_hits"]
             ),
             "persistent_misses": (
                 now["persistent_cache_misses"]
-                - self._base["persistent_cache_misses"]
+                - base["persistent_cache_misses"]
             ),
+            "events": events,
         }
 
 

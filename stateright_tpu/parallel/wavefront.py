@@ -75,6 +75,8 @@ from ..ops.buckets import (
 )
 from ..ops.hashing import EMPTY, row_hash
 from ..telemetry.spans import (
+    PROGRAM_LOAD,
+    PROGRAM_LOWER,
     STAGE_APPEND,
     STAGE_BOOKKEEP,
     STAGE_EXPAND,
@@ -84,11 +86,12 @@ from ..telemetry.spans import (
     STAGE_PROPS,
     STAGE_STATS,
     SYM_CANON,
+    record_span,
 )
 from ..telemetry.spans import span as tel_span
 from ..testing import faults
 from ._base import WavefrontChecker
-from .prewarm import CompileWatch
+from .prewarm import LOWER_EVENT, CompileWatch
 
 _STATUS_OK = 0
 _STATUS_QUEUE_FULL = 1
@@ -1759,13 +1762,13 @@ class TpuChecker(WavefrontChecker):
                 source = "in-memory"
             else:
                 eng, source = self._acquire_engine(
-                    cache, key, cap, qcap, batch, cand, kind
+                    cache, key, cap, qcap, batch, cand, kind, acq.ctx
                 )
             acq.set(source=source)
         return eng
 
     def _acquire_engine(self, cache, key, cap, qcap, batch, cand,
-                        kind: str) -> tuple:
+                        kind: str, span_ctx) -> tuple:
         """``(engine, source)`` for a key the in-memory cache lacks: the
         prewarmer's finished rung, else a build (lazy; ahead of time with
         the memory ledger on, where a persistent-cache hit shows)."""
@@ -1819,7 +1822,7 @@ class TpuChecker(WavefrontChecker):
             # event via amend() (init_fn's lazy compile still accumulates
             # there afterwards).  Persistent-cache hits flow through this
             # path too and are detected by the monitoring delta.
-            watch = CompileWatch()
+            watch = CompileWatch() if rec is not None else None
             t0 = time.monotonic()
             try:
                 exe = _aot_compile(
@@ -1832,17 +1835,22 @@ class TpuChecker(WavefrontChecker):
                 )
             except Exception:  # noqa: BLE001 - fall back to the lazy path;
                 exe = None  # accounting must never break a run
+            build = time.monotonic() - t0
+            if rec is not None:
+                # what was lowered and loaded, under the ``engine_acquire``
+                # span that paid for it; the rest of the build is tracing
+                d = watch.delta()
+                comp = min(self._record_programs(span_ctx, d["events"]), build)
+                self._stage("compile", comp)
+                self._stage("trace", build - comp)
             if exe is not None:
-                build = time.monotonic() - t0
-                self._stage("compile", build)
                 eng = (eng[0], exe)
                 mem = self._mem_ledger.attach_exec(exe)
                 if rec is not None and self._pending_compile_rec is not None:
-                    d = watch.delta()
                     hit = d["persistent_hits"] > 0
                     source = "persistent" if hit else "fresh"
                     fields = dict(
-                        duration=round(build, 6), cache_hit=hit,
+                        duration=round(comp, 6), cache_hit=hit,
                         source=source,
                     )
                     if mem:
@@ -2129,31 +2137,59 @@ class TpuChecker(WavefrontChecker):
                 # thread would otherwise idle for the process lifetime)
                 self._prewarmer.close()
 
+    def _record_programs(self, parent, events) -> float:
+        """Lay a :class:`CompileWatch`'s events down as children of the
+        span that paid for them (``dispatch``; ``engine_acquire`` ahead of
+        time): a ``program.lower`` per module lowered, a ``program.load``
+        per program that reached the backend's compile-or-load step.
+        Returns the seconds inside the ``program.load`` ones."""
+        rec = self.flight_recorder
+        loaded = 0.0
+        for event, end, secs, retrieved in events:
+            if event == LOWER_EVENT:
+                record_span(rec, PROGRAM_LOWER, parent=parent,
+                            start=end - secs, dur=secs)
+            else:
+                loaded += secs
+                record_span(
+                    rec, PROGRAM_LOAD, parent=parent, start=end - secs,
+                    dur=secs, hit=retrieved is not None,
+                    retrieved_s=None if retrieved is None
+                    else round(retrieved, 6),
+                )
+        return loaded
+
     def _timed_device_call(self, fn, arg=None):
-        """Run one device call (init or a steps block), splitting its wall
-        time into compile vs device execution via the jax monitoring
-        deltas, and amend the pending compile event with the measured
-        duration.  Blocking on the packed stats vector is what makes the
-        wall time real (dispatch alone returns immediately)."""
+        """Run one device call (init or a steps block) and say where its
+        wall time went: ``compile`` is what JAX's monitoring saw of the
+        backend's compile-or-load step (the ``program.load`` children of
+        ``dispatch``), ``trace`` the rest of ``dispatch`` (Python tracing,
+        lowering, the enqueue: what no cache saves), ``device`` the
+        ``wait`` span, the host blocked on the device.  The pending compile
+        event is amended with the measured load.  Blocking on the packed
+        stats vector is what makes the wall time real (dispatch alone
+        returns immediately)."""
         rec = self.flight_recorder
         watch = CompileWatch() if rec is not None else None
-        t0 = time.monotonic()
         # host seam span: ``dispatch`` ends when the call returns (tracing,
         # a lazy compile, the enqueue), ``wait`` is the host blocked on the
         # device — what a growth upload left in flight shows here too
         with tel_span("device_call", rec, parent=self._run_span_ctx) as call:
-            with tel_span("dispatch", rec, parent=call.ctx):
+            with tel_span("dispatch", rec, parent=call.ctx) as dispatch:
                 carry, stats = fn() if arg is None else fn(arg)
+                if watch is not None:
+                    d = watch.delta()
+                    dispatch.set(jaxprs_traced=d["jaxprs_traced"])
             carry = list(carry)
-            with tel_span("wait", rec, parent=call.ctx):
+            with tel_span("wait", rec, parent=call.ctx) as wait:
                 stats = np.asarray(stats)
             call.set(dsteps=int(stats[_ST_DSTEPS]))
         if rec is not None:
-            dt = time.monotonic() - t0
-            d = watch.delta()
-            comp = min(max(d["compile_secs"], 0.0), dt)
+            dt = dispatch.fields["dur"]
+            comp = min(self._record_programs(dispatch.ctx, d["events"]), dt)
             self._stage("compile", comp)
-            self._stage("device", dt - comp)
+            self._stage("trace", dt - comp)
+            self._stage("device", wait.fields["dur"])
             if self._pending_compile_rec is not None:
                 # accumulate: one engine acquisition covers two programs
                 # (init_fn + run_fn) whose lazy compiles land on different

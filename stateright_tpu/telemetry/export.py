@@ -116,6 +116,11 @@ def from_jsonl(path) -> FlightRecorder:
             t_in = obj.get("t")
             if t_in is not None and t_shift:
                 t_in = round(float(t_in) + t_shift, 6)
+                if kind == "span" and "start" in fields:
+                    # a span's other stamp rides the same shift
+                    fields["start"] = round(
+                        float(fields["start"]) + t_shift, 6
+                    )
             if kind == "step":
                 stored = rec.step(t=t_in, **fields)
             else:
@@ -299,16 +304,16 @@ def to_chrome_trace(rec: FlightRecorder, path) -> None:
                 })
         elif r["kind"] == "span":
             # span-structured tracing (telemetry/spans.py): proper
-            # nested duration events — the record's ``t`` is the close
-            # time, so the event anchors at ``t - dur``; every span in
-            # one lineage shares its root's lane, and the viewer nests
-            # by time containment
+            # nested duration events — the event anchors at the record's
+            # ``start`` (clamped: an adopted span may have opened before
+            # the recorder did); every span in one lineage shares its
+            # root's lane, and the viewer nests by time containment
             dur_us = max(float(r.get("dur", 0.0)) * 1e6, 1.0)
             events.append({
                 "name": str(r.get("name", "span")),
                 "cat": "span",
                 "ph": "X",
-                "ts": round(max(ts_us - dur_us, 0.0), 3),
+                "ts": round(max(float(r["start"]) * 1e6, 0.0), 3),
                 "dur": round(dur_us, 3),
                 "pid": pid,
                 "tid": span_lane.get(r.get("span_id"), 100),

@@ -16,7 +16,10 @@ check), and each unit of work inside it is a **span** with a fresh
             │                     ``device_call`` (``dispatch``, ``wait``),
             │                     ``grow`` (``grow.pull`` / ``.rehash`` /
             │                     ``.queue`` / ``.push``), ``autosave``,
-            │                     ``checkpoint.pull``, ``spill_drain``
+            │                     ``checkpoint.pull``, ``spill_drain``;
+            │                     where a program was acquired, ``dispatch``
+            │                     (or an ahead-of-time ``engine_acquire``)
+            │                     holds ``program.lower`` / ``program.load``
             └── job ...
 
 (Two seams run before an engine's run span opens and are parentless in
@@ -31,8 +34,9 @@ roots the trace, ``supervise()`` opens one span per attempt, the
 engines one per run — and the context propagates DOWN via the builder
 (``builder._span_ctx``), never through globals.  A span closes by
 recording one ``span`` record into the flight recorder's ring
-(``kind="span"``: name, trace/span/parent ids, ``dur``; the record's
-``t`` is the close time, so ``t - dur`` is the start).  The Chrome-trace
+(``kind="span"``: name, trace/span/parent ids, ``start``, ``dur``; both
+on the recorder's clock, ``start`` the open-time read itself, and the
+record's ``t`` the close time).  The Chrome-trace
 exporter (:func:`telemetry.export.to_chrome_trace`) turns the records
 into nested duration events — one Perfetto load shows the whole fleet
 timeline.
@@ -60,7 +64,7 @@ import uuid
 from typing import Optional
 
 # span record schema version (tests/test_telemetry_schema.py pins it)
-SPAN_V = 1
+SPAN_V = 2  # 2: a record carries ``start``
 
 # The device step program's stages, in program order: the
 # ``jax.named_scope`` names ``parallel/wavefront.py:_build_engine`` wraps
@@ -103,6 +107,16 @@ PROPS_LIN = "props.lin"
 # a host span ``name`` is ``sr/<name>`` in the profiler's trace
 ANNOTATION_PREFIX = "sr/"
 
+# Children of the span that acquired a program (``dispatch`` on the lazy
+# path, ``engine_acquire`` ahead of time), laid down after the fact from
+# JAX's own monitoring events (``parallel/prewarm.py:CompileWatch``): one
+# per module lowered to MLIR, one per program that reached the backend's
+# compile-or-load step (cache key, then a retrieval and deserialisation or
+# a fresh XLA compile; ``hit``, and ``retrieved_s`` for the retrieval
+# inside it).  The parent's self time is the Python tracing and the enqueue.
+PROGRAM_LOWER = "program.lower"
+PROGRAM_LOAD = "program.load"
+
 
 def new_id() -> str:
     """A fresh 64-bit id (hex) for traces and spans alike."""
@@ -141,7 +155,8 @@ class SpanHandle:
             trace_id=parent.trace_id if parent is not None else trace_id
         )
         self.parent_id = parent.span_id if parent is not None else None
-        self.fields: Optional[dict] = None  # the closed record's fields
+        # the closed span: its record, or with no recorder its fields alone
+        self.fields: Optional[dict] = None
         self._t0 = time.monotonic()
         self._closed = False
 
@@ -149,27 +164,66 @@ class SpanHandle:
         """Close the span and record it into ``recorder`` (None → the
         span is dropped, by the no-recorder-no-telemetry rule; its
         ``fields`` stay on the handle for a seam that closes before its
-        recorder exists).  Extra ``attrs`` ride the record (they must stay
-        within the golden schema's optional set).  Returns the stored
-        record (or None)."""
+        recorder exists — with ``start`` still the raw ``time.monotonic()``
+        read, there being no recorder's clock to put it on:
+        :func:`adopt_span` does that).  Extra ``attrs`` ride the record
+        (they must stay within the golden schema's optional set).  One
+        clock read closes the span: ``dur`` and the record's ``t`` both
+        come from it.  Returns the stored record (or None)."""
         if self._closed:
             return None
+        return self._close(recorder, time.monotonic(), attrs)
+
+    def _close(self, recorder, t1: float, attrs: dict) -> Optional[dict]:
         self._closed = True
-        dur = round(time.monotonic() - self._t0, 6)
         fields = {
             "v": SPAN_V,
             "name": self.name,
             "trace_id": self.ctx.trace_id,
             "span_id": self.ctx.span_id,
-            "dur": dur,
+            "start": self._t0,
+            "dur": round(t1 - self._t0, 6),
         }
         if self.parent_id is not None:
             fields["parent_id"] = self.parent_id
         fields.update({k: v for k, v in attrs.items() if v is not None})
+        if recorder is not None:
+            fields = _record(recorder, fields, self._t0, t1)
         self.fields = fields
-        if recorder is None:
-            return None
-        return recorder.record("span", **fields)
+        return fields if recorder is not None else None
+
+
+def _record(recorder, fields: dict, t0: float, t1: float) -> dict:
+    """One ``span`` record from two ``time.monotonic()`` reads: ``start``
+    and ``t`` are the reads on the recorder's clock, rounded to the µs as
+    every record is, and ``dur`` is their difference, so
+    ``start + dur == t`` to the float."""
+    start = round(recorder.rel(t0), 6)
+    t = round(recorder.rel(t1), 6)
+    return recorder.record(
+        "span", t=t, **{**fields, "start": start, "dur": round(t - start, 6)}
+    )
+
+
+def record_span(recorder, name: str, *, parent: Optional[SpanContext],
+                start: float, dur: float, **attrs) -> dict:
+    """Record a span whose interval was learnt AFTER the fact: ``start``
+    is a ``time.monotonic()`` stamp, ``dur`` seconds.  Such a span was
+    never a block of this program, so it has no ``sr/`` annotation in the
+    profiler's trace: it exists on the recorder's clock only."""
+    handle = SpanHandle(name, parent)
+    handle._t0 = start
+    return handle._close(recorder, start + max(float(dur), 0.0), attrs)
+
+
+def adopt_span(recorder, fields: dict, trace_id: str) -> dict:
+    """Record the ``fields`` a span kept when it closed with no recorder
+    (:meth:`SpanHandle.end`) into one that exists now, in ``trace_id``:
+    where it was on the clock, which may be before the recorder's origin."""
+    t0 = fields["start"]
+    return _record(
+        recorder, {**fields, "trace_id": trace_id}, t0, t0 + fields["dur"]
+    )
 
 
 def start_span(name: str, parent: Optional[SpanContext] = None) -> SpanHandle:
@@ -204,7 +258,8 @@ class span:
 
     @property
     def fields(self) -> Optional[dict]:
-        """The closed span's record fields (None while open)."""
+        """The closed span's record, or with no recorder its fields alone
+        (None while open)."""
         return self._handle.fields
 
     def set(self, **attrs) -> None:

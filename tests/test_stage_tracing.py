@@ -158,14 +158,74 @@ def test_a_span_is_one_ring_record_and_one_profiler_event(tmp_path):
     assert i["parent_id"] == o["span_id"] and i["trace_id"] == o["trace_id"]
     assert o["gen"] == 3 and i["pending"] == 7
     # the child inside the parent on the recorder's clock (t is the close)
-    assert o["t"] - o["dur"] <= i["t"] - i["dur"] + 1e-6
-    assert i["t"] <= o["t"] + 1e-6
+    assert o["start"] <= i["start"] and i["t"] <= o["t"]
     events = {n: (s, e, st) for n, s, e, st in _host_events(str(tmp_path))}
     assert set(events) == {"sr/outer", "sr/outer.inner", "sr/no_recorder"}
     (os_, oe, ostats), (is_, ie, istats) = events["sr/outer"], events["sr/outer.inner"]
     # ... and on the profiler's
     assert os_ <= is_ and ie <= oe
     assert ostats.get("gen") in (3, "3") and istats.get("pending") in (7, "7")
+
+
+def test_a_span_records_its_start_from_the_open_time_read(monkeypatch):
+    """``start`` is the open-time clock read itself and ``t`` the ONE
+    close-time read; ``dur`` is their difference.  A child opened after its
+    parent never starts before it, however late the parent's record lands."""
+    rec = FlightRecorder(capacity=8)
+    origin = rec.t0_monotonic
+    ticks = iter(range(1, 100))
+    monkeypatch.setattr(spans.time, "monotonic", lambda: origin + next(ticks))
+    outer = spans.start_span("outer")  # reads tick 1
+    inner = spans.start_span("outer.inner", outer.ctx)  # tick 2
+    inner.end(rec)  # tick 3
+    outer.end(rec, gen=1)  # tick 4: one read closes a span
+    assert next(ticks) == 5
+    i, o = rec.records("span")
+    assert (o["start"], o["dur"], o["t"]) == (1.0, 3.0, 4.0)
+    assert (i["start"], i["dur"], i["t"]) == (2.0, 1.0, 3.0)
+    assert o["start"] <= i["start"] and _inside(o, i, eps=0.0)
+    assert outer.fields is o and o["v"] == spans.SPAN_V == 2
+
+
+def test_a_span_learnt_after_the_fact_is_laid_where_it_was():
+    rec = FlightRecorder(capacity=8)
+    with spans.span("parent", rec) as parent:
+        pass
+    at = rec.t0_monotonic + parent.fields["start"]
+    got = spans.record_span(rec, spans.PROGRAM_LOAD, parent=parent.ctx,
+                            start=at + 0.25, dur=0.5, hit=True, retrieved_s=0.4)
+    assert got["parent_id"] == parent.fields["span_id"]
+    assert got["trace_id"] == parent.fields["trace_id"]
+    assert got["start"] == pytest.approx(parent.fields["start"] + 0.25, abs=2e-6)
+    assert got["dur"] == pytest.approx(0.5, abs=2e-6)
+    assert abs(got["start"] + got["dur"] - got["t"]) <= 1e-6
+    assert got["hit"] is True and got["retrieved_s"] == 0.4
+    # the other children have no attribute of a load's
+    lower = spans.record_span(rec, spans.PROGRAM_LOWER, parent=parent.ctx,
+                              start=at, dur=0.25, retrieved_s=None)
+    assert "retrieved_s" not in lower and "hit" not in lower
+
+
+def test_chrome_trace_anchors_a_span_at_its_start(tmp_path):
+    import json
+
+    from stateright_tpu.telemetry.export import to_chrome_trace
+
+    rec = FlightRecorder(capacity=8)
+    with spans.span("parent", rec) as parent:
+        pass
+    spans.record_span(rec, spans.PROGRAM_LOWER, parent=parent.ctx,
+                      start=rec.t0_monotonic + 2.0, dur=0.5)
+    # laid down late: anchored where it was, not where its record was written
+    to_chrome_trace(rec, tmp_path / "trace.json")
+    events = {e["name"]: e for e in
+              json.loads((tmp_path / "trace.json").read_text())["traceEvents"]
+              if e["cat"] == "span"}
+    assert events[spans.PROGRAM_LOWER]["ts"] == pytest.approx(2.0e6, abs=2.0)
+    assert events[spans.PROGRAM_LOWER]["dur"] == pytest.approx(0.5e6, abs=2.0)
+    assert events["parent"]["ts"] == pytest.approx(
+        parent.fields["start"] * 1e6, abs=1.0)
+    assert events["parent"]["tid"] == events[spans.PROGRAM_LOWER]["tid"]
 
 
 def test_outside_a_profiler_session_a_span_still_records():
@@ -196,7 +256,7 @@ def _spans_by_name(checker):
 
 
 def _inside(parent, child, eps=1e-5):
-    return (parent["t"] - parent["dur"] <= child["t"] - child["dur"] + eps
+    return (parent["start"] <= child["start"] + eps
             and child["t"] <= parent["t"] + eps)
 
 
@@ -212,11 +272,162 @@ def test_device_call_and_engine_acquire_seams(tiny):
         assert dispatch["parent_id"] == wait["parent_id"] == call["span_id"]
         assert _inside(call, dispatch) and _inside(call, wait)
         assert dispatch["dur"] + wait["dur"] <= call["dur"] + 1e-5
-    # the existing stage counters are untouched by the spans
+    # the stage counters are the spans': the three split the device calls
     stages = tiny["checker"].flight_recorder.stages()
-    assert stages["device_secs"] + stages["compile_secs"] <= sum(
+    assert (stages["trace_secs"] + stages["compile_secs"]
+            + stages["device_secs"]) <= sum(
         r["dur"] for r in by["device_call"]
     ) + 1e-3
+    assert stages["device_secs"] == pytest.approx(
+        sum(r["dur"] for r in by["wait"]), abs=1e-5)
+
+
+# -- what a fresh engine costs: program.lower / program.load inside dispatch -------
+
+
+def _feed(event, secs):
+    jax.monitoring.record_event_duration_secs(event, secs)
+
+
+def test_a_cache_retrieval_is_counted_once_inside_its_backend_step():
+    """JAX's backend-compile event WRAPS the retrieval event (jax 0.9.0:
+    ``pxla._cached_compilation`` around ``compile_or_get_cached``): a hit
+    fires both, a miss one, and ``compile_secs`` counts each program once."""
+    from stateright_tpu.parallel import prewarm
+
+    backend = "/jax/core/compile/backend_compile_duration"
+    retrieval = "/jax/compilation_cache/cache_retrieval_time_sec"
+    watch = prewarm.CompileWatch()
+    _feed(retrieval, 0.2)  # a hit: the retrieval, then ...
+    _feed(backend, 0.25)  # ... the step it lies in
+    hit = watch.delta()
+    assert hit["compile_secs"] == 0.25 and hit["retrieval_secs"] == 0.2
+    watch.start()
+    _feed(backend, 3.0)  # a miss: a fresh compile alone
+    miss = watch.delta()
+    assert miss["compile_secs"] == 3.0 and miss["retrieval_secs"] == 0.0
+    assert (backend, retrieval) == (prewarm.BACKEND_COMPILE_EVENT,
+                                    prewarm.RETRIEVAL_EVENT)
+
+
+def test_a_watch_keeps_each_lower_and_load_until_its_delta():
+    import time
+
+    from stateright_tpu.parallel import prewarm
+
+    prewarm.compile_counters()
+    assert prewarm._tls.events is None  # no watch open: nothing is kept
+    _feed(prewarm.LOWER_EVENT, 0.5)
+    assert prewarm._tls.events is None
+    watch = prewarm.CompileWatch()
+    t0 = time.monotonic()
+    _feed("/jax/core/compile/jaxpr_trace_duration", 9.0)  # nests: a count
+    _feed("/jax/core/compile/jaxpr_trace_duration", 4.0)
+    _feed(prewarm.LOWER_EVENT, 0.125)
+    _feed(prewarm.RETRIEVAL_EVENT, 0.25)
+    _feed(prewarm.BACKEND_COMPILE_EVENT, 0.5)
+    _feed(prewarm.BACKEND_COMPILE_EVENT, 2.0)
+    d = watch.delta()
+    assert d["jaxprs_traced"] == 2 and d["lower_secs"] == 0.125
+    assert d["compile_secs"] == 2.5 and d["retrieval_secs"] == 0.25
+    assert [(e, secs, got) for e, _, secs, got in d["events"]] == [
+        (prewarm.LOWER_EVENT, 0.125, None),
+        (prewarm.BACKEND_COMPILE_EVENT, 0.5, 0.25),  # the hit
+        (prewarm.BACKEND_COMPILE_EVENT, 2.0, None),  # the miss after it
+    ]
+    assert all(t0 <= end <= time.monotonic() for _, end, _, _ in d["events"])
+    # handed over and cleared
+    assert prewarm._tls.events is None
+    assert watch.delta()["events"] == []
+
+
+@pytest.fixture(scope="module")
+def twice(tmp_path_factory):
+    """Two EQUAL model objects checked in one process under a persistent
+    cache: the second acquires every program again, cache-served; then the
+    second object is re-checked on its resident engine."""
+    from stateright_tpu.parallel.prewarm import disable_persistent_compile_cache
+
+    d = str(tmp_path_factory.mktemp("compile-cache"))
+    kw = dict(sync=True, capacity=1 << 12, batch=32)
+    try:
+        first = TwoPhaseSys(4).checker().compile_cache(d).telemetry().spawn_tpu(**kw)
+        model = TwoPhaseSys(4)
+        second = model.checker().compile_cache(d).telemetry().spawn_tpu(**kw)
+        again = model.checker().compile_cache(d).telemetry().spawn_tpu(**kw)
+    finally:
+        disable_persistent_compile_cache()
+    assert (first.unique_state_count() == second.unique_state_count()
+            == again.unique_state_count())
+    return {"first": first, "second": second, "again": again}
+
+
+def _programs(by):
+    return by.get(spans.PROGRAM_LOWER, []), by.get(spans.PROGRAM_LOAD, [])
+
+
+@pytest.mark.parametrize("which, hit", [("first", False), ("second", True)])
+def test_dispatch_holds_what_was_lowered_and_loaded(twice, which, hit):
+    by = _spans_by_name(twice[which])
+    lowers, loads = _programs(by)
+    # the init program and the run program at the least
+    assert len(lowers) >= 2 and len(loads) >= 2
+    dispatches = {r["span_id"]: r for r in by["dispatch"]}
+    for child in lowers + loads:
+        assert _inside(dispatches[child["parent_id"]], child), child
+        assert abs(child["start"] + child["dur"] - child["t"]) <= 1e-6
+    for d in dispatches.values():
+        inside = [c for c in lowers + loads if c["parent_id"] == d["span_id"]]
+        # the self time is the Python tracing and the enqueue: never negative
+        assert sum(c["dur"] for c in inside) <= d["dur"] + 1e-5
+        assert (d["jaxprs_traced"] > 0) == bool(inside)
+    assert all(r["hit"] is hit for r in loads)
+    if hit:
+        assert all(0 < r["retrieved_s"] <= r["dur"] for r in loads)
+    else:
+        assert not any("retrieved_s" in r for r in loads)
+    assert not any("hit" in r or "retrieved_s" in r for r in lowers)
+    rec = twice[which].flight_recorder
+    loaded = sum(r["dur"] for r in loads)
+    compiles = rec.records("compile")
+    assert {e["source"] for e in compiles} == {"persistent" if hit else "fresh"}
+    # each program's load is in one compile record's duration, once
+    assert sum(e["duration"] for e in compiles) == pytest.approx(loaded, abs=1e-4)
+    stages = rec.stages()
+    assert stages["compile_secs"] == pytest.approx(loaded, abs=1e-4)
+    assert stages["trace_secs"] == pytest.approx(
+        sum(d["dur"] for d in dispatches.values()) - loaded, abs=1e-4)
+    assert stages["device_secs"] == pytest.approx(
+        sum(r["dur"] for r in by["wait"]), abs=1e-5)
+    assert (stages["trace_secs"] + stages["compile_secs"]
+            + stages["device_secs"]) <= sum(
+        r["dur"] for r in by["device_call"]) + 1e-3
+
+
+def test_a_recheck_on_the_resident_engine_acquires_no_program(twice):
+    by = _spans_by_name(twice["again"])
+    assert _programs(by) == ([], [])
+    assert by["dispatch"] and all(r["jaxprs_traced"] == 0 for r in by["dispatch"])
+    stages = twice["again"].flight_recorder.stages()
+    assert stages["compile_secs"] == 0.0
+    assert stages["device_secs"] == pytest.approx(
+        sum(r["dur"] for r in by["wait"]), abs=2e-3)
+    assert stages["trace_secs"] == pytest.approx(
+        sum(r["dur"] for r in by["dispatch"]), abs=1e-5)
+
+
+def test_without_a_recorder_no_watch_is_made_and_nothing_is_kept(monkeypatch):
+    from stateright_tpu.parallel import prewarm, wavefront
+
+    def no_watch():
+        raise AssertionError("a CompileWatch with no recorder to read it")
+
+    monkeypatch.setattr(wavefront, "CompileWatch", no_watch)
+    c = TwoPhaseSys(2).checker().spawn_tpu(sync=True, capacity=1 << 10, batch=16)
+    c.join()  # a fresh object: both programs acquired on this thread
+    assert c.flight_recorder is None and c.unique_state_count() > 0
+    prewarm.compile_counters()
+    assert prewarm._tls.events is None
 
 
 def test_growth_seams_cover_growth_secs():

@@ -79,20 +79,22 @@ SCHEMA = {
     "span": (
         # span-structured tracing (telemetry/spans.py,
         # docs/observability.md): one record per closed span, written at
-        # close time (``t - dur`` is the start).  The optional set is
-        # the union of per-span attrs: engine/error (engine_run,
-        # attempt), attempt ordinal, gen (autosave), pending
+        # close time (``t``), with its ``start`` on the same clock.  The
+        # optional set is the union of per-span attrs: engine/error
+        # (engine_run, attempt), attempt ordinal, gen (autosave), pending
         # (spill_drain), cap/unique (grow), key/slot (fleet
         # job), jobs/slots (fleet root), rung/source (engine_acquire),
-        # dsteps (device_call), status (grow), the universes, row,
+        # dsteps (device_call), jaxprs_traced (dispatch), hit/retrieved_s
+        # (program.load), status (grow), the universes, row,
         # table bytes and history codec of a compiled actor twin
         # (twin_compile)
         {"v": int, "name": str, "trace_id": str, "span_id": str,
-         "dur": _REAL},
+         "start": _REAL, "dur": _REAL},
         {"parent_id": str, "engine": str, "error": str, "attempt": int,
          "gen": int, "pending": int, "cap": int, "unique": int,
          "key": str, "slot": int, "jobs": int, "slots": int,
          "rung": str, "source": str, "dsteps": int, "status": str,
+         "jaxprs_traced": int, "hit": bool, "retrieved_s": _REAL,
          "actor_states": str, "envelopes": int, "n_slots": int,
          "row_width": int, "table_bytes": int, "hist_strategy": str,
          "hist_threads": int, "hist_bits": int},
@@ -278,6 +280,32 @@ def test_every_exported_record_matches_the_golden_schema(tmp_path):
     for r in records:
         problems += _check_record(r)
     assert not problems, "\n".join(problems)
+
+
+def test_span_records_are_versioned_and_carry_their_start(tmp_path):
+    """``SPAN_V`` 2: every ``span`` record holds its ``start`` beside ``dur``
+    and the close time ``t``, on one clock, and with the memory ledger on
+    the programs built ahead of time hang under their ``engine_acquire``."""
+    from stateright_tpu.telemetry.spans import (
+        PROGRAM_LOAD, PROGRAM_LOWER, SPAN_V,
+    )
+
+    lines = _export_lines(
+        tmp_path, TwoPhaseSys(3).checker().telemetry(memory=True),
+        capacity=1 << 12, batch=64,
+    )
+    found = [ln for ln in lines if ln.get("kind") == "span"]
+    assert found and SPAN_V == 2
+    for r in found:
+        assert r["v"] == SPAN_V
+        assert abs(r["start"] + r["dur"] - r["t"]) <= 1e-6, r
+    by_id = {r["span_id"]: r for r in found}
+    programs = [r for r in found if r["name"] in (PROGRAM_LOWER, PROGRAM_LOAD)]
+    assert {r["name"] for r in programs} == {PROGRAM_LOWER, PROGRAM_LOAD}
+    assert {by_id[r["parent_id"]]["name"] for r in programs} <= {
+        "engine_acquire", "dispatch"
+    }
+    assert "engine_acquire" in {by_id[r["parent_id"]]["name"] for r in programs}
 
 
 def test_spill_records_match_the_golden_schema(tmp_path, monkeypatch):
