@@ -21,7 +21,9 @@ with
    data-dependent memory ops (a gather reads the gathered elements, not
    the whole table; a dynamic-update-slice writes the update window, not
    the whole buffer — matching both XLA's charging model and the
-   roofline meaning of the number);
+   roofline meaning of the number); a ``reshape`` is charged its operand
+   read and written, except the flat-to-128-lane-rows view, which is a
+   bitcast on the TPU's tiled layouts (``_is_row_view``);
  - an **op class** — ``gather`` / ``scatter`` / ``sort`` / ``dot`` /
    ``elementwise`` / ``reduce`` / ``control``.
 
@@ -192,6 +194,30 @@ class EqnCost:
         return self.bytes_read + self.bytes_written
 
 
+def _is_row_view(eqn) -> bool:
+    """Whether a ``reshape`` is the one view that moves nothing on the
+    TPU: a flat array of 32-bit planes against its ``[n / ROW_LANES,
+    ROW_LANES]`` rows.  A 1-D plane is tiled ``8 * ROW_LANES`` consecutive
+    elements a tile and the row view 8 rows x ``ROW_LANES`` lanes a tile:
+    the same elements, so the compiler makes it a bitcast
+    (``ops/buckets.py``, "Where the layout is fixed": the visited table's
+    membership gather reads through it every step).  That identity holds
+    for 4-byte words (and for the 8-byte ones the chip splits into two
+    such planes); a packed dtype tiles otherwise and is charged.  Every
+    other reshape is charged as the relayout it may be - the ``[nbuckets,
+    16]`` view this replaced cost a read and a write of the whole table a
+    step."""
+    from ..ops.buckets import ROW_LANES
+
+    operand, result = eqn.invars[0].aval, eqn.outvars[0].aval
+    flat, rows = sorted((tuple(operand.shape), tuple(result.shape)), key=len)
+    return (
+        np.dtype(operand.dtype).itemsize in (4, 8)
+        and len(flat) == 1 and flat[0] % (8 * ROW_LANES) == 0
+        and rows == (flat[0] // ROW_LANES, ROW_LANES)
+    )
+
+
 def _charge_eqn(eqn) -> EqnCost:
     """FLOPs/bytes of one non-call eqn, per the module-docstring rules."""
     name = eqn.primitive.name
@@ -206,6 +232,8 @@ def _charge_eqn(eqn) -> EqnCost:
     ) if eqn.outvars else ()
     operand_shape: tuple = ()
     flops = 0
+    if name == "reshape" and _is_row_view(eqn):
+        in_bytes = out_bytes = 0
     if cls == "gather":
         # reads: the gathered window (out-sized elements of the operand)
         # + the index vector; the untouched rest of the operand is free

@@ -20,9 +20,10 @@ effectively index-serial, so the fix is architectural, not incremental:
    the pinned 2PC-7 occupancy series is back at the Poisson expectation
    (``tests/test_telemetry.py``), and ``tests/test_buckets.py`` pins
    avalanche + chi-square on the derivation itself.  Membership is ONE wide
-   gather (``[M, SLOTS]`` lines) + a vectorized lane compare — LINE gathers
-   are cheap on TPU (the measured cost is scatters, and ELEMENT gathers: the
-   values that follow a sort ride through it as operands, ``bucket_insert``).
+   gather (the ``[M, ROW_LANES]`` rows that hold the candidates' buckets) +
+   a vectorized lane compare — ROW gathers are cheap on TPU (the measured
+   cost is scatters, and ELEMENT gathers: the values that follow a sort ride
+   through it as operands, ``bucket_insert``).
  - Batch candidates are sorted ONCE by their remixed key (bucket bits are
    the key's MSBs; EMPTY lanes pin to the maximal key), which simultaneously
    (a) groups equal fingerprints adjacently for first-occurrence dedup,
@@ -40,6 +41,40 @@ effectively index-serial, so the fix is architectural, not incremental:
    grows the table and rehashes host-side.  At the engine's ≤25% load factor
    the Poisson tail P(bucket > 16 | λ=4) ≈ 1e-7 makes that a rare event.
 
+**Where the layout is fixed** (PR 38).  The table is ONE flat
+``uint64[nbuckets * SLOTS]`` array (slot ``s`` of bucket ``b`` at ``b * SLOTS
++ s``) from the engine's init program to the end of its run program, in the
+snapshot, and on the host; on the TPU that is two ``u32[cap]`` planes tiled
+``T(1024)``: 1,024 consecutive slots a tile.  Inside the step's loop body only
+two kinds of operation touch it, and neither reads or writes O(``cap``)
+elements: the membership loop's row gather and the chunked scatters (in
+place).  The membership loop used to view the table as ``[nbuckets, SLOTS]``
+lines; the compiler gives that operand the layout ``{0,1:T(8,128)}`` (sixteen
+planes, a "line" being sixteen strided words: minor dimension 16 would pad to
+128 otherwise), so EVERY step re-laid both planes, a reshape and a copy each:
+1.5 ms a step at 2^23 slots, 15 ms at 2^26, whatever the batch.  It now views
+it as ``[cap / ROW_LANES, ROW_LANES]`` rows of 128 slots, whose tiled layout
+``{1,0:T(8,128)}`` IS the flat plane's (a tile is 8 rows x 128 lanes = the same
+1,024 consecutive slots): the reshape is a bitcast, the gather fetches one
+512-byte row a plane, and the seven other buckets of the row are masked to
+EMPTY before the compare (in a 2pc-8 check the row gathers cost 0.199 s
+where the line gathers cost 0.180, and the masked 128-lane compare 0.064
+where the 16-lane one cost 0.007: 0.08 s back of the 1.38 s the relayout
+cost; my chip runs, PR 38).  Alone on one v5e (ms a
+call, 40 inserts in a ``fori_loop`` with the table as the carry, min of 5,
+window 2,048; tables 2^21 / 2^23 / 2^26): lines 0.883 / 2.544 / 15.901, rows
+0.658 / 0.732 / 1.287 (at 2^26 the planes no longer fit the chip's VMEM, where
+the compiler keeps them when it can: the gathers and scatters go to HBM).
+Also timed there and dearer: a ``[nbuckets, SLOTS]`` carry with 2-D scatters
+(the scatter is flattened to 1-D, so the relayout moves into the WRITE loop:
+1.162 / 40.670 at 2^23 / 2^26), a ``[SLOTS, nbuckets]`` carry (1.396), and a
+windowed gather with ``slice_sizes=(SLOTS,)`` from the flat table or from
+``[cap / 128, 128]`` (expanded into a serial ``while`` of ``window`` trips:
+26.4 / 29.1).  ``tests/test_table_layout.py`` pins it: no equation of the
+step's loop body but the row view, the gather and the scatters touches ``cap``
+elements, and the program compiled for a described v5e holds two table-sized
+operations in its loop (the scatters) against the old body's nine.
+
 Reference analogue: the lock-striped ``DashMap`` visited set
 (``src/checker/bfs.rs:26``); payload = parent fingerprint for trace
 reconstruction, as there.
@@ -55,6 +90,8 @@ import jax.numpy as jnp
 from .hashing import EMPTY, mix64, mix64_np
 
 SLOTS = 16  # fingerprints per bucket (one 128-byte line of u64s)
+ROW_LANES = 128  # slots the membership loop fetches at once: the TPU's lane
+#                  count, so 8 buckets a row (see "Where the layout is fixed")
 
 
 def bucket_key(fps: jnp.ndarray) -> jnp.ndarray:
@@ -184,7 +221,7 @@ def bucket_insert(
     #                       lanes first and run the pipeline at width CB
     probe_dot: bool = False,  # BLEST one-hot membership probe (ops/mxu.py):
     #                           the membership/occupancy reductions over the
-    #                           gathered bucket lines become ONE blocked
+    #                           gathered table rows become ONE blocked
     #                           bitmapped dot_general — bit-identical
     #                           (present, base) per window, pinned by test.
     #                           Off adds zero ops (the prededup contract).
@@ -247,8 +284,9 @@ def bucket_insert(
     lanes, ms a call, old -> everything carried: compaction + key sort
     1.743 -> 0.189; novel compaction 0.97 -> 0.04; segment base 0.29 ->
     0.05.  What is left beside the two kept fetches: the membership loop's
-    ``[window, SLOTS]`` line gather, the chunked scatters and the
-    whole-table passes (ROADMAP Queue 1 2b).
+    ``[window, ROW_LANES]`` row gather and the chunked scatters (the
+    whole-table passes went in PR 38: module docstring, "Where the layout
+    is fixed").
     """
     m_orig = fps.shape[0]
     cand_overflow = jnp.bool_(False)
@@ -284,7 +322,18 @@ def bucket_insert(
     # (typically 2-3 windows) makes the cost track the real candidate
     # count.  Writes stay outside: the atomic nothing-written-on-overflow
     # contract the engines' growth protocols rely on is untouched.
-    table_lines = table_fp.reshape(nbuckets, SLOTS)
+    #
+    # The fetch is a ROW of the flat table, not a line: ``ROW_LANES``
+    # consecutive slots, the ``per_row`` buckets that share them, with the
+    # other buckets' lanes masked to EMPTY so that the compare and the count
+    # below see the candidate's own bucket alone.  The row view is the one
+    # 2-D shape whose tiled layout is the flat plane's own (module
+    # docstring, "Where the layout is fixed"): no relayout of the table
+    # exists in the step, whatever its size.
+    row_lanes = min(ROW_LANES, nslots)
+    per_row = row_lanes // SLOTS  # a power of two, as nslots and SLOTS are
+    table_rows = table_fp.reshape(nslots // row_lanes, row_lanes)
+    lane_bucket = np.arange(row_lanes, dtype=np.int32) // SLOTS
     mpad_w = (-m) % window
     pbucket = bucket if mpad_w == 0 else jnp.concatenate(
         [bucket, jnp.zeros((mpad_w,), jnp.int32)]
@@ -298,7 +347,12 @@ def bucket_insert(
         off = k * window
         wbkt = jax.lax.dynamic_slice(pbucket, (off,), (window,))
         wfp = jax.lax.dynamic_slice(psfp, (off,), (window,))
-        lines = table_lines[wbkt]
+        # a shift and a mask, not ``//`` and ``%``: a signed floor division
+        # is a dozen equations to trace and lower, in every step program a
+        # fresh engine builds (0.3 s of a cold linreg check, PR 38)
+        row = wbkt >> (per_row.bit_length() - 1)
+        mine = lane_bucket[None, :] == (wbkt & (per_row - 1))[:, None]
+        lines = jnp.where(mine, table_rows[row], EMPTY)
         if probe_dot:
             # BLEST one-hot probe (ops/mxu.py): one blocked bitmapped
             # matmul over the candidate x slot comparison tile replaces

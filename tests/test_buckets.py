@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from stateright_tpu.analysis.jaxpr_audit import _iter_eqns
 from stateright_tpu.ops.buckets import (
+    ROW_LANES,
     SLOTS,
     bucket_insert,
     bucket_key,
@@ -759,10 +760,11 @@ def seeded_table(in_table):
     return tfp, tpl
 
 
-def both_inserts(tfp0, tpl0, fps, pls, generation_order, compact):
+def both_inserts(tfp0, tpl0, fps, pls, generation_order, compact,
+                 window=REF_WINDOW):
     out = {
         name: fn(
-            tfp0, tpl0, jnp.asarray(fps), jnp.asarray(pls), window=REF_WINDOW,
+            tfp0, tpl0, jnp.asarray(fps), jnp.asarray(pls), window=window,
             generation_order=generation_order, compact=compact,
         )
         for name, fn in INSERTS.items()
@@ -858,7 +860,8 @@ def test_insert_fetches_nothing_a_sort_can_carry(generation_order):
     """The static pin of PR 36: a value that follows a sort's permutation
     rides through the sort as an operand, it is not fetched afterwards by
     ``x[perm]``.  The traced ``bucket_insert(compact=CB)`` holds three
-    gathers - the membership loop's ``[window, SLOTS]`` LINE gather, the
+    gathers - the membership loop's ``[window, ROW_LANES]`` ROW gather (PR
+    38: eight buckets a fetch, the flat table's own tiled layout), the
     budget compaction's ``fps[lane]`` at ``(CB,)`` (carrying it through the
     ``m``-wide sort compiles 40 s slower a step program, PR 36) and the
     write loop's ``payloads[sel chunk]`` at ``(window,)`` (a payload is
@@ -886,7 +889,9 @@ def test_insert_fetches_nothing_a_sort_can_carry(generation_order):
         (None, [(window,)], [m, m]),
     ):
         jaxpr = trace(bucket_insert, compact)
-        assert sorted(gather_call_sites(jaxpr)) == sorted(element + [(window, SLOTS)])
+        assert sorted(gather_call_sites(jaxpr)) == sorted(
+            element + [(window, ROW_LANES)]
+        )
         assert [s[0] for s in gather_call_sites(jaxpr, "sort")] == widths
         names = [e.primitive.name for e in _iter_eqns(jaxpr)]
         assert sorted(p for p in names if p in loops) == ["while", "while"]

@@ -13,6 +13,8 @@ import numpy as np
 import pytest
 
 from stateright_tpu.models.two_phase_commit import TwoPhaseSys
+from stateright_tpu.ops.buckets import SLOTS, bucket_of
+from stateright_tpu.ops.hashing import EMPTY
 
 
 def run_full(n, **kw):
@@ -62,6 +64,22 @@ def test_checkpoint_survives_npz_round_trip():
     buf.seek(0)
     loaded = dict(np.load(buf))
 
+    # the file's table is the flat bucket-major one (``ops/buckets.py``,
+    # "Where the layout is fixed"): slot ``s`` of bucket ``b`` at ``b * SLOTS
+    # + s``, every bucket filled densely from slot 0
+    tfp, tpl = loaded["table_fp"], loaded["table_parent"]
+    assert tfp.dtype == tpl.dtype == np.uint64 and tfp.ndim == tpl.ndim == 1
+    assert tfp.shape == tpl.shape and tfp.shape[0] % SLOTS == 0
+    held = np.flatnonzero(tfp != EMPTY)
+    assert len(held) == int(loaded["unique"])
+    assert np.array_equal(
+        bucket_of(tfp[held], tfp.shape[0] // SLOTS), held // SLOTS
+    )
+    lines = (tfp != EMPTY).reshape(-1, SLOTS)
+    assert np.array_equal(
+        lines, np.arange(SLOTS)[None, :] < lines.sum(axis=1, keepdims=True)
+    )
+
     resumed = TwoPhaseSys(5).checker().spawn_tpu(sync=True, resume=loaded)
     assert resumed.unique_state_count() == 8832  # examples/2pc.rs:133
     resumed.assert_properties()
@@ -103,6 +121,11 @@ def test_growth_boundary_checkpoint_resume():
         resumed = TwoPhaseSys(5).checker().spawn_tpu(sync=True, resume=s)
         assert resumed.unique_state_count() == 8832  # examples/2pc.rs:133
         resumed.assert_properties()
+        # the rehash handed the flat table across the host (`_grow`) and the
+        # final pull reads occupancy and parents from the same flat form
+        stats = resumed.occupancy_stats()
+        assert stats["occupied"] == 8832 and stats["slots_per_bucket"] == SLOTS
+        assert all(len(p) > 1 for p in resumed.discoveries().values())
 
 
 @pytest.mark.medium

@@ -32,6 +32,7 @@ from stateright_tpu.analysis.costmodel import (
     COSTMODEL_V,
     FLOPS_BAND,
     classify_primitive,
+    walk_jaxpr,
     wavefront_costs,
     xla_cost,
 )
@@ -216,6 +217,47 @@ def test_mxu_candidates_rank_by_bytes_and_emit_jx4xx():
     assert "JX400" in rules and "JX402" in rules
     # the dedup-insert membership gather is the known top hot spot
     assert cands[0]["stage"] == "dedup-insert"
+
+
+def test_the_insert_is_charged_no_pass_over_the_table():
+    """PR 38: nothing in the insert's step reads or writes O(capacity) -
+    the membership gather fetches rows through the flat table's
+    ``[cap / 128, 128]`` view, which the walk prices as the bitcast it is
+    on the TPU (``costmodel._is_row_view``), and the scatters are charged
+    their update windows.  So the stage's bytes do not depend on the table:
+    1,379,346 B a step at 2^12 and at 2^20 slots alike (the parent, whose
+    ``[nbuckets, 16]`` view was charged read and written, 2 x 8 B a slot:
+    865,042 B at 2^12 and 17,576,722 B at 2^20)."""
+    twin = _twin(TwoPhaseSys(3))
+    charged = {
+        cap: wavefront_costs(twin, cap, 1 << 11, 64, reconcile=False)
+        .stages["dedup-insert"].bytes_total
+        for cap in (1 << 12, 1 << 20)
+    }
+    assert charged[1 << 12] == charged[1 << 20] == 1_379_346
+
+
+@pytest.mark.parametrize("dtype,shape,free", [
+    ("uint32", (16, 128), True),    # the row view of a 32-bit plane
+    ("uint64", (16, 128), True),    # ... of the table (two such planes)
+    ("uint8", (16, 128), False),    # a packed dtype tiles otherwise
+    ("bfloat16", (16, 128), False),
+    ("uint32", (128, 16), False),   # the [nbuckets, SLOTS] lines it replaced
+    ("uint32", (8, 256), False),
+])
+def test_only_the_row_view_of_whole_words_is_a_free_reshape(dtype, shape, free):
+    """``costmodel._is_row_view``: 0 bytes for ``[n] <-> [n / ROW_LANES,
+    ROW_LANES]`` of 4- or 8-byte elements, both directions; every other
+    reshape is charged its operand read and written."""
+    import jax.numpy as jnp
+
+    flat = jax.ShapeDtypeStruct((2048,), jnp.dtype(dtype))
+    for fn, aval in (
+        (lambda x: x.reshape(shape), flat),
+        (lambda x: x.reshape(-1), jax.ShapeDtypeStruct(shape, flat.dtype)),
+    ):
+        cost = walk_jaxpr(jax.make_jaxpr(fn)(aval))
+        assert cost.bytes_total == (0 if free else 2 * 2048 * flat.dtype.itemsize)
 
 
 # -- device spec + roofline classification ----------------------------------

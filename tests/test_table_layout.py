@@ -1,0 +1,299 @@
+"""The visited table keeps ONE layout through the whole run program (PR 38).
+
+``ops/buckets.py``, "Where the layout is fixed": the table is a flat
+``uint64[cap]`` from ``wavefront_init`` to the end of ``wavefront_run``, in
+the snapshot and on the host; inside the step's loop body only the
+membership loop's row gather (through a ``[cap / 128, 128]`` view that is a
+bitcast on the TPU) and the chunked scatters touch it.  Pinned here:
+
+ - on the traced step program: no other equation of the loop body has an
+   operand or a result of ``cap`` elements;
+ - on the step program compiled for a described v5e: how many operations of
+   the loop body produce ``cap`` elements (the old body's count beside it);
+ - ``bucket_insert`` against the gathering reference of ``test_buckets.py``,
+   bit for bit on the table's contents, at the benchmark cells' lane shapes.
+
+The host boundaries see the same flat table as before, so their tests are
+the ones the repo had: ``tests/test_checkpoint.py`` (the snapshot's table is
+flat and bucket-major, and resumes; growth up the ladder keeps the work).
+"""
+
+import functools
+import re
+import sys
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from stateright_tpu.models.two_phase_commit import TwoPhaseSys
+from stateright_tpu.ops.buckets import ROW_LANES, SLOTS, bucket_of
+from stateright_tpu.ops.hashing import EMPTY
+from stateright_tpu.parallel import wavefront as wf
+
+sys.path.insert(0, str(Path(__file__).parent))
+from test_buckets import INSERTS, both_inserts, fresh  # noqa: E402
+
+
+def fps_in_bucket(count, nbuckets, bucket=0):
+    """``count`` distinct fingerprints the derivation places in ``bucket``."""
+    x = np.arange(1, 64 * count * nbuckets, dtype=np.uint64)
+    found = x[bucket_of(x, nbuckets) == bucket][:count]
+    assert len(found) == count
+    return found
+
+
+# -- the traced step program -------------------------------------------------
+
+
+def sub_jaxprs(eqn):
+    for value in eqn.params.values():
+        for x in value if isinstance(value, (tuple, list)) else (value,):
+            inner = getattr(x, "jaxpr", x)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def table_sized_eqns(jaxpr, cap, inside=False):
+    """``(primitive, shapes)`` of every equation INSIDE a ``while`` body (at
+    any depth) that has an operand or a result of ``cap`` or more elements;
+    an equation that only carries them into a sub-program (a loop, a call)
+    is descended into, not listed."""
+    for eqn in jaxpr.eqns:
+        subs = list(sub_jaxprs(eqn))
+        if subs:
+            for sub in subs:
+                yield from table_sized_eqns(
+                    sub, cap, inside or eqn.primitive.name == "while"
+                )
+            continue
+        shapes = [
+            tuple(v.aval.shape)
+            for v in (*eqn.invars, *eqn.outvars)
+            if getattr(getattr(v, "aval", None), "size", 0) >= cap
+        ]
+        if inside and shapes:
+            yield eqn.primitive.name, shapes
+
+
+def step_program(n=3, cap=1 << 16, qcap=1 << 10, batch=32, cand=256, **kw):
+    model = TwoPhaseSys(n)
+    tensor, props = model.tensor_model(), list(model.properties())
+    _, run_fn = wf._build_engine(
+        tensor, props, cap, qcap, batch, 8, None, cand=cand, **kw
+    )
+    return run_fn, wf._carry_avals(tensor, len(props), cap, qcap, batch, False)
+
+
+@pytest.mark.parametrize("sym", [False, True])
+def test_the_loop_body_touches_the_table_with_a_gather_and_scatters_only(sym):
+    """POR off.  The two table arrays enter the step's ``while`` as carries
+    and meet four kinds of equation there: the row view (flat against
+    ``[cap / ROW_LANES, ROW_LANES]``, nothing else), the membership loop's
+    gather FROM that view, and the write loop's two scatters.  No
+    ``convert``, ``select_n``, ``copy``, ``transpose`` or second view of
+    ``cap`` elements: each would be a pass over the table a step."""
+    cap = 1 << 16
+    run_fn, avals = step_program(cap=cap, sym=sym)
+    found = list(table_sized_eqns(jax.make_jaxpr(run_fn)(avals).jaxpr, cap))
+    rows = (cap // ROW_LANES, ROW_LANES)
+    assert sorted(found) == sorted([
+        ("reshape", [(cap,), rows]),
+        ("gather", [rows]),
+        ("scatter", [(cap,), (cap,)]),
+        ("scatter", [(cap,), (cap,)]),
+    ])
+
+
+# -- the step program compiled for the chip ----------------------------------
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    """A described (not attached) v5e chip to compile for; described inside
+    the fixture, never at import, and skipped where it cannot be."""
+    try:
+        from jax.experimental import topologies
+        from jax.sharding import SingleDeviceSharding
+
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+        return SingleDeviceSharding(topo.devices[0])
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e topology can be described here: {e}")
+
+
+HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(\(?[a-z0-9]+\[[^=]*?)\s([a-z\-]+)\("
+)
+HLO_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
+# what names or forwards a buffer without producing one
+HLO_NO_BUFFER = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
+
+
+def table_sized_operations(hlo_text, cap):
+    """``(opcode, result shape)`` of every operation outside the entry
+    computation and outside fused computations (a fusion counts once, as
+    the operation it is) whose result holds ``cap`` or more elements: with
+    one ``while`` at the top of the run program, these are the operations
+    of its body and of the loops nested in it."""
+    found, entry, name = [], False, ""
+    for line in hlo_text.splitlines():
+        head = HLO_COMPUTATION.match(line)
+        if head:
+            entry, name = bool(head.group(1)), head.group(2)
+            continue
+        m = HLO_INSTRUCTION.match(line)
+        if not m or entry or "fused_computation" in name:
+            continue
+        shape, opcode = m.groups()
+        sizes = [
+            int(np.prod([int(d) for d in dims.split(",") if d] or [1]))
+            for dims in re.findall(r"[a-z0-9]+\[([0-9,]*)\]", shape)
+        ]
+        if opcode not in HLO_NO_BUFFER and max(sizes, default=0) >= cap:
+            found.append((opcode, shape.strip()))
+    return found
+
+
+def test_the_compiled_loop_holds_two_table_sized_operations(one_v5e_chip):
+    """The 2pc step program at a 2^23-slot table, compiled ahead of time
+    for one v5e: inside the loop TWO operations produce ``cap`` elements,
+    the write loop's two scatter fusions (each a two-plane ``u32[cap]``
+    scatter, in place).  The parent's body (PR 37's tree, compiled the
+    same way) held NINE: the same two, plus two ``reshape -> [nbuckets,
+    16]``, two ``copy`` into the gather's slot-major layout, and a
+    ``copy-start`` / ``copy-done`` / ``ConcatBitcast`` moving a plane
+    between memories around them."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cap = 1 << 23
+    run_fn, avals = step_program(cap=cap, qcap=1 << 14, batch=256, cand=2048)
+    avals = tuple(
+        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e_chip)
+        for a in avals
+    )
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        compiled = run_fn.lower(avals).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+    found = table_sized_operations(compiled.as_text(), cap)
+    assert [op for op, _ in found] == ["fusion", "fusion"], found  # parent: 9
+    assert all(shape.count(f"u32[{cap}]") == 2 for _, shape in found)
+    # and the program holds no transient copy of the table: its planes are
+    # 4 x 32 MiB, and the parent's temporaries were 405.8 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# -- bucket_insert at the cells' shapes --------------------------------------
+
+# (candidate lanes = batch x actions, the ``cand`` budget, window = batch,
+# log2 of the table) of the five presized configurations; the tables are
+# 2^6 smaller here (the lanes are what the row view, the masks and the
+# window paddings see; the table's size only sets the bucket bits)
+CELL_SHAPES = {
+    "twopc8": (2048 * 42, 32768, 2048, 23 - 6),
+    "paxos3": (4096 * 30, 16384, 4096, 23 - 6),
+    "linreg2x3o": (4096 * 20, 16384, 4096, 21 - 6),
+    "twopc13sym": (1024 * 67, 32768, 1024, 21 - 6),
+    "singlecopy4": (4096 * 20, 16384, 4096, 22 - 6),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def seeded_cell(cell):
+    """The cell's table, a third of a pool of ``cap / 16`` fingerprints in
+    it already: ``(table_fp, table_payload, pool)``."""
+    _, _, window, logcap = CELL_SHAPES[cell]
+    cap = 1 << logcap
+    rng = np.random.default_rng(0)
+    pool = rng.integers(1, 1 << 62, cap // 16).astype(np.uint64)
+    seeded = jnp.asarray(pool[: len(pool) // 3])
+    tfp, tpl, _, n, ovf, _ = INSERTS["new"](
+        *fresh(cap // SLOTS), seeded, seeded + jnp.uint64(7), window=window
+    )
+    assert int(n) == len(seeded) and not bool(ovf)
+    return tfp, tpl, pool
+
+
+def cell_batch(cell, budget):
+    """A candidate batch shaped like the cell's: the valid lanes drawn from
+    the pool with repeats (as a step's candidates are) and scattered over
+    the ``m`` lanes; ``budget`` puts their number under, at or over the
+    ``cand`` budget.  ``(fps, payloads)``."""
+    m, cb, _, _ = CELL_SHAPES[cell]
+    rng = np.random.default_rng(1)
+    n_valid = {"under": cb - 37, "at": cb, "over": cb + 1}[budget]
+    fps = np.full(m, EMPTY, np.uint64)
+    lanes = np.sort(rng.choice(m, n_valid, replace=False))
+    fps[lanes] = rng.choice(seeded_cell(cell)[2], n_valid)
+    return fps, np.arange(1, m + 1, dtype=np.uint64)
+
+
+@pytest.mark.parametrize("budget", ["under", "at", "over"])
+@pytest.mark.parametrize("order", [False, True], ids=["table", "generation"])
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_insert_equals_the_reference_at_the_cells_shapes(cell, order, budget):
+    """Both tables (in the flat form every host boundary sees),
+    ``sel[:n_new]``, ``n_new`` and both flags equal to the gathering
+    reference's (``test_buckets.both_inserts``); over the budget nothing
+    is written."""
+    _, cb, window, _ = CELL_SHAPES[cell]
+    tfp0, tpl0, _ = seeded_cell(cell)
+    fps, pls = cell_batch(cell, budget)
+    tfp, _, _, n_new, ovf, covf = both_inserts(
+        tfp0, tpl0, fps, pls, order, cb, window
+    )
+    assert tfp.shape == tfp0.shape and tfp.dtype == jnp.uint64
+    assert not bool(ovf) and bool(covf) == (budget == "over")
+    if budget != "over":
+        held = set(np.asarray(tfp0)[np.asarray(tfp0) != EMPTY].tolist())
+        assert int(n_new) == len(set(fps[fps != EMPTY].tolist()) - held) > 0
+
+
+@pytest.mark.parametrize("order", [False, True], ids=["table", "generation"])
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_a_bucket_overflow_returns_the_table_unchanged(cell, order):
+    """One bucket driven past ``SLOTS`` among a cell-shaped batch: the flag
+    is raised and not one word of either array differs (``both_inserts``
+    compares them with what went in) - in the crowded bucket's row, whose
+    seven other buckets the fetch brought along, either."""
+    _, cb, window, _ = CELL_SHAPES[cell]
+    tfp0, tpl0, _ = seeded_cell(cell)
+    fps, pls = cell_batch(cell, "under")
+    crowd = fps_in_bucket(SLOTS + 1, tfp0.shape[0] // SLOTS)
+    fps[np.flatnonzero(fps == EMPTY)[: SLOTS + 1]] = crowd
+    out = both_inserts(tfp0, tpl0, fps, pls, order, cb, window)
+    assert bool(out[4]) and not bool(out[5]) and int(out[3]) == 0
+
+
+def test_a_row_holds_eight_buckets_and_a_candidate_sees_its_own():
+    """The fetch brings ``ROW_LANES / SLOTS`` buckets along.  A candidate
+    whose fingerprint already sits in a NEIGHBOUR bucket of its row (it
+    cannot, by the bucket derivation - so the table is forged) is still
+    novel in its own, and its slot is its own bucket's count, not the
+    row's."""
+    nbuckets = 64
+    per_row = ROW_LANES // SLOTS
+    assert per_row == 8
+    mine, neighbour = 8 * 3 + 2, 8 * 3 + 5  # same row, other bucket
+    (fp,) = fps_in_bucket(1, nbuckets, bucket=mine)
+    filler = fps_in_bucket(3, nbuckets, bucket=neighbour)
+    tfp = np.full(nbuckets * SLOTS, EMPTY, np.uint64)
+    tpl = np.zeros(nbuckets * SLOTS, np.uint64)
+    tfp[neighbour * SLOTS: neighbour * SLOTS + 4] = [*filler, fp]  # forged
+    out = INSERTS["new"](
+        jnp.asarray(tfp), jnp.asarray(tpl), jnp.asarray(np.array([fp])),
+        jnp.asarray(np.array([9], np.uint64)), window=8,
+    )
+    assert int(out[3]) == 1 and not bool(out[4])
+    assert int(np.asarray(out[0])[mine * SLOTS]) == int(fp)
+    assert int(np.asarray(out[1])[mine * SLOTS]) == 9
