@@ -6,7 +6,12 @@ One process that holds the chip and starts no children.  It builds the
 cell's model, makes one untimed warm-up check that walks every engine rung
 the cell will use (set-up), then runs whole checks back to back — a closed
 loop, one client — until ``--seconds`` have passed; the check in flight is
-finished and counted.  Every check is held to the configuration's pins.
+finished and counted.  A window that holds a check also starts no check
+that its own shortest says would end past 1.5 x ``--seconds``
+(``srbench/check.py:window_closes``; the ``window:`` line says
+``closed_by=seconds`` or ``closed_by=overrun``), so a check over three
+quarters of the window gets exactly one.  Every check is held to the
+configuration's pins.
 The workload file's ``loop.kind`` names the unit of work: ``closed`` (the
 default) re-checks one model object, whose engines stay resident, and the
 window may not ask the compiler for anything; ``cold`` makes every check —
@@ -356,7 +361,9 @@ def main(argv=None) -> int:
                 f"reconstruct={spans['reconstruct']:.4f}s {cold}"
                 f"drop={time.monotonic() - t_last:.4f}s; "
                 f"{HostNoise.line(before, noise.snapshot())}")
-        if time.monotonic() - t_first >= args.seconds:
+        closed_by = chk.window_closes(time.monotonic() - t_first,
+                                      [c["check_s"] for c in checks], args.seconds)
+        if closed_by:
             break
     window = compiles.delta(setup_compiles, compiles.snapshot())
     # no fresh compile in any window; a closed window asks the compiler for
@@ -385,7 +392,8 @@ def main(argv=None) -> int:
     # the same work as a mean over the wall, with what lies between checks
     wall_rate = sum(c["generated"] for c in checks) / (t_last - t_first)
     peak = memory_peak_bytes(chips)
-    say(f"window: {len(checks)} checks in {t_last - t_first:.3f}s; check_s "
+    say(f"window: {len(checks)} checks in {t_last - t_first:.3f}s "
+        f"closed_by={closed_by}; check_s "
         f"median={check_s:.4f} min={min(times):.4f} max={max(times):.4f} "
         f"over {len(times)} checks; gen_rate={gen_rate:.1f} states/s "
         f"(mean over the wall, not a metric: {wall_rate:.1f}); "

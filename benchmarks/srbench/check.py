@@ -8,7 +8,8 @@ configuration's pins; nothing here reads the program's internals.
 
 The workload file's ``loop.kind`` says on what a check is made: ``closed``
 re-checks ONE model object (its engines stay resident), ``cold`` builds
-the model object anew INSIDE every check's timed span.
+the model object anew INSIDE every check's timed span.  When a window of
+either kind stops starting checks is ``window_closes``.
 """
 
 from __future__ import annotations
@@ -30,6 +31,22 @@ def loop_kind(workload: dict) -> str:
             f"unknown loop.kind {kind!r}: the kinds are {', '.join(LOOP_KINDS)}"
         )
     return kind
+
+
+def window_closes(elapsed: float, durations: list, seconds: float) -> Optional[str]:
+    """Whether the window starts no further check, and by which rule.
+
+    ``elapsed`` is the time since the window opened, ``durations`` the
+    ``check_s`` of the checks it holds, ``seconds`` its asked length.
+    ``"seconds"``: they have passed.  ``"overrun"``: the window holds a
+    check, and another as short as its shortest would end past one and a
+    half windows — a check over three quarters of ``seconds`` gets exactly
+    one a window, not two on noise.  ``None``: start the next check."""
+    if elapsed >= seconds:
+        return "seconds"
+    if durations and elapsed + min(durations) > 1.5 * seconds:
+        return "overrun"
+    return None
 
 
 def build_model(config: dict):

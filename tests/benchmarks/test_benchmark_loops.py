@@ -96,10 +96,10 @@ def _env(root):
     return env
 
 
-def _rehearse(root, cell, trace=0, prelude=None):
+def _rehearse(root, cell, trace=0, prelude=None, seconds=0.5):
     """``run.py`` in rehearsal mode; with ``prelude``, Python source run
     first in the same process (what breaks the timed path underneath)."""
-    argv = ["--workload", cell, "--seed", "2147483801", "--seconds", "0.5",
+    argv = ["--workload", cell, "--seed", "2147483801", "--seconds", repr(seconds),
             "--trace", str(trace), "--manifest", str(root / "BENCHMARK.json"),
             "--bench-dir", str(root / "benchmarks"), "--rehearse-cpu"]
     if prelude is None:
