@@ -304,6 +304,17 @@ def paxos_model(
     return m
 
 
+def paxos_lossy(client_count: int, server_count: int = 3) -> ActorModel:
+    """``paxos_model`` on its default unordered non-duplicating network
+    under ``LossyNetwork::Yes`` (reference ``ActorModel::lossy_network``,
+    ``src/actor/model.rs:56-57``): upstream's ``paxos check N`` with a Drop
+    action for every message in flight, from plain JSON arguments: a
+    configuration file cannot call a builder verb.  Its device twin is the
+    COMPILED one (``PaxosModel.tensor_model`` hands every lossy
+    configuration to ``_compiled_tensor``)."""
+    return paxos_model(client_count, server_count).lossy_network(True)
+
+
 def _audit_models(rest=()):
     """Default configurations for the static auditor (``audit`` verb and
     the fleet runner, ``_cli.fleet_audit``)."""

@@ -83,7 +83,7 @@ from .history_tensor import (
     MultiOpLinHistoryCodec,
 )
 from ..telemetry.spans import (
-    PROPS_LIN, TWIN_HISTORY, TWIN_NET, TWIN_TABLE, span,
+    PROPS_LIN, TWIN_DROP, TWIN_HISTORY, TWIN_NET, TWIN_TABLE, span,
 )
 from .tensor_model import (
     BitPacker,
@@ -352,7 +352,9 @@ class CompiledActorTensor(TensorModel):
         the history verdict table where the codec needs one), and the
         linearizability history the packed word carries: the codec's
         verdict strategy (``closure`` / ``table``; ``none`` for a model
-        without a history), its client threads and their bits."""
+        without a history), its client threads and their bits; whether
+        the network loses messages (``lossy``: the Drop columns) and the
+        action columns a popped state expands to (``max_actions``)."""
         tables = [
             *self._trans_np, *self._sends_np, *self._poison_np,
             self._env_dst, self._env_pair, self._env_kind, self._env_val,
@@ -383,6 +385,8 @@ class CompiledActorTensor(TensorModel):
             "hist_strategy": "none" if hist is None else hist.strategy,
             "hist_threads": 0 if hist is None else int(hist.C),
             "hist_bits": 0 if hist is None else int(hist.C * hist.thread_bits),
+            "lossy": bool(self.model.lossy),
+            "max_actions": int(self.max_actions),
         }
 
     # -- fragment check ------------------------------------------------------
@@ -1788,36 +1792,40 @@ class CompiledActorTensor(TensorModel):
             )
 
         # -- drop actions (lossy networks): consume without delivering ------
-        if self.ordered:
-            # the object model enumerates Drop only over the deliverable
-            # envelopes — flow HEADS (``actor/model.py`` iter_deliverable) —
-            # so an ordered drop's network effect is exactly the deliver
-            # effect: remove the head, advance the rest of its flow
-            same_flow = (pair[:, :, None] >= 0) & (
-                pair[:, :, None] == pair[:, None, :]
+        with jax.named_scope(TWIN_DROP):
+            if self.ordered:
+                # the object model enumerates Drop only over the
+                # deliverable envelopes — flow HEADS (``actor/model.py``
+                # iter_deliverable) — so an ordered drop's network effect is
+                # exactly the deliver effect: remove the head, advance the
+                # rest of its flow
+                same_flow = (pair[:, :, None] >= 0) & (
+                    pair[:, :, None] == pair[:, None, :]
+                )
+                slots_drop = jnp.where(
+                    diag,
+                    u64(SLOT_EMPTY),
+                    jnp.where(same_flow, slots_b - u64(1), slots_b),
+                )
+            else:
+                # a duplicating network's drop removes the envelope
+                # forever (reference ``network.rs:242-244``);
+                # non-duplicating drops one copy
+                dropped = (
+                    jnp.full_like(slots, u64(SLOT_EMPTY))
+                    if self.dup
+                    else delivered
+                )
+                slots_drop = jnp.where(diag, dropped[:, :, None], slots_b)
+            drop_rows = jnp.concatenate(
+                [
+                    jnp.broadcast_to(
+                        rows[:, None, : self.pw], (B, NS, self.pw)
+                    ),
+                    slot_canonicalize(slots_drop),
+                ],
+                axis=-1,
             )
-            slots_drop = jnp.where(
-                diag,
-                u64(SLOT_EMPTY),
-                jnp.where(same_flow, slots_b - u64(1), slots_b),
-            )
-        else:
-            # a duplicating network's drop removes the envelope forever
-            # (reference ``network.rs:242-244``); non-duplicating drops one
-            # copy
-            dropped = (
-                jnp.full_like(slots, u64(SLOT_EMPTY))
-                if self.dup
-                else delivered
-            )
-            slots_drop = jnp.where(diag, dropped[:, :, None], slots_b)
-        drop_rows = jnp.concatenate(
-            [
-                jnp.broadcast_to(rows[:, None, : self.pw], (B, NS, self.pw)),
-                slot_canonicalize(slots_drop),
-            ],
-            axis=-1,
-        )
         succ = jnp.concatenate([succ, drop_rows], axis=1)
         droppable = at_head if self.ordered else occupied
         valid = jnp.concatenate([valid, droppable], axis=1)
@@ -2173,27 +2181,28 @@ class CompiledActorTensor(TensorModel):
 
         # -- drop actions (lossy): every channel, network-only effect -------
         if self.model.lossy:
-            for ci in range(len(self._channels)):
-                cap, reg, occ, _ecode = region_view(ci)
-                if self.dup:
-                    # only drops remove from a duplicating network
-                    reg_b = jnp.broadcast_to(
-                        reg[:, None, :], (B, cap, cap)
-                    )
-                    dropped = jnp.where(
-                        jnp.eye(cap, dtype=bool)[None], EMPTYW, reg_b
-                    )
-                    droppable = occ
-                else:
-                    # a drop's network effect IS the deliver consume
-                    dropped = consumed(ci, cap, reg, occ)
-                    droppable = occ & (
-                        (reg & u64(COUNT_MASK)).astype(i32) == 1
-                    ) if self.ordered else occ
-                pieces.append(self._assemble_piece(
-                    packed_broadcast(cap), rows, cap, {ci: dropped}
-                ))
-                valids.append(droppable)
+            with jax.named_scope(TWIN_DROP):
+                for ci in range(len(self._channels)):
+                    cap, reg, occ, _ecode = region_view(ci)
+                    if self.dup:
+                        # only drops remove from a duplicating network
+                        reg_b = jnp.broadcast_to(
+                            reg[:, None, :], (B, cap, cap)
+                        )
+                        dropped = jnp.where(
+                            jnp.eye(cap, dtype=bool)[None], EMPTYW, reg_b
+                        )
+                        droppable = occ
+                    else:
+                        # a drop's network effect IS the deliver consume
+                        dropped = consumed(ci, cap, reg, occ)
+                        droppable = occ & (
+                            (reg & u64(COUNT_MASK)).astype(i32) == 1
+                        ) if self.ordered else occ
+                    pieces.append(self._assemble_piece(
+                        packed_broadcast(cap), rows, cap, {ci: dropped}
+                    ))
+                    valids.append(droppable)
 
         # -- timeout actions: one per actor ---------------------------------
         if self._has_timers:

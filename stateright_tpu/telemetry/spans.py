@@ -92,6 +92,14 @@ TWIN_NET = "twin.net"  # slot deliver / send / canonicalise
 TWIN_HISTORY = "twin.history"  # the linearizability history fields
 TWIN_SCOPES = (TWIN_TABLE, TWIN_NET, TWIN_HISTORY)
 
+# Sub-scope of ``sr.expand`` around the Drop columns of a compiled actor
+# twin under ``lossy_network(True)``: the second successor block (every
+# occupied slot consumed without a delivery) and its canonicalisation.
+# Opened by a lossy twin alone, so it is no member of ``TWIN_SCOPES``
+# (what every compiled twin opens); a Drop operation's scope path reads
+# ``sr.expand/.../twin.drop/...`` whatever kernel it calls inside.
+TWIN_DROP = "twin.drop"
+
 # Sub-scope of ``sr.hash`` around the twin's ``representative_rows``, opened
 # only by a step program built under ``.symmetry()``: the canonicaliser's
 # operations carry ``sr.hash/sym.canon`` (their stage stays ``sr.hash``).

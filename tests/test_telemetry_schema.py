@@ -86,8 +86,8 @@ SCHEMA = {
         # job), jobs/slots (fleet root), rung/source (engine_acquire),
         # dsteps (device_call), jaxprs_traced (dispatch), hit/retrieved_s
         # (program.load), status (grow), the universes, row,
-        # table bytes and history codec of a compiled actor twin
-        # (twin_compile)
+        # table bytes, history codec, lossiness and action columns of a
+        # compiled actor twin (twin_compile)
         {"v": int, "name": str, "trace_id": str, "span_id": str,
          "start": _REAL, "dur": _REAL},
         {"parent_id": str, "engine": str, "error": str, "attempt": int,
@@ -97,7 +97,8 @@ SCHEMA = {
          "jaxprs_traced": int, "hit": bool, "retrieved_s": _REAL,
          "actor_states": str, "envelopes": int, "n_slots": int,
          "row_width": int, "table_bytes": int, "hist_strategy": str,
-         "hist_threads": int, "hist_bits": int},
+         "hist_threads": int, "hist_bits": int, "lossy": bool,
+         "max_actions": int},
     ),
     "health": (
         {"v": int, "event": str},
