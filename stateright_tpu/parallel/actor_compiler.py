@@ -94,6 +94,12 @@ from .tensor_model import (
     stable_rank,
 )
 
+#: the largest envelope universe whose record is read by compare-and-max
+#: (``CompiledActorTensor._env_words``); larger ones are gathered.  Measured
+#: alone on a v5e: the select form wins 2.7x at 1,024 envelopes and loses by
+#: 4% at 4,096 (PERF.md section 6, PR 44)
+_ENV_SELECT_MAX = 2048
+
 #: envelope-kind codes for the history/property tables
 _K_OTHER, _K_PUT_OK, _K_GET_OK, _K_PUT_FAIL = 0, 1, 2, 3
 
@@ -120,6 +126,62 @@ def _orl_hint(state) -> str:
 
 class CompileError(Exception):
     """The model is outside the compilable fragment."""
+
+
+class RecordLayout:
+    """Bit layout of one look-up record: named fields packed end to end
+    into the least number of 32-bit words their widths need.
+
+    ``fields`` is ``(name, largest value)`` pairs; a field takes
+    ``bit_length(largest value)`` bits (at least one) and may straddle two
+    words, so ``words == ceil(total bits / 32)`` exactly - a record grows a
+    word when a universe outgrows it, never a flag.  :meth:`pack` is numpy
+    (the freeze); :meth:`get` takes the record's words as a sequence of
+    equally shaped ``uint32`` arrays, numpy or traced alike, and answers
+    ``int32`` with shifts and masks alone - no arithmetic, so the interval
+    pass (``analysis/interval.py``) bounds a field by its mask."""
+
+    def __init__(self, fields):
+        self.layout: dict[str, tuple[int, int]] = {}
+        off = 0
+        for name, largest in fields:
+            bits = max(1, int(largest).bit_length())
+            if bits > 31:
+                raise CompileError(
+                    f"record field {name!r} needs {bits} bits (largest "
+                    f"value {largest}); a field is read as one int32"
+                )
+            self.layout[name] = (off, bits)
+            off += bits
+        self.bits = off
+        self.words = max(1, -(-off // 32))
+        assert 32 * (self.words - 1) < max(off, 1) <= 32 * self.words
+
+    def pack(self, **cols) -> np.ndarray:
+        """``uint32[N, words]`` from one ``[N]`` integer column a field."""
+        assert set(cols) == set(self.layout), (set(cols), set(self.layout))
+        n = len(next(iter(cols.values())))
+        out = np.zeros((n, self.words), np.uint64)
+        for name, (off, bits) in self.layout.items():
+            v = np.asarray(cols[name]).astype(np.int64)
+            assert v.shape == (n,), (name, v.shape)
+            assert ((v >= 0) & (v < (1 << bits))).all(), name
+            w, s = divmod(off, 32)
+            v = v.astype(np.uint64)
+            out[:, w] |= (v << np.uint64(s)) & np.uint64(0xFFFFFFFF)
+            if s + bits > 32:
+                out[:, w + 1] |= v >> np.uint64(32 - s)
+        return out.astype(np.uint32)
+
+    def get(self, words, name: str):
+        off, bits = self.layout[name]
+        w, s = divmod(off, 32)
+        v = words[w] >> np.uint32(s) if s else words[w]
+        if s + bits > 32:
+            v = v | (words[w + 1] << np.uint32(32 - s))
+        if s + bits != 32:
+            v = v & np.uint32((1 << bits) - 1)
+        return v.astype(np.int32)
 
 
 def compile_actor_model(
@@ -347,14 +409,25 @@ class CompiledActorTensor(TensorModel):
     def compile_attrs(self) -> dict:
         """What the closure and tabulation came to (the ``twin_compile``
         span's attributes): per-actor state universes, the envelope
-        universe, the row, the bytes of the look-up tables the step
-        program holds on the device (what :meth:`_consts` uploads, plus
-        the history verdict table where the codec needs one), and the
+        universe, the row, ``table_bytes`` - the bytes of the closure's
+        TABULATION, the host's per-actor numpy tables (``_trans_np`` ...
+        ``_env_chosen``, the Timeout, boundary and property tables, the
+        history verdict table where the codec needs one) - beside what the
+        device holds of it: ``device_table_bytes`` (exactly what
+        :meth:`_consts` uploads: the two record tables of
+        :meth:`_freeze_records` in place of the per-actor ones),
+        ``record_words`` (the 32-bit words of one transition record) and
+        ``step_gathers`` (the gather equations one deliver block makes at
+        slot lanes: the mechanism is static, so it is counted where it is
+        decided, and ``tests/test_actor_tensor_ordered.py`` holds the
+        traced step to it); and the
         linearizability history the packed word carries: the codec's
         verdict strategy (``closure`` / ``table``; ``none`` for a model
         without a history), its client threads and their bits; whether
         the network loses messages (``lossy``: the Drop columns) and the
         action columns a popped state expands to (``max_actions``)."""
+        import jax
+
         tables = [
             *self._trans_np, *self._sends_np, *self._poison_np,
             self._env_dst, self._env_pair, self._env_kind, self._env_val,
@@ -382,6 +455,15 @@ class CompiledActorTensor(TensorModel):
             "n_slots": int(self.n_slots),
             "row_width": int(self.width),
             "table_bytes": int(sum(np.asarray(t).nbytes for t in tables)),
+            "device_table_bytes": int(
+                sum(
+                    t.nbytes
+                    for t in jax.tree_util.tree_leaves(self._consts_np())
+                    if isinstance(t, np.ndarray)
+                )
+            ),
+            "record_words": int(self._trans_layout.words),
+            "step_gathers": int(self._step_gathers()),
             "hist_strategy": "none" if hist is None else hist.strategy,
             "hist_threads": 0 if hist is None else int(hist.C),
             "hist_bits": 0 if hist is None else int(hist.C * hist.thread_bits),
@@ -853,6 +935,115 @@ class CompiledActorTensor(TensorModel):
                 for i in range(n)
             ],
             np.int32,
+        )
+        self._freeze_records()
+
+    def _freeze_records(self) -> None:
+        """Pack what the deliver block reads into two record tables, from
+        the per-actor tables above (which stay the host's source of truth):
+
+         - the **envelope record** ``uint32[ne, words]``: everything a bare
+           envelope code decides - its destination (``n_actors`` for an id
+           that is no actor's), on an ordered network its flow id, for a
+           register workload its history kind, value code and the client
+           index of its destination (+ 1; 0 = not a client);
+         - the **transition record** ``uint32[max_S * ne, words]``, ONE
+           table overlaid over the actors and indexed by ``(state code of
+           the envelope's destination) * ne + envelope code``: next state
+           code + valid bit, poison, the K send codes each with its
+           present bit and (ordered) its destination - the send's flow id
+           is ``deliverer * n_actors + that`` - and the timer effect + 1
+           where the model has timers.
+
+        The overlay is sound because an envelope has one destination:
+        actor ``i``'s table has entries only in the columns of envelopes
+        addressed to ``i``.  That is checked here, not assumed - two
+        actors claiming one entry, or an entry whose actor is not the
+        envelope's destination, raise :class:`CompileError`.  The word
+        counts are what the universes' bit widths need
+        (:class:`RecordLayout`): a wider protocol gets another word."""
+        n, nep, K = self.n_actors, self._ne_padded, self.K
+        env_dst = self._env_dst.astype(np.int64)
+        env_fields = [("dst", n)]
+        env_cols = {"dst": np.minimum(env_dst, n)}
+        if self.ordered:
+            env_fields.append(("pair", int(self._env_pair.max())))
+            env_cols["pair"] = self._env_pair
+        if self.C:
+            ci = np.zeros(nep, np.int64)
+            actor = env_dst < n
+            ci[actor] = self._client_of[env_dst[actor]] + 1
+            env_fields += [
+                ("kind", int(self._env_kind.max())),
+                ("val", int(self._env_val.max())),
+                ("ci", self.C),
+            ]
+            env_cols.update(kind=self._env_kind, val=self._env_val, ci=ci)
+        self._env_layout = RecordLayout(env_fields)
+        self._env_rec_np = self._env_layout.pack(**env_cols)
+
+        max_s = max(len(st) for st in self._states)
+        fields = [("next", max_s - 1), ("valid", 1), ("poison", 1)]
+        for k in range(K):
+            fields += [(f"send{k}", nep - 1), (f"has{k}", 1)]
+            if self.ordered:
+                fields.append((f"sdst{k}", int(env_dst.max())))
+        if self._has_timers:
+            fields.append(("teff", 2))
+        self._trans_layout = RecordLayout(fields)
+        cols = {
+            name: np.zeros((max_s, nep), np.int64) for name, _ in fields
+        }
+        owner = np.full((max_s, nep), -1, np.int64)
+        env_src = np.asarray(
+            [int(e.src) for e in self._envs] + [0] * (nep - len(self._envs)),
+            np.int64,
+        )
+        for i in range(n):
+            ti, ki = self._trans_np[i], self._sends_np[i]
+            si = ti.shape[0]
+            claimed = (
+                (ti >= 0)
+                | self._poison_np[i]
+                | (ki >= 0).any(-1)
+                | (self._teff_np[i] >= 0)
+            )
+            clash = claimed & (owner[:si] >= 0)
+            if clash.any():
+                sc, ec = (int(x) for x in np.argwhere(clash)[0])
+                raise CompileError(
+                    f"actors {int(owner[sc, ec])} and {i} both claim the "
+                    f"transition of (state code {sc}, envelope code {ec}): "
+                    "the per-actor tables do not overlay into one"
+                )
+            stray = claimed & (env_dst[None, :] != i)
+            if stray.any():
+                sc, ec = (int(x) for x in np.argwhere(stray)[0])
+                raise CompileError(
+                    f"actor {i} has a transition on envelope code {ec}, "
+                    f"which is addressed to {int(env_dst[ec])}: an entry's "
+                    "actor must be its envelope's destination"
+                )
+            owner[:si][claimed] = i
+            cols["next"][:si] += np.where(ti >= 0, ti, 0)
+            cols["valid"][:si] += ti >= 0
+            cols["poison"][:si] += self._poison_np[i]
+            for k in range(K):
+                code = ki[:, :, k]
+                has = code >= 0
+                # a send's source is the deliverer, so its flow id is
+                # deliverer * n + destination: what env_pair holds
+                assert (env_src[code[has]] == i).all()
+                cols[f"send{k}"][:si] += np.where(has, code, 0)
+                cols[f"has{k}"][:si] += has
+                if self.ordered:
+                    cols[f"sdst{k}"][:si] += np.where(
+                        has, env_dst[np.maximum(code, 0)], 0
+                    )
+            if self._has_timers:
+                cols["teff"][:si] += self._teff_np[i] + 1  # 0 = keep
+        self._trans_rec_np = self._trans_layout.pack(
+            **{name: c.reshape(-1) for name, c in cols.items()}
         )
 
     def _effects(self, i: int, out: Out, add_env, poison: bool):
@@ -1432,45 +1623,45 @@ class CompiledActorTensor(TensorModel):
 
     # -- device --------------------------------------------------------------
 
+    def _consts_np(self) -> dict:
+        """What the step and property programs hold on the device, as the
+        numpy it is uploaded from: the two record tables of
+        :meth:`_freeze_records` for the deliver block (no per-actor
+        ``trans`` / ``sends`` / ``poison`` / ``teff`` and no ``env_*``
+        column but ``env_chosen``, which ``sr.props`` reads), the
+        per-actor Timeout tables where the model has timers (``[B]``-lane
+        look-ups; ``env_pair`` with them where a Timeout's send needs its
+        flow id), the boundary and property tables."""
+        cst = {
+            "env_rec": self._env_rec_np,
+            "trans_rec": self._trans_rec_np,
+            "env_chosen": self._env_chosen,
+        }
+        if self._has_timers:
+            cst.update(
+                ttrans=self._ttrans_np,
+                tsends=self._tsends_np,
+                tpoison=self._tpoison_np,
+                tbit=self._tbit_np,
+            )
+            if self.ordered and self.Kt and not self.per_channel:
+                cst["env_pair"] = self._env_pair
+        if self._boundary_np is not None:
+            cst["boundary"] = self._boundary_np
+        if self.per_channel:
+            cst["chan_of"] = self._chan_of
+        cst["props"] = list(self._prop_tables)
+        return cst
+
     def _consts(self):
+        import jax
         import jax.numpy as jnp
 
         if self._device_consts is None:
-            self._device_consts = {
-                "trans": [jnp.asarray(t) for t in self._trans_np],
-                "sends": [jnp.asarray(t) for t in self._sends_np],
-                "poison": [jnp.asarray(t) for t in self._poison_np],
-                "env_dst": jnp.asarray(self._env_dst),
-                "env_pair": jnp.asarray(self._env_pair),
-                "env_kind": jnp.asarray(self._env_kind),
-                "env_val": jnp.asarray(self._env_val),
-                "env_chosen": jnp.asarray(self._env_chosen),
-            }
-            if self._has_timers:
-                self._device_consts.update(
-                    teff=[jnp.asarray(t) for t in self._teff_np],
-                    ttrans=[jnp.asarray(t) for t in self._ttrans_np],
-                    tsends=[jnp.asarray(t) for t in self._tsends_np],
-                    tpoison=[jnp.asarray(t) for t in self._tpoison_np],
-                    tbit=[jnp.asarray(t) for t in self._tbit_np],
-                )
-            if self._boundary_np is not None:
-                self._device_consts["boundary"] = [
-                    jnp.asarray(t) for t in self._boundary_np
-                ]
-            if self.per_channel:
-                self._device_consts["chan_of"] = jnp.asarray(self._chan_of)
-            self._device_consts["props"] = [
-                None
-                if entry is None
-                else (
-                    entry[0],
-                    [jnp.asarray(t) for t in entry[1]]
-                    if isinstance(entry[1], list)
-                    else {k: jnp.asarray(v) for k, v in entry[1].items()},
-                )
-                for entry in self._prop_tables
-            ]
+            self._device_consts = jax.tree_util.tree_map(
+                lambda x: jnp.asarray(x) if isinstance(x, np.ndarray) else x,
+                self._consts_np(),
+            )
         return self._device_consts
 
     def row_domain(self):
@@ -1482,8 +1673,13 @@ class CompiledActorTensor(TensorModel):
         (a 3-bit field over 5 codes proves ``< 5``), and each network slot
         word is either ``EMPTY`` or ``code << COUNT_BITS | count`` with
         ``code < len(envs)`` — which is exactly what lets the interval
-        pass prove every ``trans[sc * ne + ecode]`` table gather in range
-        instead of reporting the whole kernel undecidable."""
+        pass prove the deliver block's one table gather in range instead
+        of reporting the whole kernel undecidable: the overlaid
+        transition record is indexed by ``sc_dst * ne + ecode``, and
+        ``sc_dst`` (:meth:`_state_of`: stand-alone selects over the
+        ``a{i}`` fields joined by ``max``) is bounded by the UNION of
+        these bounds, ``max_i len(states[i]) - 1`` - below the record's
+        ``max_i len(states[i]) * ne`` rows."""
         from .tensor_model import RowDomain
 
         bounds = {
@@ -1507,6 +1703,84 @@ class CompiledActorTensor(TensorModel):
         for w in range(self.pw, self.width):
             dom.declare_word(w, slot_hi, may_empty=True)
         return dom
+
+    def _env_words(self, cst, ecode):
+        """The envelope record of every lane of ``ecode`` (in range by
+        construction), as its words: a compare-and-max over the envelope
+        universe laid along a MAJOR axis - ``max_e where(ecode == e,
+        rec[e], 0)``, one term non-zero - which fuses into vector work,
+        where an element gather is a serial fetch a lane whatever it reads
+        (alone on a v5e, ns a lane with the timing loop's own 0.07-0.15, at
+        32 / 106 / 272 / 1,024 / 4,096 envelopes: 0.26 / 0.41 / 0.45 / 2.1
+        / 7.9 against the gather's 0.47 / 8.4 / 8.5 / 5.8 / 7.6; a sum in
+        place of the max is a third cheaper and along the MINOR axis three
+        times dearer at 272, a chain of 272 constant selects compiles for
+        9 s: PERF.md section 6, PR 44).  ``max``, not ``sum``: the interval
+        pass keeps the table's own bounds through ``reduce_max``, and the
+        0.15 ns a lane between them is 0.02 s of a 110 M-lane check.  Its
+        work grows with the universe, so past ``_ENV_SELECT_MAX``
+        envelopes the record is gathered instead."""
+        import jax.numpy as jnp
+
+        tab = cst["env_rec"]  # uint32[ne, words]
+        nep, words = tab.shape
+        if self._env_gathered:
+            got = tab[ecode]
+            return [got[..., w] for w in range(words)]
+        lead = (nep,) + (1,) * ecode.ndim
+        hit = ecode[None] == jnp.arange(nep, dtype=jnp.int32).reshape(lead)
+        return [
+            jnp.max(
+                jnp.where(hit, tab[:, w].reshape(lead), jnp.uint32(0)),
+                axis=0,
+            )
+            for w in range(words)
+        ]
+
+    def _state_of(self, rows, actor):
+        """The state code of ``actor[b, ...]`` in row ``b`` (0 for
+        ``n_actors``, the code of an id that is no actor's): one select
+        an actor over the packed ``a{i}`` fields, joined by ``max`` -
+        vector work, no gather.  Not a nested chain
+        (``tensor_model.select_along_axis``) on purpose: each select
+        stands alone, so the interval pass bounds the result by the UNION
+        of the fields' declared bounds (``docs/analysis.md``), which is
+        what proves the overlaid record's index in range."""
+        import jax
+        import jax.numpy as jnp
+
+        out = None
+        for i in range(self.n_actors):
+            code = self.pk.get(rows, f"a{i}").astype(jnp.int32)
+            code = code.reshape(code.shape + (1,) * (actor.ndim - 1))
+            term = jnp.where(actor == i, code, 0)
+            out = term if out is None else jax.lax.max(out, term)
+        return out
+
+    def _trans_words(self, cst, index):
+        """The transition record at ``index`` (= destination's state code
+        * ne + envelope code), as its words: one row gather."""
+        got = cst["trans_rec"][index]
+        return [got[..., w] for w in range(got.shape[-1])]
+
+    @property
+    def _env_gathered(self) -> bool:
+        """Whether :meth:`_env_words` gathers (a universe too large for
+        the compare-and-max); read at trace time, like the step."""
+        return self._ne_padded > _ENV_SELECT_MAX
+
+    def _step_gathers(self) -> int:
+        """Record look-ups the step traces as gather equations at slot
+        lanes: one deliver block's in the slot-multiset kernel, every
+        deliver channel's in the per-channel one."""
+        env = int(self._env_gathered)
+        if not self.per_channel:
+            return 1 + env
+        return sum(
+            1 + env * bool(self._ch_ret_kind[ci] and self.C)
+            for ci, (_s, d) in enumerate(self._channels)
+            if d < self.n_actors
+        )
 
     def step_rows(self, rows):
         if self.per_channel:
@@ -1553,42 +1827,40 @@ class CompiledActorTensor(TensorModel):
 
         slots = rows[:, self.pw :]  # [B, NS]
         occupied = slots != u64(SLOT_EMPTY)
+        el, tl = self._env_layout, self._trans_layout
         with jax.named_scope(TWIN_TABLE):
             ecode = jnp.where(
                 occupied, (slots >> u64(COUNT_BITS)).astype(i32), 0
             )  # [B, NS]
-            dst = cst["env_dst"][ecode]  # [B, NS]
+            # ONE look-up by envelope code ...
+            env = self._env_words(cst, ecode)
+            dst = el.get(env, "dst")  # [B, NS]; n_actors = no actor's
             pair = None  # flow id of each slot: ordered networks only
             if self.ordered:
                 # count bits hold the 1-based rank within the directed flow;
                 # only the head (rank 1) of each flow is deliverable
                 # (reference ``model.rs:224-227``)
                 rank1 = (slots & u64(COUNT_MASK)).astype(i32)  # [B, NS]
-                pair = jnp.where(occupied, cst["env_pair"][ecode], -1)
+                pair = jnp.where(occupied, el.get(env, "pair"), -1)
                 at_head = occupied & (rank1 == 1)
 
             # -- deliver actions (slot a delivers envelope in slot a) -------
-            new_scode = jnp.zeros((B, NS), i32)
-            valid = jnp.zeros((B, NS), bool)
-            poison = jnp.zeros((B, NS), bool)
-            send_codes = jnp.full((B, NS, max(self.K, 1)), -1, i32)
-            for i in range(self.n_actors):
-                mask = occupied & (dst == i)
-                sc = pk.get(rows, f"a{i}").astype(i32)[:, None]  # [B, 1]
-                flat = sc * ne + ecode  # [B, NS]
-                nc = cst["trans"][i].reshape(-1)[flat]
-                pi = cst["poison"][i].reshape(-1)[flat]
-                ks = cst["sends"][i].reshape(-1, max(self.K, 1))[flat]
-                new_scode = jnp.where(mask, nc, new_scode)
-                valid = valid | (mask & (nc >= 0))
-                poison = poison | (mask & pi)
-                send_codes = jnp.where(mask[..., None], ks, send_codes)
+            # ... and ONE by (the destination's state, envelope code):
+            # every per-actor table is overlaid in one record
+            sc_dst = self._state_of(rows, dst)  # [B, NS]
+            rec = self._trans_words(cst, sc_dst * ne + ecode)
+            new_scode = tl.get(rec, "next")
+            valid = occupied & (tl.get(rec, "valid") == 1)
+            poison = occupied & (tl.get(rec, "poison") == 1)
+            send_codes = [tl.get(rec, f"send{k}") for k in range(self.K)]
+            send_on = [tl.get(rec, f"has{k}") == 1 for k in range(self.K)]
             if self.ordered:
                 valid = valid & at_head
-                # flow id of each send column's code: with `pair`, all the
-                # look-ups slot_send_ordered's carried ids ever need
+                # flow id of each send: its source is the deliverer.  With
+                # `pair`, all the look-ups slot_send_ordered's carried ids
+                # ever need
                 send_pairs = [
-                    cst["env_pair"][send_codes[..., k]]
+                    dst * self.n_actors + tl.get(rec, f"sdst{k}")
                     for k in range(self.K)
                 ]
 
@@ -1620,15 +1892,15 @@ class CompiledActorTensor(TensorModel):
                     )  # [B, NS]
                 slots_d = jnp.where(diag, delivered[:, :, None], slots_b)
             for k in range(self.K):
-                sk = send_codes[..., k]
+                sk = send_codes[k]
                 if self.ordered:
                     slots_d, pair_d, of = slot_send_ordered(
                         slots_d, pair_d, sk.astype(u64), send_pairs[k],
-                        valid & (sk >= 0),
+                        valid & send_on[k],
                     )
                 else:
                     slots_d, of = slot_send(
-                        slots_d, sk.astype(u64), valid & (sk >= 0),
+                        slots_d, sk.astype(u64), valid & send_on[k],
                         set_semantics=self.dup,
                     )
                 poison = poison | of
@@ -1654,14 +1926,13 @@ class CompiledActorTensor(TensorModel):
             # a deliver's handler may set/cancel the recipient's timer
             timers_cur = pk.get(rows, "timers").astype(i32)  # [B]
             tnew = jnp.broadcast_to(timers_cur[:, None], (B, NS))
+            eff = tl.get(rec, "teff")  # [B, NS]: 0 keep, 1 clear, 2 set
             for i in range(self.n_actors):
                 mask = valid & occupied & (dst == i)
-                sc = pk.get(rows, f"a{i}").astype(i32)[:, None]
-                eff = cst["teff"][i].reshape(-1)[sc * ne + ecode]  # [B, NS]
                 tnew = jnp.where(
-                    mask & (eff == 1),
+                    mask & (eff == 2),
                     tnew | (1 << i),
-                    jnp.where(mask & (eff == 0), tnew & ~(1 << i), tnew),
+                    jnp.where(mask & (eff == 1), tnew & ~(1 << i), tnew),
                 )
             fw.set("timers", tnew.astype(u64))
 
@@ -1677,11 +1948,11 @@ class CompiledActorTensor(TensorModel):
                 # unlike the K=1 layout where only the read's is non-trivial.
                 K = self.hist.K
                 eb = self.hist.snap_entry_bits
-                kind = cst["env_kind"][ecode]  # [B, NS]
-                ci = self._client_of_dev()[jnp.clip(dst, 0, self.n_actors - 1)]
-                is_ret_w = valid & (kind == _K_PUT_OK) & (ci >= 0)
-                is_ret_r = valid & (kind == _K_GET_OK) & (ci >= 0)
-                rv = cst["env_val"][ecode]
+                kind = el.get(env, "kind")  # [B, NS]
+                ci = el.get(env, "ci")  # client index + 1; 0 = no client
+                is_ret_w = valid & (kind == _K_PUT_OK) & (ci > 0)
+                is_ret_r = valid & (kind == _K_GET_OK) & (ci > 0)
+                rv = el.get(env, "val")
                 phases = jnp.stack(
                     [
                         pk.get(rows, f"h{c}_phase").astype(i32)
@@ -1691,8 +1962,8 @@ class CompiledActorTensor(TensorModel):
                 )  # [B, C]
                 comp = phases >> 1  # completed ops per thread (stored states)
                 for c in range(self.C):
-                    m_w = is_ret_w & (ci == c)
-                    m_r = is_ret_r & (ci == c)
+                    m_w = is_ret_w & (ci == c + 1)
+                    m_r = is_ret_r & (ci == c + 1)
                     cur_ph = pk.get(rows, f"h{c}_phase").astype(i32)[:, None]
                     new_ph = jnp.where(
                         m_w, cur_ph + 2, jnp.where(m_r, cur_ph + 1, cur_ph)
@@ -1720,15 +1991,15 @@ class CompiledActorTensor(TensorModel):
                         jnp.where(m_r, rv, cur_rv).astype(u64),
                     )
             elif self.C:
-                kind = cst["env_kind"][ecode]  # [B, NS]
-                ci = self._client_of_dev()[jnp.clip(dst, 0, self.n_actors - 1)]
+                kind = el.get(env, "kind")  # [B, NS]
+                ci = el.get(env, "ci")  # client index + 1; 0 = no client
                 is_ret_w = (
                     valid
                     & ((kind == _K_PUT_OK) | (kind == _K_PUT_FAIL))
-                    & (ci >= 0)
+                    & (ci > 0)
                 )
-                is_ret_r = valid & (kind == _K_GET_OK) & (ci >= 0)
-                rv = cst["env_val"][ecode]
+                is_ret_r = valid & (kind == _K_GET_OK) & (ci > 0)
+                rv = el.get(env, "val")
                 phases = jnp.stack(
                     [
                         pk.get(rows, f"h{c}_phase").astype(i32)
@@ -1743,8 +2014,8 @@ class CompiledActorTensor(TensorModel):
                     jnp.where(phases == PHASE_DONE, 2, 1),
                 )  # [B, C]
                 for c in range(self.C):
-                    m_w = is_ret_w & (ci == c)  # write returned + read invoked
-                    m_r = is_ret_r & (ci == c)
+                    m_w = is_ret_w & (ci == c + 1)  # write returned + read invoked
+                    m_r = is_ret_r & (ci == c + 1)
                     cur_ph = pk.get(rows, f"h{c}_phase").astype(i32)[:, None]
                     new_ph = jnp.where(
                         m_w,
@@ -1934,8 +2205,9 @@ class CompiledActorTensor(TensorModel):
         import jax.numpy as jnp
 
         i32, u64 = jnp.int32, jnp.uint64
-        kind = cst["env_kind"][ecode]  # [B, cap]
-        rv = cst["env_val"][ecode]
+        env = self._env_words(cst, ecode)
+        kind = self._env_layout.get(env, "kind")  # [B, cap]
+        rv = self._env_layout.get(env, "val")
         phases = jnp.stack(
             [
                 fw.get(f"h{j}_phase").astype(i32)[:, 0]
@@ -2052,8 +2324,11 @@ class CompiledActorTensor(TensorModel):
                         self._region(rows, t)[:, None, :],
                         (B, lead, self._ch_cap[t]),
                     )
+                # a send code unpacked from the record is bounded by its
+                # field's mask, not by the universe: clip both ends
                 en = valid & (sk >= 0) & (
-                    cst["chan_of"][jnp.maximum(sk, 0)] == t
+                    cst["chan_of"][jnp.clip(sk, 0, self._ne_padded - 1)]
+                    == t
                 )
                 if self.ordered:
                     cur, of = region_send_ordered(cur, sk.astype(u64), en)
@@ -2083,6 +2358,7 @@ class CompiledActorTensor(TensorModel):
         ne = self._ne_padded
         pk = self.pk
         n = self.n_actors
+        tl = self._trans_layout
         EMPTYW = u64(SLOT_EMPTY)
 
         pieces, valids = [], []
@@ -2132,17 +2408,30 @@ class CompiledActorTensor(TensorModel):
             with jax.named_scope(TWIN_TABLE):
                 cap, reg, occ, ecode = region_view(ci)
                 sc = pk.get(rows, f"a{d}").astype(i32)[:, None]  # [B, 1]
-                flat = sc * ne + ecode  # [B, cap]
-                nc = cst["trans"][d].reshape(-1)[flat]
-                valid = occ & (nc >= 0)
+                # the destination is the channel's own: the overlaid
+                # record's index needs no select here
+                rec = self._trans_words(cst, sc * ne + ecode)  # [B, cap]
+                nc = tl.get(rec, "next")
+                valid = occ & (tl.get(rec, "valid") == 1)
                 if self.ordered:
                     valid = valid & (
                         (reg & u64(COUNT_MASK)).astype(i32) == 1
                     )
                 poison = None
                 if self._ch_poison_any[ci]:
-                    poison = occ & cst["poison"][d].reshape(-1)[flat]
-                ks = cst["sends"][d].reshape(-1, max(self.K, 1))[flat]
+                    poison = occ & (tl.get(rec, "poison") == 1)
+                ks = jnp.stack(
+                    [
+                        jnp.where(
+                            tl.get(rec, f"has{k}") == 1,
+                            tl.get(rec, f"send{k}"),
+                            -1,
+                        )
+                        for k in range(self.K)
+                    ]
+                    or [jnp.full((B, cap), -1, i32)],
+                    -1,
+                )  # [B, cap, max(K, 1)]; -1 = no send
 
             if self.dup:
                 work = {}
@@ -2164,13 +2453,13 @@ class CompiledActorTensor(TensorModel):
                         B, cap,
                     )
             if self._has_timers and self._ch_timer[ci]:
-                eff = cst["teff"][d].reshape(-1)[flat]  # [B, cap]
+                eff = tl.get(rec, "teff")  # [B, cap]: 0 keep, 1 clear, 2 set
                 tcur = pk.get(rows, "timers").astype(i32)[:, None]
                 bit = (tcur >> d) & 1
                 nb = jnp.where(
-                    valid & (eff == 1),
+                    valid & (eff == 2),
                     1,
-                    jnp.where(valid & (eff == 0), 0, bit),
+                    jnp.where(valid & (eff == 1), 0, bit),
                 )
                 tnew = (tcur & ~(1 << d)) | (nb << d)
                 fw.set("timers", tnew.astype(u64))
@@ -2280,11 +2569,6 @@ class CompiledActorTensor(TensorModel):
         for x in per[1:]:
             b = (b & x) if self._boundary.kind == "forall" else (b | x)
         return b
-
-    def _client_of_dev(self):
-        import jax.numpy as jnp
-
-        return jnp.asarray(self._client_of)
 
     def property_masks(self, rows):
         import jax

@@ -202,8 +202,8 @@ def gather_shapes(fn, *args) -> collections.Counter:
 @pytest.mark.parametrize(
     "make, per_slot_gathers",
     [
-        (lambda: abd_ordered(2, 2), 14),
-        (lambda: raft_model(2, network=Network.new_ordered()), 9),
+        (lambda: abd_ordered(2, 2), 1),
+        (lambda: raft_model(2, network=Network.new_ordered()), 1),
     ],
     ids=["abd_ordered_2x2", "raft2_ordered_timers"],
 )
@@ -211,16 +211,41 @@ def test_step_rows_gathers_no_flow_id_per_successor_slot(make, per_slot_gathers)
     """Before the ids were carried, every ``slot_send_ordered`` call of the
     deliver block gathered ``env_pair`` at ``[B, NS, NS]`` lanes (K of them a
     step) and every one of ``_append_timeouts`` at ``[B, actors, NS]``.  The
-    ``[B, NS]`` look-ups (``per_slot_gathers`` then as now) have not grown."""
+    ``[B, NS]`` look-ups were ``3 * n_actors + 2 + K + 3`` (14 and 9 here)
+    until the deliver block looked a delivered envelope up ONCE: the
+    envelope record is a compare-and-max over the universe, no gather, and
+    the one gather left is the ``[B, NS, words]`` row of the transition
+    record overlaid over the actors - what ``step_gathers`` on the
+    ``twin_compile`` span says."""
     tm = make().tensor_model()
     tm.init_rows()  # device constants outside any trace
     assert tm.ordered
     B, NS = 7, tm.n_slots
+    words = tm.compile_attrs()["record_words"]
+    cst = tm._consts()
+    # the per-actor tables and the per-envelope columns are not on the device
+    # at all (env_pair only for the Timeout block's sends, at [B] lanes)
+    assert not set(cst) & {
+        "trans", "sends", "poison", "teff", "env_dst", "env_kind", "env_val"
+    }
+    assert ("env_pair" in cst) == bool(tm._has_timers and tm.Kt)
     for step in (tm.step_rows, tm.step_rows_coalesced):
-        shapes = gather_shapes(step, jnp.zeros((B, tm.width), jnp.uint64))
+        rows = jnp.zeros((B, tm.width), jnp.uint64)
+        shapes = gather_shapes(step, rows)
         assert not [s for s in shapes if int(np.prod(s)) >= B * NS * NS], shapes
         assert not [s for s in shapes if len(s) == 3 and s[-1] == NS], shapes
-        assert shapes[(B, NS)] == per_slot_gathers, shapes
+        at_slot_lanes = shapes[(B, NS)] + shapes[(B, NS, words)]
+        assert at_slot_lanes == per_slot_gathers == tm.compile_attrs()["step_gathers"], shapes
+        # ... and the one gather at slot lanes reads the overlaid record
+        closed = jax.make_jaxpr(step)(rows)
+        const_of = dict(zip(closed.jaxpr.constvars, closed.consts))
+        read = [
+            const_of.get(eqn.invars[0])
+            for eqn in _iter_eqns(closed)
+            if eqn.primitive.name == "gather"
+            and tuple(eqn.outvars[0].aval.shape)[:2] == (B, NS)
+        ]
+        assert len(read) == 1 and read[0] is cst["trans_rec"], read
 
 
 def flows_are_well_formed(tm, slot_words) -> bool:

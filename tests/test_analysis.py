@@ -531,6 +531,32 @@ def test_shipped_models_audit_clean():
     assert not bad, "\n\n".join(f"{n}:\n{r}" for n, r in bad)
 
 
+@pytest.mark.parametrize("name", ["abd_ordered_2x3", "raft2_ordered", "paxos_lossy_1"])
+def test_the_overlaid_record_gather_is_proved_in_range(name):
+    """The compiled twin's deliver block indexes ONE transition record,
+    overlaid over the actors, by ``sc_dst * ne + ecode``: the interval
+    pass PROVES it in range (``sc_dst`` is bounded by the union of the
+    ``a{i}`` bounds), every site of both kernels decided - not a weaker
+    claim than the per-actor ``trans[sc * ne + ecode]`` gathers had."""
+    from stateright_tpu.actor import Network
+    from stateright_tpu.models.linearizable_register import abd_ordered
+    from stateright_tpu.models.paxos import paxos_lossy
+    from stateright_tpu.models.raft import raft_model
+
+    model = {
+        "abd_ordered_2x3": lambda: abd_ordered(2, 3),
+        "raft2_ordered": lambda: raft_model(2, network=Network.new_ordered()),
+        "paxos_lossy_1": lambda: paxos_lossy(1, 3),
+    }[name]()
+    report = audit_model(model, deep=True)
+    kernels = report.metrics["sanitizer"]["kernels"]
+    assert kernels["step_rows"]["sites"] >= 1
+    for k in kernels.values():
+        assert k["proved"] == k["sites"] and k["undecided"] == 0, kernels
+    assert not [f for f in report.findings if f.rule_id in ("JX201", "JX203")]
+    assert not report.errors and not report.warnings, report.format()
+
+
 def test_quickstart_clock_pinned_finding():
     """The Lamport clock model is the one shipped example with a pinned
     non-clean report: logical clocks grow without bound (AH205), which is

@@ -562,9 +562,12 @@ def test_twin_compile_is_adopted_once_by_the_first_recorder(compiled_twin):
     assert rec["row_width"] == twin.width == 17 and rec["n_slots"] == twin.n_slots
     assert rec["actor_states"] == "64,50,3,3" and rec["envelopes"] == 56
     assert rec["table_bytes"] == 61432 and rec["dur"] > 0
-    # the attribute counts exactly what the step program uploads
+    # table_bytes is the closure's tabulation (the host's per-actor tables);
+    # device_table_bytes counts exactly what the step program uploads: the
+    # two record tables in their place
     on_device = jax.tree_util.tree_leaves(twin._consts())
-    assert rec["table_bytes"] == sum(int(a.nbytes) for a in on_device)
+    assert rec["device_table_bytes"] == sum(int(a.nbytes) for a in on_device) == 14616
+    assert rec["record_words"] == 1 and rec["step_gathers"] == 1
     # in the checker's trace, parentless (it closed before the run span opened)
     (run,) = by["engine_run"]
     assert rec["trace_id"] == run["trace_id"] and "parent_id" not in rec

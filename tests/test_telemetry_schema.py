@@ -86,7 +86,8 @@ SCHEMA = {
         # job), jobs/slots (fleet root), rung/source (engine_acquire),
         # dsteps (device_call), jaxprs_traced (dispatch), hit/retrieved_s
         # (program.load), status (grow), the universes, row,
-        # table bytes, history codec, lossiness and action columns of a
+        # table bytes (tabulated and on the device), record words, slot-lane
+        # gathers, history codec, lossiness and action columns of a
         # compiled actor twin (twin_compile)
         {"v": int, "name": str, "trace_id": str, "span_id": str,
          "start": _REAL, "dur": _REAL},
@@ -98,7 +99,8 @@ SCHEMA = {
          "actor_states": str, "envelopes": int, "n_slots": int,
          "row_width": int, "table_bytes": int, "hist_strategy": str,
          "hist_threads": int, "hist_bits": int, "lossy": bool,
-         "max_actions": int},
+         "max_actions": int, "device_table_bytes": int,
+         "record_words": int, "step_gathers": int},
     ),
     "health": (
         {"v": int, "event": str},
