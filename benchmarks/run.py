@@ -17,7 +17,11 @@ default) re-checks one model object, whose engines stay resident, and the
 window may not ask the compiler for anything; ``cold`` makes every check —
 warm-up, window, profiled — on a model object built inside the check's
 timed span, so each pays the twin's compilation and the engines'
-acquisition, every program served from the persistent cache.
+acquisition, every program served from the persistent cache; ``bounded`` is
+``closed`` with a check that the workload's ``target_states`` verb stops —
+a search that cannot exhaust in a window — held to what a PREFIX of a
+breadth-first search owes (``srbench/check.py:compare_bounded``,
+``bounded_prefix``), not to pins of the whole space.
 
 The LAST stdout line is the result object (``correct``, ``attempted``,
 ``failed``, ``metrics``, ``device``, traced ``breakdown``, and last
@@ -179,6 +183,93 @@ def exactness_sample(model, visited, seed: int, symmetric: bool) -> int:
     return missing
 
 
+def reference_levels(pins: dict) -> int:
+    """K: the run's own reference searches levels 0..K and every state of
+    them is compared (``prefix_missing``).  ``pins.bounded.reference_levels``;
+    absent, every pinned level - the sizes may be pinned deeper than a run
+    can afford to search, since counts cost a run nothing."""
+    return int(pins.get("reference_levels", len(pins["levels"]) - 1))
+
+
+def bounded_sample(warm: dict, config: dict, seed: int, hold) -> dict:
+    """Set-up's half of what a ``bounded`` run is held to once, on the
+    warm-up check's ``checkpoint()``: the table holds what the check counted
+    (``visited_off``); what the configuration pins lies inside what the
+    prefix owes (``levels_beyond_complete``: the reference's levels 0..K
+    inside the complete level C, each pinned discovery's witness state
+    among the rows the check popped); the queue holds as many rows of each
+    pinned level <= C as the reference has states there
+    (``level_sizes_off``: down to the complete level a depth label IS the
+    level); and the seeded walks (``sample_missing``).  Returns the prefix
+    for the half that runs after the window (``bounded_reference``)."""
+    pins = config["pins"]["bounded"]
+    t0 = time.monotonic()
+    prefix = chk.bounded_prefix(warm["checker"], bool(pins.get("graded")))
+    held = len(prefix["visited"])
+    level = prefix["complete_level"]
+    searched = reference_levels(pins)
+    say(f"bounded prefix: {held} occupied slots, head={prefix['head']} "
+        f"tail={prefix['tail']}, complete to level {level} (max_depth "
+        f"{warm['max_depth']}), pinned "
+        f"levels 0..{len(pins['levels']) - 1}, the reference's 0..{searched}; "
+        f"checkpoint + sort {time.monotonic() - t0:.3f}s")
+    sizes = pins["levels"][:level + 1]
+    labelled = (prefix["labels"] + [0] * len(sizes))[:len(sizes)]
+    say(f"bounded prefix: rows by depth label {prefix['labels']}; levels "
+        f"0..{len(sizes) - 1} hold {sum(sizes)} of the {held} states")
+    beyond = sorted(
+        name for name, at in pins["discoveries_by_level"].items()
+        if not chk.witness_popped(warm["model"], prefix, name, at,
+                                  pins.get("witnesses", {}).get(name)))
+    say(f"bounded prefix: pinned discoveries {pins['discoveries_by_level']}, "
+        f"witness not popped: {beyond}")
+    found_all = len(warm["discoveries"]) == len(list(warm["model"].properties()))
+    walks = reference.random_walks(warm["model"], seed, WALKS)
+    owed, missing, deepest = chk.walks_missing(prefix, walks, not found_all)
+    say(f"exactness sample: seed={seed} walks={WALKS} owed={owed} "
+        f"deepest={deepest} missing={missing}")
+    hold("bounded prefix", [
+        ("visited_off", abs(held - warm["unique"]), 0,
+         [f"{held} occupied slots, the check counted {warm['unique']}"]),
+        ("levels_beyond_complete", max(0, searched - level) + len(beyond), 0,
+         [f"the reference searches levels 0..{searched}, the prefix is "
+          f"complete to level {level} only",
+          f"pinned discoveries {beyond}: the witness path does not replay "
+          "to a state that decides the property, or the check did not pop it"]),
+        ("level_sizes_off", sum(abs(a - b) for a, b in zip(labelled, sizes)), 0,
+         [f"the queue holds {labelled} rows of the pinned levels, the "
+          f"reference {sizes} states"]),
+        ("sample_missing", missing, 0,
+         [f"{missing} of {owed} owed walk states not visited"]),
+    ])
+    return prefix
+
+
+def bounded_reference(model, config: dict, prefix: dict, seed: int, hold) -> None:
+    """After the window, outside ``setup_s``: EVERY state of the plain
+    reference's first levels is in the table (``prefix_missing``), and the
+    seeded soundness draw (``unreachable``)."""
+    searched = reference_levels(config["pins"]["bounded"])
+    t0 = time.monotonic()
+    kept, levels = [], []
+    reference.reference_bfs(model, max_level=searched, kept=kept, levels=levels)
+    missing = chk.missing_from(
+        prefix["visited"], [model.fingerprint_state(s) for s in kept])
+    sizes = [n for n, _ in levels]
+    t1 = time.monotonic()
+    bad = chk.unreachable(model, prefix, seed)
+    say(f"bounded reference: levels {sizes} = {len(kept)} states in "
+        f"{t1 - t0:.3f}s, missing={missing}; soundness draw of "
+        f"{min(chk.DRAWS, len(prefix['visited']))} slots in "
+        f"{time.monotonic() - t1:.3f}s, unreachable={len(bad)}")
+    hold("bounded reference", [
+        ("prefix_missing", missing, 0,
+         [f"{missing} of the reference's {len(kept)} states of levels "
+          f"0..{len(sizes) - 1} not visited"]),
+        ("unreachable", len(bad), 0, bad[:8]),
+    ])
+
+
 def label_gaps(gaps_ns, to_monotonic, markers, limit: int = 10) -> list:
     """Name each idle gap by what the host was doing: the latest marker
     (a flight-recorder record or one of the harness's own spans, all on
@@ -292,14 +383,24 @@ def main(argv=None) -> int:
                 failures.extend(f"{where}: {m}" for m in messages)
         return over
 
-    # ``closed``: the one object every check is made on; ``cold``: each
-    # check builds its own inside its timed span
-    if kind == "closed":
+    # ``closed`` / ``bounded``: the one object every check is made on;
+    # ``cold``: each check builds its own inside its timed span
+    if kind == "cold":
+        make_model = lambda: chk.build_model(config)  # noqa: E731
+    else:
         model = chk.build_model(config)
         make_model = lambda: model  # noqa: E731
-    else:
-        make_model = lambda: chk.build_model(config)  # noqa: E731
     symmetric = any(v["verb"] == "symmetry" for v in workload.get("builder", []))
+    # bounded: the counts the warm-up check stopped at, set once it has
+    # (``compare`` reads it when called; None holds the warm-up to nothing)
+    first = None
+
+    def compare(res: dict) -> list:
+        """What one check is held to: the pins of the whole space, or -
+        ``bounded`` - what a prefix owes."""
+        if kind == "bounded":
+            return chk.compare_bounded(res["model"], config, workload, res, first)
+        return chk.compare(res["model"], config, workload, res)
 
     def hold_sample(model, visited) -> None:
         missing = exactness_sample(model, visited, args.seed, symmetric)
@@ -313,12 +414,17 @@ def main(argv=None) -> int:
         f"generated={warm['generated']} depth={warm['max_depth']} "
         f"discoveries={warm['discoveries']} growth_events={warm['growth_events']} "
         f"compiles={compiles.snapshot()}")
-    hold("warm-up", chk.compare(warm["model"], config, workload, warm))
-    visited = chk.visited_fingerprints(warm["checker"])
-    if visited is None:
-        say("exactness sample skipped: checkpoint() exposes no table")
-    elif not symmetric:
-        hold_sample(warm["model"], visited)
+    hold("warm-up", compare(warm))
+    prefix = visited = None
+    if kind == "bounded":
+        first = {"unique": warm["unique"], "generated": warm["generated"]}
+        prefix = bounded_sample(warm, config, args.seed, hold)
+    else:
+        visited = chk.visited_fingerprints(warm["checker"])
+        if visited is None:
+            say("exactness sample skipped: checkpoint() exposes no table")
+        elif not symmetric:
+            hold_sample(warm["model"], visited)
     warm_records = warm.get("records", [])
     drop(warm)
     setup_compiles = compiles.snapshot()
@@ -337,8 +443,7 @@ def main(argv=None) -> int:
         before = noise.snapshot()
         try:
             res = chk.run_check(make_model, workload, telemetry=traced)
-            bad = hold(f"check {attempted}",
-                       chk.compare(res["model"], config, workload, res))
+            bad = hold(f"check {attempted}", compare(res))
         except Exception as e:  # noqa: BLE001 - a check that raises is a failed check
             failed += 1
             failures.append(f"check {attempted} raised {type(e).__name__}: {e}")
@@ -371,7 +476,7 @@ def main(argv=None) -> int:
     asks = sorted({c["compiles"]["compile_requests"] for c in checks})
     rows = [("window_persistent_misses", window["persistent_misses"], 0,
              [f"the persistent cache did not hold what the window asked for: {window}"])]
-    if kind == "closed":
+    if kind != "cold":
         rows.append(("window_compile_requests", window["compile_requests"], 0,
                      [f"the measured window compiled: {window}"]))
     elif checks:
@@ -415,8 +520,7 @@ def main(argv=None) -> int:
             f"with a --trace 0 run for the tracing overhead): {json.dumps(measured)}")
         # one more whole check, after the window, under the profiler
         profiled = profiled_check(make_model, workload, trace_dir)
-        hold("profiled check",
-             chk.compare(profiled["model"], config, workload, profiled))
+        hold("profiled check", compare(profiled))
         tensor = profiled["model"].tensor_model()
         drop(profiled)
         say(f"profiled check: {profiled['check_s']:.4f}s vs recorder-only "
@@ -430,7 +534,13 @@ def main(argv=None) -> int:
         }
         ctx = {
             "cell": cell, "config": config, "workload": workload,
-            "pins": config["pins"],
+            # a bounded check has no pin of the whole space: the readers find
+            # its own counts, ``unique`` being the rows it POPPED (what they
+            # mean by it: every state popped once) - equal in every check
+            "pins": config["pins"] if prefix is None else {
+                **config["pins"], "unique": prefix["head"],
+                "generated": profiled["generated"],
+                "max_depth": profiled["max_depth"]},
             "row": {"width": int(tensor.width),
                     "max_actions": int(tensor.max_actions)},
             "warmup_records": warm_records,
@@ -450,6 +560,9 @@ def main(argv=None) -> int:
     if symmetric and visited is not None:
         # the reference's whole search: after the window, not in setup_s
         hold_sample(make_model(), visited)
+    if prefix is not None:
+        # the reference's first levels and the soundness draw: likewise
+        bounded_reference(make_model(), config, prefix, args.seed, hold)
     for f in failures:
         say(f"NOT CORRECT: {f}")
     ordered = {"correct": not failures, "attempted": attempted,

@@ -54,11 +54,19 @@ def successors(model, state) -> list:
 
 
 def reference_bfs(model, symmetric: bool = False,
-                  kept: Optional[list] = None) -> dict:
+                  kept: Optional[list] = None,
+                  max_level: Optional[int] = None,
+                  levels: Optional[list] = None) -> dict:
     """Exhaust ``model`` on the host; returns the four pinned quantities.
     ``symmetric``: deduplicate on ``state.representative()`` (the states
     themselves are searched on, in FIFO order).  Every state that enters
-    the visited set is appended to ``kept``, in that order."""
+    the visited set is appended to ``kept``, in that order.
+
+    ``max_level=K`` searches the first K + 1 levels only (the init level is
+    0): level K's states are visited and their properties evaluated, none of
+    them is expanded, so the four quantities are the PREFIX's (``generated``
+    counts the successors of levels below K).  ``levels`` receives one
+    ``(size, [properties first discovered at this level])`` a level."""
     key = (lambda s: s.representative()) if symmetric else (lambda s: s)
     props = list(model.properties())
     found: set = set()
@@ -79,14 +87,21 @@ def reference_bfs(model, symmetric: bool = False,
     done = False
     while frontier and not done:
         depth += 1
+        last = max_level is not None and depth >= max_level
+        here: list = []  # properties first discovered at this level
+        if levels is not None:
+            levels.append((len(frontier), here))
         nxt_frontier: list = []
         for s in frontier:
             for p in props:
                 if p.name not in found and _discovers(p, model, s):
                     found.add(p.name)
+                    here.append(p.name)
             if props and len(found) == len(props):
                 done = True
                 break
+            if last:
+                continue
             for n in successors(model, s):
                 generated += 1
                 k = key(n)
@@ -123,19 +138,17 @@ def kept_fingerprints(model, seed: int, count: int) -> list:
     return [model.fingerprint_state(s.representative()) for s in kept]
 
 
-def random_walk_fingerprints(model, seed: int, walks: int,
-                             max_steps: int = 64) -> list:
-    """Fingerprints of every state on ``walks`` seeded random walks of the
-    host object model (each from a random init state, one random enabled
-    action at a time, until a terminal state or ``max_steps``).  Every one
-    of them is reachable, so a checker that exhausted the space must hold
-    all of them in its visited set."""
+def random_walks(model, seed: int, walks: int, max_steps: int = 64) -> list:
+    """``walks`` seeded random walks of the host object model, each the list
+    of its states' fingerprints in walk order (from a random init state, one
+    random enabled action at a time, until a terminal state or
+    ``max_steps``): the state at index i is reachable in i transitions."""
     rng = random.Random(seed)
     inits = [s for s in model.init_states() if model.within_boundary(s)]
-    fps = []
+    out = []
     for _ in range(walks):
         s = rng.choice(inits)
-        fps.append(model.fingerprint_state(s))
+        fps = [model.fingerprint_state(s)]
         for _ in range(max_steps):
             actions = list(model.actions(s))
             rng.shuffle(actions)
@@ -147,4 +160,15 @@ def random_walk_fingerprints(model, seed: int, walks: int,
                 break  # terminal: no enabled action leaves the state
             s = nxt
             fps.append(model.fingerprint_state(s))
-    return fps
+        out.append(fps)
+    return out
+
+
+def random_walk_fingerprints(model, seed: int, walks: int,
+                             max_steps: int = 64) -> list:
+    """Fingerprints of every state on ``walks`` seeded random walks
+    (``random_walks``), one flat list.  Every one of them is reachable, so a
+    checker that exhausted the space must hold all of them in its visited
+    set."""
+    return [fp for walk in random_walks(model, seed, walks, max_steps)
+            for fp in walk]

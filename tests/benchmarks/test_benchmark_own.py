@@ -304,9 +304,18 @@ def test_config_files_state_source_cut_guarantees_and_pins(manifest):
         for key in entry["reduced"]:
             assert cfg[key] == cfg["reduced_from"][key]["here"]
             assert cfg[key] in cfg["model"]["args"]
+        # the whole space's pins, or - a configuration whose run is bounded
+        # - the first levels of the plain reference's search (or both)
         pins = cfg["pins"]
-        assert pins["generated"] >= pins["unique"] > 0
-        assert pins["provenance"]
+        if "bounded" in pins:
+            prefix = pins["bounded"]
+            assert prefix["levels"][0] >= 1 and all(n > 0 for n in prefix["levels"])
+            assert set(prefix["discoveries_by_level"].values()) <= set(
+                range(len(prefix["levels"])))
+            assert prefix["provenance"]
+        if "bounded" not in pins or "unique" in pins:
+            assert pins["generated"] >= pins["unique"] > 0
+            assert pins["provenance"]
 
 
 def test_manifest_problems_are_found(manifest, tmp_path):

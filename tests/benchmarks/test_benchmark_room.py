@@ -7,8 +7,8 @@ the harness that counts the cells, or fixes a reader's ``workloads`` list,
 breaks that promise for every later PR: it may not edit the test (the test
 is under the benchmark's ``paths``), and it may not leave it red.  So here a
 copy of the committed manifest gains what such a PR brings — one closed
-cell, one cold cell, one configuration, one reader listed for a new cell
-alone — and EVERY test under ``tests/benchmarks/`` that reads the manifest
+cell, one cold cell, one bounded cell, two configurations, one reader listed
+for a new cell alone — and EVERY test under ``tests/benchmarks/`` that reads the manifest
 (each ``test_*`` function whose one argument is the ``manifest`` fixture,
 found by that signature, so a test added later is held to this too) is run
 on the copy.  CPU-only, unit-cheap.
@@ -39,6 +39,7 @@ from srbench.manifest import Manifest  # noqa: E402
 
 CLOSED, COLD, CONFIG, READER = ("linreg2x2o-tiny", "linreg2x2o-cold", "linreg2x2o",
                                 "checks_in_window")
+BOUNDED, PREFIX = "twopc5-bounded-tiny", "twopc5-prefix"
 MANIFEST_READERS = sorted(
     (fn for module in (loops, own, stages, sym, twin)
      for name, fn in vars(module).items()
@@ -68,8 +69,17 @@ def roomier(tmp_path_factory):
         "guarantees": ["exact unique-state count over the whole reachable space"],
     })
     (bench / "configs" / f"{CONFIG}.json").write_text(json.dumps(cfg))
-    # one closed cell and one cold cell on it
-    for cell in (CLOSED, COLD):
+    # ... and one whose run is bounded: the first levels pinned, not the space
+    prefix = json.load(open(os.path.join(DATA, f"{PREFIX}.json")))
+    prefix.update({
+        "rm_count": 5,
+        "reduced_from": {"rm_count": {"source": 10, "here": 5, "why": "tiny"}},
+        "deployment": {"resource_managers": 5},
+        "assumed": {"target": "2,000 states: the tests' choice"},
+    })
+    (bench / "configs" / f"{PREFIX}.json").write_text(json.dumps(prefix))
+    # one closed cell and one cold cell on the first, one bounded on the second
+    for cell in (CLOSED, COLD, BOUNDED):
         shutil.copy(os.path.join(DATA, f"{cell}.json"), bench / "workloads")
     # one reader, listed for the new cold cell alone
     (bench / "layer_metrics" / f"{READER}.py").write_text(
@@ -84,10 +94,15 @@ def roomier(tmp_path_factory):
         "file": f"benchmarks/configs/{CONFIG}.json", "reduced": ["client_count"],
         "why": "tiny",
     })
-    for cell in (CLOSED, COLD):
+    doc["configs"].append({
+        "name": PREFIX, "source": "stateright examples/2pc.rs, bounded",
+        "file": f"benchmarks/configs/{PREFIX}.json", "reduced": ["rm_count"],
+        "why": "tiny",
+    })
+    for cell, config in ((CLOSED, CONFIG), (COLD, CONFIG), (BOUNDED, PREFIX)):
         wl = json.load(open(os.path.join(DATA, f"{cell}.json")))
         doc["workloads"].append({
-            "name": cell, "config": CONFIG, "traffic": wl["traffic"], "chips": 1,
+            "name": cell, "config": config, "traffic": wl["traffic"], "chips": 1,
             "why": "a tiny cell of the benchmark's own tests",
         })
     for m in doc["per_layer"]:
@@ -111,11 +126,12 @@ def test_the_copy_has_more_of_everything_and_is_sound(roomier):
     _, more = roomier
     committed = Manifest(os.path.join(REPO, "BENCHMARK.json"), BENCH).doc
     assert more.problems() == []
-    for key, added in (("workloads", 2), ("configs", 1), ("per_layer", 1)):
+    for key, added in (("workloads", 3), ("configs", 2), ("per_layer", 1)):
         assert len(more.doc[key]) == len(committed[key]) + added
     kinds = {w["name"]: chk.loop_kind(more.workload(w["name"]))
              for w in more.doc["workloads"]}
-    assert (kinds[CLOSED], kinds[COLD]) == ("closed", "cold")
+    assert (kinds[CLOSED], kinds[COLD], kinds[BOUNDED]) == (
+        "closed", "cold", "bounded")
     # the new reader is the new cold cell's alone
     assert [w["name"] for w in more.doc["workloads"]
             if READER in {m["name"] for m in more.metrics_for("per_layer", w["name"])}
