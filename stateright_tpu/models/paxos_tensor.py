@@ -704,6 +704,16 @@ class PaxosTensor(TensorModel):
             )
         return succ, valid
 
+    def poison_rows(self, rows):
+        """True per row iff a send on the way to it found no free network
+        slot (``step_rows`` sets the row's ``overflow`` bit and every
+        successor keeps it): the message was dropped, so the engines turn
+        any such POPPED row into a loud run failure (status ``poison``)
+        instead of a quietly smaller space.  ``n_slots`` is the cure."""
+        import jax.numpy as jnp
+
+        return self.pk.get(rows, "overflow") == jnp.uint64(1)
+
     def property_masks(self, rows):
         import jax
         import jax.numpy as jnp

@@ -86,6 +86,7 @@ from ..telemetry.spans import (
     STAGE_PROPS,
     STAGE_STATS,
     SYM_CANON,
+    TWIN_POISON,
     record_span,
 )
 from ..telemetry.spans import span as tel_span
@@ -719,11 +720,12 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
                 # by a REACHABLE transition — silently wrong counts otherwise;
                 # surface it as a terminal host-visible status (takes priority
                 # over growth: growing cannot fix a bound)
-                status = jnp.where(
-                    jnp.any(poison_fn(rows) & live),
-                    jnp.int32(_STATUS_POISON),
-                    status,
-                )
+                with jax.named_scope(TWIN_POISON):
+                    status = jnp.where(
+                        jnp.any(poison_fn(rows) & live),
+                        jnp.int32(_STATUS_POISON),
+                        status,
+                    )
         out = (tfp, tpl, qrows, qfp, qebits, qdepth, head, tail,
                unique, scount, disc, maxdepth, status)
         if checked:
@@ -2434,9 +2436,10 @@ class TpuChecker(WavefrontChecker):
                 raise RuntimeError(
                     "poisoned rows reached by the device run: a compiled "
                     "transition crossed its compile-time state_bound/"
-                    "env_bound, so counts would be silently wrong. Loosen "
-                    "the bounds (they must cover everything the bounded "
-                    "configuration actually reaches)."
+                    "env_bound, or a hand-written twin's send found no free "
+                    "network slot (n_slots), so counts would be silently "
+                    "wrong. Loosen the bounds, or raise n_slots (they must "
+                    "cover everything the configuration actually reaches)."
                 )
             if status != _STATUS_OK:
                 # chaos seam: a growth boundary is where device OOM

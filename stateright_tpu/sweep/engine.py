@@ -992,7 +992,9 @@ class SweepChecker(Checker):
                 raise RuntimeError(
                     "poisoned rows reached by a sweep instance: a "
                     "compiled transition crossed its compile-time "
-                    "state_bound/env_bound; loosen the bounds"
+                    "state_bound/env_bound, or a hand-written twin's send "
+                    "found no free network slot (n_slots); loosen the "
+                    "bounds or raise n_slots"
                 )
             if status != _STATUS_OK:
                 self.growth_events.append((status, tot_u))

@@ -100,6 +100,13 @@ TWIN_SCOPES = (TWIN_TABLE, TWIN_NET, TWIN_HISTORY)
 # ``sr.expand/.../twin.drop/...`` whatever kernel it calls inside.
 TWIN_DROP = "twin.drop"
 
+# Sub-scope of ``sr.bookkeep`` around the step's test of the popped rows'
+# poison bit (``poison_rows``: a compiled twin's crossed compile-time bound,
+# ``PaxosTensor``'s network slot overflow), opened only where the twin has
+# one: the test's operations carry ``sr.bookkeep/twin.poison``, a named
+# stage, so it adds nothing to the share of busy time that has no name.
+TWIN_POISON = "twin.poison"
+
 # Sub-scope of ``sr.hash`` around the twin's ``representative_rows``, opened
 # only by a step program built under ``.symmetry()``: the canonicaliser's
 # operations carry ``sr.hash/sym.canon`` (their stage stays ``sr.hash``).
