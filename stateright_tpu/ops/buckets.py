@@ -82,6 +82,8 @@ reconstruction, as there.
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 import jax
@@ -463,6 +465,116 @@ def bucket_insert(
         )
 
     return table_fp, table_payload, sel, n_new, overflow, cand_overflow
+
+
+# how a chain of :func:`parent_chains` ended
+CHAIN_ROOT, CHAIN_MISS, CHAIN_BOUND = 0, 1, 2
+
+
+@functools.partial(jax.jit, static_argnames="bound")
+def parent_chains(
+    table_fp: jnp.ndarray,  # uint64[nbuckets * SLOTS], as it lies in the carry
+    table_payload: jnp.ndarray,  # uint64[nbuckets * SLOTS]
+    starts: jnp.ndarray,  # uint64[K] fingerprints to trace; 0 = none
+    bound: int,  # static: the longest chain there is room for
+):
+    """Follow the parent links of ``starts`` in the table, where it lies:
+    ``(chains, lens, ends)``.
+
+    ``chains[k, :lens[k]]`` is ``starts[k]``, its parent, its parent's
+    parent ... down to an init state (the LEAF first: the host reverses
+    it); ``ends[k]`` says how the walk stopped — ``CHAIN_ROOT`` at a state
+    whose payload is 0 ("is an init state"; a start of 0 is a chain of
+    length 0 that ends so), ``CHAIN_MISS`` at a fingerprint that is not in
+    its bucket (``chains[k, lens[k]]``, not counted), ``CHAIN_BOUND`` after
+    ``bound`` states with the last one's parent still to resolve.  The
+    caller decides what a miss or a bound hit means; nothing is truncated
+    in silence.
+
+    The table has no probe chain (module docstring): a fingerprint lives
+    in the ``SLOTS`` consecutive slots of the bucket its key names, and its
+    payload IS its parent's fingerprint.  So one link is one ``SLOTS``-wide
+    ``dynamic_slice`` of each array and a compare: the walk itself touches
+    O(sum of ``lens``) elements whatever the table holds, the two arrays
+    are arguments (not donated, not viewed in another shape), and what
+    comes back is ``K * bound`` words.  The chains are walked one after the
+    other, a scalar loop each: a handful of discoveries of a few dozen
+    states.
+
+    **On the TPU the call is still one pass over the table** (PR 47).  The
+    chip has no 64-bit words: every program that takes a ``u64`` argument
+    begins by writing it out as two ``u32`` planes (``X64SplitLow`` /
+    ``X64SplitHigh``; the run program does that to its whole carry on
+    every device call), and no spelling of the read moves the slice before
+    the split.  ``tests/test_table_layout.py`` holds the compiled module
+    to exactly that: the four splits in its entry, NOTHING of ``cap``
+    elements inside its loops, the planes its only temporaries (0.4 / 34 /
+    202 / 1,074 MB at 2^21 / 2^23 / 2^24 / 2^26 slots by
+    ``memory_analysis()``; the allocator's peak does not hold them).  Alone
+    on one v5e, a forged table, ms a call with its sync and three pulls
+    (min / median of 30): 2.3 / 2.6 at 2^21 slots, 2.8 / 4.8 at 2^23, 3.8 /
+    4.2 at 2^24, 5.5 / 5.9 at 2^25, 8.8 / 9.1 at 2^26 - where pulling both
+    arrays to the host and building a dict of them took 0.15 s to 4.6 s in
+    the benchmark's cells, and 21-24 s at 2^28.
+
+    Module-level and keyed by shapes and ``bound`` alone: one program a
+    table capacity, property count and (power-of-two) bound, whatever the
+    model or the twin object, so a fresh model object in a warm process
+    finds it compiled."""
+    nslots = table_fp.shape[0]
+    nbuckets = nslots // SLOTS
+    assert nbuckets & (nbuckets - 1) == 0, "bucket count must be a power of two"
+    bucket_bits = int(nbuckets).bit_length() - 1
+    n_chains = starts.shape[0]
+    walking = jnp.int32(-1)
+
+    def parent_of(fp):
+        # (found, parent): the one slot of fp's bucket that holds it
+        if bucket_bits:
+            key = bucket_key(fp) >> jnp.uint64(64 - bucket_bits)
+            off = key.astype(jnp.int32) * SLOTS
+        else:
+            off = jnp.int32(0)
+        hit = (jax.lax.dynamic_slice(table_fp, (off,), (SLOTS,)) == fp) & (
+            fp != EMPTY
+        )
+        pay = jax.lax.dynamic_slice(table_payload, (off,), (SLOTS,))
+        return jnp.any(hit), jnp.max(jnp.where(hit, pay, jnp.uint64(0)))
+
+    def link(state):
+        n, fp, row, _ = state
+        found, parent = parent_of(fp)
+        row = jax.lax.dynamic_update_slice(row, fp[None], (n,))
+        n = n + found.astype(jnp.int32)
+        end = jnp.where(
+            ~found, CHAIN_MISS,
+            jnp.where(parent == 0, CHAIN_ROOT,
+                      jnp.where(n >= bound, CHAIN_BOUND, walking)),
+        ).astype(jnp.int32)
+        return n, parent, row, end
+
+    def chain(k, out):
+        chains, lens, ends = out
+        start = starts[k]
+        n, _, row, end = jax.lax.while_loop(
+            lambda state: state[3] == walking,
+            link,
+            (
+                jnp.int32(0), start, jnp.zeros((bound,), jnp.uint64),
+                jnp.where(start == 0, CHAIN_ROOT, walking).astype(jnp.int32),
+            ),
+        )
+        chains = jax.lax.dynamic_update_slice(chains, row[None], (k, 0))
+        return chains, lens.at[k].set(n), ends.at[k].set(end)
+
+    return jax.lax.fori_loop(
+        0, n_chains, chain,
+        (
+            jnp.zeros((n_chains, bound), jnp.uint64),
+            jnp.zeros((n_chains,), jnp.int32),
+            jnp.zeros((n_chains,), jnp.int32),
+        ),
+    )
 
 
 def occupancy_stats(table_fp) -> dict:

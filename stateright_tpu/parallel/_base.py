@@ -360,7 +360,10 @@ class WavefrontChecker(Checker):
         # trip counts of the device calls' while_loops, summed at each
         # host sync (the ``dsteps`` lane of the packed stats vector)
         self._device_steps = 0
+        # trace reconstruction resolves parents once a run: the discovered
+        # chains off the device (_chains), or every state's on the host
         self._parent_map: Optional[dict[int, int]] = None
+        self._chain_map: Optional[dict[int, np.ndarray]] = None
         self._done = threading.Event()
         # builder timeout parity (reference: the pool checkers' deadline):
         # a timer requests a cooperative stop, honored at the next host
@@ -940,22 +943,88 @@ class WavefrontChecker(Checker):
         fps.reverse()
         return fps
 
+    def _host_parents(self, tfp: np.ndarray, tpl: np.ndarray) -> dict[int, int]:
+        """The fp -> parent map of every tier, from the pulled table (hook:
+        the spill tier merges its store's segments in)."""
+        return self._parents_from_table(tfp, tpl)
+
     def _parents(self, parent=None) -> dict[int, int]:
-        """The fp -> parent map, built once a run; ``parent`` is the
-        ``reconstruct`` span the two phases are children of."""
+        """The fp -> parent map of every visited state, built once a run:
+        the HOST path of reconstruction (a table sharded over a mesh, a
+        spill store that holds the roots; ``_device_table``).  ``parent``
+        is the ``reconstruct`` span the two phases are children of."""
         if self._parent_map is None:
             rec = self.flight_recorder
-            with tel_span("reconstruct.pull", rec, parent=parent):
+            with tel_span("reconstruct.pull", rec, parent=parent) as sp:
                 table = self._table_np()
-            with tel_span("reconstruct.parents", rec, parent=parent):
-                self._parent_map = self._parents_from_table(*table)
+                sp.set(bytes=sum(int(a.nbytes) for a in table))
+            with tel_span("reconstruct.parents", rec, parent=parent,
+                          path="host") as sp:
+                self._parent_map = self._host_parents(*table)
+                sp.set(lookups=len(self._parent_map))
         return self._parent_map
 
+    def _device_table(self):
+        """``(table_fp, table_payload)`` as they lie in the final carry,
+        where ONE device holds them and they hold every parent; None
+        where the run reconstructs through the host map (hook)."""
+        return None
+
+    def _chains(self, parent=None) -> Optional[dict[int, np.ndarray]]:
+        """Discovered fingerprint -> its parent chain (the leaf first),
+        resolved ON THE DEVICE against the final carry's table
+        (``ops/buckets.parent_chains``): one dispatch a run for every
+        discovered property, and only the chains cross to the host.  None
+        where the run reconstructs through the host map (``_parents``).
+
+        A chain that misses or outgrows its bound is a bug (the table
+        holds every visited state and no chain is longer than the search
+        was deep) and raises: never a silently shorter path."""
+        if self._chain_map is not None:
+            return self._chain_map
+        table = self._device_table()
+        if table is None:
+            return None
+        from ..ops.buckets import CHAIN_BOUND, CHAIN_ROOT, parent_chains
+
+        rec = self.flight_recorder
+        starts = np.asarray(self._results["disc"], np.uint64)
+        # a power of two over the deepest chain there can be: one program
+        # a table capacity for every model whose search is this deep
+        bound = 1 << max(self.max_depth(), 15).bit_length()
+        with tel_span("reconstruct.parents", rec, parent=parent,
+                      path="device") as sp:
+            chains, lens, ends = parent_chains(*table, starts, bound=bound)
+            # how each chain ended is the device call's answer, and the sync
+            lens, ends = np.asarray(lens), np.asarray(ends)
+            sp.set(lookups=int(lens.sum()))
+        with tel_span("reconstruct.pull", rec, parent=parent) as sp:
+            chains = np.asarray(chains)
+            sp.set(bytes=int(chains.nbytes + lens.nbytes + ends.nbytes))
+        broken = np.flatnonzero(ends != CHAIN_ROOT)
+        if broken.size:
+            k = broken[0]
+            raise RuntimeError(
+                f"the parent chain of discovery {int(starts[k]):#x} "
+                + (f"is longer than its bound of {bound} states"
+                   if ends[k] == CHAIN_BOUND else
+                   f"leaves the visited table after {int(lens[k])} states")
+                + ": the table does not hold the search that found it"
+            )
+        self._chain_map = {
+            int(fp): chains[k, :lens[k]] for k, fp in enumerate(starts) if fp
+        }
+        return self._chain_map
+
     def _trace(self, fp: int, parent=None) -> list[int]:
-        parents = self._parents(parent)
+        """Fingerprints from an init state down to ``fp``."""
+        chains = self._chains(parent)
+        parents = self._parents(parent) if chains is None else None
         with tel_span("reconstruct.walk", self.flight_recorder,
                       parent=parent):
-            return self._walk(parents, fp)
+            if chains is None:
+                return self._walk(parents, fp)
+            return chains[fp][::-1].tolist()
 
     def _symmetry_key(self):
         if self._symmetry is None:
@@ -976,8 +1045,9 @@ class WavefrontChecker(Checker):
         key = self._symmetry_key()
         out = {}
         rec = self.flight_recorder
-        # host seam span: the table pull, the parent dict, the chain walk
-        # and the host replay.  Whoever asks does so after the run span
+        # host seam span: resolving the parent links (on the device, or a
+        # dict of the pulled table), what crosses to the host, the chain
+        # walk and the host replay.  Whoever asks does so after the run span
         # (and a supervisor's attempt span) closed, so this is a span of
         # the run's trace with no parent — never a child that outlives one
         with tel_span("reconstruct", rec, trace_id=self._trace_id) as sp:

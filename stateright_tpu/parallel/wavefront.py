@@ -37,10 +37,12 @@ host-visible carry powers **checkpoint/resume** (SURVEY §5: wavefront
 checkpointing): :meth:`TpuChecker.checkpoint` snapshots the run mid-flight
 and ``spawn_tpu(resume=snapshot)`` continues it, surviving process restarts.
 
-Trace reconstruction is host-side and identical in spirit to the reference
-(``bfs.rs:314-342``): walk parent fingerprints back to an init state, then
-re-execute the *object-form* model (``Path.from_fingerprints``), which works
-because host and device fingerprint functions agree bit-for-bit.
+Trace reconstruction is identical in spirit to the reference
+(``bfs.rs:314-342``): walk parent fingerprints back to an init state — on
+the device, against the final carry's table where it lies
+(``ops/buckets.parent_chains``; only the chains cross to the host) — then
+re-execute the *object-form* model on the host (``Path.from_fingerprints``),
+which works because host and device fingerprint functions agree bit-for-bit.
 
 **Symmetry reduction** (beyond the reference, whose symmetry is DFS-only):
 when the builder requests ``symmetry()`` and the tensor twin provides a
@@ -1716,19 +1718,21 @@ class TpuChecker(WavefrontChecker):
             jnp.zeros((2,), jnp.int64),
         ]
 
-    def _parents(self, parent=None) -> dict:
+    def _spilled(self) -> bool:
+        return getattr(self, "_spill", False) and len(self._spill_store) > 0
+
+    def _host_parents(self, tfp, tpl) -> dict:
         """Trace reconstruction merges every tier: host/disk-resident
         parents first, then the hot table's (the sets are disjoint —
         eviction removes what it spills)."""
-        if self._parent_map is None:
-            hot = super()._parents(parent)
-            if getattr(self, "_spill", False) and len(self._spill_store):
-                parents: dict = {}
-                for fps, pars in self._spill_store.iter_segments():
-                    parents.update(zip(fps.tolist(), pars.tolist()))
-                parents.update(hot)
-                self._parent_map = parents
-        return self._parent_map
+        hot = super()._host_parents(tfp, tpl)
+        if not self._spilled():
+            return hot
+        parents: dict = {}
+        for fps, pars in self._spill_store.iter_segments():
+            parents.update(zip(fps.tolist(), pars.tolist()))
+        parents.update(hot)
+        return parents
 
     def _engine(self, cap, qcap, batch, cand, kind: str = "growth"):
         """The compiled engine for these capacities, through (in order) the
@@ -2645,6 +2649,17 @@ class TpuChecker(WavefrontChecker):
             np.asarray(self._final_carry[_TFP]),
             np.asarray(self._final_carry[_TPL]),
         )
+
+    def _device_table(self):
+        """The final carry's table, for reconstruction on the device: not
+        where it is sharded over a mesh (a dynamic 16-slot read from a
+        bucket-sharded array is a collective nobody has priced), and not
+        once the spill tier evicted (an eviction clears the WHOLE hot
+        table, the init states with it, so every chain leaves it)."""
+        tfp, tpl = self._final_carry[_TFP], self._final_carry[_TPL]
+        if self._spilled() or len(tfp.sharding.device_set) != 1:
+            return None
+        return tfp, tpl
 
     # -- live progress + checkpointing ---------------------------------------
 

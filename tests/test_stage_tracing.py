@@ -471,7 +471,14 @@ def test_reconstruct_seams_and_checkpoint_pull(tiny):
                  "reconstruct.replay"):
         assert all(r["parent_id"] == root["span_id"] and _inside(root, r)
                    for r in by[name]), name
-    # the parent map is built once a run: a second call pulls nothing
+    # the parent links are resolved on the device, in one call for both
+    # discoveries, and only the chains cross to the host
+    (parents,), (pull,) = by["reconstruct.parents"], by["reconstruct.pull"]
+    assert parents["path"] == "device"
+    assert parents["lookups"] == sum(len(p) for p in found.values())
+    assert 0 < pull["bytes"] < 64 << 10
+    assert parents["start"] + parents["dur"] <= pull["start"]
+    # the chains are resolved once a run: a second call dispatches nothing
     mark = len(c.flight_recorder.records("span"))
     c.discovery("abort agreement")
     again = {r["name"] for r in c.flight_recorder.records("span")[mark:]}

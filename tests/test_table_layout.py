@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from stateright_tpu.models.two_phase_commit import TwoPhaseSys
-from stateright_tpu.ops.buckets import ROW_LANES, SLOTS, bucket_of
+from stateright_tpu.ops.buckets import ROW_LANES, SLOTS, bucket_of, parent_chains
 from stateright_tpu.ops.hashing import EMPTY
 from stateright_tpu.parallel import wavefront as wf
 
@@ -135,12 +135,13 @@ HLO_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
 HLO_NO_BUFFER = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
 
 
-def table_sized_operations(hlo_text, cap):
+def table_sized_operations(hlo_text, cap, in_entry=False):
     """``(opcode, result shape)`` of every operation outside the entry
     computation and outside fused computations (a fusion counts once, as
     the operation it is) whose result holds ``cap`` or more elements: with
     one ``while`` at the top of the run program, these are the operations
-    of its body and of the loops nested in it."""
+    of its body and of the loops nested in it.  ``in_entry``: those of the
+    entry computation instead (a custom call under its target's name)."""
     found, entry, name = [], False, ""
     for line in hlo_text.splitlines():
         head = HLO_COMPUTATION.match(line)
@@ -148,7 +149,7 @@ def table_sized_operations(hlo_text, cap):
             entry, name = bool(head.group(1)), head.group(2)
             continue
         m = HLO_INSTRUCTION.match(line)
-        if not m or entry or "fused_computation" in name:
+        if not m or entry != in_entry or "fused_computation" in name:
             continue
         shape, opcode = m.groups()
         sizes = [
@@ -156,8 +157,28 @@ def table_sized_operations(hlo_text, cap):
             for dims in re.findall(r"[a-z0-9]+\[([0-9,]*)\]", shape)
         ]
         if opcode not in HLO_NO_BUFFER and max(sizes, default=0) >= cap:
-            found.append((opcode, shape.strip()))
+            target = re.search(r'custom_call_target="(\w+)"', line)
+            found.append((target.group(1) if target else opcode, shape.strip()))
     return found
+
+
+def compiled_for(sharding, fn, *avals, **static):
+    """``fn`` compiled ahead of time for the described chip, the persistent
+    cache off around it (an entry written for a chip that is not attached
+    cannot be read back, and warns)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    avals = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), avals
+    )
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return fn.lower(*avals, **static).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
 
 
 def test_the_compiled_loop_holds_two_table_sized_operations(one_v5e_chip):
@@ -169,28 +190,63 @@ def test_the_compiled_loop_holds_two_table_sized_operations(one_v5e_chip):
     16]``, two ``copy`` into the gather's slot-major layout, and a
     ``copy-start`` / ``copy-done`` / ``ConcatBitcast`` moving a plane
     between memories around them."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     cap = 1 << 23
     run_fn, avals = step_program(cap=cap, qcap=1 << 14, batch=256, cand=2048)
-    avals = tuple(
-        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_v5e_chip)
-        for a in avals
-    )
-    cache_was_on = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        compiled = run_fn.lower(avals).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was_on)
-        compilation_cache.reset_cache()
+    compiled = compiled_for(one_v5e_chip, run_fn, avals)
     found = table_sized_operations(compiled.as_text(), cap)
     assert [op for op, _ in found] == ["fusion", "fusion"], found  # parent: 9
     assert all(shape.count(f"u32[{cap}]") == 2 for _, shape in found)
     # and the program holds no transient copy of the table: its planes are
     # 4 x 32 MiB, and the parent's temporaries were 405.8 MB
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# -- the parent walk of trace reconstruction (PR 47) -------------------------
+
+
+def test_the_parent_walk_reads_the_table_with_two_slices_a_link():
+    """``parent_chains`` as traced: inside its loops the two table arrays
+    meet ONE kind of equation, the ``SLOTS``-wide ``dynamic_slice`` of a
+    link's bucket.  No view, gather, compare or copy of ``cap`` elements:
+    what the walk touches grows with the paths, not with the table."""
+    cap = 1 << 16
+    table = jax.ShapeDtypeStruct((cap,), jnp.uint64)
+    starts = jax.ShapeDtypeStruct((3,), jnp.uint64)
+    jaxpr = jax.make_jaxpr(parent_chains, static_argnums=3)(
+        table, table, starts, 32
+    ).jaxpr
+    # inside=True: the whole program, not its loops alone
+    assert sorted(table_sized_eqns(jaxpr, cap, inside=True)) == [
+        ("dynamic_slice", [(cap,)]), ("dynamic_slice", [(cap,)]),
+    ]
+
+
+def test_the_compiled_parent_walk_adds_no_table_sized_operation(one_v5e_chip):
+    """``parent_chains`` at a 2^24-slot table (2 x 128 MiB), compiled ahead
+    of time for one v5e.  Its loops hold NO operation of ``cap`` elements:
+    no copy, no reshape, no relayout of a plane.  Its entry holds four, and
+    they are not the walk's: the TPU has no 64-bit words, so EVERY program
+    that takes a ``u64`` argument first splits it into two ``u32`` planes
+    (``X64SplitLow`` / ``X64SplitHigh``; the run program does that to its
+    whole carry, the table included, on every device call).  One pass over
+    the table at the chip's memory speed, where the host path pulled it at
+    200-245 MB/s and built a dict of it; the planes are its only
+    temporaries, a subset of what the run program's calls already held."""
+    cap = 1 << 24
+    table = jax.ShapeDtypeStruct((cap,), jnp.uint64)
+    starts = jax.ShapeDtypeStruct((4,), jnp.uint64)
+    compiled = compiled_for(
+        one_v5e_chip, parent_chains, table, table, starts, bound=32
+    )
+    text = compiled.as_text()
+    assert table_sized_operations(text, cap) == []
+    assert sorted(op for op, _ in table_sized_operations(text, cap, in_entry=True)) == [
+        "X64SplitHigh", "X64SplitHigh", "X64SplitLow", "X64SplitLow",
+    ]
+    mem = compiled.memory_analysis()
+    planes = 4 * 4 * cap  # two arrays, two u32 planes each
+    assert mem.temp_size_in_bytes <= planes + (1 << 20)  # read: 3 planes + 0.4 MB
+    assert mem.output_size_in_bytes < 64 << 10 and mem.alias_size_in_bytes == 0
 
 
 # -- bucket_insert at the cells' shapes --------------------------------------

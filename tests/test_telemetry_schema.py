@@ -85,7 +85,8 @@ SCHEMA = {
         # (spill_drain), cap/unique (grow), key/slot (fleet
         # job), jobs/slots (fleet root), rung/source (engine_acquire),
         # dsteps (device_call), jaxprs_traced (dispatch), hit/retrieved_s
-        # (program.load), status (grow), the universes, row,
+        # (program.load), status (grow), bytes (reconstruct.pull), path /
+        # lookups (reconstruct.parents), the universes, row,
         # table bytes (tabulated and on the device), record words, slot-lane
         # gathers, history codec, lossiness and action columns of a
         # compiled actor twin (twin_compile)
@@ -100,7 +101,8 @@ SCHEMA = {
          "row_width": int, "table_bytes": int, "hist_strategy": str,
          "hist_threads": int, "hist_bits": int, "lossy": bool,
          "max_actions": int, "device_table_bytes": int,
-         "record_words": int, "step_gathers": int},
+         "record_words": int, "step_gathers": int,
+         "bytes": int, "path": str, "lookups": int},
     ),
     "health": (
         {"v": int, "event": str},
