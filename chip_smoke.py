@@ -10,7 +10,8 @@ checks every answer against the repo's own pins / a host BFS run:
      1,194,428 unique, {"value chosen"}, no "linearizable"
      counterexample, the discovery path replayed on the host, zero
      fresh backend compiles on the warm run, peak device memory
-  C  2pc-5 from a 2^11 table: growth (carry -> host -> rehash ->
+  C  2pc-5 from a 2^11 table: growth (since PR 48 where the carry lies:
+     buckets split, queue window slid; to PR 47 carry -> host -> rehash ->
      re-upload) with buffer donation live, 8,832 unique
   D  compile-cache round trip in this process: in-memory caches
      dropped, A rerun from persistent-cache hits only

@@ -89,6 +89,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..telemetry.spans import STAGE_GROW
 from .hashing import EMPTY, mix64, mix64_np
 
 SLOTS = 16  # fingerprints per bucket (one 128-byte line of u64s)
@@ -577,6 +578,105 @@ def parent_chains(
     )
 
 
+@functools.partial(jax.jit, static_argnames="new_nbuckets")
+def bucket_split(
+    table_fp: jnp.ndarray,  # uint64[nbuckets * SLOTS], as it lies in the carry
+    table_payload: jnp.ndarray,  # uint64[nbuckets * SLOTS]
+    new_nbuckets: int,  # static: a power-of-two multiple of nbuckets
+):
+    """Grow the table to ``new_nbuckets`` buckets where it lies:
+    ``(table_fp, table_payload, histogram)``, the two arrays bit for bit
+    what :func:`host_bucket_rehash` returns for the same table, and the
+    ``SLOTS + 1``-bin per-bucket occupancy histogram of the NEW table
+    (``occupancy_stats``'s ``histogram``: a reduction this has in hand).
+
+    No sort, no scatter and no gather: a bucket is the TOP bits of
+    :func:`bucket_key`, so a table of ``factor`` times the buckets takes
+    ``log2(factor)`` more of them and old bucket ``b`` splits into the new
+    buckets ``b * factor .. b * factor + factor - 1``, adjacent, and into
+    no other.  The new table viewed as ``[nbuckets, factor * SLOTS]`` is
+    therefore, bucket by bucket, a STABLE PARTITION of that bucket's
+    ``SLOTS`` entries by those bits: an entry's new slot is its rank among
+    the earlier entries of its sub-bucket (a compare over ``[SLOTS,
+    SLOTS]`` pairs a bucket), and each new slot takes the one entry whose
+    target it is (a one-hot compare-and-or over ``[factor * SLOTS, SLOTS]``
+    a bucket).  The host's rehash fills each new bucket densely in the old
+    table's slot order (a stable argsort over entries in table order),
+    which is exactly that partition; and a split can never overflow a
+    bucket, since a new bucket holds a subset of one old one (the host's
+    ``ValueError`` arm does not exist here).  An element gather costs
+    7-22 ns a lane on one v5e and a compare-and-select under 0.5 (ROADMAP
+    Queue 1); a sort costs ~10 s of compile an operand past 16,384 lanes
+    (:func:`lane_compact`).
+
+    Not donated: jax 0.9.0 gives a donated input only to an output of its
+    own size, and a split has none; the caller drops the old arrays as it
+    takes the new ones.  Module-level and keyed by shapes and
+    ``new_nbuckets`` alone, so a fresh model object in a warm process
+    finds every rung's program compiled (:func:`parent_chains`).  Its
+    operations carry the ``sr.grow`` scope: a stage of their own in the
+    profiler's trace."""
+    nslots = table_fp.shape[0]
+    nbuckets = nslots // SLOTS
+    factor = new_nbuckets // nbuckets
+    assert nbuckets * factor == new_nbuckets and factor > 1, (
+        "a split multiplies the bucket count"
+    )
+    assert new_nbuckets & (new_nbuckets - 1) == 0, (
+        "bucket count must be a power of two"
+    )
+    new_bits = int(new_nbuckets).bit_length() - 1
+    with jax.named_scope(STAGE_GROW):
+        fp = table_fp.reshape(nbuckets, SLOTS)
+        pl = table_payload.reshape(nbuckets, SLOTS)
+        occ = fp != EMPTY
+        # the sub-bucket: the bits a table of ``new_nbuckets`` reads below
+        # the old bucket's own
+        sub = (
+            bucket_key(fp) >> jnp.uint64(64 - new_bits)
+        ).astype(jnp.int32) & (factor - 1)
+        slot = np.arange(SLOTS, dtype=np.int32)
+        # rank among the EARLIER occupied entries of the same sub-bucket
+        earlier = (
+            (sub[:, :, None] == sub[:, None, :])
+            & occ[:, None, :]
+            & (slot[None, :] < slot[:, None])
+        )
+        rank = jnp.sum(earlier, axis=-1, dtype=jnp.int32)
+        target = jnp.where(occ, sub * SLOTS + rank, -1)
+        # each new slot takes the one entry it is the target of: at most
+        # one lane of the reduced axis is set, so an OR is a select.  The
+        # fingerprint goes through complemented, since EMPTY is all ones:
+        # a slot nobody targets reads ~0 = EMPTY, its payload 0.
+        hit = (
+            target[:, None, :]
+            == np.arange(factor * SLOTS, dtype=np.int32)[None, :, None]
+        )
+        zero = jnp.uint64(0)
+        new_fp = ~jax.lax.reduce(
+            jnp.where(hit, ~fp[:, None, :], zero), zero,
+            jax.lax.bitwise_or, (2,),
+        )
+        new_pl = jax.lax.reduce(
+            jnp.where(hit, pl[:, None, :], zero), zero,
+            jax.lax.bitwise_or, (2,),
+        )
+        # occupancy of the new buckets, and how many hold 0 .. SLOTS; counted
+        # from the old view (the new table viewed ``[new_nbuckets, SLOTS]``
+        # is a relayout on the TPU: 420 MB of temporaries at 2^23 slots
+        # where this holds 186)
+        count = jnp.sum(
+            occ[:, None, :]
+            & (sub[:, None, :] == np.arange(factor, dtype=np.int32)[None, :, None]),
+            axis=-1, dtype=jnp.int32,
+        )
+        hist = jnp.sum(
+            count[:, :, None] == np.arange(SLOTS + 1, dtype=np.int32),
+            axis=(0, 1), dtype=jnp.int32,
+        )
+        return new_fp.reshape(-1), new_pl.reshape(-1), hist
+
+
 def occupancy_stats(table_fp) -> dict:
     """Bucket-occupancy counters for a visited table (numpy, JSON-safe).
 
@@ -595,9 +695,18 @@ def occupancy_stats(table_fp) -> dict:
     """
     t = np.asarray(table_fp).reshape(-1, SLOTS)
     per_bucket = (t != EMPTY).sum(axis=1)
-    nbuckets = int(t.shape[0])
-    occupied = int(per_bucket.sum())
-    hist = np.bincount(per_bucket, minlength=SLOTS + 1)
+    return occupancy_from_histogram(np.bincount(per_bucket, minlength=SLOTS + 1))
+
+
+def occupancy_from_histogram(histogram) -> dict:
+    """:func:`occupancy_stats` from the table's per-bucket occupancy
+    histogram alone (``histogram[k]`` buckets hold exactly ``k``
+    fingerprints): every counter of the record is a function of it, so a
+    table that was never pulled (:func:`bucket_split` returns the new
+    table's histogram) is described in the same fields."""
+    hist = np.asarray(histogram, np.int64)
+    nbuckets = int(hist.sum())
+    occupied = int((hist * np.arange(hist.size)).sum())
     lam = occupied / nbuckets if nbuckets else 0.0
     # Poisson tail mass at/over SLOTS for the observed load — the model the
     # ≤25%-load growth policy assumes; compare with full_buckets/nbuckets
@@ -617,8 +726,8 @@ def occupancy_stats(table_fp) -> dict:
         "occupied": occupied,
         "load_factor": occupied / (nbuckets * SLOTS) if nbuckets else 0.0,
         "mean_bucket": lam,
-        "max_bucket": int(per_bucket.max()) if nbuckets else 0,
-        "full_buckets": int((per_bucket >= SLOTS).sum()),
+        "max_bucket": int(np.flatnonzero(hist).max()) if nbuckets else 0,
+        "full_buckets": int(hist[SLOTS:].sum()),
         "poisson_full_expect": tail * nbuckets,
         "histogram": hist.tolist(),
     }

@@ -96,6 +96,24 @@ def queue_depth_hist_np(qdepth, tail: int) -> np.ndarray:
     return np.bincount(dep, minlength=DEPTH_BINS).astype(np.int64)
 
 
+def prefix_depth_hist(qdepth, n):
+    """:func:`queue_depth_hist_np` of ``qdepth[:n]`` where the queue lies
+    (the same clamp-into-last-bin semantics, ``DEPTH_BINS`` words back):
+    what a growth on the device banks of the prefix it reclaims, and what
+    the sync after it reads of the queue it left, so that no depth lane
+    crosses to the host.  A compare and a sum a bin - no scatter, no
+    search, and no assumption about the lanes' order."""
+    import jax.numpy as jnp
+
+    dep = jnp.minimum(qdepth, DEPTH_BINS - 1).astype(jnp.int32)
+    live = jnp.arange(qdepth.shape[0], dtype=jnp.int32) < n
+    bins = jnp.arange(DEPTH_BINS, dtype=jnp.int32)
+    return jnp.sum(
+        (dep[None, :] == bins[:, None]) & live[None, :], axis=1,
+        dtype=jnp.int64,
+    )
+
+
 def action_hist_delta(valid):
     """Per-action-slot generated-successor counts for one batch: a column
     sum of the enabled-action mask the step already computed."""

@@ -609,6 +609,20 @@ class WavefrontChecker(Checker):
             rec.add_bytes(d2h=arr.nbytes)
         rec.record("occupancy", at=at, **occupancy_stats(arr))
 
+    def _telemetry_occupancy_hist(self, histogram, *, at: str) -> None:
+        """:meth:`_telemetry_occupancy` of a table that stays on the
+        device: the record is built from its per-bucket occupancy
+        histogram (``ops/buckets.bucket_split`` returns the new table's),
+        whose ``SLOTS + 1`` words are all that crosses."""
+        rec = self.flight_recorder
+        if rec is None:
+            return
+        from ..ops.buckets import occupancy_from_histogram
+
+        hist = np.asarray(histogram)
+        rec.add_bytes(d2h=hist.nbytes)
+        rec.record("occupancy", at=at, **occupancy_from_histogram(hist))
+
     # -- autosave + durability (stateright_tpu/checkpoint.py) ----------------
 
     def _autosave_manifest(self, snap: dict) -> dict:

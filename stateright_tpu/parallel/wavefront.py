@@ -71,10 +71,12 @@ from ..core import Expectation
 from ..ops.buckets import (
     SLOTS,
     bucket_insert,
+    bucket_split,
     host_bucket_rehash,
     lane_compact,
     window_unique,
 )
+from ..ops.cartography import prefix_depth_hist, queue_depth_hist_np
 from ..ops.hashing import EMPTY, row_hash
 from ..telemetry.spans import (
     PROGRAM_LOAD,
@@ -82,6 +84,7 @@ from ..telemetry.spans import (
     STAGE_APPEND,
     STAGE_BOOKKEEP,
     STAGE_EXPAND,
+    STAGE_GROW,
     STAGE_HASH,
     STAGE_INSERT,
     STAGE_POP,
@@ -184,13 +187,7 @@ def _stats_np(carry, cart_start: Optional[int] = None,
         vals.append(np.asarray(carry[spill_start + _SP_BASE]))
         vals.extend(np.asarray(carry[spill_start + _SP_STATS]).reshape(-1))
     if cart_start is not None:
-        from ..ops.cartography import queue_depth_hist_np
-
-        vals.extend(
-            queue_depth_hist_np(
-                np.asarray(carry[_QDEPTH]), int(np.asarray(carry[_TAIL]))
-            )
-        )
+        vals.extend(_depth_hist(carry[_QDEPTH], carry[_TAIL]))
         for arr in carry[cart_start:]:
             vals.extend(np.asarray(arr).reshape(-1))
     return np.asarray(vals, dtype=np.uint64)
@@ -885,6 +882,25 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
     return init_fn, run_fn
 
 
+def _grown_cap(hot_unique: int, cap: int, cand: int, status: int) -> int:
+    """The table capacity a growth event leaves: doubled until the hot
+    occupancy sits at or under 25% and the candidate budget fits
+    (``cap >= 4 * cand``, the engine's actual precondition), or doubled
+    once where a single bucket clustered past ``SLOTS`` entries; ``cap``
+    itself where the table is not what is full.  The one decision the
+    host path (``_grow``) and the device path (``_grow_on_device``)
+    share."""
+
+    def small(c: int) -> bool:
+        return hot_unique * 4 > c or cand * 4 > c
+
+    if not small(cap):
+        return cap * 2 if status == _STATUS_TABLE_FULL else cap
+    while small(cap):
+        cap *= 2
+    return cap
+
+
 def _repad_queue(carry_np: list, qalloc: int) -> None:
     """Pad (EMPTY/0 fill) or truncate the queue buffers to ``qalloc`` rows,
     in place.  Shared by snapshot-resume and growth."""
@@ -895,6 +911,55 @@ def _repad_queue(carry_np: list, qalloc: int) -> None:
             fill = EMPTY if i == _QFP else 0
             arr = np.concatenate([arr, np.full(pad_shape, fill, arr.dtype)])
         carry_np[i] = arr[:qalloc] if arr.ndim == 1 else arr[:qalloc, :]
+
+
+def _slide_queue(qrows, qfp, qebits, qdepth, head, tail, *, qalloc: int):
+    """The four queue buffers after a growth, where they lie: the live
+    window ``[head, tail)`` at row 0 of buffers of ``qalloc`` rows, every
+    lane past it the buffer's fill (``EMPTY`` for ``qfp``, 0 else) - bit
+    for bit what ``_grow_queue``'s slice and copy and :func:`_repad_queue`
+    leave on the host (the rows the step wrote past ``tail`` are garbage
+    there too, and go the same way).  A buffer is padded by ``qalloc``
+    rows of fill behind it, so the window's ``dynamic_slice`` never clamps
+    whatever ``head`` is; the compiler fuses pad, slice and mask into one
+    pass a buffer (``tests/test_table_layout.py`` holds its temporaries to
+    the ``u32`` planes of what it reads and writes)."""
+    live = jnp.arange(qalloc, dtype=jnp.int32) < tail - head
+
+    def slide(buf, fill):
+        fill = jnp.asarray(fill, buf.dtype)
+        rest = (1,) * (buf.ndim - 1)
+        window = jax.lax.dynamic_slice_in_dim(
+            jax.lax.pad(buf, fill, [(0, qalloc, 0)] + [(0, 0, 0)] * len(rest)),
+            head, qalloc,
+        )
+        return jnp.where(live.reshape((qalloc,) + rest), window, fill)
+
+    with jax.named_scope(STAGE_GROW):
+        return (slide(qrows, 0), slide(qfp, EMPTY), slide(qebits, 0),
+                slide(qdepth, 0))
+
+
+# Module-level and keyed by shapes and ``qalloc`` alone, not by the twin
+# object: a fresh model object in a warm process finds them compiled.  A
+# slide that keeps the buffers' size runs in place (donated); one into larger
+# buffers cannot (jax 0.9.0 donates an input only to an output of its size,
+# and warns of every other), so the old buffers live until the host drops
+# them.
+_slide_queue_grown = jax.jit(_slide_queue, static_argnames="qalloc")
+_slide_queue_in_place = jax.jit(
+    _slide_queue, static_argnames="qalloc", donate_argnums=(0, 1, 2, 3)
+)
+_prefix_depth_hist = jax.jit(prefix_depth_hist)
+
+
+def _depth_hist(qdepth, n) -> np.ndarray:
+    """The per-depth histogram of ``qdepth[:n]``, counted where the lanes
+    lie: of a queue on the device ``DEPTH_BINS`` words cross, not 4 B a
+    lane (a growth on the device, and the sync after it)."""
+    if isinstance(qdepth, jax.Array):
+        return np.asarray(_prefix_depth_hist(qdepth, n))
+    return queue_depth_hist_np(qdepth, int(np.asarray(n)))
 
 
 def _carry_avals(tensor, n_props: int, cap: int, qcap: int, batch: int,
@@ -1219,11 +1284,14 @@ class TpuChecker(WavefrontChecker):
         at one forgotten site.  No-op when cartography is off."""
         if not self._cartography or n <= 0:
             return
-        from ..ops.cartography import DEPTH_BINS, queue_depth_hist_np
+        from ..ops.cartography import DEPTH_BINS
 
         if self._cart_depth_base is None:
             self._cart_depth_base = np.zeros(DEPTH_BINS, np.int64)
-        self._cart_depth_base += sign * queue_depth_hist_np(qdepth, n)
+        hist = _depth_hist(qdepth, np.int32(n))
+        if isinstance(qdepth, jax.Array) and self.flight_recorder is not None:
+            self.flight_recorder.add_bytes(d2h=hist.nbytes)
+        self._cart_depth_base += sign * hist
 
     def _sync_cartography(self, tail, *, states: int, unique: int) -> None:
         """Parse the cartography section of the packed stats vector (the
@@ -2078,18 +2146,11 @@ class TpuChecker(WavefrontChecker):
         )
         rec = self.flight_recorder
         parent = parent or self._run_span_ctx
-
-        def table_small():
-            return (
-                (int(carry_np[_UNIQUE]) - spill_base) * 4 > cap
-            ) or (cand * 4 > cap)
-
-        if table_small() or status == _STATUS_TABLE_FULL:
-            if table_small():
-                while table_small():
-                    cap *= 2
-            elif status == _STATUS_TABLE_FULL:
-                cap *= 2  # a single bucket clustered past SLOTS entries
+        grown = _grown_cap(
+            int(carry_np[_UNIQUE]) - spill_base, cap, cand, status
+        )
+        if grown != cap:
+            cap = grown
             with tel_span("grow.rehash", rec, parent=parent, cap=cap):
                 tfp, tpl = host_bucket_rehash(
                     carry_np[_TFP], carry_np[_TPL], cap // SLOTS
@@ -2098,6 +2159,76 @@ class TpuChecker(WavefrontChecker):
         with tel_span("grow.queue", rec, parent=parent):
             qcap = self._grow_queue(carry_np, cap, qcap, batch)
         return cap, qcap, carry_np
+
+    def _grows_on_device(self, carry) -> bool:
+        """Whether a growth event transforms ``carry`` where it lies
+        (:meth:`_grow_on_device`) or on the host (:meth:`_grow`) - by what
+        the engine observes, not by a knob.  The spill tier works on a
+        carry that is on the host by design (its eviction, its queue
+        offload and its transient forecast); and the mesh engine runs this
+        loop over a carry sharded by bucket and by queue shard, a head and
+        a tail a shard, which no single-device program here addresses
+        (``_device_table`` draws the same two lines)."""
+        return not self._spill and len(carry[_TFP].sharding.device_set) == 1
+
+    def _grow_on_device(self, carry: list, cap: int, qcap: int, batch: int,
+                        status: int, cand: int, stats, parent):
+        """:meth:`_grow` where the carry lies: returns ``(cap, qcap,
+        occupancy)`` with ``carry``'s buffers replaced in place, bit for
+        bit what ``_grow`` leaves of the pulled carry, and ``occupancy``
+        the new table's per-bucket histogram, still on the device (None
+        where the table kept its size).
+
+        The host decides from what it already holds - ``unique``, ``head``
+        and ``tail`` are in ``stats``, the packed vector of the sync that
+        found the status - and dispatches two programs:
+        :func:`_slide_queue` for the queue's four buffers,
+        ``ops/buckets.bucket_split`` for the table's two.  No table or
+        queue buffer crosses to the host: ``grow.queue`` and
+        ``grow.rehash`` wrap the dispatch of the two transforms (their
+        device time is the ``sr.grow`` stage of the trace and shows in the
+        next ``wait``), ``grow.pull`` what cartography banks of the popped
+        prefix (``DEPTH_BINS`` words) and ``grow.push`` the three scalars
+        the host rewrites."""
+        rec = self.flight_recorder
+        head, tail = int(stats[_ST_HEAD]), int(stats[_ST_TAIL])
+        with tel_span("grow.pull", rec, parent=parent):
+            # the slide below drops the consumed prefix: bank its depth
+            # lanes first (see _grow_queue), counted where they lie
+            self._bank_depth_lanes(carry[_QDEPTH], head)
+        # the queue before the table: a slide's temporaries are the u32
+        # planes of what it reads and writes (up to 1.4 x the queue), so it
+        # runs while the table is still the small one
+        with tel_span("grow.queue", rec, parent=parent):
+            pending = tail - head
+            while pending * 2 > qcap:
+                qcap *= 2
+            qalloc = self._qalloc(qcap, batch)
+            slide = (
+                _slide_queue_in_place if carry[_QROWS].shape[0] == qalloc
+                else _slide_queue_grown
+            )
+            carry[_QROWS:_HEAD] = slide(
+                *carry[_QROWS:_HEAD], carry[_HEAD], carry[_TAIL],
+                qalloc=qalloc,
+            )
+        occupancy = None
+        grown = _grown_cap(int(stats[_ST_UNIQUE]), cap, cand, status)
+        if grown != cap:
+            cap = grown
+            with tel_span("grow.rehash", rec, parent=parent, cap=cap):
+                carry[_TFP], carry[_TPL], occupancy = bucket_split(
+                    carry[_TFP], carry[_TPL], new_nbuckets=cap // SLOTS
+                )
+        with tel_span("grow.push", rec, parent=parent):
+            scalars = (
+                (_HEAD, 0), (_TAIL, pending), (_STATUS, _STATUS_OK),
+            )
+            for i, value in scalars:
+                carry[i] = jnp.int32(value)
+            if rec is not None:
+                rec.add_bytes(h2d=4 * len(scalars))
+        return cap, qcap, occupancy
 
     def _grow_queue(self, carry_np: list, cap: int, qcap: int,
                     batch: int) -> int:
@@ -2320,10 +2451,17 @@ class TpuChecker(WavefrontChecker):
                     **({"budget_bytes": int(budget)} if budget else {}),
                 )
                 self._refresh_spill()
+        grown_occ = None  # a split table's histogram, still on the device
         while True:
             # one host sync per iteration: the packed stats vector
             if stats is None:
                 stats = _stats_np(carry, cart_start, por_start, spill_start)
+            elif grown_occ is not None:
+                # the device call behind this sync ran after the split, so
+                # its histogram is there to read at no wait: the occupancy
+                # sample a growth boundary offers, of the table it left
+                self._telemetry_occupancy_hist(grown_occ, at="growth")
+                grown_occ = None
             head, tail, unique, scount, maxdepth, status = (
                 int(stats[_ST_HEAD]), int(stats[_ST_TAIL]),
                 int(stats[_ST_UNIQUE]), int(stats[_ST_SCOUNT]),
@@ -2458,14 +2596,17 @@ class TpuChecker(WavefrontChecker):
                 # host seam span: one ``grow`` per growth event, its phases
                 # as children (pull / rehash / queue / push) — on the
                 # recorder's clock and, as ``sr/grow*``, the profiler's
+                on_device = self._grows_on_device(carry)
                 with tel_span(
                     "grow", rec, parent=self._run_span_ctx,
                     status=status_name, unique=unique, cap=cap,
                 ) as grow:
                     if rec is not None:
-                        rec.record(
+                        crossed = rec.counters()
+                        event = rec.record(
                             "growth", status=status_name,
                             unique=unique, cap=cap, qcap=qcap, cand=cand,
+                            path="device" if on_device else "host",
                         )
                         if status == _STATUS_CAND_FULL:
                             rec.add("compaction_hits")
@@ -2495,6 +2636,12 @@ class TpuChecker(WavefrontChecker):
                         # otherwise consistent), rebuild, replay
                         cand = min(cand * 2, batch * arity)
                         carry[_STATUS] = jnp.int32(_STATUS_OK)
+                        if on_device and cand * 4 > cap:
+                            # one call: it doubles until the budget fits
+                            cap, qcap, grown_occ = self._grow_on_device(
+                                carry, cap, qcap, batch, _STATUS_TABLE_FULL,
+                                cand, stats, grow.ctx,
+                            )
                         while cand * 4 > cap:
                             with tel_span("grow.pull", rec, parent=grow.ctx):
                                 carry_np = [np.asarray(c) for c in carry]
@@ -2505,6 +2652,14 @@ class TpuChecker(WavefrontChecker):
                             with tel_span("grow.push", rec, parent=grow.ctx):
                                 carry = [jnp.asarray(c) for c in carry_np]
                         carry = list(carry) + tail_extra
+                    elif on_device:
+                        # a device program from one carry to a larger one:
+                        # no table or queue buffer crosses to the host
+                        cap, qcap, grown_occ = self._grow_on_device(
+                            carry, cap, qcap, batch, status, cand, stats,
+                            grow.ctx,
+                        )
+                        carry = carry + tail_extra
                     else:
                         with tel_span("grow.pull", rec, parent=grow.ctx):
                             carry_np = [np.asarray(c) for c in carry]
@@ -2547,6 +2702,14 @@ class TpuChecker(WavefrontChecker):
                             carry = [
                                 jnp.asarray(c) for c in carry_np
                             ] + tail_extra
+                    if rec is not None:
+                        # what this event moved between host and device,
+                        # by the recorder's own byte counters
+                        now = rec.counters()
+                        rec.amend(event, **{
+                            k: int(now.get(k, 0) - crossed.get(k, 0))
+                            for k in ("d2h_bytes", "h2d_bytes")
+                        })
                 self._stage("growth", time.monotonic() - t_grow)
                 stats = None
                 continue
@@ -2594,6 +2757,9 @@ class TpuChecker(WavefrontChecker):
         self._cap, self._qcap, self._cand = cap, qcap, cand
         if self._profiler is not None:
             self._profiler.stop()
+        if grown_occ is not None:
+            # a run that ended on the sync after a growth
+            self._telemetry_occupancy_hist(grown_occ, at="growth")
         if rec is not None and occ_every:
             # close the occupancy time series with the final table (an
             # explicit D2H pull, taken only when sampling was requested)

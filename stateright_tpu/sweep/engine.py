@@ -1003,6 +1003,7 @@ class SweepChecker(Checker):
                         "growth",
                         status=_STATUS_NAMES.get(status, str(status)),
                         unique=tot_u, cap=cap, qcap=qcap, cand=cand,
+                        path="host",  # this engine's own _grow: pulled
                     )
                 cart_tail = list(carry[_CART_START:])
                 carry_np = [

@@ -243,7 +243,9 @@ def exec_memory(compiled) -> Optional[dict]:
 def next_rung_block(spec_fn: Callable, caps: dict) -> dict:
     """The analytic forecast for the NEXT table-doubling rung: steady
     bytes and the migration transient (old + new carry live across the
-    growth swap)."""
+    growth swap).  An upper bound since PR 48: a growth on the device
+    holds the old and new TABLE beside one queue, then the planes of the
+    queue it slides; the spill tier and the mesh engine still hold this."""
     cur_total = total_bytes(spec_fn(caps))
     nxt = dict(caps)
     nxt["cap"] = int(caps["cap"]) * 2

@@ -82,6 +82,12 @@ STAGE_STATS = "sr.stats"
 STAGES = (STAGE_POP, STAGE_PROPS, STAGE_EXPAND, STAGE_HASH, STAGE_INSERT,
           STAGE_APPEND, STAGE_BOOKKEEP, STAGE_STATS)
 
+# The growth programs' scope (``ops/buckets.bucket_split``, and
+# ``parallel/wavefront.py``'s queue slide): no stage of the step program
+# and so no member of ``STAGES``, but a stage of the trace all the same -
+# the device time of a growth event reads ``sr.grow``, not ``unnamed``.
+STAGE_GROW = "sr.grow"
+
 # Sub-scopes of ``sr.expand`` that a compiled actor twin's ``step_rows``
 # opens (``parallel/actor_compiler.py``; ``twin.net`` lives in
 # ``parallel/actor_tensor.py``'s slot kernels, which hand-written twins

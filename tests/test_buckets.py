@@ -2,6 +2,7 @@
 straightforward host-side set on arbitrary candidate streams (duplicates
 in-batch, duplicates vs the table, EMPTY lanes, bucket collisions)."""
 
+import re
 import sys
 from pathlib import Path
 
@@ -18,10 +19,12 @@ from stateright_tpu.ops.buckets import (
     bucket_insert,
     bucket_key,
     bucket_of,
+    bucket_split,
     host_bucket_rehash,
     lane_compact,
+    occupancy_stats,
 )
-from stateright_tpu.ops.hashing import EMPTY, mix64_np
+from stateright_tpu.ops.hashing import EMPTY, mix64_np, unmix64
 
 sys.path.insert(0, str(Path(__file__).parent))
 from test_paxos_tensor import gather_call_sites  # noqa: E402
@@ -227,6 +230,99 @@ def test_host_rehash_round_trip():
     state2, _, n_new2, _ = insert(state2, [123456789, int(fps[0])])
     assert n_new2 == 1
 
+
+# ---------------------------------------------------------------------------
+# growth where the table lies (PR 48): bucket_split == host_bucket_rehash
+# ---------------------------------------------------------------------------
+
+SPLIT_FROM = 64  # buckets of the table a split starts from
+
+
+def dense_table(fps, nbuckets):
+    """The ``nbuckets``-bucket table that holds ``fps`` (distinct; no more
+    than ``SLOTS`` a bucket), filled densely in their order as the insert
+    fills it, payloads derived from the fingerprints."""
+    fps = np_u64(fps)
+    return host_bucket_rehash(fps, fps ^ np.uint64(0x5A5A), nbuckets)
+
+
+def split_case(kind):
+    rng = np.random.default_rng(48)
+    if kind == "empty":
+        fps = []
+    elif kind == "load25":
+        fps = np.unique(
+            rng.integers(1, 1 << 63, SPLIT_FROM * SLOTS // 4, dtype=np.uint64)
+        )
+        rng.shuffle(fps)
+    elif kind == "full_buckets":
+        # several buckets at SLOTS entries, the rest sparse: what a table
+        # looks like when one bucket's overflow is what asked for the growth
+        draw = rng.integers(1, 1 << 63, 40_000, dtype=np.uint64)
+        home = bucket_of(draw, SPLIT_FROM)
+        fps = np.concatenate(
+            [draw[home == b][:SLOTS] for b in (0, 7, 31, SPLIT_FROM - 1)]
+            + [draw[home == b][:3] for b in (1, 8, 30)]
+        )
+        assert fps.size == 4 * SLOTS + 9
+        rng.shuffle(fps)
+    else:
+        # the fingerprints whose key is, or collides with, EMPTY's remap:
+        # mix64(fp) == EMPTY is keyed EMPTY - 1, as is the one fingerprint
+        # that mixes to EMPTY - 1 itself; both live in the LAST bucket,
+        # beside the keys just below them
+        assert kind == "empty_remap"
+        top = int(EMPTY)
+        fps = np.asarray(
+            unmix64(jnp.asarray(np_u64([top - i for i in range(6)])))
+        )
+        assert (bucket_of(fps, 1 << 20) == (1 << 20) - 1).all()
+    return dense_table(fps, SPLIT_FROM)
+
+
+@pytest.mark.parametrize(
+    "kind", ["empty", "load25", "full_buckets", "empty_remap"]
+)
+@pytest.mark.parametrize("factor", [2, 4, 8])
+def test_bucket_split_equals_host_rehash_bit_for_bit(factor, kind):
+    """The device's growth transform against the host's, both arrays and
+    every slot: a split is the stable partition of each bucket by the next
+    key bits, which is what the host's stable argsort over entries in
+    table order leaves.  The histogram it returns describes the new table
+    as ``occupancy_stats`` describes the pulled one."""
+    tfp, tpl = split_case(kind)
+    want_fp, want_pl = host_bucket_rehash(tfp, tpl, SPLIT_FROM * factor)
+    got_fp, got_pl, hist = bucket_split(
+        jnp.asarray(tfp), jnp.asarray(tpl), new_nbuckets=SPLIT_FROM * factor
+    )
+    np.testing.assert_array_equal(np.asarray(got_fp), want_fp)
+    np.testing.assert_array_equal(np.asarray(got_pl), want_pl)
+    assert got_fp.dtype == jnp.uint64 and got_pl.dtype == jnp.uint64
+    assert np.asarray(hist).tolist() == occupancy_stats(want_fp)["histogram"]
+
+
+def test_bucket_split_compiles_to_no_sort_scatter_or_gather():
+    """The compile-time fence: a TPU sort costs ~10 s of compile an
+    operand past 16,384 lanes (PR 36), and a table-wide scatter or gather
+    7-22 ns a lane; the split is compares, selects and reductions along a
+    16-lane axis alone - in the module as lowered and as compiled."""
+    tfp, tpl = fresh(SPLIT_FROM)
+
+    def fenced_ops(lowered):
+        return {
+            op
+            for text in (lowered.as_text(), lowered.compile().as_text())
+            for op in re.findall(r"\b(sort|scatter|gather|while)\b", text)
+        }
+
+    assert not fenced_ops(
+        bucket_split.lower(tfp, tpl, new_nbuckets=SPLIT_FROM * 4)
+    )
+    # the fence sees what it is there to stop
+    control = jax.jit(lambda x: jnp.sort(x).at[jnp.argsort(x)].set(x))
+    assert fenced_ops(control.lower(tfp)) == {"sort", "scatter"}
+    control = jax.jit(lambda x: x[jnp.argsort(x)])
+    assert fenced_ops(control.lower(tfp)) == {"sort", "gather"}
 
 # ---------------------------------------------------------------------------
 # the bucket-mix fix (ROADMAP table-size anomaly): avalanche + chi-square

@@ -127,6 +127,53 @@ def test_growth_replay_never_double_counts():
         assert sum(snap["depth_hist"]) == snap["fresh_inserts"]
 
 
+def test_device_growth_banks_the_true_depths_bin_by_bin():
+    """The per-depth HISTOGRAM, not its sum, of a run whose queue grew on
+    the device (2pc-3 at ``queue_capacity=64``: two ``queue_full`` slides).
+    A growth reclaims the popped prefix of the queue and banks its depth
+    lanes first, counted where they lie (``prefix_depth_hist``): the bank
+    plus the lanes the final queue holds is the TRUE histogram, bin by
+    bin, so the growth seam loses and misplaces nothing.
+
+    What the run REPORTS is the bank plus ``queue_depth_hist`` of the
+    queue, and that is where ROADMAP Queue 3 item 2d's wrong numbers come
+    from, with or without a growth: it searches ``qdepth[:tail]`` as if
+    sorted, and a batch that straddles two levels appends its children in
+    the insert's order, not by depth.  Pinned as it reads, beside the
+    true one, so that the repair shows here."""
+    true = [1, 7, 21, 38, 50, 54, 49, 36, 21, 9, 2]
+    caps = dict(sync=True, batch=32, capacity=1 << 12)
+    c = TwoPhaseSys(3).checker().telemetry(cartography=True).spawn_tpu(
+        queue_capacity=64, **caps
+    )
+    growth = c.flight_recorder.records("growth")
+    assert [g["status"] for g in growth] == ["queue_full", "queue_full"]
+    assert all(g["path"] == "device" for g in growth)
+
+    def lanes(checker):
+        from stateright_tpu.parallel.wavefront import _QDEPTH, _TAIL
+
+        carry = checker._final_carry
+        return np.bincount(
+            np.asarray(carry[_QDEPTH])[: int(carry[_TAIL])],
+            minlength=c._cart_depth_base.size,
+        )
+
+    assert (c._cart_depth_base + lanes(c)).tolist()[: len(true)] == true
+    assert int(c._cart_depth_base.sum()) + int(lanes(c).sum()) == TPC3_UNIQUE
+    reported = c.cartography()["depth_hist"]
+    assert reported[: len(true)] == [1, 7, 21, 38, 50, 47, 52, 46, 13, 12, 1]
+    # a queue that never grew holds every lane, and reads wrong as well
+    whole = TwoPhaseSys(3).checker().telemetry(cartography=True).spawn_tpu(
+        queue_capacity=1 << 10, **caps
+    )
+    assert not whole.flight_recorder.records("growth")
+    assert lanes(whole).tolist()[: len(true)] == true
+    assert whole.cartography()["depth_hist"][: len(true)] == [
+        1, 7, 21, 38, 50, 64, 31, 50, 13, 9, 4,
+    ]
+
+
 def test_resume_preserves_banked_depth_histogram():
     """Growth compactions bank consumed queue prefixes' depth lanes in
     ``_cart_depth_base``; a snapshot must carry the bank or a resumed

@@ -30,7 +30,13 @@ import jax
 import jax.numpy as jnp
 
 from stateright_tpu.models.two_phase_commit import TwoPhaseSys
-from stateright_tpu.ops.buckets import ROW_LANES, SLOTS, bucket_of, parent_chains
+from stateright_tpu.ops.buckets import (
+    ROW_LANES,
+    SLOTS,
+    bucket_of,
+    bucket_split,
+    parent_chains,
+)
 from stateright_tpu.ops.hashing import EMPTY
 from stateright_tpu.parallel import wavefront as wf
 
@@ -247,6 +253,70 @@ def test_the_compiled_parent_walk_adds_no_table_sized_operation(one_v5e_chip):
     planes = 4 * 4 * cap  # two arrays, two u32 planes each
     assert mem.temp_size_in_bytes <= planes + (1 << 20)  # read: 3 planes + 0.4 MB
     assert mem.output_size_in_bytes < 64 << 10 and mem.alias_size_in_bytes == 0
+
+
+# -- growth where the carry lies (PR 48) -------------------------------------
+
+
+def test_the_compiled_split_is_compares_and_selects_alone(one_v5e_chip):
+    """``bucket_split`` from 2^22 to 2^23 slots (``paxos3-defaults``' last
+    rung), compiled ahead of time for one v5e: no ``sort`` (~10 s of
+    compile an operand past 16,384 lanes, PR 36), no ``scatter``, no
+    ``gather``, no loop; besides the entry's ``u64`` plane splits and combines
+    it is fusions.  Its temporaries are the planes of what it reads and
+    writes and the ``[nbuckets, 32, 16]`` select, fused: under three times
+    the new table (read here: 185.6 MB against 134.2 MB of output)."""
+    cap = 1 << 22
+    table = jax.ShapeDtypeStruct((cap,), jnp.uint64)
+    compiled = compiled_for(
+        one_v5e_chip, bucket_split, table, table,
+        new_nbuckets=2 * cap // SLOTS,
+    )
+    text = compiled.as_text()
+    assert not re.findall(r"\b(sort|scatter|gather|while)\b", text)
+    assert sorted(
+        op for op, _ in table_sized_operations(text, cap, in_entry=True)
+        if op.startswith("X64")
+    ) == ["X64Combine", "X64Combine", "X64SplitHigh", "X64SplitHigh",
+          "X64SplitLow", "X64SplitLow"]
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= 2 * 8 * 2 * cap
+    assert mem.temp_size_in_bytes < 3 * 8 * 2 * cap
+
+
+@pytest.mark.parametrize("grown", [False, True])
+def test_the_compiled_slide_holds_the_planes_and_no_more(one_v5e_chip, grown):
+    """The slide of paxos-3's queue (33 ``u64`` a row, 2^19 rows and one
+    append window) at ``paxos3-defaults``' last rung: one pass a buffer,
+    no loop, no gather.  In place its outputs are its donated inputs and
+    its temporaries are the ``u32`` planes of what it reads and of what it
+    writes (2 x the buffers); into doubled buffers, the planes of what it
+    writes (1 x the new buffers).  What a growth event holds at most is
+    read off these: ``_grow_on_device`` runs the queue before the table."""
+    m, width = 2048 * 30, 33
+    new = (1 << 19) + m
+    old = (1 << 18) + m if grown else new
+    slide = wf._slide_queue_grown if grown else wf._slide_queue_in_place
+
+    def lanes(dtype, *rest):
+        return jax.ShapeDtypeStruct((old, *rest), dtype)
+
+    scalar = jax.ShapeDtypeStruct((), jnp.int32)
+    compiled = compiled_for(
+        one_v5e_chip, slide,
+        lanes(jnp.uint64, width), lanes(jnp.uint64), lanes(jnp.uint32),
+        lanes(jnp.uint32), scalar, scalar, qalloc=new,
+    )
+    assert not re.findall(r"\b(sort|scatter|gather|while)\b", compiled.as_text())
+    mem = compiled.memory_analysis()
+    out = mem.output_size_in_bytes  # the rows are tiled to 40 words: 187 MB
+    assert out >= new * (width * 8 + 8 + 4 + 4)
+    aliased = mem.alias_size_in_bytes
+    if grown:
+        assert aliased == 0
+    else:  # all of it but the output tuple's index table (512 B)
+        assert out - 4096 < aliased <= out
+    assert mem.temp_size_in_bytes <= (1 if grown else 2) * out + (1 << 20)
 
 
 # -- bucket_insert at the cells' shapes --------------------------------------

@@ -223,7 +223,7 @@ def test_wavefront_step_records_and_counts():
 
 def test_wavefront_growth_records_with_occupancy():
     """Growth boundaries record a named event plus a free occupancy sample
-    (the carry is host-side there anyway)."""
+    (of the table a split leaves: its histogram comes back with it)."""
     c = (
         TwoPhaseSys(5).checker().telemetry()
         .spawn_tpu(sync=True, capacity=1 << 10, batch=64)

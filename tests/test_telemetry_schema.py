@@ -50,8 +50,11 @@ SCHEMA = {
     ),
     "growth": (
         {"status": str},
+        # path: where the carry was transformed (``device`` / ``host``),
+        # with the bytes the event moved each way (PR 48)
         {"unique": int, "cap": int, "qcap": int, "cand": int,
-         "from_init": bool},
+         "from_init": bool, "path": str, "d2h_bytes": int,
+         "h2d_bytes": int},
     ),
     "occupancy": (
         {
