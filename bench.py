@@ -108,7 +108,7 @@ Process structure — one process for each chip:
  2. ONE device child (``--device-child``), ONE attempt: backend init,
     compile cache on (``prewarm.resolve_compile_cache_dir``), parity
     configs, then the primary ``paxos check 3`` timed run FIRST (so a
-    later failure cannot lose it), then ``2pc check 4``, the Pallas A/B,
+    later failure cannot lose it), then ``2pc check 4``
     and the remaining reference bench configs.  The child appends
     cumulative results to a stage file after every milestone; the parent
     merges + emits on change, kills the child only at the deadline, and
@@ -187,7 +187,7 @@ _LINE_KEYS = (
     "tpu_paxos3_states_per_sec", "tpu_paxos3_unique", "tpu_paxos3_sec",
     "cpu_baseline_states_per_sec", "cpu_baseline_src",
     "cpu_baseline_engine", "cpu_cores",
-    "cpu_load1", "baseline_def", "insert_path", "parity", "details",
+    "cpu_load1", "baseline_def", "parity", "details",
 )
 
 
@@ -272,9 +272,7 @@ def _perf_regressions(trend=None) -> list:
 def _compute_headline() -> dict:
     """value/vs_baseline + provenance fields from EXTRAS ∪ VALIDATED.
     Returned keys OVERRIDE the raw extras in the emitted record (merge
-    order in emit()), so when the Pallas path wins, the describing fields
-    (sec) are replaced by the Pallas run's own — value, sec and unique
-    must stay mutually consistent on every line."""
+    order in emit())."""
     out: dict = {}
     cpu_base, cpu_src, _ = _cpu_baseline()
     if cpu_base is not None:
@@ -290,16 +288,6 @@ def _compute_headline() -> dict:
     out["platform"] = EXTRAS.get("platform") or "none yet"
     on_tpu = EXTRAS.get("platform") == "tpu"
     tpu_sps = EXTRAS.get("tpu_paxos3_states_per_sec") if on_tpu else None
-    pallas_sps = EXTRAS.get("tpu_paxos3_pallas_states_per_sec")
-    if tpu_sps is not None and pallas_sps is not None:
-        if pallas_sps > tpu_sps:
-            out["insert_path"] = "pallas"
-            tpu_sps = pallas_sps
-            out["tpu_paxos3_states_per_sec"] = pallas_sps
-            if EXTRAS.get("tpu_paxos3_pallas_sec") is not None:
-                out["tpu_paxos3_sec"] = EXTRAS["tpu_paxos3_pallas_sec"]
-        else:
-            out["insert_path"] = "xla-scatter"
     if tpu_sps is not None:
         out["value"], out["fresh"] = tpu_sps, True
         # trend deltas vs the BENCH_VALIDATED history (details artifact)
@@ -434,11 +422,6 @@ def record_validated() -> None:
         doc["tpu_paxos3_report"] = EXTRAS["tpu_paxos3_report"]
     if EXTRAS.get("tpu_phases"):
         doc["tpu_phases"] = EXTRAS["tpu_phases"]
-    pallas = EXTRAS.get("tpu_paxos3_pallas_states_per_sec")
-    if pallas and pallas > (doc["tpu_paxos3_states_per_sec"] or 0):
-        doc["tpu_paxos3_states_per_sec"] = pallas
-        doc["tpu_paxos3_sec"] = EXTRAS.get("tpu_paxos3_pallas_sec")
-        doc["provenance"] += " (pallas insert path)"
     cpu_stored = VALIDATED.get("cpu_paxos3_uncontended_states_per_sec")
     _, _, uncontended = _cpu_baseline()
     if uncontended:
@@ -986,33 +969,6 @@ def device_phase() -> dict:
         _mark("2pc4 done")
     except Exception as e:  # noqa: BLE001
         out["tpu_2pc4_error"] = f"{type(e).__name__}: {e}"
-    _persist(out)
-
-    # A/B the Pallas visited-set insert kernel (ops/pallas_insert.py) on the
-    # same primary config; count parity is asserted so a miscompiled kernel
-    # can't silently report a win.
-    try:
-        def spawn3p():
-            b = m3.checker()
-            if target:
-                b = b.target_states(int(target))
-            return b.spawn_tpu(sync=True, pallas=True, **caps)
-
-        spawn3p()  # warm-up (compile)
-        tpu_p3p, dtp = timed(spawn3p)
-        if tpu_p3p.unique_state_count() != tpu_p3.unique_state_count():
-            raise AssertionError(
-                f"pallas path unique {tpu_p3p.unique_state_count()} != "
-                f"{tpu_p3.unique_state_count()}"
-            )
-        out["tpu_paxos3_pallas_states_per_sec"] = round(
-            tpu_p3p.state_count() / dtp, 1
-        )
-        out["tpu_paxos3_pallas_sec"] = round(dtp, 3)
-        _register(tpu_p3p, "paxos3_pallas")
-        _mark("paxos3 pallas A/B done")
-    except Exception as e:  # noqa: BLE001
-        out["tpu_paxos3_pallas_error"] = f"{type(e).__name__}: {e}"
     _persist(out)
 
     # secondary: 2pc check 7; a failure here keeps the primary metric's

@@ -880,7 +880,7 @@ def _stage_fns(tensor, cap: int, qcap: int, batch: int, cand: int,
 
     The insert/queue wiring here MIRRORS ``wavefront._build_engine``'s
     default path (window=batch, compact=eff_cand, qalloc=qcap+m) by
-    hand, not derived from ``_carry_avals`` as the memory ledger's
+    hand, not derived from ``parallel/carry.carry_avals`` as the memory ledger's
     specs are: the engine's step is one fused jaxpr,
     and standalone stage kernels are the whole point of per-stage
     attribution.  The XLA reconciliation checks each stage against its

@@ -216,8 +216,6 @@ def bucket_insert(
     fps: jnp.ndarray,  # uint64[M] candidates (EMPTY = invalid lane)
     payloads: jnp.ndarray,  # uint64[M]
     window: int,  # scatter chunk size (≈ expected novel per batch)
-    use_pallas: bool = False,  # write via the Pallas DMA kernel instead of
-    #                            windowed XLA scatters (ops/pallas_insert.py)
     generation_order: bool = False,  # compact novel rows in generation order
     #                            (needed for symmetry runs; see below)
     compact: int = None,  # optional valid-candidate budget CB: compact valid
@@ -404,8 +402,7 @@ def bucket_insert(
     n_new = jnp.where(blocked, 0, jnp.sum(novel)).astype(jnp.int32)
 
     # Compact novel candidates to the front.  Plain runs keep sorted-fp
-    # order (bucket-contiguous — the Pallas kernel then touches each line
-    # group once); the visited SET is order-independent there.  Symmetry
+    # order (bucket-contiguous); the visited SET is order-independent there.  Symmetry
     # runs compact in GENERATION order (original batch position): the dedup
     # key is the canonical fp of a not-necessarily-class-invariant
     # representative, so enqueue order decides which class member gets
@@ -438,32 +435,25 @@ def bucket_insert(
 
     # The payload is needed only where something is written, so it follows
     # no sort: it is fetched by original lane, ``window`` lanes a chunk.
-    if use_pallas:
-        from .pallas_insert import pallas_scatter_insert
+    ptgt = padded(tgt, nslots)
+    pcfp = padded(cfp, EMPTY)
+    psel = padded(sel, 0)
 
-        table_fp, table_payload = pallas_scatter_insert(
-            table_fp, table_payload, tgt, cfp, payloads[sel], n_new
-        )
-    else:
-        ptgt = padded(tgt, nslots)
-        pcfp = padded(cfp, EMPTY)
-        psel = padded(sel, 0)
+    def chunk_body(state):
+        k, tfp, tpl = state
+        off = k * window
+        t = jax.lax.dynamic_slice(ptgt, (off,), (window,))
+        f = jax.lax.dynamic_slice(pcfp, (off,), (window,))
+        p = payloads[jax.lax.dynamic_slice(psel, (off,), (window,))]
+        in_range = jnp.arange(window, dtype=jnp.int32) + off < n_new
+        t = jnp.where(in_range, t, nslots)
+        tfp = tfp.at[t].set(f, mode="drop")
+        tpl = tpl.at[t].set(p, mode="drop")
+        return k + 1, tfp, tpl
 
-        def chunk_body(state):
-            k, tfp, tpl = state
-            off = k * window
-            t = jax.lax.dynamic_slice(ptgt, (off,), (window,))
-            f = jax.lax.dynamic_slice(pcfp, (off,), (window,))
-            p = payloads[jax.lax.dynamic_slice(psel, (off,), (window,))]
-            in_range = jnp.arange(window, dtype=jnp.int32) + off < n_new
-            t = jnp.where(in_range, t, nslots)
-            tfp = tfp.at[t].set(f, mode="drop")
-            tpl = tpl.at[t].set(p, mode="drop")
-            return k + 1, tfp, tpl
-
-        _, table_fp, table_payload = jax.lax.while_loop(
-            chunk_cond, chunk_body, (jnp.int32(0), table_fp, table_payload)
-        )
+    _, table_fp, table_payload = jax.lax.while_loop(
+        chunk_cond, chunk_body, (jnp.int32(0), table_fp, table_payload)
+    )
 
     return table_fp, table_payload, sel, n_new, overflow, cand_overflow
 

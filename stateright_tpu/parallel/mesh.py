@@ -34,8 +34,7 @@ not; a ``jax.distributed`` run would need a process-spanning host loop,
 docs/mesh.md "Multi-host").
 
 The spill tier stays single-device (the inherited ``_init_common``
-rejection), and ``pallas=True`` is rejected — the Pallas insert kernel
-is a single-device program (docs/pallas-insert-verdict.md).
+rejection).
 """
 
 from __future__ import annotations
@@ -49,14 +48,14 @@ from jax.sharding import Mesh
 
 from ..ops.buckets import SLOTS, bucket_of
 from ..ops.hashing import EMPTY
+from .carry import Carry, leaf_names
 from .partition import (
     WAVEFRONT_CARRY_RULES,
     build_mesh,
     match_partition_rules,
     replicated,
-    wavefront_carry_names,
 )
-from .wavefront import TpuChecker, _carry_avals
+from .wavefront import TpuChecker
 
 
 class MeshTpuChecker(TpuChecker):
@@ -76,13 +75,6 @@ class MeshTpuChecker(TpuChecker):
         n_devices: Optional[int] = None,
         **kw,
     ):
-        if kw.get("pallas"):
-            raise NotImplementedError(
-                "the Pallas insert kernel is a single-device program "
-                "(docs/pallas-insert-verdict.md); drop pallas=True for "
-                "the mesh engine"
-            )
-        kw["pallas"] = False  # neutralize STATERIGHT_TPU_PALLAS too
         self._mesh = mesh if mesh is not None else build_mesh(n_devices)
         self._mesh_stats_cache = None
         super().__init__(options, **kw)
@@ -105,43 +97,15 @@ class MeshTpuChecker(TpuChecker):
             ("mesh",) + tuple(d.id for d in self._mesh.devices.flat),
         )
 
-    def _place(self, avals):
+    def _place(self, avals: Carry) -> Carry:
         """One ``NamedSharding`` per carry buffer, by the partition rules
-        (``avals``: anything with the carry's shapes, in carry order)."""
-        names = wavefront_carry_names(
-            len(avals), checked=self._checked, por=self._por,
-            spill=bool(self._spill),
-        )
-        return match_partition_rules(
-            WAVEFRONT_CARRY_RULES, names, avals, self._mesh
-        )
-
-    def _carry_shardings(self, cap, qcap, batch):
-        return self._place(_carry_avals(
-            self.tensor, len(self._props), cap, qcap, batch,
-            self._checked, self._cartography, self._por,
-            self._spill_cfg if self._spill else None,
+        over the carry's own names.  The memory ledger reads
+        ``per_device_bytes`` off the same placement's shard shapes, so it
+        cannot drift from ``partition.py`` (telemetry/memory.py)."""
+        leaves, treedef = jax.tree.flatten(avals)
+        return treedef.unflatten(match_partition_rules(
+            WAVEFRONT_CARRY_RULES, leaf_names(avals), leaves, self._mesh
         ))
-
-    def _memory_spec_fn(self):
-        """The wavefront carry's own specs, each with the sharding the
-        rules give it: ``per_device_bytes`` is read off the shard shapes,
-        so it cannot drift from ``partition.py`` (telemetry/memory.py)."""
-        from ..telemetry.memory import BufferSpec
-
-        base = super()._memory_spec_fn()
-
-        def spec_fn(caps):
-            specs = base(caps)
-            placed = self._place(
-                [jax.ShapeDtypeStruct(s.shape, s.dtype) for s in specs]
-            )
-            return [
-                BufferSpec(s.name, s.shape, s.dtype, sh)
-                for s, sh in zip(specs, placed)
-            ]
-
-        return spec_fn
 
     def _memory_extra(self) -> dict:
         return {**super()._memory_extra(), "devices": self.n_devices}
@@ -152,7 +116,7 @@ class MeshTpuChecker(TpuChecker):
         cross-shard collectives; the traced computation — hence every
         count, verdict, and discovery — is untouched."""
         init_fn, run_fn = super()._build(cap, qcap, batch, cand)
-        shardings = self._carry_shardings(cap, qcap, batch)
+        shardings = self._place(self._avals(cap, qcap, batch))
         rep = replicated(self._mesh)
         mesh_init = jax.jit(init_fn, out_shardings=(shardings, rep))
         mesh_run = jax.jit(

@@ -122,7 +122,7 @@ def match_partition_rules(rules, names: Sequence[str], avals,
     first pattern that ``re.search``-matches a buffer's name decides its
     spec (a name no rule matches is an error — rule tables end with a
     catch-all on purpose, so a miss means the table and the carry layout
-    drifted apart).  Two guards override any matched spec:
+    drifted apart; so does a name without a buffer).  Two guards override any matched spec:
 
      - rank-0 buffers are replicated (nothing to shard);
      - a dimension whose global size the product of the spec's mesh axes
@@ -130,7 +130,7 @@ def match_partition_rules(rules, names: Sequence[str], avals,
        GSPMD shards, and replication is always semantically equivalent.
     """
     out = []
-    for name, aval in zip(names, avals):
+    for name, aval in zip(names, avals, strict=True):
         spec = None
         for pat, rule_spec in rules:
             if re.search(pat, name):
@@ -157,41 +157,3 @@ def match_partition_rules(rules, names: Sequence[str], avals,
             spec = P(*parts)
         out.append(NamedSharding(mesh, spec))
     return tuple(out)
-
-
-# Wavefront carry buffer names, in carry order (mirrors _SNAPSHOT_KEYS +
-# the optional tails _carry_avals appends).  The mesh engine derives the
-# names from the SAME flags it builds avals with, so the two cannot
-# disagree in length without tripping the zip in match_partition_rules.
-_BASE_CARRY_NAMES = (
-    "table_fp", "table_parent", "q_rows", "q_fp", "q_ebits",
-    "q_depth", "head", "tail", "unique", "scount", "disc", "maxdepth",
-    "status",
-)
-
-_SPILL_TAIL_NAMES = (
-    "spill_bloom", "spill_base", "spill_pend_fp", "spill_pend_rows",
-    "spill_pend_par", "spill_pend_ebt", "spill_pend_dep",
-    "spill_pend_n", "spill_stats",
-)
-
-
-def wavefront_carry_names(n_total: int, *, checked: bool = False,
-                          por: bool = False, spill: bool = False) -> tuple:
-    """Names for an ``n_total``-element wavefront carry built with these
-    feature flags (the cartography counter tail, whatever its length,
-    fills the remainder — it is replicated either way)."""
-    names = list(_BASE_CARRY_NAMES)
-    if checked:
-        names.append("err")
-    if por:
-        names += ["por_boost", "por_stats"]
-    if spill:
-        names += list(_SPILL_TAIL_NAMES)
-    if len(names) > n_total:
-        raise ValueError(
-            f"carry has {n_total} buffers but the flags imply at least "
-            f"{len(names)} — feature flags and carry layout disagree"
-        )
-    names += [f"cart_{i}" for i in range(n_total - len(names))]
-    return tuple(names)

@@ -76,7 +76,6 @@ from ..ops.buckets import (
     lane_compact,
     window_unique,
 )
-from ..ops.cartography import prefix_depth_hist, queue_depth_hist_np
 from ..ops.hashing import EMPTY, row_hash
 from ..telemetry.spans import (
     PROGRAM_LOAD,
@@ -97,6 +96,23 @@ from ..telemetry.spans import (
 from ..telemetry.spans import span as tel_span
 from ..testing import faults
 from ._base import WavefrontChecker
+from .carry import (
+    QUEUE_FIELDS,
+    SNAPSHOT_KEYS,
+    ST_DSTEPS,
+    Carry,
+    CartTail,
+    PorTail,
+    SpillTail,
+    carry_avals,
+    depth_hist,
+    fresh_tails,
+    queue_alloc,
+    read_stats,
+    repad_queue,
+    stats_np,
+    stats_of,
+)
 from .prewarm import LOWER_EVENT, CompileWatch
 
 _STATUS_OK = 0
@@ -118,83 +134,9 @@ _STATUS_TELEMETRY_NAMES = {
     _STATUS_SPILL_SYNC: "spill_sync",
 }
 
-# Carry tuple indices (shared by the jitted program and the host loop).
-# No occupancy-counts buffer exists: bucket occupancy is implicit in the
-# table (slots fill densely; see ops/buckets.py).
-_TFP, _TPL, _QROWS, _QFP, _QEBITS, _QDEPTH = 0, 1, 2, 3, 4, 5
-_HEAD, _TAIL, _UNIQUE, _SCOUNT, _DISC, _MAXDEPTH, _STATUS = (
-    6, 7, 8, 9, 10, 11, 12,
-)
-# checked mode only: the checkify Error pytree rides the carry tail
-# (snapshots zip against _SNAPSHOT_KEYS and so deliberately drop it — a
-# resumed checked run re-seeds an all-clear error)
-_ERR = 13
-# cartography mode only: the search counters (ops/cartography.py — action
-# histogram + per-property tallies; the depth histogram is queue-derived
-# at sync time, never carried) ride the carry tail AFTER the checked
-# error flag; snapshots drop them too (per-step tallies restart at a
-# resume boundary, like the error flag re-seed)
-
-# spill mode only (stateright_tpu/spill/, docs/spill.md): the spill tail
-# rides the carry AFTER the POR pair and BEFORE the cartography counters:
-# the device Bloom filter over the spilled fingerprint set (read-only on
-# device; the host sets bits at eviction boundaries), the spill base
-# (how many unique states live off-device — the growth trigger reads hot
-# occupancy as ``unique - spill_base``), the pending buffers holding
-# Bloom-positive candidates deferred to host resolution, the pending
-# count, and the deferred/on-device tally pair.  Offsets below are
-# relative to the engine's ``spill_start``.
-_SPILL_LEN = 9
-(_SP_BLOOM, _SP_BASE, _SP_PFP, _SP_PROWS, _SP_PPAR, _SP_PEBT, _SP_PDEP,
- _SP_PCOUNT, _SP_STATS) = range(_SPILL_LEN)
-# packed stats-vector section when spill is on: [pend_count, spill_base,
-# deferred_total, on_device_total]
-_SPILL_STATS_SECTION = 4
-
-_SNAPSHOT_KEYS = (
-    "table_fp", "table_parent", "q_rows", "q_fp", "q_ebits",
-    "q_depth", "head", "tail", "unique", "scount", "disc", "maxdepth",
-    "status",
-)
-
-# Packed stats-vector layout: [head, tail, unique, scount, maxdepth, status,
-# dsteps, disc...].  Shared by the device-side ``stats_of`` and the host
-# loop.  ``dsteps`` is the trip count of the device call's ``while_loop``
-# (0 from ``init_fn`` and from the host-side ``_stats_np``).
-_ST_HEAD, _ST_TAIL, _ST_UNIQUE, _ST_SCOUNT, _ST_MAXDEPTH, _ST_STATUS = range(6)
-_ST_DSTEPS = 6
-_ST_DISC = 7
-_STATS_CARRY_ORDER = (_HEAD, _TAIL, _UNIQUE, _SCOUNT, _MAXDEPTH, _STATUS)
-
-
-def _stats_np(carry, cart_start: Optional[int] = None,
-              por_start: Optional[int] = None,
-              spill_start: Optional[int] = None) -> np.ndarray:
-    """Host-side equivalent of the jitted ``stats_of`` (same layout).
-    ``por_start`` appends the POR stats triple (carry[por_start + 1]);
-    ``spill_start`` appends the spill section (pend count, spill base,
-    deferred/on-device tallies); ``cart_start`` appends the cartography
-    section: the queue-derived depth histogram first, then the counter
-    buffers (carry tail from that index on), exactly as the device
-    ``stats_of`` does."""
-    vals = [np.asarray(carry[i]) for i in _STATS_CARRY_ORDER] + [0] + list(
-        np.asarray(carry[_DISC])
-    )
-    if por_start is not None:
-        vals.extend(np.asarray(carry[por_start + 1]).reshape(-1))
-    if spill_start is not None:
-        vals.append(np.asarray(carry[spill_start + _SP_PCOUNT]))
-        vals.append(np.asarray(carry[spill_start + _SP_BASE]))
-        vals.extend(np.asarray(carry[spill_start + _SP_STATS]).reshape(-1))
-    if cart_start is not None:
-        vals.extend(_depth_hist(carry[_QDEPTH], carry[_TAIL]))
-        for arr in carry[cart_start:]:
-            vals.extend(np.asarray(arr).reshape(-1))
-    return np.asarray(vals, dtype=np.uint64)
-
 
 def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
-                  steps: int, target: Optional[int], pallas: bool = False,
+                  steps: int, target: Optional[int],
                   sym: bool = False, cand: Optional[int] = None,
                   checked: bool = False, prededup: bool = False,
                   cartography: bool = False, por=None, spill=None,
@@ -279,18 +221,7 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
     slim_queue = bool(
         mxu is not None and mxu.slim_queue and eff_cand % qchunk == 0
     )
-    # POR's cycle proviso appends a SECOND novel window per step (at
-    # tail + n_new): over-allocate one more window so both appends stay
-    # in bounds without clamping — a clamped dynamic_update_slice would
-    # silently shift the write onto live queue rows.  The spill inject
-    # program appends a pend_cap-wide window the same way, so its
-    # (larger) width governs the slack when the tier is armed.
-    if por is not None:
-        qalloc = qcap + 2 * m
-    elif spill is not None:
-        qalloc = qcap + max(spill[1], m)
-    else:
-        qalloc = qcap + m
+    qalloc = queue_alloc(qcap, m, por is not None, spill)
     n_props = len(props)
     ev_idx = [
         i for i, p in enumerate(props) if p.expectation is Expectation.EVENTUALLY
@@ -313,13 +244,6 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
         # reconstructs the full message anyway
         checked_kernels = checkify_kernels(tensor)
 
-    # carry tail layout: [base 13] + [err]? + [por boost, por stats]? +
-    # [spill tail]? + [cartography buffers]?  (snapshots keep only the
-    # base; every tail element re-seeds at resume — the spill tail from
-    # the snapshot's host-tier manifest)
-    por_start = (_ERR + 1) if checked else _ERR
-    spill_start = por_start + (2 if por is not None else 0)
-    cart_start = spill_start + (_SPILL_LEN if spill is not None else 0)
     if spill is not None:
         # spill tier (stateright_tpu/spill/, docs/spill.md): POR's
         # two-phase insert and the Bloom deferral do not compose yet —
@@ -328,24 +252,18 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
         from ..spill.bloom import bloom_test
 
         spill_bits, pend_cap = spill
-        palloc = pend_cap + m
     if por is not None:
         from ..analysis.footprint import conjunct_eval_fn
         from ..ops.por import ample_mask, candidate_novelty
 
         conjunct_kernel = conjunct_eval_fn(tensor)
-    # search-cartography counters (ops/cartography.py): carry tail AFTER
-    # the checked error flag — action histogram + property tallies only;
-    # the depth histogram is queue-derived at sync time (queue_depth_hist),
-    # so the per-step cost stays at two small column-sums.  Off means zero
-    # extra ops in the step jaxpr (same contract as
-    # telemetry/checked/prededup, pinned by test)
+    # search-cartography counters (ops/cartography.py): action histogram +
+    # property tallies only; the depth histogram is queue-derived at sync
+    # time (carry.stats_of), so the per-step cost stays at two small
+    # column-sums.  Off means zero extra ops in the step jaxpr (same
+    # contract as telemetry/checked/prededup, pinned by test)
     if cartography:
-        from ..ops.cartography import (
-            action_hist_delta,
-            prop_tally_delta,
-            queue_depth_hist,
-        )
+        from ..ops.cartography import action_hist_delta, prop_tally_delta
 
     def record_first(disc, i, hit, fps):
         """First-wins discovery of property ``i`` at the first hit row."""
@@ -438,10 +356,8 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
     def step(carry):
         """Pop one batch, expand, dedup+insert, append novel rows."""
         (tfp, tpl, qrows, qfp, qebits, qdepth, head, tail,
-         unique, scount, disc, maxdepth, status) = carry[:_ERR]
-        if checked:
-            err = carry[_ERR]
-        cart = carry[cart_start:]
+         unique, scount, disc, maxdepth, status) = carry.base()
+        err, cart, sp = carry.err, carry.cart, carry.spill
         with jax.named_scope(STAGE_POP):
             n_avail = tail - head
             rows = jax.lax.dynamic_slice(qrows, (head, jnp.int32(0)), (batch, width))
@@ -508,8 +424,7 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
                 # subset of each row's enabled actions; the boost scalar (set
                 # by the host at growth/resume boundaries) forces one fully
                 # expanded batch, and stays armed until a batch succeeds
-                boost = carry[por_start]
-                pstats = carry[por_start + 1]
+                boost, pstats = carry.por.boost, carry.por.stats
                 amp = ample_mask(valid, rows, por, conjunct_kernel)
                 amp = jnp.where(boost > 0, valid, amp)
                 v1 = amp
@@ -535,10 +450,9 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
                 # is a proof of off-device absence (no false negatives), so
                 # the common case never leaves the chip; before the first
                 # eviction the filter is all-zero and nothing defers.
-                sp_bloom = carry[spill_start + _SP_BLOOM]
                 fp_full = cand_fp
                 maybe_spilled = (cand_fp != EMPTY) & bloom_test(
-                    sp_bloom, cand_fp, spill_bits
+                    sp.bloom, cand_fp, spill_bits
                 )
                 cand_fp = jnp.where(maybe_spilled, EMPTY, cand_fp)
             cand_rows = succ.reshape(m, width)
@@ -556,8 +470,7 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
             # lanes; the compaction budget only bounds the pipeline width)
             tfp, tpl, sel, n_new, toverflow, coverflow = bucket_insert(
                 tfp, tpl, cand_fp, cand_par, window=batch,
-                use_pallas=pallas, generation_order=sym, compact=eff_cand,
-                probe_dot=probe_dot,
+                generation_order=sym, compact=eff_cand, probe_dot=probe_dot,
             )
         with jax.named_scope(STAGE_APPEND):
             # Append novel rows (novel-compacted ``sel`` prefix) at the queue
@@ -587,7 +500,7 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
                 tail1 = tail + n_new
                 tfp, tpl, sel2, n_new2, tovf2, covf2 = bucket_insert(
                     tfp, tpl, cand_fp2, cand_par, window=batch,
-                    use_pallas=pallas, generation_order=sym, compact=eff_cand,
+                    generation_order=sym, compact=eff_cand,
                     probe_dot=probe_dot,
                 )
             with jax.named_scope(STAGE_APPEND):
@@ -617,32 +530,34 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
                 # an overflowed batch — the cursor then does not advance, so
                 # the post-growth replay overwrites the same window (the
                 # counters' replay discipline).
-                pcount = carry[spill_start + _SP_PCOUNT]
-                sp_stats = carry[spill_start + _SP_STATS]
+                pcount = sp.pend_count
                 didx, dlive, n_def = lane_compact(maybe_spilled, m)
                 pfp_b = jax.lax.dynamic_update_slice(
-                    carry[spill_start + _SP_PFP],
+                    sp.pend_fp,
                     jnp.where(dlive, fp_full[didx], EMPTY), (pcount,),
                 )
                 prows_b = jax.lax.dynamic_update_slice(
-                    carry[spill_start + _SP_PROWS], cand_rows[didx],
-                    (pcount, jnp.int32(0)),
+                    sp.pend_rows, cand_rows[didx], (pcount, jnp.int32(0)),
                 )
                 ppar_b = jax.lax.dynamic_update_slice(
-                    carry[spill_start + _SP_PPAR], cand_par[didx], (pcount,)
+                    sp.pend_parent, cand_par[didx], (pcount,)
                 )
                 pebt_b = jax.lax.dynamic_update_slice(
-                    carry[spill_start + _SP_PEBT], cand_ebt[didx], (pcount,)
+                    sp.pend_ebits, cand_ebt[didx], (pcount,)
                 )
                 pdep_b = jax.lax.dynamic_update_slice(
-                    carry[spill_start + _SP_PDEP], cand_dep[didx], (pcount,)
+                    sp.pend_depth, cand_dep[didx], (pcount,)
                 )
                 pcount = pcount + jnp.where(overflow, jnp.int32(0), n_def)
                 d_sp = jnp.stack([
                     n_def.astype(jnp.int64),
                     jnp.sum(valid, dtype=jnp.int64) - n_def.astype(jnp.int64),
                 ])
-                sp_stats = sp_stats + jnp.where(overflow, jnp.int64(0), d_sp)
+                sp = sp.replace(
+                    pend_fp=pfp_b, pend_rows=prows_b, pend_parent=ppar_b,
+                    pend_ebits=pebt_b, pend_depth=pdep_b, pend_count=pcount,
+                    stats=sp.stats + jnp.where(overflow, jnp.int64(0), d_sp),
+                )
         with jax.named_scope(STAGE_BOOKKEEP):
             if por is not None:
                 tfp = jnp.where(overflow, tfp_pre, tfp)
@@ -677,22 +592,23 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
                 # nothing.)  Under POR the histogram counts what was actually
                 # GENERATED (ample + proviso re-expansions), which is what
                 # reconciles against scount.
-                act_hist, p_evals, p_hits = cart
                 zero = jnp.int64(0)
-                act_hist = act_hist + jnp.where(
+                act_hist = cart.action_hist + jnp.where(
                     overflow, zero, action_hist_delta(gen_mask)
                 )
                 d_evals, d_hits = prop_tally_delta(live, masks, n_props)
-                p_evals = p_evals + jnp.where(overflow, zero, d_evals)
-                p_hits = p_hits + jnp.where(overflow, zero, d_hits)
-                cart = (act_hist, p_evals, p_hits)
+                cart = CartTail(
+                    act_hist,
+                    cart.prop_evals + jnp.where(overflow, zero, d_evals),
+                    cart.prop_hits + jnp.where(overflow, zero, d_hits),
+                )
             # Clean-boundary growth triggers: past these thresholds the host
             # grows buffers and resumes (table target load ≤ 25%: the Poisson
             # bucket-overflow tail stays negligible).  With the spill tier
             # armed the trigger reads HOT occupancy — evicted uniques live
             # off-device and must not count against the hot table's load.
             if spill is not None:
-                hot_unique = unique - carry[spill_start + _SP_BASE]
+                hot_unique = unique - sp.base
             else:
                 hot_unique = unique
             status = jnp.where(
@@ -725,66 +641,24 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
                         jnp.int32(_STATUS_POISON),
                         status,
                     )
-        out = (tfp, tpl, qrows, qfp, qebits, qdepth, head, tail,
-               unique, scount, disc, maxdepth, status)
-        if checked:
-            out = out + (err,)
-        if por is not None:
-            out = out + (boost, pstats)
-        if spill is not None:
-            out = out + (
-                sp_bloom, carry[spill_start + _SP_BASE], pfp_b, prows_b,
-                ppar_b, pebt_b, pdep_b, pcount, sp_stats,
-            )
-        return out + tuple(cart)
+        return Carry(
+            tfp, tpl, qrows, qfp, qebits, qdepth, head, tail,
+            unique, scount, disc, maxdepth, status,
+            err=err,
+            por=PorTail(boost, pstats) if por is not None else None,
+            spill=sp, cart=cart,
+        )
 
     def cond(state):
         k, carry = state
-        go = (carry[_STATUS] == jnp.int32(_STATUS_OK)) & (k < steps)
-        go = go & (carry[_TAIL] > carry[_HEAD]) & ~all_discovered(carry[_DISC])
+        go = (carry.status == jnp.int32(_STATUS_OK)) & (k < steps)
+        go = go & (carry.tail > carry.head) & ~all_discovered(carry.disc)
         if target is not None:
-            go = go & (carry[_UNIQUE] < jnp.int64(target))
+            go = go & (carry.unique < jnp.int64(target))
         if checked:
             # stop at the first failing batch: the host raises from it
-            go = go & ~carry[_ERR]
+            go = go & ~carry.err
         return go
-
-    def stats_of(carry, dsteps):
-        """Pack every scalar the host loop reads into one small vector so a
-        host sync costs a single device round-trip.  Layout: ``_ST_*``;
-        ``dsteps`` is the device call's ``while_loop`` trip count."""
-        parts = [
-            jnp.stack(
-                [carry[i].astype(jnp.uint64) for i in _STATS_CARRY_ORDER]
-                + [dsteps.astype(jnp.uint64)]
-            ),
-            carry[_DISC],
-        ]
-        if por is not None:
-            # the reduced-vs-full tallies ride the same packed vector,
-            # right after the discovery fps (before any cartography)
-            parts.append(carry[por_start + 1].astype(jnp.uint64))
-        if spill is not None:
-            # spill section: pending count (the host's resolve trigger),
-            # the spill base, and the deferred/on-device tally pair —
-            # all on the SAME packed vector, no extra round-trip
-            parts.append(jnp.stack([
-                carry[spill_start + _SP_PCOUNT].astype(jnp.uint64),
-                carry[spill_start + _SP_BASE].astype(jnp.uint64),
-            ]))
-            parts.append(carry[spill_start + _SP_STATS].astype(jnp.uint64))
-        if cartography:
-            # the counters ride the SAME packed vector: cartography never
-            # adds a second host round-trip per sync.  The depth histogram
-            # is derived HERE — once per sync, from the depth-sorted queue
-            # (every fresh insert ever made sits in qdepth[:tail]) — so
-            # the per-step program pays nothing for it
-            parts.append(
-                queue_depth_hist(carry[_QDEPTH], carry[_TAIL])
-                .astype(jnp.uint64)
-            )
-            parts += [c.astype(jnp.uint64) for c in carry[cart_start:]]
-        return jnp.concatenate(parts)
 
     # The two programs' function names are their XLA module names
     # (``jit_wavefront_run`` / ``jit_wavefront_init``: the profiler's
@@ -819,8 +693,7 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
         tfp, tpl, sel, n_new, overflow, _ = bucket_insert(
             tfp, tpl, ifp,
             jnp.zeros((n_init,), jnp.uint64),  # parent 0 = "is an init state"
-            window=n_init, use_pallas=pallas, generation_order=sym,
-            probe_dot=probe_dot,
+            window=n_init, generation_order=sym, probe_dot=probe_dot,
         )
         qrows = jax.lax.dynamic_update_slice(
             qrows, irows[sel], (jnp.int32(0), jnp.int32(0))
@@ -840,41 +713,22 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
                 jnp.int32(_STATUS_OK),
             ),
         )
-        carry = (tfp, tpl, qrows, qfp, qebits, qdepth,
-                 jnp.int32(0), n_new,
-                 n_new.astype(jnp.int64),
-                 jnp.int64(n_init),  # state_count counts all inits (bfs parity)
-                 jnp.zeros((max(n_props, 1),), jnp.uint64),
-                 jnp.int32(0),
-                 status)
-        if checked:
-            carry = carry + (jnp.bool_(False),)
-        if por is not None:
-            # boost=0: the init batch is not a growth/resume boundary
-            carry = carry + (jnp.int32(0), jnp.zeros((3,), jnp.int64))
-        if spill is not None:
-            # all-zero Bloom (nothing spilled yet -> nothing ever defers),
-            # empty pending buffers, spill base 0
-            carry = carry + (
-                jnp.zeros((spill_bits // 32,), jnp.uint32),
-                jnp.int64(0),
-                jnp.full((palloc,), EMPTY, jnp.uint64),
-                jnp.zeros((palloc, width), jnp.uint64),
-                jnp.zeros((palloc,), jnp.uint64),
-                jnp.zeros((palloc,), jnp.uint32),
-                jnp.zeros((palloc,), jnp.uint32),
-                jnp.int32(0),
-                jnp.zeros((2,), jnp.int64),
-            )
-        if cartography:
-            # per-step tallies start at zero; the depth histogram is not
-            # carried — the init states' depth-0 lanes already sit in
-            # qdepth[:n_new], where stats_of derives the histogram
-            carry = carry + (
-                jnp.zeros((max(arity, 1),), jnp.int64),
-                jnp.zeros((max(n_props, 1),), jnp.int64),
-                jnp.zeros((max(n_props, 1),), jnp.int64),
-            )
+        carry = Carry(
+            tfp, tpl, qrows, qfp, qebits, qdepth,
+            jnp.int32(0), n_new,
+            n_new.astype(jnp.int64),
+            jnp.int64(n_init),  # state_count counts all inits (bfs parity)
+            jnp.zeros((max(n_props, 1),), jnp.uint64),
+            jnp.int32(0),
+            status,
+            # (boost 0: the init batch is not a growth/resume boundary; the
+            # depth histogram is not carried - the init states' depth-0
+            # lanes already sit in qdepth[:n_new], where stats_of derives it)
+            **fresh_tails(carry_avals(
+                tensor, n_props, cap, qcap, batch, checked, cartography,
+                por is not None, spill,
+            )),
+        )
         with jax.named_scope(STAGE_STATS):
             return carry, stats_of(carry, jnp.int32(0))
 
@@ -887,9 +741,7 @@ def _grown_cap(hot_unique: int, cap: int, cand: int, status: int) -> int:
     occupancy sits at or under 25% and the candidate budget fits
     (``cap >= 4 * cand``, the engine's actual precondition), or doubled
     once where a single bucket clustered past ``SLOTS`` entries; ``cap``
-    itself where the table is not what is full.  The one decision the
-    host path (``_grow``) and the device path (``_grow_on_device``)
-    share."""
+    itself where the table is not what is full."""
 
     def small(c: int) -> bool:
         return hot_unique * 4 > c or cand * 4 > c
@@ -901,24 +753,18 @@ def _grown_cap(hot_unique: int, cap: int, cand: int, status: int) -> int:
     return cap
 
 
-def _repad_queue(carry_np: list, qalloc: int) -> None:
-    """Pad (EMPTY/0 fill) or truncate the queue buffers to ``qalloc`` rows,
-    in place.  Shared by snapshot-resume and growth."""
-    for i in (_QROWS, _QFP, _QEBITS, _QDEPTH):
-        arr = np.asarray(carry_np[i])
-        if arr.shape[0] < qalloc:
-            pad_shape = (qalloc - arr.shape[0],) + arr.shape[1:]
-            fill = EMPTY if i == _QFP else 0
-            arr = np.concatenate([arr, np.full(pad_shape, fill, arr.dtype)])
-        carry_np[i] = arr[:qalloc] if arr.ndim == 1 else arr[:qalloc, :]
+def _array_bytes(carry: Carry) -> int:
+    """What the base's buffers weigh (its scalars apart): the bytes a
+    growth on the host moves each way."""
+    return sum(a.nbytes for a in carry.base() if a.ndim)
 
 
 def _slide_queue(qrows, qfp, qebits, qdepth, head, tail, *, qalloc: int):
     """The four queue buffers after a growth, where they lie: the live
     window ``[head, tail)`` at row 0 of buffers of ``qalloc`` rows, every
     lane past it the buffer's fill (``EMPTY`` for ``qfp``, 0 else) - bit
-    for bit what ``_grow_queue``'s slice and copy and :func:`_repad_queue`
-    leave on the host (the rows the step wrote past ``tail`` are garbage
+    for bit what ``TpuChecker._grow``'s slice and copy and
+    :func:`~.carry.repad_queue` leave on the host (the rows the step wrote past ``tail`` are garbage
     there too, and go the same way).  A buffer is padded by ``qalloc``
     rows of fill behind it, so the window's ``dynamic_slice`` never clamps
     whatever ``head`` is; the compiler fuses pad, slice and mask into one
@@ -950,72 +796,10 @@ _slide_queue_grown = jax.jit(_slide_queue, static_argnames="qalloc")
 _slide_queue_in_place = jax.jit(
     _slide_queue, static_argnames="qalloc", donate_argnums=(0, 1, 2, 3)
 )
-_prefix_depth_hist = jax.jit(prefix_depth_hist)
-
-
-def _depth_hist(qdepth, n) -> np.ndarray:
-    """The per-depth histogram of ``qdepth[:n]``, counted where the lanes
-    lie: of a queue on the device ``DEPTH_BINS`` words cross, not 4 B a
-    lane (a growth on the device, and the sync after it)."""
-    if isinstance(qdepth, jax.Array):
-        return np.asarray(_prefix_depth_hist(qdepth, n))
-    return queue_depth_hist_np(qdepth, int(np.asarray(n)))
-
-
-def _carry_avals(tensor, n_props: int, cap: int, qcap: int, batch: int,
-                 checked: bool, cartography: bool = False,
-                 por: bool = False, spill=None) -> tuple:
-    """Abstract carry signature of the engine built for these capacities —
-    what ahead-of-time compilation (``run_fn.lower(avals).compile()``)
-    needs instead of concrete arrays.  Must mirror ``init_fn``'s output
-    exactly (shapes, dtypes, tuple order); the prewarm test drives a
-    prewarmed executable with real carries, which pins the agreement."""
-    import jax
-
-    width, arity = tensor.width, tensor.max_actions
-    m = batch * arity
-    if por:
-        qalloc = qcap + 2 * m
-    elif spill:
-        qalloc = qcap + max(spill[1], m)
-    else:
-        qalloc = qcap + m
-    sds = jax.ShapeDtypeStruct
-    avals = (
-        sds((cap,), jnp.uint64), sds((cap,), jnp.uint64),
-        sds((qalloc, width), jnp.uint64), sds((qalloc,), jnp.uint64),
-        sds((qalloc,), jnp.uint32), sds((qalloc,), jnp.uint32),
-        sds((), jnp.int32), sds((), jnp.int32),
-        sds((), jnp.int64), sds((), jnp.int64),
-        sds((max(n_props, 1),), jnp.uint64),
-        sds((), jnp.int32), sds((), jnp.int32),
-    )
-    if checked:
-        avals = avals + (sds((), jnp.bool_),)
-    if por:
-        avals = avals + (sds((), jnp.int32), sds((3,), jnp.int64))
-    if spill:
-        spill_bits, pend_cap = spill
-        palloc = pend_cap + batch * arity
-        avals = avals + (
-            sds((spill_bits // 32,), jnp.uint32), sds((), jnp.int64),
-            sds((palloc,), jnp.uint64), sds((palloc, width), jnp.uint64),
-            sds((palloc,), jnp.uint64), sds((palloc,), jnp.uint32),
-            sds((palloc,), jnp.uint32), sds((), jnp.int32),
-            sds((2,), jnp.int64),
-        )
-    if cartography:
-        from ..ops.cartography import cart_carry_shapes
-
-        avals = avals + tuple(
-            sds(s, jnp.int64) for s in cart_carry_shapes(arity, n_props)
-        )
-    return avals
 
 
 def _build_inject(tensor, cap: int, qcap: int, batch: int,
-                  pallas: bool, sym: bool, checked: bool, spill,
-                  mxu=None):
+                  sym: bool, spill, mxu=None):
     """Jitted pending-injection program for the spill tier: insert one
     host-VERIFIED batch of novel ``(fp, row, parent, ebits, depth)``
     tuples into the hot table + queue, bump ``unique``/``tail``, and
@@ -1025,53 +809,48 @@ def _build_inject(tensor, cap: int, qcap: int, batch: int,
     whose fingerprint was meanwhile injected simply drops out.  Growth
     statuses mirror the step's; on a table overflow NOTHING is written
     and the host evicts-or-grows and retries."""
-    width, arity = tensor.width, tensor.max_actions
-    spill_bits, pend_cap = spill
-    spill_start = (_ERR + 1) if checked else _ERR  # por never composes
+    _, pend_cap = spill
     probe_dot = bool(mxu is not None and mxu.probe)
 
     @jax.jit
     def inject_fn(carry, ifp, irows, ipar, iebt, idep, n):
-        (tfp, tpl, qrows, qfp, qebits, qdepth, head, tail,
-         unique, scount, disc, maxdepth, status) = carry[:_ERR]
+        tail = carry.tail
         live = jnp.arange(pend_cap, dtype=jnp.int32) < n
         cfp = jnp.where(live, ifp, EMPTY)
         tfp, tpl, sel, n_new, tovf, _ = bucket_insert(
-            tfp, tpl, cfp, ipar, window=min(batch, pend_cap),
-            use_pallas=pallas, generation_order=sym, probe_dot=probe_dot,
+            carry.table_fp, carry.table_parent, cfp, ipar,
+            window=min(batch, pend_cap), generation_order=sym,
+            probe_dot=probe_dot,
         )
         qrows = jax.lax.dynamic_update_slice(
-            qrows, irows[sel], (tail, jnp.int32(0))
+            carry.q_rows, irows[sel], (tail, jnp.int32(0))
         )
-        qfp = jax.lax.dynamic_update_slice(qfp, cfp[sel], (tail,))
-        qebits = jax.lax.dynamic_update_slice(qebits, iebt[sel], (tail,))
-        qdepth = jax.lax.dynamic_update_slice(qdepth, idep[sel], (tail,))
+        qfp = jax.lax.dynamic_update_slice(carry.q_fp, cfp[sel], (tail,))
+        qebits = jax.lax.dynamic_update_slice(
+            carry.q_ebits, iebt[sel], (tail,)
+        )
+        qdepth = jax.lax.dynamic_update_slice(
+            carry.q_depth, idep[sel], (tail,)
+        )
         tail = tail + n_new
-        unique = unique + n_new.astype(jnp.int64)
-        base = carry[spill_start + _SP_BASE]
+        unique = carry.unique + n_new.astype(jnp.int64)
         status = jnp.where(
-            status == jnp.int32(_STATUS_SPILL_SYNC),
-            jnp.int32(_STATUS_OK), status,
+            carry.status == jnp.int32(_STATUS_SPILL_SYNC),
+            jnp.int32(_STATUS_OK), carry.status,
         )
         status = jnp.where(
-            tovf | ((unique - base) * 4 > cap),
+            tovf | ((unique - carry.spill.base) * 4 > cap),
             jnp.int32(_STATUS_TABLE_FULL),
             jnp.where(
                 tail > qcap, jnp.int32(_STATUS_QUEUE_FULL), status
             ),
         )
-        out = (tfp, tpl, qrows, qfp, qebits, qdepth, head, tail,
-               unique, scount, disc, maxdepth, status)
-        if checked:
-            out = out + (carry[_ERR],)
-        st = spill_start
-        out = out + (
-            carry[st + _SP_BLOOM], base, carry[st + _SP_PFP],
-            carry[st + _SP_PROWS], carry[st + _SP_PPAR],
-            carry[st + _SP_PEBT], carry[st + _SP_PDEP],
-            jnp.int32(0), carry[st + _SP_STATS],
+        out = carry.replace(
+            table_fp=tfp, table_parent=tpl, q_rows=qrows, q_fp=qfp,
+            q_ebits=qebits, q_depth=qdepth, tail=tail, unique=unique,
+            status=status,
+            spill=carry.spill.replace(pend_count=jnp.int32(0)),
         )
-        out = out + tuple(carry[st + _SPILL_LEN:])
         return out, jnp.stack([n_new, tovf.astype(jnp.int32)])
 
     return inject_fn
@@ -1104,17 +883,6 @@ class TpuChecker(WavefrontChecker):
     ``steps_per_call`` — device steps per host round-trip: the host syncs
     this often to refresh live counters and serve checkpoint requests.
     ``resume`` — a snapshot from :meth:`checkpoint` to continue from.
-    ``pallas`` — use the Pallas DMA insert kernel for the visited set
-    (``ops/pallas_insert.py``); default is the env knob
-    ``STATERIGHT_TPU_PALLAS=1`` (off otherwise).  The kernel compiles
-    under the installed Mosaic and keeps count parity on a v5e
-    (``chip_smoke.py`` leg E) and loses to the XLA windowed scatter
-    (paxos-3, one ``bench.py`` run on a v5e, PR 22: 148k vs 330k
-    states/s; ``docs/pallas-insert-verdict.md`` argues
-    tile-granularity DMA read-modify-write must lose at
-    ~1-candidate-per-block density).  The bench A/B measures both on
-    every run and reports whichever path wins (``bench.py``).
-    Single-device engine only: the mesh engine rejects ``pallas=True``.
     """
 
     def __init__(
@@ -1127,14 +895,11 @@ class TpuChecker(WavefrontChecker):
         steps_per_call: int = 64,
         sync: bool = False,
         resume: Optional[dict] = None,
-        pallas: Optional[bool] = None,
         cand: Optional[int] = None,
         spill_bloom_bits: Optional[int] = None,
         spill_dir: Optional[str] = None,
         spill_host_bytes: Optional[int] = None,
     ):
-        import os
-
         self._cap = max(_pow2(capacity), 4 * SLOTS)
         # spill-tier knobs (docs/spill.md); consumed by _init_spill when
         # the builder armed the tier (CheckerBuilder.spill() / --spill /
@@ -1142,9 +907,6 @@ class TpuChecker(WavefrontChecker):
         self._spill_bloom_bits = spill_bloom_bits
         self._spill_dir = spill_dir
         self._spill_host_bytes = spill_host_bytes
-        if pallas is None:
-            pallas = os.environ.get("STATERIGHT_TPU_PALLAS", "") == "1"
-        self._pallas = bool(pallas)
         # checked execution mode (builder.checked() / --checked): checkify
         # instrumentation of the model kernels; see _build_engine
         self._checked = bool(getattr(options, "checked_mode", False))
@@ -1176,7 +938,7 @@ class TpuChecker(WavefrontChecker):
         # step jaxpr bit-identical): the engine cache — in-memory and the
         # persistent XLA cache both — is unkeyed by the feature's absence
         key = (cap, qcap, batch, cand, self._steps, self._target,
-               self._pallas, self._symmetry is not None, self._checked,
+               self._symmetry is not None, self._checked,
                self._prededup, self._cartography, self._por)
         if self._spill:
             key = key + (("spill",) + self._spill_cfg)
@@ -1205,8 +967,7 @@ class TpuChecker(WavefrontChecker):
     def _build(self, cap, qcap, batch, cand):
         return _build_engine(
             self.tensor, self._props, cap, qcap, batch, self._steps,
-            self._target, pallas=self._pallas,
-            sym=self._symmetry is not None, cand=cand,
+            self._target, sym=self._symmetry is not None, cand=cand,
             checked=self._checked, prededup=self._prededup,
             cartography=self._cartography,
             por=self._por_plan if self._por else None,
@@ -1216,24 +977,33 @@ class TpuChecker(WavefrontChecker):
 
     # -- memory-ledger hooks (telemetry/memory.py) ---------------------------
 
+    def _avals(self, cap: int, qcap: int, batch: int) -> Carry:
+        """Abstract carry of THIS engine's build at these capacities."""
+        return carry_avals(
+            self.tensor, len(self._props), cap, qcap, batch,
+            self._checked, self._cartography, self._por,
+            self._spill_cfg if self._spill else None,
+        )
+
+    def _place(self, avals: Carry) -> Optional[Carry]:
+        """The sharding of each buffer of ``avals``; None on one device."""
+        return None
+
     def _memory_spec_fn(self):
         """Analytic per-buffer model of THIS engine's carry: derived from
-        ``_carry_avals`` (the prewarm-AOT signature), so the bytes
+        its abstract signature (what prewarm compiles for), so the bytes
         reconcile exactly against the live buffers (pinned by test)."""
-        from ..telemetry.memory import wavefront_specs
+        from ..telemetry.memory import carry_specs
 
-        tensor, n_props = self.tensor, len(self._props)
-        checked, cart, por = self._checked, self._cartography, self._por
-        spill = self._spill_cfg if self._spill else None
         batch = self._batch
 
         def spec_fn(caps):
-            return wavefront_specs(
-                tensor, n_props, int(caps["cap"]),
+            avals = self._avals(
+                int(caps["cap"]),
                 int(caps.get("qcap", max(int(caps["cap"]) // 2, 1))),
                 int(caps.get("batch", batch)),
-                checked=checked, cartography=cart, por=por, spill=spill,
             )
+            return carry_specs(avals, self._place(avals))
 
         return spec_fn
 
@@ -1261,21 +1031,6 @@ class TpuChecker(WavefrontChecker):
     def _memory_extra(self) -> dict:
         return {"queue_capacity": self._qcap}
 
-    @property
-    def _por_start(self) -> int:
-        """Carry index of the POR tail (boost scalar + stats triple)."""
-        return (_ERR + 1) if self._checked else _ERR
-
-    @property
-    def _spill_start(self) -> int:
-        """Carry index of the spill tail (bloom, base, pending, stats)."""
-        return self._por_start + (2 if self._por else 0)
-
-    @property
-    def _cart_start(self) -> int:
-        """Carry index where the cartography counter tail begins."""
-        return self._spill_start + (_SPILL_LEN if self._spill else 0)
-
     def _bank_depth_lanes(self, qdepth, n: int, sign: int = 1) -> None:
         """Fold the depth lanes of ``qdepth[:n]`` into the cartography
         depth bank (``sign=-1`` un-banks) — the ONE definition of the
@@ -1288,7 +1043,7 @@ class TpuChecker(WavefrontChecker):
 
         if self._cart_depth_base is None:
             self._cart_depth_base = np.zeros(DEPTH_BINS, np.int64)
-        hist = _depth_hist(qdepth, np.int32(n))
+        hist = depth_hist(qdepth, np.int32(n))
         if isinstance(qdepth, jax.Array) and self.flight_recorder is not None:
             self.flight_recorder.add_bytes(d2h=hist.nbytes)
         self._cart_depth_base += sign * hist
@@ -1453,28 +1208,31 @@ class TpuChecker(WavefrontChecker):
             {"cap": cap * 2, "qcap": qcap, "batch": batch},
         )
 
-    def _evict_hot_table(self, carry_np: list, tail_extra: list) -> list:
-        """Sweep the hot table into the host tier at a growth boundary:
-        append every occupied ``(fp, parent)`` to the spill store, fold
-        the evicted fingerprints into the Bloom mirror, clear the hot
-        table in place, and refresh the carry's bloom/base tail elements.
+    def _evict_hot_table(self, carry: Carry) -> Carry:
+        """Sweep the hot table (on the host) into the host tier at a
+        growth boundary: append every occupied ``(fp, parent)`` to the
+        spill store, fold the evicted fingerprints into the Bloom mirror,
+        and return the carry with the table cleared and the spill tail's
+        bloom/base refreshed.
         Exactness: evicted fingerprints remain reachable through the
         Bloom -> pending -> host-index path, and their parents merge back
         at trace reconstruction (``_parents``)."""
         from ..spill import SPILL_V
         from ..spill.bloom import bloom_est_false_pos, bloom_set_np
 
-        tfp, tpl = carry_np[_TFP], carry_np[_TPL]
+        tfp, tpl = carry.table_fp, carry.table_parent
         occ = tfp != np.uint64(EMPTY)
         fps, pars = tfp[occ], tpl[occ]
         self._spill_store.append(fps, pars)
         bloom_set_np(self._spill_bloom_np, fps)
-        carry_np[_TFP] = np.full(tfp.shape, EMPTY, np.uint64)
-        carry_np[_TPL] = np.zeros(tpl.shape, np.uint64)
-        off = self._spill_start - _ERR
-        tail_extra = list(tail_extra)
-        tail_extra[off + _SP_BLOOM] = jnp.asarray(self._spill_bloom_np)
-        tail_extra[off + _SP_BASE] = jnp.int64(len(self._spill_store))
+        carry = carry.replace(
+            table_fp=np.full(tfp.shape, EMPTY, np.uint64),
+            table_parent=np.zeros(tpl.shape, np.uint64),
+            spill=carry.spill.replace(
+                bloom=jnp.asarray(self._spill_bloom_np),
+                base=jnp.int64(len(self._spill_store)),
+            ),
+        )
         self._spill_tally["evictions"] += 1
         rec = self.flight_recorder
         if rec is not None:
@@ -1492,7 +1250,7 @@ class TpuChecker(WavefrontChecker):
                 ),
             )
             self._refresh_spill()
-        return tail_extra
+        return carry
 
     def _inject(self, cap, qcap, batch):
         """The compiled pending-injection program for these capacities
@@ -1506,9 +1264,8 @@ class TpuChecker(WavefrontChecker):
         fn = self._inject_cache.get(key)
         if fn is None:
             fn = _build_inject(
-                self.tensor, cap, qcap, batch, self._pallas,
-                self._symmetry is not None, self._checked, self._spill_cfg,
-                mxu=self._mxu,
+                self.tensor, cap, qcap, batch,
+                self._symmetry is not None, self._spill_cfg, mxu=self._mxu,
             )
             self._inject_cache[key] = fn
         return fn
@@ -1523,16 +1280,16 @@ class TpuChecker(WavefrontChecker):
         """
         from ..spill import SPILL_V
 
-        st = self._spill_start
+        sp = carry.spill
         bits, pend_cap = self._spill_cfg
-        n = int(np.asarray(carry[st + _SP_PCOUNT]))
+        n = int(np.asarray(sp.pend_count))
         if n == 0:
             return cap, qcap, carry
-        pfp = np.asarray(carry[st + _SP_PFP])[:n]
-        prows = np.asarray(carry[st + _SP_PROWS])[:n]
-        ppar = np.asarray(carry[st + _SP_PPAR])[:n]
-        pebt = np.asarray(carry[st + _SP_PEBT])[:n]
-        pdep = np.asarray(carry[st + _SP_PDEP])[:n]
+        pfp = np.asarray(sp.pend_fp)[:n]
+        prows = np.asarray(sp.pend_rows)[:n]
+        ppar = np.asarray(sp.pend_parent)[:n]
+        pebt = np.asarray(sp.pend_ebits)[:n]
+        pdep = np.asarray(sp.pend_depth)[:n]
         rec = self.flight_recorder
         if rec is not None:
             rec.add_bytes(d2h=pfp.nbytes + prows.nbytes + ppar.nbytes
@@ -1552,10 +1309,9 @@ class TpuChecker(WavefrontChecker):
         injected = 0
         if k == 0:
             # nothing to inject: clear the count with cheap eager updates
-            carry = list(carry)
-            carry[st + _SP_PCOUNT] = jnp.int32(0)
-            if int(np.asarray(carry[_STATUS])) == _STATUS_SPILL_SYNC:
-                carry[_STATUS] = jnp.int32(_STATUS_OK)
+            carry = carry.replace(spill=sp.replace(pend_count=jnp.int32(0)))
+            if int(np.asarray(carry.status)) == _STATUS_SPILL_SYNC:
+                carry = carry.replace(status=jnp.int32(_STATUS_OK))
         else:
             nfp = pfp[novel_idx]
             nrows = prows[novel_idx]
@@ -1575,11 +1331,10 @@ class TpuChecker(WavefrontChecker):
                 idep[:k] = ndep
                 args = tuple(jnp.asarray(a) for a in
                              (ifp, irows, ipar, iebt, idep))
-                out, io = self._inject(cap, qcap, batch)(
-                    tuple(carry), *args, jnp.int32(k)
+                carry, io = self._inject(cap, qcap, batch)(
+                    carry, *args, jnp.int32(k)
                 )
                 io = np.asarray(io)
-                carry = list(out)
                 if int(io[1]) == 0:
                     # tally what actually ENTERED the hot table: the
                     # inject's dedup drops hot-resident Bloom false
@@ -1587,10 +1342,11 @@ class TpuChecker(WavefrontChecker):
                     injected = int(io[0])
                     dups += k - injected
                     break
-                # the hot table cannot take the batch: evict-or-grow,
-                # rebuild the inject program for the new rung, retry
-                cap, qcap, carry = self._spill_inject_boundary(
-                    carry, cap, qcap, batch, cand
+                # the hot table cannot take the batch: evict under budget
+                # pressure, else grow (the decision the step boundary
+                # makes), rebuild the inject program for the new rung, retry
+                carry, cap, qcap, _, _ = self._grow(
+                    carry, _STATUS_TABLE_FULL, cap, qcap, batch, cand
                 )
                 # the boundary may have EVICTED: pending fps that were
                 # hot-resident (Bloom false positives the inject's
@@ -1620,38 +1376,21 @@ class TpuChecker(WavefrontChecker):
             self._refresh_spill()
         return cap, qcap, carry
 
-    def _spill_inject_boundary(self, carry, cap, qcap, batch, cand):
-        """Growth boundary hit from inside pending injection (the hot
-        table overflowed taking the batch): evict under budget pressure,
-        else grow — the same decision the step boundary makes."""
-        arity = self.tensor.max_actions
-        tail_extra = list(carry[_ERR:])
-        carry_np = [np.asarray(c) for c in carry[:_ERR]]
-        status = _STATUS_TABLE_FULL
-        if self._spill_should_evict(cap, qcap, batch):
-            tail_extra = self._evict_hot_table(carry_np, tail_extra)
-            status = _STATUS_OK
-        carry_np[_STATUS] = np.int32(_STATUS_OK)
-        cap, qcap, carry_np = self._grow(
-            carry_np, cap, qcap, batch, arity, status, cand
-        )
-        return cap, qcap, [jnp.asarray(c) for c in carry_np] + tail_extra
-
-    def _offload_queue_tail(self, carry_np: list, pending: int,
+    def _offload_queue_tail(self, queue: dict, pending: int,
                             qcap: int) -> int:
         """The queue outgrew a budget-blocked doubling: move the tail
         excess (the rows furthest from being popped) to the host FIFO;
         they re-enter via ``_queue_refill`` when the device queue drains.
-        Called from ``_grow`` AFTER the consumed-prefix compaction, so
-        live rows sit at ``[0:pending]``."""
+        ``queue`` holds the four (host) buffers AFTER the consumed-prefix
+        compaction, so live rows sit at ``[0:pending]``; returns how many
+        stay."""
         from ..spill import SPILL_V
 
         keep = max(qcap // 2, 1)
         if pending <= keep:
             return pending
         chunk = tuple(
-            np.asarray(carry_np[i][keep:pending]).copy()
-            for i in (_QROWS, _QFP, _QEBITS, _QDEPTH)
+            np.asarray(queue[k][keep:pending]).copy() for k in QUEUE_FIELDS
         )
         self._spill_qrows.append(chunk)
         # the offloaded rows leave qdepth[:tail], which the queue-derived
@@ -1660,7 +1399,6 @@ class TpuChecker(WavefrontChecker):
         # holds at every sync — including a run that ends (target hit,
         # all props discovered) with rows still in the host FIFO
         self._bank_depth_lanes(chunk[3], int(chunk[3].shape[0]))
-        carry_np[_TAIL] = np.int32(keep)
         moved = pending - keep
         self._spill_tally["queue_offloaded"] += moved
         rec = self.flight_recorder
@@ -1680,12 +1418,10 @@ class TpuChecker(WavefrontChecker):
         — rare by construction (once per ``qcap`` drained rows)."""
         from ..spill import SPILL_V
 
-        tail_extra = list(carry[_ERR:])
-        carry_np = [np.asarray(c).copy() for c in carry[:_ERR]]
-        head, tail = int(carry_np[_HEAD]), int(carry_np[_TAIL])
-        self._bank_depth_lanes(carry_np[_QDEPTH], head)
-        for i in (_QROWS, _QFP, _QEBITS, _QDEPTH):
-            carry_np[i] = carry_np[i][head:tail].copy()
+        carry = carry.pulled(QUEUE_FIELDS + ("head", "tail"))
+        head, tail = int(carry.head), int(carry.tail)
+        self._bank_depth_lanes(carry.q_depth, head)
+        live = [getattr(carry, k)[head:tail] for k in QUEUE_FIELDS]
         pending = tail - head
         room = qcap - pending
         taken = [[], [], [], []]
@@ -1708,11 +1444,14 @@ class TpuChecker(WavefrontChecker):
             self._bank_depth_lanes(take[3], cn, sign=-1)
             moved += cn
             room -= cn
-        for j, i in enumerate((_QROWS, _QFP, _QEBITS, _QDEPTH)):
-            carry_np[i] = np.concatenate([carry_np[i]] + taken[j])
-        carry_np[_HEAD] = np.int32(0)
-        carry_np[_TAIL] = np.int32(pending + moved)
-        _repad_queue(carry_np, self._qalloc(qcap, batch))
+        carry = repad_queue(
+            carry.replace(
+                head=np.int32(0), tail=np.int32(pending + moved),
+                **{k: np.concatenate([live[j]] + taken[j])
+                   for j, k in enumerate(QUEUE_FIELDS)},
+            ),
+            self._qalloc(qcap, batch),
+        )
         self._spill_tally["queue_refilled"] += moved
         rec = self.flight_recorder
         if rec is not None:
@@ -1722,7 +1461,7 @@ class TpuChecker(WavefrontChecker):
                               for c in self._spill_qrows),
             )
             self._refresh_spill()
-        return [jnp.asarray(c) for c in carry_np] + tail_extra
+        return carry.pushed(QUEUE_FIELDS + ("head", "tail"))
 
     def _restore_spill_host(self, snap: dict) -> None:
         """Restore the HOST half of the spill tier from the snapshot
@@ -1754,7 +1493,7 @@ class TpuChecker(WavefrontChecker):
                           "spill_q_depth")
             ))
 
-    def _spill_resume_tail(self, snap: dict) -> list:
+    def _spill_resume_tail(self, snap: dict) -> SpillTail:
         """Rebuild the spill CARRY tail at resume (host state already
         restored by ``_restore_spill_host``): the Bloom + base from the
         restored store, pending from the snapshot's mid-resolution
@@ -1778,13 +1517,13 @@ class TpuChecker(WavefrontChecker):
             ppar[:pn] = np.asarray(snap["spill_pend_parent"])[:pn]
             pebt[:pn] = np.asarray(snap["spill_pend_ebits"])[:pn]
             pdep[:pn] = np.asarray(snap["spill_pend_depth"])[:pn]
-        return [
+        return SpillTail(
             jnp.asarray(self._spill_bloom_np),
             jnp.int64(len(self._spill_store)),
             jnp.asarray(pfp), jnp.asarray(prows), jnp.asarray(ppar),
             jnp.asarray(pebt), jnp.asarray(pdep), jnp.int32(pn),
             jnp.zeros((2,), jnp.int64),
-        ]
+        )
 
     def _spilled(self) -> bool:
         return getattr(self, "_spill", False) and len(self._spill_store) > 0
@@ -1899,14 +1638,7 @@ class TpuChecker(WavefrontChecker):
             watch = CompileWatch() if rec is not None else None
             t0 = time.monotonic()
             try:
-                exe = _aot_compile(
-                    eng[1],
-                    _carry_avals(
-                        self.tensor, len(self._props), cap, qcap, batch,
-                        self._checked, self._cartography, self._por,
-                        self._spill_cfg if self._spill else None,
-                    ),
-                )
+                exe = _aot_compile(eng[1], self._avals(cap, qcap, batch))
             except Exception:  # noqa: BLE001 - fall back to the lazy path;
                 exe = None  # accounting must never break a run
             build = time.monotonic() - t0
@@ -1975,19 +1707,11 @@ class TpuChecker(WavefrontChecker):
         for (ncap, nqcap, ncand), key in zip(rungs, keys):
             if key in cache or self._prewarmer.scheduled(key):
                 continue
-            checked, n_props = self._checked, len(self._props)
-            cartography, por = self._cartography, self._por
-            spill = self._spill_cfg if self._spill else None
-            tensor = self.tensor
+            avals = self._avals(ncap, nqcap, batch)
 
-            def build(ncap=ncap, nqcap=nqcap, ncand=ncand):
+            def build(ncap=ncap, nqcap=nqcap, ncand=ncand, avals=avals):
                 init_fn, run_fn = self._build(ncap, nqcap, batch, ncand)
-                exe = _aot_compile(
-                    run_fn,
-                    _carry_avals(tensor, n_props, ncap, nqcap, batch,
-                                 checked, cartography, por, spill),
-                )
-                return init_fn, exe
+                return init_fn, _aot_compile(run_fn, avals)
             if self._prewarmer.schedule(key, build):
                 if self.flight_recorder is not None:
                     self.flight_recorder.add("prewarm_scheduled")
@@ -1998,11 +1722,11 @@ class TpuChecker(WavefrontChecker):
         offending row in the last popped batch window (per-row checkified
         replay reconstructs the full check message) and raise
         CheckedExecutionError."""
-        if not bool(np.asarray(carry[_ERR])):
+        if not bool(np.asarray(carry.err)):
             return
         from ..analysis.sanitizer import localize_checked_failure
 
-        qrows = np.asarray(carry[_QROWS])
+        qrows = np.asarray(carry.q_rows)
         # the failing batch sits at [head - batch, head) after a normal
         # pop, or [head, head + batch) when an overflow replay kept the
         # cursor — scan the union, clipped at tail (rows past tail are
@@ -2021,9 +1745,7 @@ class TpuChecker(WavefrontChecker):
             parent=None if self._done.is_set() else self._run_span_ctx,
             trace_id=self._trace_id,
         ):
-            snap = {
-                k: np.asarray(v) for k, v in zip(_SNAPSHOT_KEYS, carry)
-            }
+            snap = dict(zip(SNAPSHOT_KEYS, carry.pulled().base()))
         snap["cap"], snap["qcap"], snap["batch"] = cap, qcap, self._batch
         # self-tuned budget survives resume.  The run loop passes its LIVE
         # cand: self._cand is only written back when the run ends, so a
@@ -2071,19 +1793,12 @@ class TpuChecker(WavefrontChecker):
                     snap[k] = np.concatenate(
                         [c[j] for c in self._spill_qrows]
                     )
-            st = self._spill_start
-            pn = int(np.asarray(carry[st + _SP_PCOUNT]))
+            sp = carry.spill
+            pn = int(np.asarray(sp.pend_count))
             if pn > 0:
-                snap["spill_pend_fp"] = np.asarray(
-                    carry[st + _SP_PFP])[:pn]
-                snap["spill_pend_rows"] = np.asarray(
-                    carry[st + _SP_PROWS])[:pn]
-                snap["spill_pend_parent"] = np.asarray(
-                    carry[st + _SP_PPAR])[:pn]
-                snap["spill_pend_ebits"] = np.asarray(
-                    carry[st + _SP_PEBT])[:pn]
-                snap["spill_pend_depth"] = np.asarray(
-                    carry[st + _SP_PDEP])[:pn]
+                for k in ("fp", "rows", "parent", "ebits", "depth"):
+                    snap[f"spill_pend_{k}"] = np.asarray(
+                        getattr(sp, f"pend_{k}"))[:pn]
         return snap
 
     def _pre_run_validate(self) -> None:
@@ -2091,17 +1806,21 @@ class TpuChecker(WavefrontChecker):
             self._check_snapshot_sig(self._resume)
 
     def _qalloc(self, qcap: int, batch: int) -> int:
-        """Queue allocation for these capacities — must mirror the
-        engine's (POR over-allocates a second append window; the spill
-        inject's pend_cap-wide append governs when the tier is armed)."""
-        m = batch * self.tensor.max_actions
-        if self._por:
-            return qcap + 2 * m
-        if self._spill:
-            return qcap + max(self._spill_cfg[1], m)
-        return qcap + m
+        """Queue allocation of THIS engine's build for these capacities."""
+        return queue_alloc(
+            qcap, batch * self.tensor.max_actions, self._por,
+            self._spill_cfg if self._spill else None,
+        )
 
     def _snapshot_to_carry(self, snap: dict):
+        """``(cap, qcap, carry)`` of a snapshot: its thirteen buffers by
+        name, the queue re-padded to this build's allocation, and every
+        tail seeded anew - the failure flag clear, the tallies at zero
+        (totals keep counting; the depth histogram, queue-derived, comes
+        back COMPLETE, since the snapshot kept the queue), ``por.boost``
+        armed (a resume IS a boundary: one fully expanded batch), and the
+        spill tail rebuilt from the snapshot's host tier, whose pending
+        buffer a boundary checkpoint can carry."""
         self._check_snapshot_sig(snap)
         cap = int(snap["cap"])
         qcap = int(snap["qcap"])
@@ -2115,155 +1834,213 @@ class TpuChecker(WavefrontChecker):
         base = snap.get("cart_depth_base")
         if base is not None:
             self._cart_depth_base = np.asarray(base, np.int64).copy()
-        carry = [np.asarray(snap[k]) for k in _SNAPSHOT_KEYS]
-        # snapshots may have been taken at a different qalloc; re-pad
-        _repad_queue(carry, qalloc)
-        return cap, qcap, [jnp.asarray(c) for c in carry]
-
-    def _grow(self, carry_np: list, cap: int, qcap: int, batch: int,
-              arity: int, status: int, cand: int, parent=None):
-        """Grow whatever is (near) full; returns (cap, qcap, carry).
-        ``parent`` is the ``grow`` span the two phases here
-        (``grow.rehash``, ``grow.queue``) are children of.
-
-        Both conditions are always re-checked regardless of which status code
-        fired: table-full and queue-full can trip in the same batch, and
-        resuming with ``tail`` still past the high-water mark would let the
-        next append clamp its write window onto unexpanded queue rows.
-
-        The static table bound follows the engine's actual precondition,
-        ``cap >= 4*cand`` (the candidate budget caps how many inserts one
-        step attempts) — NOT the fully padded ``4*batch*arity``, which would
-        make the first growth event of any kind inflate the table to cover a
-        width the candidate-compaction pipeline exists to avoid paying for.
-
-        With the spill tier armed, the table trigger reads HOT occupancy
-        (``unique - spilled``) and a budget-blocked queue doubling
-        offloads the tail excess to the host FIFO instead of growing.
-        """
-        spill_base = (
-            len(self._spill_store) if getattr(self, "_spill", False) else 0
+        tails = fresh_tails(
+            self._avals(cap, qcap, self._batch).replace(spill=None)
         )
+        if self._por:
+            tails["por"] = tails["por"].replace(boost=jnp.int32(1))
+        if self._spill:
+            tails["spill"] = self._spill_resume_tail(snap)
+        carry = Carry(*(np.asarray(snap[k]) for k in SNAPSHOT_KEYS), **tails)
+        # snapshots may have been taken at a different qalloc; re-pad
+        return cap, qcap, repad_queue(carry, qalloc).pushed()
+
+    def _grows_on_device(self, carry: Carry) -> bool:
+        """Whether a growth event transforms ``carry`` where it lies or on
+        the host - by what the engine observes, not by a knob.  The spill
+        tier works on a carry that is on the host by design (its eviction,
+        its queue offload and its transient forecast); and the mesh engine
+        runs this loop over a carry sharded by bucket and by queue shard, a
+        head and a tail a shard, which no single-device program here
+        addresses (``_device_table`` draws the same two lines)."""
+        return (
+            not self._spill
+            and len(carry.table_fp.sharding.device_set) == 1
+        )
+
+    def _grow(self, carry: Carry, status: int, cap: int, qcap: int,
+              batch: int, cand: int, at=None, parent=None):
+        """Grow whatever is (near) full: ``(carry, cap, qcap, cand,
+        occupancy)``, ``occupancy`` the new table's per-bucket histogram
+        where a split on the device left one (still there; else None).
+        ``at`` is ``(head, tail, unique)`` where the caller holds them
+        (the sync that found the status), ``parent`` the ``grow`` span
+        the phases here are children of.
+
+        One decision.  A full candidate budget doubles ``cand`` - an
+        engine parameter, not a carry buffer; the insert wrote nothing,
+        so only the status word is cleared unless the table must follow
+        (``cap >= 4 * cand``, the engine's actual precondition - NOT the
+        padded ``4 * batch * arity``, a width the candidate compaction
+        exists to avoid paying for).  Otherwise table and queue are both
+        re-checked whichever status fired: they can trip in the same
+        batch, and a ``tail`` left past the high-water mark would let the
+        next append clamp its window onto unexpanded rows.  The spill
+        tier reads HOT occupancy (``unique - spilled``), evicts the hot
+        table INSTEAD of growing it where the next rung's migration does
+        not fit the device budget (the cleared table satisfies the
+        trigger at the same capacity), and offloads the queue's tail
+        excess to the host FIFO where a doubling does not.
+
+        Two executors, chosen by :meth:`_grows_on_device`: where the carry
+        lies, ``ops/buckets.bucket_split`` and :func:`_slide_queue`
+        (``grow.queue`` / ``grow.rehash`` wrap their dispatch - the device
+        time is the trace's ``sr.grow`` stage and shows in the next
+        ``wait`` - ``grow.pull`` what cartography banks of the popped
+        prefix, ``grow.push`` the three scalars rewritten); or the base
+        pulled, re-hashed and compacted in numpy and uploaded, bit for
+        bit the same carry (``tests/test_growth_on_device.py``).  The
+        tails stay where they are; ``por.boost`` is armed (growth is a
+        boundary: one fully expanded batch)."""
         rec = self.flight_recorder
         parent = parent or self._run_span_ctx
-        grown = _grown_cap(
-            int(carry_np[_UNIQUE]) - spill_base, cap, cand, status
+        head, tail, unique = at or (
+            int(np.asarray(v)) for v in (carry.head, carry.tail, carry.unique)
         )
-        if grown != cap:
-            cap = grown
-            with tel_span("grow.rehash", rec, parent=parent, cap=cap):
-                tfp, tpl = host_bucket_rehash(
-                    carry_np[_TFP], carry_np[_TPL], cap // SLOTS
-                )
-            carry_np[_TFP], carry_np[_TPL] = tfp, tpl
-        with tel_span("grow.queue", rec, parent=parent):
-            qcap = self._grow_queue(carry_np, cap, qcap, batch)
-        return cap, qcap, carry_np
-
-    def _grows_on_device(self, carry) -> bool:
-        """Whether a growth event transforms ``carry`` where it lies
-        (:meth:`_grow_on_device`) or on the host (:meth:`_grow`) - by what
-        the engine observes, not by a knob.  The spill tier works on a
-        carry that is on the host by design (its eviction, its queue
-        offload and its transient forecast); and the mesh engine runs this
-        loop over a carry sharded by bucket and by queue shard, a head and
-        a tail a shard, which no single-device program here addresses
-        (``_device_table`` draws the same two lines)."""
-        return not self._spill and len(carry[_TFP].sharding.device_set) == 1
-
-    def _grow_on_device(self, carry: list, cap: int, qcap: int, batch: int,
-                        status: int, cand: int, stats, parent):
-        """:meth:`_grow` where the carry lies: returns ``(cap, qcap,
-        occupancy)`` with ``carry``'s buffers replaced in place, bit for
-        bit what ``_grow`` leaves of the pulled carry, and ``occupancy``
-        the new table's per-bucket histogram, still on the device (None
-        where the table kept its size).
-
-        The host decides from what it already holds - ``unique``, ``head``
-        and ``tail`` are in ``stats``, the packed vector of the sync that
-        found the status - and dispatches two programs:
-        :func:`_slide_queue` for the queue's four buffers,
-        ``ops/buckets.bucket_split`` for the table's two.  No table or
-        queue buffer crosses to the host: ``grow.queue`` and
-        ``grow.rehash`` wrap the dispatch of the two transforms (their
-        device time is the ``sr.grow`` stage of the trace and shows in the
-        next ``wait``), ``grow.pull`` what cartography banks of the popped
-        prefix (``DEPTH_BINS`` words) and ``grow.push`` the three scalars
-        the host rewrites."""
-        rec = self.flight_recorder
-        head, tail = int(stats[_ST_HEAD]), int(stats[_ST_TAIL])
+        if status == _STATUS_CAND_FULL:
+            cand = min(cand * 2, batch * self.tensor.max_actions)
+        if carry.por is not None:
+            carry = carry.replace(por=carry.por.replace(boost=jnp.int32(1)))
+        if status == _STATUS_CAND_FULL and cand * 4 <= cap:
+            return (carry.replace(status=jnp.int32(_STATUS_OK)), cap, qcap,
+                    cand, None)
+        on_device = self._grows_on_device(carry)
         with tel_span("grow.pull", rec, parent=parent):
-            # the slide below drops the consumed prefix: bank its depth
-            # lanes first (see _grow_queue), counted where they lie
-            self._bank_depth_lanes(carry[_QDEPTH], head)
-        # the queue before the table: a slide's temporaries are the u32
-        # planes of what it reads and writes (up to 1.4 x the queue), so it
-        # runs while the table is still the small one
-        with tel_span("grow.queue", rec, parent=parent):
-            pending = tail - head
-            while pending * 2 > qcap:
-                qcap *= 2
-            qalloc = self._qalloc(qcap, batch)
-            slide = (
-                _slide_queue_in_place if carry[_QROWS].shape[0] == qalloc
-                else _slide_queue_grown
-            )
-            carry[_QROWS:_HEAD] = slide(
-                *carry[_QROWS:_HEAD], carry[_HEAD], carry[_TAIL],
-                qalloc=qalloc,
-            )
-        occupancy = None
-        grown = _grown_cap(int(stats[_ST_UNIQUE]), cap, cand, status)
-        if grown != cap:
-            cap = grown
-            with tel_span("grow.rehash", rec, parent=parent, cap=cap):
-                carry[_TFP], carry[_TPL], occupancy = bucket_split(
-                    carry[_TFP], carry[_TPL], new_nbuckets=cap // SLOTS
-                )
-        with tel_span("grow.push", rec, parent=parent):
-            scalars = (
-                (_HEAD, 0), (_TAIL, pending), (_STATUS, _STATUS_OK),
-            )
-            for i, value in scalars:
-                carry[i] = jnp.int32(value)
+            if not on_device:
+                carry = carry.pulled()
+            # the transforms drop the consumed queue prefix: bank its depth
+            # lanes first, or the queue-derived histogram
+            # (ops/cartography.queue_depth_hist) would forget every state
+            # popped before this growth - counted where they lie
+            self._bank_depth_lanes(carry.q_depth, head)
+        if not on_device:
             if rec is not None:
-                rec.add_bytes(h2d=4 * len(scalars))
-        return cap, qcap, occupancy
-
-    def _grow_queue(self, carry_np: list, cap: int, qcap: int,
-                    batch: int) -> int:
-        """The queue half of :meth:`_grow`: reclaim the consumed prefix,
-        double (or offload) while still needed, re-pad — in place; returns
-        the new ``qcap``."""
-        head, tail = int(carry_np[_HEAD]), int(carry_np[_TAIL])
-        pending = tail - head
-        # the compaction below drops the consumed queue prefix — bank its
-        # depth lanes first, or the queue-derived histogram
-        # (ops/cartography.queue_depth_hist) would forget every state
-        # popped before this growth.  Free: the carry is already on the
-        # host here.
-        self._bank_depth_lanes(carry_np[_QDEPTH], head)
-        # reclaim the consumed prefix; grow only if still needed
-        for i in (_QROWS, _QFP, _QEBITS, _QDEPTH):
-            carry_np[i] = carry_np[i][head:tail].copy()
-        carry_np[_HEAD] = np.int32(0)
-        carry_np[_TAIL] = np.int32(pending)
-        while pending * 2 > qcap:
-            if getattr(self, "_spill", False) and not (
-                self._spill_fits_transient(
-                    {"cap": cap, "qcap": qcap, "batch": batch},
-                    {"cap": cap, "qcap": qcap * 2, "batch": batch},
+                # the base just crossed to the host (and goes back after
+                # growth) - price it, and take the free occupancy sample
+                # growth boundaries offer
+                rec.add_bytes(d2h=_array_bytes(carry))
+                self._telemetry_occupancy(
+                    carry.table_fp, at="growth", transferred=False
                 )
+            if (
+                self._spill
+                and status == _STATUS_TABLE_FULL
+                and self._spill_should_evict(cap, qcap, batch)
             ):
-                # budget-blocked queue doubling: the frontier's tail
-                # excess moves to the host FIFO instead (re-injected by
-                # _queue_refill when the device queue drains)
-                pending = self._offload_queue_tail(carry_np, pending, qcap)
-                break
-            qcap *= 2
-        carry_np[_STATUS] = np.int32(_STATUS_OK)
-        _repad_queue(carry_np, self._qalloc(qcap, batch))
-        return qcap
+                carry = self._evict_hot_table(carry)
+                status = _STATUS_OK
+        spilled = len(self._spill_store) if self._spill else 0
+        old_cap, cap = cap, _grown_cap(unique - spilled, cap, cand, status)
+        pending, offload = tail - head, False
+        while pending * 2 > qcap and not offload:
+            offload = self._spill and not self._spill_fits_transient(
+                {"cap": cap, "qcap": qcap, "batch": batch},
+                {"cap": cap, "qcap": qcap * 2, "batch": batch},
+            )
+            if not offload:
+                qcap *= 2
+        qalloc = self._qalloc(qcap, batch)
+        occupancy = None
+        if on_device:
+            # the queue before the table: a slide's temporaries are the u32
+            # planes of what it reads and writes (up to 1.4 x the queue), so
+            # it runs while the table is still the small one
+            with tel_span("grow.queue", rec, parent=parent):
+                slide = (
+                    _slide_queue_in_place if carry.q_rows.shape[0] == qalloc
+                    else _slide_queue_grown
+                )
+                carry = carry.replace(**dict(zip(QUEUE_FIELDS, slide(
+                    *(getattr(carry, k) for k in QUEUE_FIELDS),
+                    carry.head, carry.tail, qalloc=qalloc,
+                ))))
+            if cap != old_cap:
+                with tel_span("grow.rehash", rec, parent=parent, cap=cap):
+                    tfp, tpl, occupancy = bucket_split(
+                        carry.table_fp, carry.table_parent,
+                        new_nbuckets=cap // SLOTS,
+                    )
+                carry = carry.replace(table_fp=tfp, table_parent=tpl)
+        else:
+            if cap != old_cap:
+                with tel_span("grow.rehash", rec, parent=parent, cap=cap):
+                    tfp, tpl = host_bucket_rehash(
+                        carry.table_fp, carry.table_parent, cap // SLOTS
+                    )
+                carry = carry.replace(table_fp=tfp, table_parent=tpl)
+            with tel_span("grow.queue", rec, parent=parent):
+                # reclaim the consumed prefix, then pad to the allocation
+                queue = {
+                    k: getattr(carry, k)[head:tail].copy()
+                    for k in QUEUE_FIELDS
+                }
+                if offload:
+                    # the frontier's tail excess moves to the host FIFO
+                    # (re-injected by _queue_refill when the device queue
+                    # drains)
+                    pending = self._offload_queue_tail(queue, pending, qcap)
+                carry = repad_queue(carry.replace(**queue), qalloc)
+        carry = carry.replace(
+            head=np.int32(0), tail=np.int32(pending),
+            status=np.int32(_STATUS_OK),
+        )
+        if rec is not None:
+            rec.add_bytes(h2d=12 if on_device else _array_bytes(carry))
+        # no block_until_ready here: what an upload leaves in flight shows
+        # in the next device_call's wait
+        with tel_span("grow.push", rec, parent=parent):
+            carry = carry.pushed(
+                ("head", "tail", "status") if on_device else None
+            )
+        return carry, cap, qcap, cand, occupancy
+
+    def _growth_event(self, carry: Carry, st, cap: int, qcap: int,
+                      batch: int, cand: int):
+        """One growth event of the run loop, found by the sync that read
+        ``st``: :meth:`_grow` under a ``grow`` span (its phases the span's
+        children - on the recorder's clock and, as ``sr/grow*``, the
+        profiler's), the ``growth`` record with what the event moved
+        between host and device, and the ``growth`` stage's seconds."""
+        rec = self.flight_recorder
+        status, unique = st.status, st.unique
+        # chaos seam: a growth boundary is where device OOM strikes in the
+        # wild (the migration transient) — the chaos suite injects
+        # RESOURCE_EXHAUSTED exactly here
+        faults.fire("growth", recorder=rec, status=status, unique=unique)
+        t_grow = time.monotonic()
+        self.growth_events.append((status, unique))
+        status_name = _STATUS_TELEMETRY_NAMES.get(status, str(status))
+        with tel_span(
+            "grow", rec, parent=self._run_span_ctx,
+            status=status_name, unique=unique, cap=cap,
+        ) as grow:
+            if rec is not None:
+                crossed = rec.counters()
+                event = rec.record(
+                    "growth", status=status_name,
+                    unique=unique, cap=cap, qcap=qcap, cand=cand,
+                    path="device" if self._grows_on_device(carry) else "host",
+                )
+                if status == _STATUS_CAND_FULL:
+                    rec.add("compaction_hits")
+                if self._cartography and getattr(self, "_live_cart", None):
+                    # growth boundaries are the cartography time series:
+                    # one ring record each (plus the closing "final")
+                    rec.record("cartography", at="growth", **self._live_cart)
+            out = self._grow(
+                carry, status, cap, qcap, batch, cand,
+                at=(st.head, st.tail, unique), parent=grow.ctx,
+            )
+            if rec is not None:
+                # what this event moved between host and device, by the
+                # recorder's own byte counters
+                now = rec.counters()
+                rec.amend(event, **{
+                    k: int(now.get(k, 0) - crossed.get(k, 0))
+                    for k in ("d2h_bytes", "h2d_bytes")
+                })
+        self._stage("growth", time.monotonic() - t_grow)
+        return out
 
     def _run(self):
         try:
@@ -2317,10 +2094,9 @@ class TpuChecker(WavefrontChecker):
                 if watch is not None:
                     d = watch.delta()
                     dispatch.set(jaxprs_traced=d["jaxprs_traced"])
-            carry = list(carry)
             with tel_span("wait", rec, parent=call.ctx) as wait:
                 stats = np.asarray(stats)
-            call.set(dsteps=int(stats[_ST_DSTEPS]))
+            call.set(dsteps=int(stats[ST_DSTEPS]))
         if rec is not None:
             dt = dispatch.fields["dur"]
             comp = min(self._record_programs(dispatch.ctx, d["events"]), dt)
@@ -2368,41 +2144,11 @@ class TpuChecker(WavefrontChecker):
             cand = min(self._cand, batch * arity)  # snapshot's tuned budget
             stats = None
             # a snapshot taken at a growth boundary still carries the flag
-            st = int(np.asarray(carry[_STATUS]))
+            st = int(np.asarray(carry.status))
             if st != _STATUS_OK:
-                if st == _STATUS_CAND_FULL:
-                    cand = min(cand * 2, batch * arity)
-                carry_np = [np.asarray(c) for c in carry]
-                cap, qcap, carry_np = self._grow(
-                    carry_np, cap, qcap, batch, arity, st, cand
+                carry, cap, qcap, cand, _ = self._grow(
+                    carry, st, cap, qcap, batch, cand
                 )
-                carry = [jnp.asarray(c) for c in carry_np]
-            if self._checked:
-                # snapshots never carry the error flag: re-seed all-clear
-                carry = list(carry) + [jnp.bool_(False)]
-            if self._por:
-                # a resume IS a snapshot boundary: the proviso arms one
-                # fully expanded batch (boost=1); the reduced-vs-full
-                # tallies restart at zero like the cartography counters
-                carry = list(carry) + [
-                    jnp.int32(1), jnp.zeros((3,), jnp.int64)
-                ]
-            if self._spill:
-                # the spill tail re-seeds from the snapshot's host-tier
-                # manifest: store + Bloom rebuilt, pending restored (a
-                # boundary checkpoint can carry deferred candidates)
-                carry = list(carry) + self._spill_resume_tail(self._resume)
-            if self._cartography:
-                # snapshots never carry the counters either: a resumed run
-                # restarts its per-step tallies at zero (totals keep
-                # counting, and the depth histogram — queue-derived — comes
-                # back COMPLETE, since the snapshot kept the queue)
-                from ..ops.cartography import cart_carry_shapes
-
-                carry = list(carry) + [
-                    jnp.zeros(s, jnp.int64)
-                    for s in cart_carry_shapes(arity, len(self._props))
-                ]
         else:
             while True:
                 init_fn, _ = self._engine(cap, qcap, batch, cand,
@@ -2413,7 +2159,7 @@ class TpuChecker(WavefrontChecker):
                 # than resuming an inconsistent carry.  A queue-full init is
                 # consistent (table + queue both hold every init row) and the
                 # main loop's generic growth compacts/extends it in place.
-                if int(stats[_ST_STATUS]) != _STATUS_TABLE_FULL:
+                if read_stats(stats, carry).status != _STATUS_TABLE_FULL:
                     break
                 n_init = len(self.model.init_states())
                 prev = cap
@@ -2426,19 +2172,8 @@ class TpuChecker(WavefrontChecker):
         occ_every = int(self._telemetry_opts.get("occupancy_every") or 0)
         syncs = 0
         hs = 0  # host-sync ordinal for the chaos seam (recorder-independent)
-        disc_len = max(len(self._props), 1)
-        cart_start = self._cart_start if self._cartography else None
-        por_start = self._por_start if self._por else None
-        spill_start = self._spill_start if self._spill else None
         if rec is not None:
-            meta = dict(
-                batch=batch, steps_per_call=self._steps, pallas=self._pallas,
-            )
-            if self._pallas:
-                from ..ops.pallas_insert import interpret_mode
-
-                meta["pallas_interpret"] = interpret_mode()
-            rec.update_meta(**meta)
+            rec.update_meta(batch=batch, steps_per_call=self._steps)
             if self._spill:
                 from ..spill import SPILL_V
                 from ..telemetry.memory import device_budget
@@ -2455,43 +2190,29 @@ class TpuChecker(WavefrontChecker):
         while True:
             # one host sync per iteration: the packed stats vector
             if stats is None:
-                stats = _stats_np(carry, cart_start, por_start, spill_start)
+                stats = stats_np(carry)
             elif grown_occ is not None:
                 # the device call behind this sync ran after the split, so
                 # its histogram is there to read at no wait: the occupancy
                 # sample a growth boundary offers, of the table it left
                 self._telemetry_occupancy_hist(grown_occ, at="growth")
                 grown_occ = None
-            head, tail, unique, scount, maxdepth, status = (
-                int(stats[_ST_HEAD]), int(stats[_ST_TAIL]),
-                int(stats[_ST_UNIQUE]), int(stats[_ST_SCOUNT]),
-                int(stats[_ST_MAXDEPTH]), int(stats[_ST_STATUS]),
-            )
-            dsteps = int(stats[_ST_DSTEPS])
+            st = read_stats(stats, carry)
+            head, tail, unique, scount, maxdepth, status, dsteps, disc = st[:8]
             self._device_steps += dsteps
-            disc = stats[_ST_DISC:_ST_DISC + disc_len]
             with self._live_lock:
                 self._live = (scount, unique, maxdepth)
-                self._live_disc = np.asarray(disc)
-            tail_off = _ST_DISC + disc_len
-            if self._por:
-                self._live_por = self._por_stats_dict(
-                    stats[tail_off:tail_off + 3]
-                )
-                tail_off += 3
+                self._live_disc = disc
+            if st.por is not None:
+                self._live_por = self._por_stats_dict(st.por)
             pend_live, spilled_live = 0, 0
-            if self._spill:
-                sp = stats[tail_off:tail_off + _SPILL_STATS_SECTION]
-                pend_live = int(sp[0])
-                spilled_live = int(sp[1])
-                self._spill_tally["deferred"] = int(sp[2])
-                self._spill_tally["on_device"] = int(sp[3])
-                tail_off += _SPILL_STATS_SECTION
-            if self._cartography:
-                self._sync_cartography(
-                    stats[tail_off:], states=scount, unique=unique
-                )
-            if self._checked and len(carry) > _ERR:
+            if st.spill is not None:
+                pend_live, spilled_live = int(st.spill[0]), int(st.spill[1])
+                self._spill_tally["deferred"] = int(st.spill[2])
+                self._spill_tally["on_device"] = int(st.spill[3])
+            if st.cart is not None:
+                self._sync_cartography(st.cart, states=scount, unique=unique)
+            if carry.err is not None:
                 # a failed kernel check raises HERE, before any growth or
                 # checkpoint handling touches the (possibly garbage) carry
                 self._raise_on_checked_error(carry, head, tail, batch)
@@ -2519,7 +2240,7 @@ class TpuChecker(WavefrontChecker):
                 )
                 if occ_every and syncs % occ_every == 0:
                     self._telemetry_occupancy(
-                        carry[_TFP], at=f"sync{syncs}", transferred=True
+                        carry.table_fp, at=f"sync{syncs}", transferred=True
                     )
                 if self._mem_ledger is not None:
                     # rung changes emit a ``memory`` ring record (the
@@ -2584,133 +2305,9 @@ class TpuChecker(WavefrontChecker):
                     "cover everything the configuration actually reaches)."
                 )
             if status != _STATUS_OK:
-                # chaos seam: a growth boundary is where device OOM
-                # strikes in the wild (the migration transient) — the
-                # chaos suite injects RESOURCE_EXHAUSTED exactly here
-                faults.fire(
-                    "growth", recorder=rec, status=status, unique=unique
+                carry, cap, qcap, cand, grown_occ = self._growth_event(
+                    carry, st, cap, qcap, batch, cand
                 )
-                t_grow = time.monotonic()
-                self.growth_events.append((status, unique))
-                status_name = _STATUS_TELEMETRY_NAMES.get(status, str(status))
-                # host seam span: one ``grow`` per growth event, its phases
-                # as children (pull / rehash / queue / push) — on the
-                # recorder's clock and, as ``sr/grow*``, the profiler's
-                on_device = self._grows_on_device(carry)
-                with tel_span(
-                    "grow", rec, parent=self._run_span_ctx,
-                    status=status_name, unique=unique, cap=cap,
-                ) as grow:
-                    if rec is not None:
-                        crossed = rec.counters()
-                        event = rec.record(
-                            "growth", status=status_name,
-                            unique=unique, cap=cap, qcap=qcap, cand=cand,
-                            path="device" if on_device else "host",
-                        )
-                        if status == _STATUS_CAND_FULL:
-                            rec.add("compaction_hits")
-                        if self._cartography and getattr(
-                            self, "_live_cart", None
-                        ):
-                            # growth boundaries are the cartography time
-                            # series: one ring record each (plus the
-                            # closing "final")
-                            rec.record(
-                                "cartography", at="growth", **self._live_cart
-                            )
-                    # the carry TAIL (checked error flag, cartography
-                    # counters) is not part of the growth transform: strip
-                    # it around the host-side growth and re-attach unchanged
-                    # after (the error check above already passed; the
-                    # counters are capacity-independent)
-                    tail_extra = list(carry[_ERR:])
-                    if self._por:
-                        # growth is a boundary: arm one fully expanded batch
-                        tail_extra[self._por_start - _ERR] = jnp.int32(1)
-                    carry = list(carry[:_ERR])
-                    if status == _STATUS_CAND_FULL:
-                        # the candidate budget is an engine parameter, not a
-                        # carry buffer: double it, clear the carry's status
-                        # word (the insert wrote nothing, so the carry is
-                        # otherwise consistent), rebuild, replay
-                        cand = min(cand * 2, batch * arity)
-                        carry[_STATUS] = jnp.int32(_STATUS_OK)
-                        if on_device and cand * 4 > cap:
-                            # one call: it doubles until the budget fits
-                            cap, qcap, grown_occ = self._grow_on_device(
-                                carry, cap, qcap, batch, _STATUS_TABLE_FULL,
-                                cand, stats, grow.ctx,
-                            )
-                        while cand * 4 > cap:
-                            with tel_span("grow.pull", rec, parent=grow.ctx):
-                                carry_np = [np.asarray(c) for c in carry]
-                            cap, qcap, carry_np = self._grow(
-                                carry_np, cap, qcap, batch, arity,
-                                _STATUS_TABLE_FULL, cand, parent=grow.ctx,
-                            )
-                            with tel_span("grow.push", rec, parent=grow.ctx):
-                                carry = [jnp.asarray(c) for c in carry_np]
-                        carry = list(carry) + tail_extra
-                    elif on_device:
-                        # a device program from one carry to a larger one:
-                        # no table or queue buffer crosses to the host
-                        cap, qcap, grown_occ = self._grow_on_device(
-                            carry, cap, qcap, batch, status, cand, stats,
-                            grow.ctx,
-                        )
-                        carry = carry + tail_extra
-                    else:
-                        with tel_span("grow.pull", rec, parent=grow.ctx):
-                            carry_np = [np.asarray(c) for c in carry]
-                        if rec is not None:
-                            # the whole carry just crossed to the host (and
-                            # goes back after growth) — price it, and take
-                            # the free occupancy sample growth boundaries
-                            # offer
-                            nbytes = sum(a.nbytes for a in carry_np if a.ndim)
-                            rec.add_bytes(d2h=nbytes)
-                            self._telemetry_occupancy(
-                                carry_np[_TFP], at="growth", transferred=False
-                            )
-                        if (
-                            self._spill
-                            and status == _STATUS_TABLE_FULL
-                            and self._spill_should_evict(cap, qcap, batch)
-                        ):
-                            # the tentpole move: the next rung's migration
-                            # transient does not fit the device budget, so
-                            # the hot table's contents spill to the host
-                            # tier at this boundary INSTEAD of growing (the
-                            # cleared table satisfies the trigger at the
-                            # same capacity)
-                            tail_extra = self._evict_hot_table(
-                                carry_np, tail_extra
-                            )
-                            status = _STATUS_OK
-                        cap, qcap, carry_np = self._grow(
-                            carry_np, cap, qcap, batch, arity, status, cand,
-                            parent=grow.ctx,
-                        )
-                        if rec is not None:
-                            rec.add_bytes(
-                                h2d=sum(a.nbytes for a in carry_np if a.ndim)
-                            )
-                        # no block_until_ready here: what the upload leaves
-                        # in flight shows in the next device_call's wait
-                        with tel_span("grow.push", rec, parent=grow.ctx):
-                            carry = [
-                                jnp.asarray(c) for c in carry_np
-                            ] + tail_extra
-                    if rec is not None:
-                        # what this event moved between host and device,
-                        # by the recorder's own byte counters
-                        now = rec.counters()
-                        rec.amend(event, **{
-                            k: int(now.get(k, 0) - crossed.get(k, 0))
-                            for k in ("d2h_bytes", "h2d_bytes")
-                        })
-                self._stage("growth", time.monotonic() - t_grow)
                 stats = None
                 continue
             if self._stop.is_set():
@@ -2750,7 +2347,7 @@ class TpuChecker(WavefrontChecker):
             _, run_fn = self._engine(cap, qcap, batch, cand)
             if self._profiler is not None:
                 self._profiler.maybe_start()
-            carry, stats = self._timed_device_call(run_fn, tuple(carry))
+            carry, stats = self._timed_device_call(run_fn, carry)
             if self._profiler is not None:
                 self._profiler.tick()
 
@@ -2763,7 +2360,7 @@ class TpuChecker(WavefrontChecker):
         if rec is not None and occ_every:
             # close the occupancy time series with the final table (an
             # explicit D2H pull, taken only when sampling was requested)
-            self._telemetry_occupancy(carry[_TFP], at="final",
+            self._telemetry_occupancy(carry.table_fp, at="final",
                                       transferred=True)
         # Keep final buffers on device; pulling the table/queue to the host
         # costs far more than the run's last batches, so snapshots and
@@ -2812,8 +2409,8 @@ class TpuChecker(WavefrontChecker):
 
     def _table_np(self):
         return (
-            np.asarray(self._final_carry[_TFP]),
-            np.asarray(self._final_carry[_TPL]),
+            np.asarray(self._final_carry.table_fp),
+            np.asarray(self._final_carry.table_parent),
         )
 
     def _device_table(self):
@@ -2822,7 +2419,8 @@ class TpuChecker(WavefrontChecker):
         bucket-sharded array is a collective nobody has priced), and not
         once the spill tier evicted (an eviction clears the WHOLE hot
         table, the init states with it, so every chain leaves it)."""
-        tfp, tpl = self._final_carry[_TFP], self._final_carry[_TPL]
+        tfp = self._final_carry.table_fp
+        tpl = self._final_carry.table_parent
         if self._spilled() or len(tfp.sharding.device_set) != 1:
             return None
         return tfp, tpl

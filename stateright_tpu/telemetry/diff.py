@@ -11,8 +11,8 @@ promises — and classifies the pair
  - ``ISOMORPHIC`` — property verdicts agree while explored counts differ,
    under a flag delta that promises verdict-isomorphism only
    (``--por``, ``--per-channel``, ``symmetry()``);
- - ``PERF-ONLY`` — the delta is pure perf knobs (prewarm, pallas,
-   compile cache, device/git drift): counts still must agree, and the
+ - ``PERF-ONLY`` — the delta is pure perf knobs (prewarm, compile cache,
+   device/git drift): counts still must agree, and the
    interesting difference is throughput;
  - ``DIVERGENT`` — a promised contract is broken; the ``violations``
    list names every break (machine-readable: rule + field + both sides).
@@ -28,7 +28,7 @@ table is the single place the diff engine encodes them):
  - *isomorphic* (``por``/``symmetry``, and an ``encoding`` delta):
    identical verdicts, explored counts may shrink (a reduction that
    GROWS the space is a violation).
- - *perf* (``prewarm``/``pallas``/``compile_cache``, ``device``/
+ - *perf* (``prewarm``/``compile_cache``, ``device``/
    ``git_rev`` drift): bit-identical counts; only wall-clock may move.
  - *incomparable* (different model or instance): no contract applies —
    the pair diverges with a single named ``incomparable`` violation.
@@ -74,8 +74,6 @@ FLAG_CLASS = {
     "por": "isomorphic",
     "symmetry": "isomorphic",
     "prewarm": "perf",
-    "pallas": "perf",
-    "pallas_interpret": "perf",
     "compile_cache": "perf",
     # the MXU recast knobs (ops/mxu.py): counts bit-identical by
     # contract, program shapes differ — a pure perf delta

@@ -16,7 +16,7 @@ Two reconciling views, deliberately separated:
    rows, cartography counters, POR tensors, scalars), derived from the
    engines' dtypes and shapes at the current capacity AND at every future
    growth rung.  Computable and testable on CPU: the wavefront specs are
-   derived from the engine's own ``_carry_avals`` (the same signature the
+   derived from the engine's own ``carry_avals`` (the same signature the
    prewarm AOT path compiles against, so agreement is already pinned),
    and ``tests/test_memory.py`` pins analytic bytes == the live engine
    buffers' ``nbytes`` EXACTLY, on one device and on a mesh.
@@ -119,13 +119,25 @@ def buffers_dict(specs: list) -> dict:
 
 # -- per-engine analytic models ----------------------------------------------
 
-# wavefront carry names, in exact carry order (parallel/wavefront.py
-# _SNAPSHOT_KEYS + the optional tails); zipped against _carry_avals so
-# shapes/dtypes can never drift from what the engine actually allocates
-_WAVEFRONT_NAMES = (
-    "table_fp", "table_parent", "q_rows", "q_fp", "q_ebits", "q_depth",
-    "head", "tail", "unique", "scount", "disc", "maxdepth", "status",
-)
+def carry_specs(avals, shardings=None) -> list:
+    """Per-buffer specs of the wavefront carry ``avals`` describes (a
+    ``parallel/carry.Carry`` of shapes): the carry's own buffers in its
+    own order under the ledger's names, so the analytic bytes reconcile
+    EXACTLY against the live buffers' nbytes.  ``shardings`` is the mesh
+    engine's placement of the same carry."""
+    import jax
+
+    from ..parallel.carry import ledger_name, leaf_names
+
+    leaves = jax.tree.leaves(avals)
+    placed = (
+        [None] * len(leaves) if shardings is None
+        else jax.tree.leaves(shardings)
+    )
+    return [
+        BufferSpec(ledger_name(n), a.shape, a.dtype, sh)
+        for n, a, sh in zip(leaf_names(avals), leaves, placed, strict=True)
+    ]
 
 
 def wavefront_specs(
@@ -133,37 +145,19 @@ def wavefront_specs(
     *, checked: bool = False, cartography: bool = False, por: bool = False,
     spill=None,
 ) -> list:
-    """Per-buffer specs of the wavefront carry at these
-    capacities — derived from the engine's own abstract carry signature
-    (``wavefront._carry_avals``, the prewarm-AOT contract), so the
-    analytic bytes reconcile EXACTLY against the live buffers' nbytes.
+    """:func:`carry_specs` of the single-device carry at these capacities
+    (``parallel/carry.carry_avals``, the prewarm-AOT contract).
     ``spill`` is the spill-tier config ``(bloom_bits, pend_cap)`` when
     the tier is armed: the Bloom filter and pending buffers are
     device-resident and count against the budget like any carry buffer
     (the HOST/DISK tier contents deliberately do not — they are what the
-    budget is being traded against).  The mesh engine places the same
-    specs (``MeshTpuChecker._memory_spec_fn``)."""
-    from ..parallel.wavefront import _carry_avals
+    budget is being traded against)."""
+    from ..parallel.carry import carry_avals
 
-    avals = _carry_avals(
+    return carry_specs(carry_avals(
         tensor, n_props, cap, qcap, batch, checked, cartography, por,
         spill,
-    )
-    names = list(_WAVEFRONT_NAMES)
-    if checked:
-        names.append("checked_err")
-    if por:
-        names += ["por_boost", "por_stats"]
-    if spill:
-        names += ["spill_bloom", "spill_base", "pend_fp", "pend_rows",
-                  "pend_parent", "pend_ebits", "pend_depth", "pend_count",
-                  "spill_stats"]
-    if cartography:
-        names += ["cart_action_hist", "cart_prop_evals", "cart_prop_hits"]
-    assert len(names) == len(avals), (len(names), len(avals))
-    return [
-        BufferSpec(n, a.shape, a.dtype) for n, a in zip(names, avals)
-    ]
+    ))
 
 
 # -- live device readings ----------------------------------------------------

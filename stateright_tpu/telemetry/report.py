@@ -176,17 +176,10 @@ def build_config(checker) -> dict:
         "por": bool(getattr(checker, "_por", False)),
         "symmetry": getattr(checker, "_symmetry", None) is not None,
         "prewarm": bool(getattr(checker, "_prewarm", False)),
-        "pallas": bool(getattr(checker, "_pallas", False)),
         "compile_cache": bool(
             getattr(checker, "_compile_cache_dir", None)
         ),
     }
-    if flags["pallas"]:
-        # Mosaic compiles the kernel only on a TPU: anywhere else the run
-        # was INTERPRETED, and the report must say so next to the flag
-        from ..ops.pallas_insert import interpret_mode
-
-        flags["pallas_interpret"] = interpret_mode()
     try:
         import jax
 

@@ -176,30 +176,6 @@ def test_perf_regression_guard_clean_run_emits_empty_list(bench_env, capsys):
     assert line["regressed"] == []
 
 
-def test_emit_prefers_winning_insert_path(bench_env, capsys):
-    b = _load_bench()
-    b.emit(
-        platform="tpu",
-        cpu_paxos3_states_per_sec=1000.0,
-        tpu_paxos3_states_per_sec=2000.0,
-        tpu_paxos3_sec=100.0,
-        tpu_paxos3_pallas_states_per_sec=3000.0,
-        tpu_paxos3_pallas_sec=66.7,
-    )
-    (line,) = _lines(capsys)
-    assert line["value"] == 3000.0  # best path wins
-    assert line["insert_path"] == "pallas"
-    # the fields describing the run stay mutually consistent: when the
-    # pallas path wins, rate AND wall-time come from the pallas run
-    assert line["tpu_paxos3_states_per_sec"] == 3000.0
-    assert line["tpu_paxos3_sec"] == 66.7
-    b.emit(tpu_paxos3_pallas_states_per_sec=1500.0)
-    (line2,) = _lines(capsys)
-    assert line2["value"] == 2000.0
-    assert line2["insert_path"] == "xla-scatter"
-    assert line2["tpu_paxos3_sec"] == 100.0
-
-
 def test_emit_suppresses_duplicate_lines(bench_env, capsys):
     b = _load_bench()
     b.emit(cpu_paxos3_states_per_sec=8000.0)

@@ -675,7 +675,7 @@ def test_compacted_insert_is_bit_identical_to_reference_pipeline(
 
 
 def ref_bucket_insert(
-    table_fp, table_payload, fps, payloads, window, use_pallas=False,
+    table_fp, table_payload, fps, payloads, window,
     generation_order=False, compact=None, probe_dot=False,
 ):
     """The pre-PR-36 ``bucket_insert`` body, verbatim."""
@@ -775,8 +775,7 @@ def ref_bucket_insert(
     n_new = jnp.where(blocked, 0, jnp.sum(novel)).astype(jnp.int32)
 
     # Compact novel candidates to the front.  Plain runs keep sorted-fp
-    # order (bucket-contiguous — the Pallas kernel then touches each line
-    # group once); the visited SET is order-independent there.  Symmetry
+    # order (bucket-contiguous); the visited SET is order-independent there.  Symmetry
     # runs compact in GENERATION order (original batch position): the dedup
     # key is the canonical fp of a not-necessarily-class-invariant
     # representative, so enqueue order decides which class member gets
@@ -806,32 +805,25 @@ def ref_bucket_insert(
         k, *_ = state
         return k * window < n_new  # n_new is 0 on overflow: nothing written
 
-    if use_pallas:
-        from .pallas_insert import pallas_scatter_insert
+    ptgt = padded(tgt, nslots)
+    pcfp = padded(cfp, EMPTY)
+    pcpl = padded(cpl, 0)
 
-        table_fp, table_payload = pallas_scatter_insert(
-            table_fp, table_payload, tgt, cfp, cpl, n_new
-        )
-    else:
-        ptgt = padded(tgt, nslots)
-        pcfp = padded(cfp, EMPTY)
-        pcpl = padded(cpl, 0)
+    def chunk_body(state):
+        k, tfp, tpl = state
+        off = k * window
+        t = jax.lax.dynamic_slice(ptgt, (off,), (window,))
+        f = jax.lax.dynamic_slice(pcfp, (off,), (window,))
+        p = jax.lax.dynamic_slice(pcpl, (off,), (window,))
+        in_range = jnp.arange(window, dtype=jnp.int32) + off < n_new
+        t = jnp.where(in_range, t, nslots)
+        tfp = tfp.at[t].set(f, mode="drop")
+        tpl = tpl.at[t].set(p, mode="drop")
+        return k + 1, tfp, tpl
 
-        def chunk_body(state):
-            k, tfp, tpl = state
-            off = k * window
-            t = jax.lax.dynamic_slice(ptgt, (off,), (window,))
-            f = jax.lax.dynamic_slice(pcfp, (off,), (window,))
-            p = jax.lax.dynamic_slice(pcpl, (off,), (window,))
-            in_range = jnp.arange(window, dtype=jnp.int32) + off < n_new
-            t = jnp.where(in_range, t, nslots)
-            tfp = tfp.at[t].set(f, mode="drop")
-            tpl = tpl.at[t].set(p, mode="drop")
-            return k + 1, tfp, tpl
-
-        _, table_fp, table_payload = jax.lax.while_loop(
-            chunk_cond, chunk_body, (jnp.int32(0), table_fp, table_payload)
-        )
+    _, table_fp, table_payload = jax.lax.while_loop(
+        chunk_cond, chunk_body, (jnp.int32(0), table_fp, table_payload)
+    )
 
     sel = order[perm]
     if cidx is not None:

@@ -76,7 +76,7 @@ def test_cartography_off_leaves_run_jaxpr_bit_identical():
         init_fn, run_fn = c._engine(c._cap, c._qcap, c._batch, c._cand)
         carry, _ = init_fn()
         # fresh lambda per call: make_jaxpr memoizes on fn identity
-        return str(jax.make_jaxpr(lambda cr: run_fn(cr))(tuple(carry)))
+        return str(jax.make_jaxpr(lambda cr: run_fn(cr))(carry))
 
     plain = run_jaxpr(False, False)
     assert plain == run_jaxpr(True, False)
@@ -151,11 +151,9 @@ def test_device_growth_banks_the_true_depths_bin_by_bin():
     assert all(g["path"] == "device" for g in growth)
 
     def lanes(checker):
-        from stateright_tpu.parallel.wavefront import _QDEPTH, _TAIL
-
         carry = checker._final_carry
         return np.bincount(
-            np.asarray(carry[_QDEPTH])[: int(carry[_TAIL])],
+            np.asarray(carry.q_depth)[: int(carry.tail)],
             minlength=c._cart_depth_base.size,
         )
 

@@ -68,7 +68,7 @@ def _wavefront_build_jaxpr(roofline: bool) -> str:
     init_fn, run_fn = c._build(c._cap, c._qcap, c._batch, c._cand)
     carry, _ = init_fn()
     # fresh lambda per call: make_jaxpr memoizes on fn identity
-    return str(jax.make_jaxpr(lambda cr: run_fn(cr))(tuple(carry)))
+    return str(jax.make_jaxpr(lambda cr: run_fn(cr))(carry))
 
 
 def test_roofline_leaves_run_jaxpr_bit_identical():

@@ -1,16 +1,17 @@
 """A growth event is a device program from one carry to a larger one (PR 48).
 
-``TpuChecker._grow_on_device`` splits every bucket where the table lies
-(``ops/buckets.bucket_split``) and slides the live queue window to row 0 of
-buffers of the new allocation (``wavefront._slide_queue``); the host decides
-from the packed stats vector it already holds.  Held here, on XLA:CPU:
+``TpuChecker._grow``, where ``_grows_on_device`` says so, splits every bucket
+where the table lies (``ops/buckets.bucket_split``) and slides the live queue
+window to row 0 of buffers of the new allocation (``wavefront._slide_queue``);
+the host decides from the packed stats vector it already holds.  Held here, on
+XLA:CPU:
 
  - a check from a tiny table and queue (seven table growths, queue doublings,
    candidate-budget doublings) equals the presized check in ``unique``,
    ``states``, max depth and every discovery path, says ``path="device"`` on
    every ``growth`` record, and moves under 4 KB across over all of them;
- - the carry after EVERY growth of such a run equals ``_grow`` applied to the
-   pulled carry, buffer by buffer and bit for bit - and so does a jump of
+ - the carry after EVERY growth of such a run equals the host executor's of
+   the pulled carry, buffer by buffer and bit for bit - and so does a jump of
    several rungs at once, which the run loop itself never asks for (a step
    inserts at most ``cand <= cap / 4`` states past a load of 25%);
  - who keeps the host path: a spill-armed run says ``path="host"``.
@@ -26,6 +27,7 @@ import jax
 
 from stateright_tpu.models.two_phase_commit import TwoPhaseSys
 from stateright_tpu.parallel import wavefront as wf
+from stateright_tpu.parallel.carry import QUEUE_FIELDS, ST_DISC, leaf_names
 from stateright_tpu.telemetry.memory import ENV_DEVICE_BYTES
 
 # the smallest table the engine takes (4 buckets) under a queue of 64 rows
@@ -44,54 +46,61 @@ def _check(**spawn):
 
 
 def _equal_carries(got, want):
-    assert len(got) == len(want)
-    for i, (g, w) in enumerate(zip(got, want)):
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    names = leaf_names(got)
+    for name, g, w in zip(names, jax.tree.leaves(got), jax.tree.leaves(want)):
         g, w = np.asarray(g), np.asarray(w)
-        assert g.dtype == w.dtype and g.shape == w.shape, (i, g.shape, w.shape)
-        np.testing.assert_array_equal(g, w, err_msg=f"carry[{i}]")
+        assert g.dtype == w.dtype and g.shape == w.shape, (name, g.shape, w.shape)
+        np.testing.assert_array_equal(g, w, err_msg=name)
 
 
 def _held_to_the_host(real, seen):
-    """``_grow_on_device`` with every call of it held to ``_grow`` on the
-    pulled carry, every buffer of the thirteen, bit for bit; the FIRST call
-    is preceded by a jump of three rungs at once on a copy of its carry."""
+    """``_grow`` with every call of it that transforms the carry where it
+    lies held to the host executor on the pulled carry, every buffer of
+    the thirteen, bit for bit; the FIRST such call is preceded by a jump of
+    three rungs at once on a copy of its carry."""
 
-    def on_device(self, carry, cap, qcap, batch, status, cand, stats, parent):
-        pulled = [np.asarray(c) for c in carry]
+    def grow(self, carry, status, cap, qcap, batch, cand, at=None, parent=None):
+        assert self._grows_on_device(carry)
+        pulled = carry.pulled()
 
-        def host(cand):
-            return self._grow(
-                [p.copy() for p in pulled], cap, qcap, batch,
-                self.tensor.max_actions, status, cand,
-            )
+        def host(status, cand):
+            # the other executor, off the record: no span, no byte counted
+            with pytest.MonkeyPatch.context() as patch:
+                patch.setattr(self, "flight_recorder", None)
+                patch.setattr(self, "_grows_on_device", lambda carry: False)
+                return real(self, pulled, status, cap, qcap, batch, cand, at=at)
 
         if not seen:
             # a budget no run reaches at this table: 64 -> 512 slots
-            jump = list(carry)
-            got = real(self, jump, cap, qcap, batch, status, cap * 2, stats,
-                       parent)
-            want = host(cap * 2)
-            assert got[0] == want[0] == cap * 8 and got[1] == want[1]
-            _equal_carries(jump, want[2])
-            seen.append(("jump", cap, got[0]))
+            full = wf._STATUS_TABLE_FULL
+            got = real(self, carry, full, cap, qcap, batch, cap * 2, at=at,
+                       parent=parent)
+            want = host(full, cap * 2)
+            assert got[1] == want[1] == cap * 8 and got[2:4] == want[2:4]
+            _equal_carries(got[0], want[0])
+            seen.append(("jump", cap, got[1]))
             # the jump's slides ran in place: hand the run its buffers back
-            carry[:] = [jax.numpy.asarray(p) for p in pulled]
-        out = real(self, carry, cap, qcap, batch, status, cand, stats, parent)
-        want = host(cand)
-        assert out[:2] == want[:2]
-        _equal_carries(carry, want[2])
-        assert all(isinstance(c, jax.Array) for c in carry)
-        if out[2] is not None:
+            carry = pulled.pushed()
+        out = real(self, carry, status, cap, qcap, batch, cand, at=at,
+                   parent=parent)
+        want = host(status, cand)
+        assert out[1:4] == want[1:4]
+        _equal_carries(out[0], want[0])
+        assert all(isinstance(c, jax.Array) for c in jax.tree.leaves(out[0]))
+        if out[4] is not None:
             per_bucket = (
-                want[2][wf._TFP].reshape(-1, wf.SLOTS) != wf.EMPTY
+                np.asarray(want[0].table_fp).reshape(-1, wf.SLOTS) != wf.EMPTY
             ).sum(axis=1)
-            assert np.asarray(out[2]).tolist() == np.bincount(
+            assert np.asarray(out[4]).tolist() == np.bincount(
                 per_bucket, minlength=wf.SLOTS + 1
             ).tolist()
-        seen.append((status, cap, out[0], qcap, out[1]))
+        if out[0].q_rows is not carry.q_rows:
+            # (a candidate budget that fits the table transforms nothing)
+            seen.append((status, cap, out[1], qcap, out[2]))
         return out
 
-    return on_device
+    return grow
 
 
 @pytest.fixture(scope="module")
@@ -101,8 +110,8 @@ def grown():
     seen = []
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(
-            wf.TpuChecker, "_grow_on_device",
-            _held_to_the_host(wf.TpuChecker._grow_on_device, seen),
+            wf.TpuChecker, "_grow",
+            _held_to_the_host(wf.TpuChecker._grow, seen),
         )
         return _check(**TINY), seen
 
@@ -132,8 +141,8 @@ def test_the_carry_after_every_growth_is_the_hosts_bit_for_bit(grown):
     assert sum(1 for e in events if e[2] > e[1]) >= 3  # table growths
     assert sum(1 for e in events if e[4] > e[3]) >= 1  # queue doublings
     assert any(e[2] == e[1] for e in events)  # a slide alone
-    for i in (wf._QROWS, wf._QFP, wf._QEBITS, wf._QDEPTH):
-        assert c._final_carry[i].shape[0] == c._qalloc(c._qcap, c._batch)
+    for k in QUEUE_FIELDS:
+        assert getattr(c._final_carry, k).shape[0] == c._qalloc(c._qcap, c._batch)
 
 
 def test_every_growth_says_device_and_moves_bytes_not_buffers(grown):
@@ -157,7 +166,7 @@ def test_every_growth_says_device_and_moves_bytes_not_buffers(grown):
     # over the whole run: what crossed besides the packed stats vector of
     # each sync stays under 4 KB (one buffer of this carry is more)
     syncs = len(rec.records("step"))
-    stats_bytes = 8 * (wf._ST_DISC + len(c._props))
+    stats_bytes = 8 * (ST_DISC + len(c._props))
     crossed = rec.counters()["d2h_bytes"] - syncs * stats_bytes
     assert 0 < crossed < 4096
     assert rec.counters()["h2d_bytes"] < 4096

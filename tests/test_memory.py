@@ -59,7 +59,7 @@ def test_wavefront_analytic_bytes_reconcile_exactly():
     specs = c._memory_spec_fn()(
         {"cap": c._cap, "qcap": c._qcap, "batch": c._batch}
     )
-    carry = c._final_carry
+    carry = jax.tree.leaves(c._final_carry)
     assert len(specs) == len(carry)
     for s, arr in zip(specs, carry):
         a = np.asarray(arr)
@@ -82,7 +82,7 @@ def test_mesh_analytic_bytes_reconcile_exactly():
         .spawn_tpu(sync=True, devices=2, capacity=1 << 12)
     )
     specs = c._memory_spec_fn()(c._memory_caps())
-    carry = c._final_carry
+    carry = jax.tree.leaves(c._final_carry)
     assert len(specs) == len(carry)
     for s, arr in zip(specs, carry):
         assert arr.nbytes == s.nbytes, (s.name, arr.nbytes, s.nbytes)
@@ -132,7 +132,7 @@ def _wavefront_build_jaxpr(memory: bool) -> str:
     init_fn, run_fn = c._build(c._cap, c._qcap, c._batch, c._cand)
     carry, _ = init_fn()
     # fresh lambda per call: make_jaxpr memoizes on fn identity
-    return str(jax.make_jaxpr(lambda cr: run_fn(cr))(tuple(carry)))
+    return str(jax.make_jaxpr(lambda cr: run_fn(cr))(carry))
 
 
 def test_ledger_leaves_run_jaxpr_bit_identical():

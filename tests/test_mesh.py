@@ -48,7 +48,6 @@ from stateright_tpu.parallel.partition import (
     build_mesh,
     match_partition_rules,
     resolve_mesh_flag,
-    wavefront_carry_names,
 )
 from stateright_tpu.parallel.wavefront import TpuChecker
 
@@ -172,7 +171,7 @@ def test_mesh_growth_preserves_work_and_sharding():
     ref = _solo_spawn(m, capacity=1 << 12, batch=128)
     assert mesh.unique_state_count() == ref.unique_state_count()
     assert mesh.state_count() == ref.state_count()
-    table = mesh._final_carry[0]
+    table = mesh._final_carry.table_fp
     assert table.sharding.spec == P(MESH_AXES)
     assert not table.sharding.is_fully_replicated
     assert len(table.addressable_shards) == 8
@@ -484,11 +483,7 @@ def test_sweep_x_mesh_is_fenced():
         TwoPhaseSys(3).checker().sweep(spec).mesh().spawn_tpu(sync=True)
 
 
-def test_mesh_rejects_pallas_and_oversized_mesh():
-    with pytest.raises(NotImplementedError, match="[Pp]allas"):
-        TwoPhaseSys(3).checker().mesh().spawn_tpu(
-            sync=True, pallas=True, capacity=1 << 12, batch=64
-        )
+def test_mesh_rejects_oversized_mesh():
     with pytest.raises(ValueError, match="visible"):
         build_mesh(n_devices=99)
 
@@ -539,15 +534,6 @@ def test_match_partition_rules_guards():
             ((r"^table_", P(MESH_AXES)),), ("stray",),
             (jax.ShapeDtypeStruct((8,), np.int32),), mesh,
         )
-
-
-def test_wavefront_carry_names_flag_guards():
-    base = wavefront_carry_names(13)
-    assert base[0] == "table_fp" and base[12] == "status"
-    with_err = wavefront_carry_names(16, checked=True)
-    assert with_err[13] == "err" and with_err[14] == "cart_0"
-    with pytest.raises(ValueError, match="carry has"):
-        wavefront_carry_names(13, checked=True, por=True)
 
 
 # -- one engine, no hand-written collectives ----------------------------------

@@ -164,7 +164,7 @@ def test_metrics_attach_adds_zero_ops_to_step_jaxpr():
         c = _spawn_2pc3(metrics)
         init_fn, run_fn = c._engine(c._cap, c._qcap, c._batch, c._cand)
         carry, _ = init_fn()
-        return str(jax.make_jaxpr(lambda cr: run_fn(cr))(tuple(carry)))
+        return str(jax.make_jaxpr(lambda cr: run_fn(cr))(carry))
 
     assert run_jaxpr(False) == run_jaxpr(True)
 

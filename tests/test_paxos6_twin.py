@@ -178,7 +178,6 @@ def _run_program(model, **spawn):
     c.join()
     init_fn, run_fn = c._engine(c._cap, c._qcap, c._batch, c._cand)
     carry, _ = init_fn()
-    carry = tuple(carry)
     return c, run_fn.trace(carry).jaxpr, run_fn.lower(carry).as_text(
         debug_info=True)
 

@@ -179,7 +179,7 @@ def test_a_lossy_twins_run_program_names_its_drop_block_inside_expand(device_che
     _, c = device_check
     init_fn, run_fn = c._engine(c._cap, c._qcap, c._batch, c._cand)
     carry, _ = init_fn()
-    text = run_fn.lower(tuple(carry)).as_text(debug_info=True)
+    text = run_fn.lower(carry).as_text(debug_info=True)
     assert f"/{spans.STAGE_EXPAND}/{spans.TWIN_DROP}/" in text
     # the canonicalising sort the Drop block calls is charged to the Drop
     # block: ``twin.drop`` comes first on its scope path

@@ -260,8 +260,6 @@ def test_paxos6_device_engine_prefix():
     device engine: C=6 twin compiles, expands, dedups and evaluates the
     closure linearizability verdict with no slot-overflow rows and no false
     violations on a bounded prefix."""
-    from stateright_tpu.parallel import wavefront as wf
-
     m = paxos_model(6, 3)
     c = m.checker().target_states(4000).spawn_tpu(
         sync=True, capacity=1 << 16, frontier_capacity=1 << 9
@@ -270,8 +268,8 @@ def test_paxos6_device_engine_prefix():
     assert "linearizable" not in c.discoveries()
     # every enqueued row is clean: the network never overflowed its slots
     tm = c.tensor
-    rows = np.asarray(c._final_carry[wf._QROWS])
-    tail = int(np.asarray(c._final_carry[wf._TAIL]))
+    rows = np.asarray(c._final_carry.q_rows)
+    tail = int(np.asarray(c._final_carry.tail))
     for r in rows[:tail:37]:  # stride-sample the queue
         assert tm.pk.unpack(r[: tm.pw])["overflow"] == 0
 

@@ -419,7 +419,7 @@ def _build_jaxpr(checker) -> str:
         checker._cap, checker._qcap, checker._batch, checker._cand
     )
     carry, _ = init_fn()
-    return str(jax.make_jaxpr(lambda cr: run_fn(cr))(tuple(carry)))
+    return str(jax.make_jaxpr(lambda cr: run_fn(cr))(carry))
 
 
 def test_autosave_and_faults_leave_step_jaxpr_bit_identical(tmp_path):

@@ -100,7 +100,7 @@ def test_report_carries_config_and_run_identity(tmp_path):
     assert cfg["key"] == config_key(cfg)
     for flag in ("telemetry", "cartography", "memory", "checked",
                  "prededup", "spill", "por", "symmetry", "prewarm",
-                 "pallas", "compile_cache", "roofline", "sweep"):
+                 "compile_cache", "roofline", "sweep"):
         assert flag in cfg["flags"], flag
     # different instance arguments -> different config_key
     from stateright_tpu.telemetry.report import build_config
@@ -218,7 +218,7 @@ def _wavefront_build_jaxpr(runs_dir) -> str:
     init_fn, run_fn = c._build(c._cap, c._qcap, c._batch, c._cand)
     carry, _ = init_fn()
     # fresh lambda per call: make_jaxpr memoizes on fn identity
-    return str(jax.make_jaxpr(lambda cr: run_fn(cr))(tuple(carry)))
+    return str(jax.make_jaxpr(lambda cr: run_fn(cr))(carry))
 
 
 def test_registry_leaves_run_jaxpr_bit_identical(tmp_path):

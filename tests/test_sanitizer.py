@@ -388,7 +388,7 @@ def test_checked_false_leaves_run_jaxpr_bit_identical():
         c = b.spawn_tpu(sync=True, capacity=1 << 12, batch=64)
         init_fn, run_fn = c._engine(c._cap, c._qcap, c._batch, c._cand)
         carry, _ = init_fn()
-        return str(jax.make_jaxpr(lambda cr: run_fn(cr))(tuple(carry)))
+        return str(jax.make_jaxpr(lambda cr: run_fn(cr))(carry))
 
     baseline = run_jaxpr(None)
     assert baseline == run_jaxpr(False)

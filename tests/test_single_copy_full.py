@@ -208,7 +208,7 @@ def _step_program(model, **kw):
     c.join()
     init_fn, run_fn = c._build(c._cap, c._qcap, c._batch, c._cand)
     carry, _ = init_fn()
-    low = run_fn.lower(tuple(carry))
+    low = run_fn.lower(carry)
     return (hashlib.sha256(low.as_text().encode()).hexdigest(),
             low.as_text(debug_info=True))
 

@@ -189,7 +189,7 @@ def test_spill_analytic_bytes_reconcile_exactly(monkeypatch):
     specs = c._memory_spec_fn()(
         {"cap": c._cap, "qcap": c._qcap, "batch": c._batch}
     )
-    carry = c._final_carry
+    carry = jax.tree.leaves(c._final_carry)
     assert len(specs) == len(carry)
     for s, arr in zip(specs, carry):
         a = np.asarray(arr)
@@ -208,7 +208,7 @@ def _build_jaxpr(checker) -> str:
         checker._cap, checker._qcap, checker._batch, checker._cand
     )
     carry, _ = init_fn()
-    return str(jax.make_jaxpr(lambda cr: run_fn(cr))(tuple(carry)))
+    return str(jax.make_jaxpr(lambda cr: run_fn(cr))(carry))
 
 
 def test_spill_off_leaves_step_jaxpr_bit_identical():
@@ -279,7 +279,7 @@ def test_2pc5_under_budget_completes_bit_identical(monkeypatch):
     assert sp["resolved_novel"] + sp["resolved_dups"] > 0
     # spilled + hot == unique (the tiers partition the visited set)
     hot = int(
-        (np.asarray(c._final_carry[0]) != np.uint64(EMPTY)).sum()
+        (np.asarray(c._final_carry.table_fp) != np.uint64(EMPTY)).sum()
     )
     assert hot + sp["spilled_fps"] == c.unique_state_count()
     # cartography reconciles EXACTLY across evictions/injections

@@ -28,7 +28,7 @@ def tiny():
     carry, _ = init_fn()
     return {
         "checker": c,
-        "run": run_fn.lower(tuple(carry)).as_text(debug_info=True),
+        "run": run_fn.lower(carry).as_text(debug_info=True),
         "init": init_fn.lower().as_text(debug_info=True),
     }
 
@@ -59,7 +59,7 @@ def test_scopes_are_metadata_the_jaxpr_does_not_change(tiny, monkeypatch):
     def run_jaxpr():
         init_fn, run_fn = c._build(c._cap, c._qcap, c._batch, c._cand)
         carry, _ = init_fn()
-        return str(jax.make_jaxpr(lambda cr: run_fn(cr))(tuple(carry)))
+        return str(jax.make_jaxpr(lambda cr: run_fn(cr))(carry))
 
     named = run_jaxpr()
     monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
@@ -77,7 +77,7 @@ def test_symmetry_names_the_canonicaliser_inside_sr_hash_and_only_then(tiny):
     init_fn, run_fn = c._engine(c._cap, c._qcap, c._batch, c._cand)
     carry, _ = init_fn()
     canon = f"/{spans.STAGE_HASH}/{spans.SYM_CANON}/"
-    assert canon in run_fn.lower(tuple(carry)).as_text(debug_info=True)
+    assert canon in run_fn.lower(carry).as_text(debug_info=True)
     assert canon in init_fn.lower().as_text(debug_info=True)
 
 
@@ -538,7 +538,7 @@ def compiled_twin():
     init_fn, run_fn = c._engine(c._cap, c._qcap, c._batch, c._cand)
     carry, _ = init_fn()
     return {"model": model, "checker": c,
-            "run": run_fn.lower(tuple(carry)).as_text(debug_info=True)}
+            "run": run_fn.lower(carry).as_text(debug_info=True)}
 
 
 @pytest.mark.parametrize("scope", spans.TWIN_SCOPES)

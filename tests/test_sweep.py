@@ -299,9 +299,7 @@ def test_sweep_off_is_the_plain_engine_and_cache_unkeyed(monkeypatch):
         key = c._engine_key(c._cap, c._qcap, c._batch, c._cand)
         init_fn, run_fn = c._engine(c._cap, c._qcap, c._batch, c._cand)
         carry, _ = init_fn()
-        return key, str(jax.make_jaxpr(lambda cr: run_fn(cr))(
-            tuple(carry)
-        ))
+        return key, str(jax.make_jaxpr(lambda cr: run_fn(cr))(carry))
 
     k_off, j_off = spawn()
     assert not any("sweep" in str(e) for e in k_off)

@@ -39,6 +39,7 @@ from stateright_tpu.ops.buckets import (
 )
 from stateright_tpu.ops.hashing import EMPTY
 from stateright_tpu.parallel import wavefront as wf
+from stateright_tpu.parallel.carry import carry_avals
 
 sys.path.insert(0, str(Path(__file__).parent))
 from test_buckets import INSERTS, both_inserts, fresh  # noqa: E402
@@ -91,7 +92,7 @@ def step_program(n=3, cap=1 << 16, qcap=1 << 10, batch=32, cand=256, **kw):
     _, run_fn = wf._build_engine(
         tensor, props, cap, qcap, batch, 8, None, cand=cand, **kw
     )
-    return run_fn, wf._carry_avals(tensor, len(props), cap, qcap, batch, False)
+    return run_fn, carry_avals(tensor, len(props), cap, qcap, batch, False)
 
 
 @pytest.mark.parametrize("sym", [False, True])

@@ -348,7 +348,7 @@ def _wavefront_run_jaxpr(telemetry: bool) -> str:
     carry, _ = init_fn()
     # fresh lambda per call: jax.make_jaxpr memoizes on fn identity (the
     # PR-1 double-trace lesson, analysis/jaxpr_audit.py JX104)
-    return str(jax.make_jaxpr(lambda cr: run_fn(cr))(tuple(carry)))
+    return str(jax.make_jaxpr(lambda cr: run_fn(cr))(carry))
 
 
 # two full engine compiles for one jaxpr diff is integration-shaped —
