@@ -568,6 +568,26 @@ def parent_chains(
     )
 
 
+@functools.lru_cache(maxsize=None)
+def sharded_parent_chains(sharding):
+    """:func:`parent_chains` for a table that lies sharded over a mesh
+    (``sharding``: the ``NamedSharding`` both table arrays came out of the
+    run program with): the same walk, jitted with the table's own
+    sharding as ``in_shardings`` - so no chip is handed the whole table
+    before the call - and every output replicated, one dispatch a run.
+    How a chip reads sixteen slots of another chip's bucket range is the
+    partitioner's business (no collective is written here); the ``starts``
+    and the chains are a few hundred bytes on every chip."""
+    everywhere = jax.sharding.NamedSharding(
+        sharding.mesh, jax.sharding.PartitionSpec()
+    )
+    return jax.jit(
+        parent_chains.__wrapped__, static_argnums=3,  # ``bound``
+        in_shardings=(sharding, sharding, everywhere),
+        out_shardings=everywhere,
+    )
+
+
 @functools.partial(jax.jit, static_argnames="new_nbuckets")
 def bucket_split(
     table_fp: jnp.ndarray,  # uint64[nbuckets * SLOTS], as it lies in the carry

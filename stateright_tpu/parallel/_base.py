@@ -964,8 +964,8 @@ class WavefrontChecker(Checker):
 
     def _parents(self, parent=None) -> dict[int, int]:
         """The fp -> parent map of every visited state, built once a run:
-        the HOST path of reconstruction (a table sharded over a mesh, a
-        spill store that holds the roots; ``_device_table``).  ``parent``
+        the HOST path of reconstruction (a spill store that holds the
+        roots; ``_device_table``).  ``parent``
         is the ``reconstruct`` span the two phases are children of."""
         if self._parent_map is None:
             rec = self.flight_recorder
@@ -979,9 +979,10 @@ class WavefrontChecker(Checker):
         return self._parent_map
 
     def _device_table(self):
-        """``(table_fp, table_payload)`` as they lie in the final carry,
-        where ONE device holds them and they hold every parent; None
-        where the run reconstructs through the host map (hook)."""
+        """``(table_fp, table_payload)`` as they lie in the final carry -
+        on one device or sharded over a mesh - where they hold every
+        parent; None where the run reconstructs through the host map
+        (hook)."""
         return None
 
     def _chains(self, parent=None) -> Optional[dict[int, np.ndarray]]:
@@ -999,16 +1000,25 @@ class WavefrontChecker(Checker):
         table = self._device_table()
         if table is None:
             return None
-        from ..ops.buckets import CHAIN_BOUND, CHAIN_ROOT, parent_chains
+        from ..ops.buckets import (
+            CHAIN_BOUND,
+            CHAIN_ROOT,
+            parent_chains,
+            sharded_parent_chains,
+        )
 
         rec = self.flight_recorder
         starts = np.asarray(self._results["disc"], np.uint64)
         # a power of two over the deepest chain there can be: one program
         # a table capacity for every model whose search is this deep
         bound = 1 << max(self.max_depth(), 15).bit_length()
+        # a table sharded over a mesh is walked under its own sharding
+        lies = table[0].sharding
+        shards = len(lies.device_set)
+        walk = parent_chains if shards == 1 else sharded_parent_chains(lies)
         with tel_span("reconstruct.parents", rec, parent=parent,
-                      path="device") as sp:
-            chains, lens, ends = parent_chains(*table, starts, bound=bound)
+                      path="device", shards=shards) as sp:
+            chains, lens, ends = walk(*table, starts, bound)
             # how each chain ended is the device call's answer, and the sync
             lens, ends = np.asarray(lens), np.asarray(ends)
             sp.set(lookups=int(lens.sum()))

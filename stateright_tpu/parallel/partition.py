@@ -114,6 +114,73 @@ WAVEFRONT_CARRY_RULES = (
 )
 
 
+class StepPlacement:
+    """What the mesh engine asks of the step program INSIDE it
+    (``wavefront._build_engine(place=...)``): where values lie, never
+    where the carry lies - its in/out shardings are the rules' above, so
+    what is live between two device calls is the same bytes a chip
+    whatever this class does.
+
+    The queue is sharded by contiguous row range and has ONE head and ONE
+    tail.  A ``dynamic_slice`` / ``dynamic_update_slice`` at a traced
+    offset of a sharded dimension the partitioner can only do by gathering
+    the whole operand on every chip (four 2.4 GB all-gathers a step at
+    paxos-6's 512 B rows); the same window read or written BY ROW INDEX it
+    splits by shard: every chip gathers / scatters the indices inside its
+    own row range, and a read is completed by an all-reduce of the
+    window's rows.  No collective is written here: each is the compiler's.
+    """
+
+    def __init__(self, mesh: Mesh):
+        self._mesh = mesh
+        self._lanes = NamedSharding(mesh, P(MESH_AXES))
+        self._whole = replicated(mesh)
+
+    def lanes(self, x):
+        """``x`` split along its first dimension (a batch's lanes, a
+        candidate block's): a chip computes its share.  Replicated where
+        the mesh does not divide it, as the rules decide for the carry."""
+        if x.ndim == 0 or x.shape[0] % self._mesh.size:
+            return self.whole(x)
+        return jax.lax.with_sharding_constraint(x, self._lanes)
+
+    def whole(self, x):
+        """``x`` on every chip."""
+        return jax.lax.with_sharding_constraint(x, self._whole)
+
+    def pop(self, q, head, n: int):
+        """Rows ``[head, head + n)`` of queue buffer ``q``, split by lane
+        (``dynamic_slice(q, head, n)``'s value: the queue's allocation
+        keeps the window in bounds, ``carry.queue_alloc``)."""
+        at = head + jax.lax.iota(head.dtype, n)
+        window = q.at[at].get(
+            indices_are_sorted=True, unique_indices=True,
+            mode="promise_in_bounds",
+        )
+        return self.lanes(self.whole(window))
+
+    def append(self, q, rows, tail):
+        """``q`` with ``rows`` written at ``[tail, tail + len(rows))``
+        (``dynamic_update_slice(q, rows, tail)``'s value), every chip
+        holding all of ``rows`` to do so.  The 512 B payload rows go in by
+        row index: a chip writes those that fall in its own range.  A
+        NARROW column (one word a row: ``q_fp``, ``q_ebits``, ``q_depth``)
+        keeps the update slice, which the partitioner does by gathering
+        that column - 37 MB a ``u32`` plane at paxos-6, 0.4 ms over ICI -
+        where a scatter of the window's words costs the chip its price an
+        index (with all four buffers scattered a check read 27.9 s, with
+        the payload alone 22.1 s; PERF.md section 6, PR 51).  Either way
+        the column stays SHARDED in the carry."""
+        rows = self.whole(rows)
+        if q.ndim == 1:
+            return jax.lax.dynamic_update_slice(q, rows, (tail,))
+        at = tail + jax.lax.iota(tail.dtype, rows.shape[0])
+        return q.at[at].set(
+            rows, indices_are_sorted=True, unique_indices=True,
+            mode="promise_in_bounds",
+        )
+
+
 def match_partition_rules(rules, names: Sequence[str], avals,
                           mesh: Mesh):
     """Resolve one :class:`NamedSharding` per named buffer.

@@ -140,7 +140,7 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
                   sym: bool = False, cand: Optional[int] = None,
                   checked: bool = False, prededup: bool = False,
                   cartography: bool = False, por=None, spill=None,
-                  mxu=None):
+                  mxu=None, place=None):
     """Build ``(init_fn, run_fn)`` for fixed capacities.
 
     ``qcap`` is the queue high-water mark; the buffers are over-allocated by
@@ -202,6 +202,14 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
     lanes), which the OOB check would flag by design.  ``checked=False``
     is bit-identical to an engine built before the flag existed (pinned
     by test, same contract as telemetry).
+
+    ``place`` is the mesh engine's hook (``partition.StepPlacement``, None
+    on one device): where the step's VALUES lie across the chips - the
+    popped batch and the candidate block by lane, what the insert and the
+    append take replicated - and the pop and the append spelled by row
+    index, which the partitioner splits by queue shard.  It never touches
+    the carry's own placement (the run program's in/out shardings), and
+    off it is the identity: no equation of the step's jaxpr comes from it.
     """
     width, arity = tensor.width, tensor.max_actions
     m = batch * arity
@@ -299,6 +307,10 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
         else None
     )
     poison_fn = getattr(tensor, "poison_rows", None)
+    if place is None:
+        lanes = whole = lambda x: x  # one device: no equation left behind
+    else:
+        lanes, whole = place.lanes, place.whole
 
     def append_novel(qrows, qfp, qebits, qdepth, tail0, sel, n_new,
                      crows, cfp, cebt, cdep):
@@ -315,6 +327,13 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
         ``tail0 + eff_cand`` — inside the same ``qalloc`` slack the
         plain window uses; an overflowed batch (``n_new == 0``) writes
         nothing, which only strengthens the replay contract."""
+        if place is not None:
+            # the same window, written by row index (StepPlacement.append)
+            return tuple(
+                place.append(q, c[sel], tail0)
+                for q, c in ((qrows, crows), (qfp, cfp), (qebits, cebt),
+                             (qdepth, cdep))
+            )
         if not slim_queue:
             qrows = jax.lax.dynamic_update_slice(
                 qrows, crows[sel], (tail0, jnp.int32(0))
@@ -360,10 +379,16 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
         err, cart, sp = carry.err, carry.cart, carry.spill
         with jax.named_scope(STAGE_POP):
             n_avail = tail - head
-            rows = jax.lax.dynamic_slice(qrows, (head, jnp.int32(0)), (batch, width))
-            fps = jax.lax.dynamic_slice(qfp, (head,), (batch,))
-            ebits = jax.lax.dynamic_slice(qebits, (head,), (batch,))
-            depths = jax.lax.dynamic_slice(qdepth, (head,), (batch,))
+            if place is not None:
+                rows, fps, ebits, depths = (
+                    place.pop(q, head, batch)
+                    for q in (qrows, qfp, qebits, qdepth)
+                )
+            else:
+                rows = jax.lax.dynamic_slice(qrows, (head, jnp.int32(0)), (batch, width))
+                fps = jax.lax.dynamic_slice(qfp, (head,), (batch,))
+                ebits = jax.lax.dynamic_slice(qebits, (head,), (batch,))
+                depths = jax.lax.dynamic_slice(qdepth, (head,), (batch,))
             live = jnp.arange(batch, dtype=jnp.int32) < n_avail
 
         with jax.named_scope(STAGE_PROPS):
@@ -392,6 +417,7 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
         with jax.named_scope(STAGE_EXPAND):
             if not checked:
                 succ, valid = step_rows_fn(rows)  # [B, A, W], [B, A]
+            succ, valid = lanes(succ), lanes(valid)
             if boundary_fn is not None:
                 # mirror the host checkers: out-of-boundary successors are
                 # neither counted nor enqueued, and a state whose successors
@@ -455,8 +481,13 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
                     sp.bloom, cand_fp, spill_bits
                 )
                 cand_fp = jnp.where(maybe_spilled, EMPTY, cand_fp)
-            cand_rows = succ.reshape(m, width)
-            cand_par = jnp.broadcast_to(fps[:, None], (batch, arity)).reshape(-1)
+            # the insert ranks the whole block's keys against each other:
+            # it takes them on every chip (m words, not m rows)
+            cand_fp = whole(lanes(cand_fp))
+            cand_rows = lanes(succ.reshape(m, width))
+            cand_par = whole(
+                jnp.broadcast_to(fps[:, None], (batch, arity)).reshape(-1)
+            )
             cand_ebt = jnp.broadcast_to(ebits[:, None], (batch, arity)).reshape(-1)
             cand_dep = jnp.broadcast_to(
                 depths[:, None] + jnp.uint32(1), (batch, arity)
@@ -972,8 +1003,13 @@ class TpuChecker(WavefrontChecker):
             cartography=self._cartography,
             por=self._por_plan if self._por else None,
             spill=self._spill_cfg if self._spill else None,
-            mxu=self._mxu,
+            mxu=self._mxu, place=self._step_placement(),
         )
+
+    def _step_placement(self):
+        """Where the step program's values lie across devices (hook:
+        ``partition.StepPlacement`` on a mesh); None on one device."""
+        return None
 
     # -- memory-ledger hooks (telemetry/memory.py) ---------------------------
 
@@ -1625,7 +1661,7 @@ class TpuChecker(WavefrontChecker):
                 rung=kind, source="fresh", cache_hit=False, duration=0.0,
             )
         eng = self._build(cap, qcap, batch, cand)
-        if self._mem_ledger is not None:
+        if self._compiles_ahead():
             # With the ledger on, the fresh path compiles the run program
             # AHEAD OF TIME (the same executable the lazy path would
             # build — the prewarm contract, pinned by its tests) so the
@@ -1651,7 +1687,8 @@ class TpuChecker(WavefrontChecker):
                 self._stage("trace", build - comp)
             if exe is not None:
                 eng = (eng[0], exe)
-                mem = self._mem_ledger.attach_exec(exe)
+                mem = (self._mem_ledger.attach_exec(exe)
+                       if self._mem_ledger is not None else None)
                 if rec is not None and self._pending_compile_rec is not None:
                     hit = d["persistent_hits"] > 0
                     source = "persistent" if hit else "fresh"
@@ -1664,6 +1701,11 @@ class TpuChecker(WavefrontChecker):
                     rec.amend(self._pending_compile_rec, **fields)
         cache[key] = eng
         return eng, source
+
+    def _compiles_ahead(self) -> bool:
+        """Whether a fresh engine's run program is compiled when it is
+        built, the executable at hand, rather than at its first call."""
+        return self._mem_ledger is not None
 
     def _maybe_schedule_prewarm(self, cap, qcap, batch, cand,
                                 unique: int, tail: int) -> None:
@@ -1850,9 +1892,10 @@ class TpuChecker(WavefrontChecker):
         the host - by what the engine observes, not by a knob.  The spill
         tier works on a carry that is on the host by design (its eviction,
         its queue offload and its transient forecast); and the mesh engine
-        runs this loop over a carry sharded by bucket and by queue shard, a
-        head and a tail a shard, which no single-device program here
-        addresses (``_device_table`` draws the same two lines)."""
+        runs this loop over a carry sharded by bucket range and by queue
+        row range - ONE queue, one head and one tail, replicated scalars -
+        whose growth programs nobody has placed yet (``bucket_split`` and
+        ``_slide_queue`` move rows across the shards' ranges)."""
         return (
             not self._spill
             and len(carry.table_fp.sharding.device_set) == 1
@@ -2414,16 +2457,14 @@ class TpuChecker(WavefrontChecker):
         )
 
     def _device_table(self):
-        """The final carry's table, for reconstruction on the device: not
-        where it is sharded over a mesh (a dynamic 16-slot read from a
-        bucket-sharded array is a collective nobody has priced), and not
+        """The final carry's table, for reconstruction on the device -
+        on one chip or sharded by bucket range over a mesh (the walk then
+        runs under the table's own sharding, ``_base._chains``) - but not
         once the spill tier evicted (an eviction clears the WHOLE hot
         table, the init states with it, so every chain leaves it)."""
-        tfp = self._final_carry.table_fp
-        tpl = self._final_carry.table_parent
-        if self._spilled() or len(tfp.sharding.device_set) != 1:
+        if self._spilled():
             return None
-        return tfp, tpl
+        return self._final_carry.table_fp, self._final_carry.table_parent
 
     # -- live progress + checkpointing ---------------------------------------
 

@@ -11,8 +11,8 @@ run, and only the chains cross to the host.  Held here, on XLA:CPU:
  - a chain that leaves the table, or outgrows its bound, raises: never a
    silently shorter path;
  - who keeps the host path: a spill-tier run whose store holds the roots
-   and a ``devices=4`` mesh run return the paths they returned and say
-   ``path="host"``;
+   returns the paths it returned and says ``path="host"``; a ``devices=4``
+   mesh run walks its sharded table where it lies (``shards`` 4);
  - one program a table capacity: a second equal model object compiles
    nothing.
 
@@ -199,19 +199,22 @@ def test_a_spilled_run_reconstructs_through_the_host(monkeypatch):
     assert pull["bytes"] == 2 * 8 * c._cap
 
 
-def test_a_mesh_run_reconstructs_through_the_host():
-    """The table is sharded by bucket over four (virtual) devices: read off
-    the array's sharding, no knob."""
+def test_a_mesh_run_reconstructs_on_the_device_too():
+    """The table is sharded by bucket range over four (virtual) devices:
+    read off the array's sharding, no knob - and walked where it lies
+    (``tests/test_mesh_reconstruct.py`` holds the sharded walk itself)."""
     mesh = TwoPhaseSys(3).checker().telemetry().spawn_tpu(
         sync=True, devices=4, capacity=1 << 12, batch=64
     )
     solo = TwoPhaseSys(3).checker().telemetry().spawn_tpu(
         sync=True, capacity=1 << 12, batch=64
     )
-    assert mesh._device_table() is None and solo._device_table() is not None
+    assert len(mesh._device_table()[0].sharding.device_set) == 4
+    assert len(solo._device_table()[0].sharding.device_set) == 1
     assert _states(mesh.discoveries()) == _states(solo.discoveries())
     (parents,), (pull,) = (_spans(mesh, "reconstruct.parents"),
                            _spans(mesh, "reconstruct.pull"))
-    assert parents["path"] == "host"
-    assert pull["bytes"] == 2 * 8 * mesh._cap
-    assert _spans(solo, "reconstruct.parents")[0]["path"] == "device"
+    assert (parents["path"], parents["shards"]) == ("device", 4)
+    assert 0 < pull["bytes"] < 64 << 10
+    (alone,) = _spans(solo, "reconstruct.parents")
+    assert (alone["path"], alone["shards"]) == ("device", 1)
