@@ -55,7 +55,7 @@ the computed budget.
 with ``CheckerBuilder.mxu()`` armed, count parity ASSERTED, and the
 flagged roofline ledgers embedded as ``tpu_paxos3_mxu_roofline`` /
 ``tpu_2pc7_mxu_roofline`` next to the same run's unflagged blocks —
-``regress.py --mxu`` gates the before/after pair (expand+queue charged
+``regress.py --mxu`` gates the before/after pair (expand charged
 bytes drop >=30% on paxos-3; a dot-class dedup-insert op on 2pc-7).
 
 ``BENCH_SWEEP=1`` adds the flag-gated hyper-batched sweep leg
@@ -1107,13 +1107,13 @@ def device_phase() -> dict:
 
     # flag-gated MXU-recast legs (BENCH_MXU=1; docs/roofline.md
     # "Executing the hot-spot list"): the same paxos-3 and 2pc-7 configs
-    # with CheckerBuilder.mxu() armed — expand-scatter coalescing, slim
-    # queue traffic, and the BLEST one-hot probe.  Count parity against
+    # with CheckerBuilder.mxu() armed — expand-scatter coalescing and
+    # the BLEST one-hot probe.  Count parity against
     # the unflagged legs is ASSERTED (a broken recast cannot report a
     # win), and each leg embeds its FLAGGED roofline block
     # (tpu_*_mxu_roofline) next to the same run's unflagged block —
     # exactly the before/after pair regress.py --mxu gates: paxos-3
-    # expand+queue charged bytes must drop >=30%, and 2pc-7's
+    # expand charged bytes must drop >=30%, and 2pc-7's
     # dedup-insert stage must carry a dot-class op.
     if os.environ.get("BENCH_MXU", "") == "1":
         try:

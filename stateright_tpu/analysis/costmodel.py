@@ -683,16 +683,6 @@ _LANDED_HATCH = {
         "--mxu / CheckerBuilder.mxu() (BLEST one-hot probe; "
         "docs/roofline.md)",
     ),
-    ("queue", "gather"): (
-        "slim_queue",
-        "--mxu / CheckerBuilder.mxu() (slim queue traffic; "
-        "docs/roofline.md)",
-    ),
-    ("queue", "scatter"): (
-        "slim_queue",
-        "--mxu / CheckerBuilder.mxu() (slim queue traffic; "
-        "docs/roofline.md)",
-    ),
     ("expand", "scatter"): (
         "coalesce",
         "--mxu / CheckerBuilder.mxu() (expand-scatter coalescing; "
@@ -890,30 +880,28 @@ def _stage_fns(tensor, cap: int, qcap: int, batch: int, cand: int,
 
     ``mxu`` (``ops/mxu.MxuConfig``, None = off) mirrors the engine's
     MXU-recast knobs (docs/roofline.md "Executing the hot-spot list"):
-    ``coalesce`` traces the twin's coalesced expand kernel, ``probe``
-    passes ``probe_dot`` into the insert mirror, and ``slim_queue``
-    swaps the queue mirror's stack-wide append for the engine's
-    ``batch``-chunked loop gated on a traced ``n_new`` — so the ledger
-    charges exactly what the flagged engine program moves."""
+    ``coalesce`` traces the twin's coalesced expand kernel and ``probe``
+    passes ``probe_dot`` into the insert mirror — so the ledger charges
+    exactly what the flagged engine program moves.  The queue mirror's
+    append is the engine's ``append_novel``: ``qchunk``-row chunks gated
+    on a traced ``n_new`` (the walk charges a loop body once, so the
+    charged bytes are one chunk's - and, where a row is wider than a
+    word, the payload's one ``cand``-row gather before the loop -
+    whatever the flags)."""
     import jax
     import jax.numpy as jnp
 
     from ..ops.buckets import bucket_insert
     from ..ops.hashing import row_hash
     from ..ops.mxu import coalesced_step_fn
+    from ..parallel.wavefront import append_novel
 
     width, arity = tensor.width, tensor.max_actions
     m = batch * arity
     eff_cand = min(cand, m) if cand else m
     qalloc = qcap + m
     probe_dot = bool(mxu is not None and mxu.probe)
-    # the engine's static slim-queue decision, mirrored (wavefront
-    # _build_engine): chunk width min(batch, eff_cand), plain fallback
-    # when it does not divide the candidate stack
-    qchunk = min(batch, eff_cand)
-    slim_queue = bool(
-        mxu is not None and mxu.slim_queue and eff_cand % qchunk == 0
-    )
+    qchunk = min(batch, eff_cand)  # the append's chunk (_build_engine)
     step_rows_fn = coalesced_step_fn(tensor, mxu)
     sds = jax.ShapeDtypeStruct
     rows = sds((batch, width), jnp.uint64)
@@ -930,54 +918,19 @@ def _stage_fns(tensor, cap: int, qcap: int, batch: int, cand: int,
         )
 
     def queue_fn(qrows, qfp, qebits, qdepth, head, tail, crows, cfp,
-                 cebt, cdep, sel, n_new=None):
+                 cebt, cdep, sel, n_new):
         # the engine's per-step queue traffic: pop one batch window,
-        # append the novel-compacted candidate window at the tail
+        # append the novel-compacted candidates at the tail in chunks
         out_rows = jax.lax.dynamic_slice(
             qrows, (head, jnp.int32(0)), (batch, width)
         )
         out_fp = jax.lax.dynamic_slice(qfp, (head,), (batch,))
         out_eb = jax.lax.dynamic_slice(qebits, (head,), (batch,))
         out_dp = jax.lax.dynamic_slice(qdepth, (head,), (batch,))
-        if slim_queue:
-            # the engine's append_novel slim path (wavefront.py): one
-            # batch-sized chunk per loop trip, gated on n_new — the
-            # walk charges the body once, so charged bytes track the
-            # chunk window, matching the flagged engine program
-            def chunk(state):
-                k, qr, qf, qe, qd = state
-                off = k * qchunk
-                w_idx = jax.lax.dynamic_slice(sel, (off,), (qchunk,))
-                qr = jax.lax.dynamic_update_slice(
-                    qr, crows[w_idx], (tail + off, jnp.int32(0))
-                )
-                qf = jax.lax.dynamic_update_slice(
-                    qf, cfp[w_idx], (tail + off,)
-                )
-                qe = jax.lax.dynamic_update_slice(
-                    qe, cebt[w_idx], (tail + off,)
-                )
-                qd = jax.lax.dynamic_update_slice(
-                    qd, cdep[w_idx], (tail + off,)
-                )
-                return k + 1, qr, qf, qe, qd
-
-            _, qrows, qfp, qebits, qdepth = jax.lax.while_loop(
-                lambda st: st[0] * qchunk < n_new,
-                chunk,
-                (jnp.int32(0), qrows, qfp, qebits, qdepth),
-            )
-        else:
-            qrows = jax.lax.dynamic_update_slice(
-                qrows, crows[sel], (tail, jnp.int32(0))
-            )
-            qfp = jax.lax.dynamic_update_slice(qfp, cfp[sel], (tail,))
-            qebits = jax.lax.dynamic_update_slice(
-                qebits, cebt[sel], (tail,)
-            )
-            qdepth = jax.lax.dynamic_update_slice(
-                qdepth, cdep[sel], (tail,)
-            )
+        (qrows, qfp, qebits, qdepth), _ = append_novel(
+            (qrows, qfp, qebits, qdepth), tail, sel, n_new,
+            (crows, cfp, cebt, cdep), qchunk,
+        )
         return (out_rows, out_fp, out_eb, out_dp, qrows, qfp, qebits,
                 qdepth)
 
@@ -993,10 +946,8 @@ def _stage_fns(tensor, cap: int, qcap: int, batch: int, cand: int,
         sds((), jnp.int32), sds((), jnp.int32),
         sds((m, width), jnp.uint64), sds((m,), jnp.uint64),
         sds((m,), jnp.uint32), sds((m,), jnp.uint32),
-        sds((m,), jnp.int32),
+        sds((eff_cand,), jnp.int32), sds((), jnp.int32),
     )
-    if slim_queue:
-        queue_avals = queue_avals + (sds((), jnp.int32),)
     return {
         "property": (tensor.property_masks, (rows,)),
         "expand": (expand_fn, (rows,)),
@@ -1082,18 +1033,11 @@ def wavefront_costs(
         except Exception:  # noqa: BLE001 - attribution only, never fatal
             actions = None
     # landed-recast bookkeeping prices what actually traced: coalesce
-    # downgrades when the twin has no coalesced kernel (effective_mxu),
-    # slim_queue when the chunk width does not divide the candidate
-    # stack (the _stage_fns/_build_engine static fallback) — a fallen-
-    # back component must never silence its JX400 findings
+    # downgrades when the twin has no coalesced kernel (effective_mxu) —
+    # a fallen-back component must never silence its JX400 findings
     from ..ops.mxu import effective_mxu
 
-    mxu_eff = effective_mxu(tensor, mxu)
-    if mxu_eff is not None and mxu_eff.slim_queue:
-        ec = min(cand, batch * arity)
-        if ec % min(batch, ec):
-            mxu_eff = mxu_eff._replace(slim_queue=False)
-    candidates = mxu_candidates(stages, mxu=mxu_eff)
+    candidates = mxu_candidates(stages, mxu=effective_mxu(tensor, mxu))
     out = CostReport(
         engine="wavefront",
         shapes={"batch": batch, "capacity": cap, "queue_capacity": qcap,

@@ -382,12 +382,11 @@ class CheckerBuilder:
         enabled: bool = True,
         *,
         coalesce: bool = True,
-        slim_queue: bool = True,
         probe: bool = True,
     ) -> "CheckerBuilder":
         """Arm the MXU recast round on the device engines
         (``stateright_tpu/ops/mxu.py``; docs/roofline.md "Executing the
-        hot-spot list"): three flag-gated bytes-moved reductions
+        hot-spot list"): two flag-gated bytes-moved reductions
         executing PR 11's ranked JX4xx hot spots —
 
         - ``coalesce``: trace the twin's expand-scatter-coalesced step
@@ -396,10 +395,6 @@ class CheckerBuilder:
           assemble as one word-stacked block instead of one scatter per
           field (the paxos-3 #1 hot spot: 37 sites, 109 MB/step).
           Twins without a coalesced form silently keep the plain kernel;
-        - ``slim_queue``: append novel queue rows in ``batch``-sized
-          chunks gated on the novel count instead of one
-          candidate-stack-wide ``dynamic_update_slice`` window (queue
-          rows 1-3 of the ledger);
         - ``probe``: the BLEST one-hot membership probe — the bucket
           membership/occupancy reductions become one blocked bitmapped
           ``dot_general`` over the candidate x slot comparison tile,
@@ -413,21 +408,15 @@ class CheckerBuilder:
         transforms move the same information through cheaper shapes.
         The roofline ledger (``.roofline()``) measures the payoff;
         ``regress.py --mxu`` gates it.  Env override
-        ``STATERIGHT_TPU_MXU=1`` (all three components); composes with
+        ``STATERIGHT_TPU_MXU=1`` (both components); composes with
         ``symmetry()``/``por()``/``prededup()``/``spill()``."""
         if not enabled:
             # explicit off wins over the env knob (resolve_flag's rule):
             # an all-off component dict resolves to None without ever
             # consulting STATERIGHT_TPU_MXU
-            self.mxu_opts = {
-                "coalesce": False, "slim_queue": False, "probe": False,
-            }
+            self.mxu_opts = {"coalesce": False, "probe": False}
             return self
-        self.mxu_opts = {
-            "coalesce": bool(coalesce),
-            "slim_queue": bool(slim_queue),
-            "probe": bool(probe),
-        }
+        self.mxu_opts = {"coalesce": bool(coalesce), "probe": bool(probe)}
         return self
 
     def mesh(

@@ -817,8 +817,8 @@ def costmodel_and_report(
     ``out`` collects the per-config live blocks into a JSON file (the
     schema round-trip fixture / CI artifact).  ``mxu`` prices the
     ``--mxu``-flagged engine program instead (docs/roofline.md
-    "Executing the hot-spot list"): the coalesced expand kernel, the
-    slim queue mirror, and the BLEST probe — landed-recast findings go
+    "Executing the hot-spot list"): the coalesced expand kernel and
+    the BLEST probe — landed-recast findings go
     silent (the JX305 pattern).  Returns True iff every
     twin-bearing configuration produced a well-formed, XLA-reconciling
     ledger (twin-less models are disclosed and skipped — host checkers

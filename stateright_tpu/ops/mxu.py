@@ -4,7 +4,9 @@ PR 11's roofline ledger proved every pipeline stage memory-bound at
 0.008-0.099 FLOPs/byte and ranked the hot spots (the JX4xx catalogue).
 This module holds the execution half's shared pieces — the resolved
 flag configuration and the BLEST one-hot membership probe — for the
-three flag-gated step-program transforms:
+two flag-gated step-program transforms (the queue append in
+``batch``-sized chunks gated on ``n_new`` is no flag's: it is the engine's
+ONE append, ``wavefront.append_novel``):
 
  - **expand-scatter coalescing** (``coalesce``): the hand-twin and
    per-channel step kernels assemble each action piece's packed-field
@@ -12,11 +14,6 @@ three flag-gated step-program transforms:
    instead of one ``.at[..., word].set`` scatter per field — the
    paxos-3 ledger charged 37 such sites at 109 MB/step, each paying a
    full-array slice read on top of its scatter;
- - **slim queue traffic** (``slim_queue``): the engines append novel
-   rows in ``window``-sized chunks gated on ``n_new`` instead of one
-   candidate-stack-wide ``dynamic_update_slice`` (queue rows 1-3 of the
-   ledger: 97 + 65 MB/step on paxos-3 for windows that are >90% dead
-   lanes);
  - **BLEST one-hot probe** (``probe``): the bucket membership/occupancy
    reductions recast as one blocked bitmapped ``dot_general`` over the
    candidate x slot comparison tile (:func:`blest_probe`), giving the
@@ -30,7 +27,7 @@ the transforms move the same bytes' worth of INFORMATION through
 cheaper shapes, never different information.
 
 Armed via ``CheckerBuilder.mxu()`` / ``--mxu`` / ``STATERIGHT_TPU_MXU=1``
-(all three components; keyword arguments select a subset).
+(both components; keyword arguments select a subset).
 """
 
 from __future__ import annotations
@@ -46,14 +43,13 @@ class MxuConfig(NamedTuple):
     engines carry ``None`` instead, keeping caches unkeyed)."""
 
     coalesce: bool = True
-    slim_queue: bool = True
     probe: bool = True
 
     def key(self) -> tuple:
         """Engine-cache key suffix — appended ONLY when armed, so the
         off-path cache key is exactly the pre-MXU tuple (the spill
         discipline, ``wavefront._engine_key``)."""
-        return ("mxu", self.coalesce, self.slim_queue, self.probe)
+        return ("mxu", self.coalesce, self.probe)
 
 
 def resolve_mxu(opts: Optional[dict]) -> Optional[MxuConfig]:
@@ -61,7 +57,7 @@ def resolve_mxu(opts: Optional[dict]) -> Optional[MxuConfig]:
 
     ``opts`` is ``CheckerBuilder.mxu_opts`` (a dict of component booleans,
     or None = unset); unset falls back to the ``STATERIGHT_TPU_MXU=1``
-    env knob, which arms all three components.  A config with every
+    env knob, which arms both components.  A config with every
     component off resolves to None — indistinguishable from never asking.
     """
     if opts is None:
@@ -70,10 +66,9 @@ def resolve_mxu(opts: Optional[dict]) -> Optional[MxuConfig]:
         return None
     cfg = MxuConfig(
         coalesce=bool(opts.get("coalesce", True)),
-        slim_queue=bool(opts.get("slim_queue", True)),
         probe=bool(opts.get("probe", True)),
     )
-    if not (cfg.coalesce or cfg.slim_queue or cfg.probe):
+    if not (cfg.coalesce or cfg.probe):
         return None
     return cfg
 
@@ -96,7 +91,7 @@ def coalesced_step_fn(tensor, mxu: Optional[MxuConfig]):
     (:func:`has_coalesced_step`), else the plain ``step_rows``.  Twins
     without a coalesced form (slot-multiset compiled twins, exotic hand
     twins) silently keep the plain kernel — the flag then still buys the
-    queue/probe recasts, and counts stay identical either way."""
+    probe recast, and counts stay identical either way."""
     if mxu is not None and mxu.coalesce and has_coalesced_step(tensor):
         return tensor.step_rows_coalesced
     return tensor.step_rows

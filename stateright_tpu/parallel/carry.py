@@ -235,12 +235,15 @@ def repad_queue(carry: Carry, qalloc: int) -> Carry:
 #
 # Every scalar the host loop reads rides one small ``u64`` vector, so a host
 # sync costs a single device round-trip: [head, tail, unique, scount,
-# maxdepth, status, dsteps, disc..., por stats (3)?, spill section (4)?,
-# cartography section?].  ``dsteps`` is the trip count of the device call's
-# ``while_loop`` (0 from ``init_fn`` and from :func:`stats_np`).
+# maxdepth, status, dsteps, append_chunks, disc..., por stats (3)?, spill
+# section (4)?, cartography section?].  ``dsteps`` is the trip count of the
+# device call's ``while_loop`` and ``append_chunks`` the chunk writes of its
+# appends (``wavefront.append_novel``'s trips, summed over the call's steps);
+# both ride the run program's loop beside the carry, not in it, and are 0
+# from ``init_fn`` and from :func:`stats_np`.
 _STATS_SCALARS = ("head", "tail", "unique", "scount", "maxdepth", "status")
 ST_DSTEPS = len(_STATS_SCALARS)
-ST_DISC = ST_DSTEPS + 1
+ST_DISC = ST_DSTEPS + 2
 
 
 class Stats(NamedTuple):
@@ -253,6 +256,7 @@ class Stats(NamedTuple):
     maxdepth: int
     status: int
     dsteps: int
+    append_chunks: int
     disc: np.ndarray
     por: Optional[np.ndarray]  # the three POR tallies
     # [pending count, spill base, deferred total, on-device total]
@@ -261,13 +265,13 @@ class Stats(NamedTuple):
     cart: Optional[np.ndarray]
 
 
-def stats_of(carry: Carry, dsteps):
+def stats_of(carry: Carry, dsteps, append_chunks):
     """The packed stats vector of ``carry``, on the device (traced into
     the run and init programs)."""
     parts = [
         jnp.stack(
             [getattr(carry, k).astype(jnp.uint64) for k in _STATS_SCALARS]
-            + [dsteps.astype(jnp.uint64)]
+            + [dsteps.astype(jnp.uint64), append_chunks.astype(jnp.uint64)]
         ),
         carry.disc,
     ]
@@ -308,7 +312,7 @@ def depth_hist(qdepth, n) -> np.ndarray:
 def stats_np(carry: Carry) -> np.ndarray:
     """Host-side equivalent of :func:`stats_of` (same layout), for a carry
     the host has just transformed."""
-    vals = [np.asarray(getattr(carry, k)) for k in _STATS_SCALARS] + [0]
+    vals = [np.asarray(getattr(carry, k)) for k in _STATS_SCALARS] + [0, 0]
     vals.extend(np.asarray(carry.disc))
     if carry.por is not None:
         vals.extend(np.asarray(carry.por.stats).reshape(-1))

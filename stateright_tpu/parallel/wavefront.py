@@ -135,6 +135,115 @@ _STATUS_TELEMETRY_NAMES = {
 }
 
 
+def _in_column_planes(rows):
+    """``rows`` (a chunk ``[n, width]`` of payload rows), its value
+    unchanged, pinned to the physical order the queue's payload buffer
+    lies in on the TPU: one plane a word (``u32[qalloc, 33]`` pads its 33
+    words to 40 that way, and to 128 row by row).  An update slice inside
+    a nested loop takes the BUFFER's layout from its update, and a row
+    gather emits rows word by word - so without the pin the compiler
+    carries the queue row-major (3.9x the bytes at 33 words) through the
+    whole run program and re-lays it out for every pop, two whole-queue
+    copies a step.  A one-dimensional array has ONE physical order, so
+    flattening the transposed chunk behind a barrier fixes it; the
+    transposition is the chunk's, never the queue's.
+    ``tests/test_table_layout.py`` holds the compiled step to no copy of
+    the queue."""
+    n, width = rows.shape
+    planes = jax.lax.optimization_barrier(rows.T.reshape(n * width))
+    return planes.reshape(width, n).T
+
+
+def _chunk_rows(block, off, n: int):
+    """Rows ``[off, off + n)`` of ``block`` (``[cand, width]``, the gathered
+    payload rows a ``while_loop`` slices), cut loose from the layout the
+    loop's body wants them in: the slice is flattened behind a barrier - a
+    one-dimensional array has ONE physical order - so whatever order the
+    body's update slice asks of the chunk stops there, and ``block`` enters
+    the loop as its gather laid it out, with no copy at the loop's edge.
+    The rows are padded to the TPU tile's 128 lanes first: a row-major
+    ``[n, 128]`` and its flattening are the same bytes, which a ``[n, 21]``
+    and its flattening are not (a pass, and a megabyte of code a step
+    program at 4,096 rows)."""
+    width = block.shape[1]
+    lanes = -(-width // 128) * 128
+    rows = jax.lax.dynamic_slice(block, (off, jnp.int32(0)), (n, width))
+    flat = jax.lax.optimization_barrier(
+        jnp.pad(rows, ((0, 0), (0, lanes - width))).reshape(n * lanes)
+    )
+    return flat.reshape(n, lanes)[:, :width]
+
+
+def append_novel(bufs, tail0, sel, n_new, cands, qchunk: int, place=None):
+    """Append the novel-compacted ``sel`` prefix of the candidate arrays
+    ``cands`` to the queue buffers ``bufs`` (rows, fp, ebits, depth: one
+    candidate array a buffer) at ``tail0``: the buffers, and how many
+    chunks were written.
+
+    The work follows ``n_new``, not ``cand``: ONE body, a ``while_loop``
+    over ``qchunk``-row chunks while ``k * qchunk < n_new`` (a step's
+    novel rows are a twentieth to a fifth of ``cand``); a chunk slices
+    ``qchunk`` lanes of ``sel``, takes the candidate arrays' rows there
+    and writes them at ``tail0 +`` the chunk's start.  Where ``qchunk``
+    does not divide ``len(sel)`` the last chunk starts at ``len(sel) -
+    qchunk``: a slice whose start clamped would misalign the rows it
+    writes, and this one rewrites rows the chunk before it wrote, with the
+    same values.  Rows ``[tail0, tail0 + n_new)`` are exact; rows past
+    them, up to the last chunk's end - inside ``carry.queue_alloc``'s
+    slack - are garbage that later appends overwrite before ``tail``
+    reaches them; a batch with nothing novel (an overflowed one too)
+    writes nothing.
+
+    A column of words is gathered inside the loop, a chunk's lanes a
+    trip.  A payload WIDER than a word is gathered once, ``len(sel)``
+    rows before the loop, and the loop slices it (``_chunk_rows``): the
+    candidate block reaches the gather through a relayout (planes to
+    rows: a pass or two over ``batch x actions x width`` words), and the
+    TPU compiler files that pass under the stage that shaped the block
+    only while the gather that reads it stands outside any loop - as a
+    ``while``'s operand the relaid block gets a copy with no name, which
+    the profile's reader files under no stage
+    (``tests/test_table_layout.py`` holds the compiled step to that).
+    One-word rows have no such relayout and go with the columns.
+
+    The mesh engine (``place``: ``partition.StepPlacement``) keeps the
+    window this loop replaced - ``len(sel)`` rows gathered and written by
+    row index a step, zero chunks counted: the loop on a mesh is the
+    chunk's write through ``place.append`` and nothing else, and waits for
+    a reading of the committed files on four chips (PERF.md section 6)."""
+    if place is not None:
+        return tuple(
+            place.append(q, c[sel], tail0) for q, c in zip(bufs, cands)
+        ), jnp.int32(0)
+    last = jnp.int32(sel.shape[0] - qchunk)
+    # a payload wider than a word: gathered here, sliced in the loop
+    wide = [c.ndim == 2 and c.shape[1] > 1 for c in cands]
+    srcs = [c[sel] if w else c for c, w in zip(cands, wide)]
+
+    def written(q, src, is_wide, off, w_idx):
+        rows = _chunk_rows(src, off, qchunk) if is_wide else src[w_idx]
+        at = tail0 + off
+        if q.ndim == 2:
+            rows = _in_column_planes(rows)
+        return jax.lax.dynamic_update_slice(
+            q, rows, (at,) + (jnp.int32(0),) * (q.ndim - 1)
+        )
+
+    def chunk(state):
+        k, bufs = state
+        off = jnp.minimum(k * qchunk, last)
+        w_idx = jax.lax.dynamic_slice(sel, (off,), (qchunk,))
+        return k + 1, tuple(
+            written(q, src, w, off, w_idx)
+            for q, src, w in zip(bufs, srcs, wide)
+        )
+
+    n_chunks, bufs = jax.lax.while_loop(
+        lambda s: s[0] * qchunk < n_new, chunk, (jnp.int32(0), tuple(bufs))
+    )
+    return bufs, n_chunks
+
+
 def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
                   steps: int, target: Optional[int],
                   sym: bool = False, cand: Optional[int] = None,
@@ -176,19 +285,17 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
     contract, pinned by test).
 
     ``mxu`` is the resolved MXU-recast config (``ops/mxu.MxuConfig``,
-    None = off; docs/roofline.md "Executing the hot-spot list"): three
+    None = off; docs/roofline.md "Executing the hot-spot list"): two
     flag-gated bytes-moved reductions executing the JX4xx hot-spot
     ranking — ``coalesce`` traces the twin's scatter-coalesced step
-    kernel (``step_rows_coalesced``) when it provides one, ``slim_queue``
-    appends novel rows in ``batch``-sized chunks gated on ``n_new``
-    instead of one candidate-stack-wide window, and ``probe`` recasts
-    the bucket membership reductions as one blocked bitmapped
+    kernel (``step_rows_coalesced``) when it provides one, and ``probe``
+    recasts the bucket membership reductions as one blocked bitmapped
     ``dot_general`` (``bucket_insert(probe_dot=True)``).  Off means zero
-    extra ops AND the exact pre-MXU jaxpr (the prededup contract); on,
+    extra ops AND the exact unflagged jaxpr (the prededup contract); on,
     counts/verdicts/traces are bit-identical — pinned by tests.
     ``checked`` mode keeps the plain step under its checkify wrapper
     (the coalesced kernel is a perf shape, not a debug surface); the
-    queue/probe recasts still apply.
+    probe recast still applies.
 
     ``checked`` is the sanitizer's dynamic guard
     (``stateright_tpu/analysis/sanitizer.py``): the MODEL kernels
@@ -220,15 +327,9 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
 
     step_rows_fn = coalesced_step_fn(tensor, mxu)
     probe_dot = bool(mxu is not None and mxu.probe)
-    # the slim chunk width must DIVIDE the candidate stack: a final
-    # dynamic_slice whose start clamps would misalign the written rows
-    # (queue corruption).  Every shipped config is a power-of-two
-    # multiple; an exotic cand budget statically falls back to the
-    # plain window (a build-time decision — both are Python ints here).
+    # the append's chunk: what one trip of append_novel's loop gathers
+    # and writes (a ``cand`` under ``batch`` is one chunk)
     qchunk = min(batch, eff_cand)
-    slim_queue = bool(
-        mxu is not None and mxu.slim_queue and eff_cand % qchunk == 0
-    )
     qalloc = queue_alloc(qcap, m, por is not None, spill)
     n_props = len(props)
     ev_idx = [
@@ -311,66 +412,6 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
         lanes = whole = lambda x: x  # one device: no equation left behind
     else:
         lanes, whole = place.lanes, place.whole
-
-    def append_novel(qrows, qfp, qebits, qdepth, tail0, sel, n_new,
-                     crows, cfp, cebt, cdep):
-        """Append the novel-compacted ``sel`` prefix at ``tail0``.
-
-        Plain path: one candidate-stack-wide window per buffer (the
-        pre-MXU expressions verbatim — jaxpr pin).  Slim-queue path
-        (``mxu.slim_queue``): ``qchunk``-sized chunks gated on
-        ``n_new``, so the gather + ``dynamic_update_slice`` windows the
-        roofline ledger charges track the NOVEL count, not the padded
-        stack (queue rows 1-3 of docs/roofline.md's tables).  ``qchunk``
-        divides ``eff_cand`` (enforced at build time), so no chunk's
-        slice start ever clamps and the last write ends at most at
-        ``tail0 + eff_cand`` — inside the same ``qalloc`` slack the
-        plain window uses; an overflowed batch (``n_new == 0``) writes
-        nothing, which only strengthens the replay contract."""
-        if place is not None:
-            # the same window, written by row index (StepPlacement.append)
-            return tuple(
-                place.append(q, c[sel], tail0)
-                for q, c in ((qrows, crows), (qfp, cfp), (qebits, cebt),
-                             (qdepth, cdep))
-            )
-        if not slim_queue:
-            qrows = jax.lax.dynamic_update_slice(
-                qrows, crows[sel], (tail0, jnp.int32(0))
-            )
-            qfp = jax.lax.dynamic_update_slice(qfp, cfp[sel], (tail0,))
-            qebits = jax.lax.dynamic_update_slice(
-                qebits, cebt[sel], (tail0,)
-            )
-            qdepth = jax.lax.dynamic_update_slice(
-                qdepth, cdep[sel], (tail0,)
-            )
-            return qrows, qfp, qebits, qdepth
-
-        def chunk(state):
-            k, qr, qf, qe, qd = state
-            off = k * qchunk
-            w_idx = jax.lax.dynamic_slice(sel, (off,), (qchunk,))
-            qr = jax.lax.dynamic_update_slice(
-                qr, crows[w_idx], (tail0 + off, jnp.int32(0))
-            )
-            qf = jax.lax.dynamic_update_slice(
-                qf, cfp[w_idx], (tail0 + off,)
-            )
-            qe = jax.lax.dynamic_update_slice(
-                qe, cebt[w_idx], (tail0 + off,)
-            )
-            qd = jax.lax.dynamic_update_slice(
-                qd, cdep[w_idx], (tail0 + off,)
-            )
-            return k + 1, qr, qf, qe, qd
-
-        _, qrows, qfp, qebits, qdepth = jax.lax.while_loop(
-            lambda s: s[0] * qchunk < n_new,
-            chunk,
-            (jnp.int32(0), qrows, qfp, qebits, qdepth),
-        )
-        return qrows, qfp, qebits, qdepth
 
     def step(carry):
         """Pop one batch, expand, dedup+insert, append novel rows."""
@@ -505,13 +546,10 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
             )
         with jax.named_scope(STAGE_APPEND):
             # Append novel rows (novel-compacted ``sel`` prefix) at the queue
-            # tail.  Rows past ``n_new`` in the written window are garbage; they
-            # sit in [tail+n_new, tail+eff_cand) which later appends overwrite
-            # before ``tail`` ever reaches them.  (Slim-queue mode writes only
-            # whole batch-chunks up to n_new; see append_novel.)
-            qrows, qfp, qebits, qdepth = append_novel(
-                qrows, qfp, qebits, qdepth, tail, sel, n_new,
-                cand_rows, cand_fp, cand_ebt, cand_dep,
+            # tail, in whole chunks up to ``n_new`` (see append_novel)
+            (qrows, qfp, qebits, qdepth), n_chunks = append_novel(
+                (qrows, qfp, qebits, qdepth), tail, sel, n_new,
+                (cand_rows, cand_fp, cand_ebt, cand_dep), qchunk, place,
             )
 
         if por is not None:
@@ -535,10 +573,11 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
                     probe_dot=probe_dot,
                 )
             with jax.named_scope(STAGE_APPEND):
-                qrows, qfp, qebits, qdepth = append_novel(
-                    qrows, qfp, qebits, qdepth, tail1, sel2, n_new2,
-                    cand_rows, cand_fp2, cand_ebt, cand_dep,
+                (qrows, qfp, qebits, qdepth), n_chunks2 = append_novel(
+                    (qrows, qfp, qebits, qdepth), tail1, sel2, n_new2,
+                    (cand_rows, cand_fp2, cand_ebt, cand_dep), qchunk, place,
                 )
+                n_chunks = n_chunks + n_chunks2
             toverflow = toverflow | tovf2
             coverflow = coverflow | covf2
             n_new_all = n_new + n_new2
@@ -678,10 +717,10 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
             err=err,
             por=PorTail(boost, pstats) if por is not None else None,
             spill=sp, cart=cart,
-        )
+        ), n_chunks
 
     def cond(state):
-        k, carry = state
+        k, _, carry = state
         go = (carry.status == jnp.int32(_STATUS_OK)) & (k < steps)
         go = go & (carry.tail > carry.head) & ~all_discovered(carry.disc)
         if target is not None:
@@ -696,12 +735,19 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
     # ``XLA Modules`` line) AND part of the persistent compile cache's key,
     # which otherwise ignores debug metadata: an executable cached before
     # the stages had names must not be served in place of this one.
+    def body(state):
+        # ``k`` and ``chunks`` ride the loop beside the carry, not in it:
+        # the device steps of this call and its append's chunk writes
+        k, chunks, carry = state
+        carry, n_chunks = step(carry)
+        return k + 1, chunks + n_chunks, carry
+
     def wavefront_run(carry):
-        k, carry = jax.lax.while_loop(
-            cond, lambda s: (s[0] + 1, step(s[1])), (jnp.int32(0), carry)
+        k, chunks, carry = jax.lax.while_loop(
+            cond, body, (jnp.int32(0), jnp.int32(0), carry)
         )
         with jax.named_scope(STAGE_STATS):
-            return carry, stats_of(carry, k)
+            return carry, stats_of(carry, k, chunks)
 
     # the carry is donated on every backend (jax 0.9.0 donates on CPU
     # too): the host loop never touches a carry after passing it in
@@ -761,7 +807,7 @@ def _build_engine(tensor, props, cap: int, qcap: int, batch: int,
             )),
         )
         with jax.named_scope(STAGE_STATS):
-            return carry, stats_of(carry, jnp.int32(0))
+            return carry, stats_of(carry, jnp.int32(0), jnp.int32(0))
 
     init_fn = jax.jit(wavefront_init)
     return init_fn, run_fn
@@ -978,20 +1024,12 @@ class TpuChecker(WavefrontChecker):
             # pre-MXU tuple (cache unkeyed by the feature's absence) —
             # and the key carries the EFFECTIVE config, so component
             # subsets that fall back to an identical program (no
-            # coalesced kernel on this twin; slim chunk width not
-            # dividing the candidate stack) share one cache entry
+            # coalesced kernel on this twin) share one cache entry
             # instead of paying a duplicate engine compile
             from ..ops.mxu import effective_mxu
 
             eff = effective_mxu(self.tensor, self._mxu)
-            if eff is not None and eff.slim_queue:
-                m = batch * self.tensor.max_actions
-                ec = min(cand, m) if cand else m
-                if ec % min(batch, ec):
-                    eff = eff._replace(slim_queue=False)
-            if eff is not None and (
-                eff.coalesce or eff.slim_queue or eff.probe
-            ):
+            if eff is not None and (eff.coalesce or eff.probe):
                 key = key + (eff.key(),)
         return key
 
@@ -2241,7 +2279,8 @@ class TpuChecker(WavefrontChecker):
                 self._telemetry_occupancy_hist(grown_occ, at="growth")
                 grown_occ = None
             st = read_stats(stats, carry)
-            head, tail, unique, scount, maxdepth, status, dsteps, disc = st[:8]
+            (head, tail, unique, scount, maxdepth, status, dsteps,
+             append_chunks, disc) = st[:9]
             self._device_steps += dsteps
             with self._live_lock:
                 self._live = (scount, unique, maxdepth)
@@ -2277,6 +2316,10 @@ class TpuChecker(WavefrontChecker):
                     # device steps of the call this sync closed, and the
                     # lanes each popped: dsteps * batch lanes were offered
                     dsteps=dsteps, batch=batch,
+                    # chunk writes of that call's appends (append_novel):
+                    # / dsteps the trips a step, x min(batch, cand) against
+                    # dsteps x cand the lanes the cand-wide window wrote
+                    append_chunks=append_chunks,
                     # HOT occupancy with the spill tier armed: evicted
                     # uniques live off-device (spilled_live is 0 otherwise)
                     load_factor=round((unique - spilled_live) / cap, 6),

@@ -315,7 +315,9 @@ def test_the_placement_leaves_no_equation_in_the_one_device_step():
     assert alone.count("dynamic_slice") >= 4
     placed = primitives(StepPlacement(build_mesh(4)))
     assert placed.count("sharding_constraint") >= 10
-    assert placed.count("dynamic_slice") == alone.count("dynamic_slice") - 4
+    # (and the one-device append's loop slices a chunk of ``sel`` a trip,
+    # where the mesh keeps the window: wavefront.append_novel)
+    assert placed.count("dynamic_slice") == alone.count("dynamic_slice") - 4 - 1
     # the payload rows go in by index; the three narrow columns keep the
     # update slice (the partitioner gathers a column: StepPlacement.append)
     assert (placed.count("dynamic_update_slice")

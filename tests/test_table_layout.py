@@ -11,7 +11,14 @@ bitcast on the TPU) and the chunked scatters touch it.  Pinned here:
  - on the step program compiled for a described v5e: how many operations of
    the loop body produce ``cap`` elements (the old body's count beside it);
  - ``bucket_insert`` against the gathering reference of ``test_buckets.py``,
-   bit for bit on the table's contents, at the benchmark cells' lane shapes.
+   bit for bit on the table's contents, at the benchmark cells' lane shapes;
+ - on the same compiled step: the append moves ``qchunk`` rows a trip,
+   never the ``cand``-wide window but for a wide payload's one gather
+   before the loop; on the mesh step compiled for the described 2x2: the
+   window, as it was;
+ - on a wide-rowed twin's compiled step: no copy of the payload queue, and
+   every operation of a block's size under the stage its predecessor's
+   module filed it under.
 
 The host boundaries see the same flat table as before, so their tests are
 the ones the repo had: ``tests/test_checkpoint.py`` (the snapshot's table is
@@ -42,6 +49,7 @@ from stateright_tpu.parallel import wavefront as wf
 from stateright_tpu.parallel.carry import carry_avals
 
 sys.path.insert(0, str(Path(__file__).parent))
+from hlo_stage import stage_movers  # noqa: E402
 from test_buckets import INSERTS, both_inserts, fresh  # noqa: E402
 
 
@@ -86,8 +94,9 @@ def table_sized_eqns(jaxpr, cap, inside=False):
             yield eqn.primitive.name, shapes
 
 
-def step_program(n=3, cap=1 << 16, qcap=1 << 10, batch=32, cand=256, **kw):
-    model = TwoPhaseSys(n)
+def step_program(n=3, cap=1 << 16, qcap=1 << 10, batch=32, cand=256,
+                 model=None, **kw):
+    model = model or TwoPhaseSys(n)
     tensor, props = model.tensor_model(), list(model.properties())
     _, run_fn = wf._build_engine(
         tensor, props, cap, qcap, batch, 8, None, cand=cand, **kw
@@ -119,19 +128,25 @@ def test_the_loop_body_touches_the_table_with_a_gather_and_scatters_only(sym):
 
 
 @pytest.fixture(scope="module")
-def one_v5e_chip():
-    """A described (not attached) v5e chip to compile for; described inside
-    the fixture, never at import, and skipped where it cannot be."""
+def v5e_2x2():
+    """The devices of a described (not attached) v5e 2x2 to compile for;
+    described inside the fixture, never at import, and skipped where it
+    cannot be."""
     try:
         from jax.experimental import topologies
-        from jax.sharding import SingleDeviceSharding
 
-        topo = topologies.get_topology_desc(
+        return topologies.get_topology_desc(
             platform="tpu", topology_name="v5e:2x2"
-        )
-        return SingleDeviceSharding(topo.devices[0])
+        ).devices
     except Exception as e:  # noqa: BLE001 - no TPU compiler here
         pytest.skip(f"no v5e topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 HLO_INSTRUCTION = re.compile(
@@ -142,13 +157,15 @@ HLO_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
 HLO_NO_BUFFER = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
 
 
-def table_sized_operations(hlo_text, cap, in_entry=False):
+def table_sized_operations(hlo_text, cap, in_entry=False, op_names=False):
     """``(opcode, result shape)`` of every operation outside the entry
     computation and outside fused computations (a fusion counts once, as
     the operation it is) whose result holds ``cap`` or more elements: with
     one ``while`` at the top of the run program, these are the operations
     of its body and of the loops nested in it.  ``in_entry``: those of the
-    entry computation instead (a custom call under its target's name)."""
+    entry computation instead (a custom call under its target's name).
+    ``op_names``: each with its ``op_name`` as a third member ("" for
+    none)."""
     found, entry, name = [], False, ""
     for line in hlo_text.splitlines():
         head = HLO_COMPUTATION.match(line)
@@ -166,6 +183,9 @@ def table_sized_operations(hlo_text, cap, in_entry=False):
         if opcode not in HLO_NO_BUFFER and max(sizes, default=0) >= cap:
             target = re.search(r'custom_call_target="(\w+)"', line)
             found.append((target.group(1) if target else opcode, shape.strip()))
+            if op_names:
+                op_name = re.search(r'op_name="([^"]*)"', line)
+                found[-1] += (op_name.group(1) if op_name else "",)
     return found
 
 
@@ -175,9 +195,11 @@ def compiled_for(sharding, fn, *avals, **static):
     cannot be read back, and warns)."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    avals = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), avals
-    )
+    if sharding is not None:  # None: the avals carry their own
+        avals = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+            avals,
+        )
     cache_was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -188,7 +210,18 @@ def compiled_for(sharding, fn, *avals, **static):
         compilation_cache.reset_cache()
 
 
-def test_the_compiled_loop_holds_two_table_sized_operations(one_v5e_chip):
+BIG = dict(cap=1 << 23, qcap=1 << 14, batch=256, cand=2048)
+
+
+@pytest.fixture(scope="module")
+def big_step(one_v5e_chip):
+    """The 2pc step program at a 2^23-slot table, compiled ahead of time
+    for one v5e: ONE compile for the tests that read its text."""
+    run_fn, avals = step_program(**BIG)
+    return compiled_for(one_v5e_chip, run_fn, avals)
+
+
+def test_the_compiled_loop_holds_two_table_sized_operations(big_step):
     """The 2pc step program at a 2^23-slot table, compiled ahead of time
     for one v5e: inside the loop TWO operations produce ``cap`` elements,
     the write loop's two scatter fusions (each a two-plane ``u32[cap]``
@@ -197,9 +230,7 @@ def test_the_compiled_loop_holds_two_table_sized_operations(one_v5e_chip):
     16]``, two ``copy`` into the gather's slot-major layout, and a
     ``copy-start`` / ``copy-done`` / ``ConcatBitcast`` moving a plane
     between memories around them."""
-    cap = 1 << 23
-    run_fn, avals = step_program(cap=cap, qcap=1 << 14, batch=256, cand=2048)
-    compiled = compiled_for(one_v5e_chip, run_fn, avals)
+    cap, compiled = BIG["cap"], big_step
     found = table_sized_operations(compiled.as_text(), cap)
     assert [op for op, _ in found] == ["fusion", "fusion"], found  # parent: 9
     assert all(shape.count(f"u32[{cap}]") == 2 for _, shape in found)
@@ -208,7 +239,197 @@ def test_the_compiled_loop_holds_two_table_sized_operations(one_v5e_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
-# -- the parent walk of trace reconstruction (PR 47) -------------------------
+def test_the_compiled_append_moves_a_chunk_a_trip(big_step):
+    """The same module's ``sr.append``: the TPU compiler's gathers
+    and update slices of the append are ``qchunk`` = ``batch`` = 256 rows
+    (the fingerprint's two ``u32`` planes a gather each), none the 2,048
+    lanes of a ``cand``-wide window, and
+    the loop around them kept the stage's name."""
+    movers = stage_movers(big_step.as_text(), "sr.append")
+    assert {kind for kind, _ in movers} == {"gather", "dynamic-update-slice"}
+    assert {rows for _, rows in movers} == {BIG["batch"]}
+    assert "sr.append/while/body" in big_step.as_text()
+
+
+WIDE = dict(cap=1 << 16, qcap=1 << 14, batch=256, cand=1024)
+
+
+def wide_step_text(sharding):
+    """paxos-2's step (22-word rows, 20 actions: a 5,120-row candidate
+    block) compiled for one v5e: the module's text, and the payload
+    buffer's ``(qalloc, width)`` and the block's ``(m, width)``."""
+    from stateright_tpu.models.paxos import paxos_model
+
+    model = paxos_model(2)
+    run_fn, avals = step_program(model=model, **WIDE)
+    m = WIDE["batch"] * model.tensor_model().max_actions
+    return (compiled_for(sharding, run_fn, avals).as_text(), avals.q_rows.shape,
+            (m, avals.q_rows.shape[1]))
+
+
+@pytest.fixture(scope="module")
+def wide_step(one_v5e_chip):
+    return wide_step_text(one_v5e_chip)
+
+
+def whole_queue_copies(text, queue):
+    return [line for line in text.splitlines()
+            if re.search(rf"= \w+\[{queue[0]},{queue[1]}\]\S* copy(-start)?\(", line)]
+
+
+# a buffer moved between the chip's memories, not an operation of the step:
+# the compiler schedules these around any program and names none of them
+HLO_MEMORY_MOVES = {"copy-start", "copy-done", "slice-start", "slice-done"}
+
+
+def block_sized_operations(text, elements):
+    """``(opcode, result shape, op_name)`` of every operation of the run
+    program's loops (``table_sized_operations``'s scope) that produces at
+    least ``elements`` elements, the moves between memories apart."""
+    return [found for found in table_sized_operations(
+                text, elements, op_names=True)
+            if found[0] not in HLO_MEMORY_MOVES]
+
+
+def test_a_wide_rowed_queue_is_never_copied_whole(
+        wide_step, one_v5e_chip, monkeypatch):
+    """paxos-2's step compiled for one v5e: no ``copy`` of the whole
+    payload buffer anywhere in the run program - the queue keeps the
+    layout it arrives in (one plane a word) through the append's loop,
+    because a chunk is handed to the update slice in that order
+    (``wavefront._in_column_planes``).  Without the pin the compiler carries
+    the queue row-major (22 words padded to 128) and copies it whole for
+    every pop: five copies, two of them a step (PERF.md section 6, PR 53)."""
+    text, queue, _ = wide_step
+    assert whole_queue_copies(text, queue) == []
+    monkeypatch.setattr(wf, "_in_column_planes", lambda rows: rows)
+    text, queue, _ = wide_step_text(one_v5e_chip)
+    assert len(whole_queue_copies(text, queue)) == 5  # what the pin is there for
+
+
+def while_only_append(bufs, tail0, sel, n_new, cands, qchunk, place=None):
+    """``append_novel`` with the payload gathered inside the loop like the
+    columns: no gather reads the candidate block outside a ``while``."""
+    last = jnp.int32(sel.shape[0] - qchunk)
+
+    def chunk(state):
+        k, bufs = state
+        off = jnp.minimum(k * qchunk, last)
+        w_idx = jax.lax.dynamic_slice(sel, (off,), (qchunk,))
+        return k + 1, tuple(
+            jax.lax.dynamic_update_slice(
+                q, wf._in_column_planes(c[w_idx]) if q.ndim == 2 else c[w_idx],
+                (tail0 + off,) + (jnp.int32(0),) * (q.ndim - 1))
+            for q, c in zip(bufs, cands))
+
+    n_chunks, bufs = jax.lax.while_loop(
+        lambda s: s[0] * qchunk < n_new, chunk, (jnp.int32(0), tuple(bufs)))
+    return bufs, n_chunks
+
+
+def test_every_pass_over_the_candidate_block_carries_its_stages_name(
+        wide_step, one_v5e_chip, monkeypatch):
+    """The same module: every operation of the step that produces at least
+    the smallest of the step's blocks - the candidate block's ``m x width``
+    words, its slot part ``batch x actions x slots``, the gathered payload's
+    ``cand x width`` - carries an ``op_name`` under one of the ``sr.``
+    stages, and the stage its predecessor's module gave it (commit fa77ed2,
+    compiled the same way), so the profile's reader
+    (``benchmarks/srbench/xstages.py``) files the same seconds under the
+    same stage on both sides of this change:
+
+     - the block's relayout for the append's row gather - a ``copy`` into
+       row-major ``[batch, actions, width]`` and the ``reshape`` to ``[m,
+       width]``, a plane each of a ``u64`` - is ``sr.hash``'s;
+     - the slot block's two copies into the hash's order are ``sr.hash``'s;
+     - the payload's gather is ``sr.append``'s, before the loop, and what
+       it gathered takes no further pass (the predecessor copied it whole
+       into plane order, under ``sr.append`` too): the loop slices it as
+       it lies (``wavefront._chunk_rows``).
+
+    The one operation without a name is the slot sort's ``iota``, which has
+    none at the predecessor either.  With the payload gathered INSIDE the
+    loop the relayout's two passes run under no name (what
+    ``while_only_append`` compiles to): why ``append_novel`` gathers a wide
+    payload before it."""
+    batch, cand = WIDE["batch"], WIDE["cand"]
+
+    def passes(text, block):
+        (m, width), actions = block, block[0] // batch
+        slots = (batch, actions, actions)  # paxos-2: a slot an action
+        ops = block_sized_operations(
+            text, min(m * width, int(np.prod(slots)), cand * width))
+        assert ops
+
+        def named(shape_prefix, *opcodes):
+            return [name for op, shape, name in ops
+                    if op in opcodes and shape.startswith(shape_prefix)]
+
+        return ops, {
+            "relayout": named(f"u32[{batch},{actions},{width}]", "copy")
+            + named(f"u32[{m},{width}]", "reshape"),
+            "slots": named("u32[%d,%d,%d]" % slots, "copy"),
+            "gathered": named(f"u32[{cand},{width}]", "fusion", "copy"),
+        }
+
+    text, _, block = wide_step
+    ops, found = passes(text, block)
+    assert [(op, shape) for op, shape, name in ops
+            if "/sr." not in name and op != "iota"] == []
+    assert len(found["relayout"]) == 4
+    assert all("/sr.hash/" in name for name in found["relayout"])
+    assert len(found["slots"]) == 2
+    assert all("/sr.hash/" in name for name in found["slots"])
+    assert len(found["gathered"]) == 2  # the gather alone, a plane each
+    assert all(name.endswith("/sr.append/gather") for name in found["gathered"])
+    monkeypatch.setattr(wf, "append_novel", while_only_append)
+    text, _, block = wide_step_text(one_v5e_chip)
+    _, found = passes(text, block)
+    assert len(found["relayout"]) == 4
+    assert all(name == "" for name in found["relayout"])
+
+
+@pytest.mark.parametrize("rows", ["one_word", "wide"])
+def test_the_mesh_append_compiled_for_the_2x2_is_the_window(v5e_2x2, rows):
+    """The mesh step (batch 64, cand 512; 2pc-3's one-word rows and
+    paxos-2's 22-word ones) compiled for the described four chips keeps the
+    window ``append_novel``'s loop replaced on one chip: no ``while`` under
+    ``sr.append``, every gather, scatter and update slice of the stage
+    ``cand`` rows - the payload by row index, a narrow column by an update
+    slice whose operand the partitioner gathers (``StepPlacement.append``) -
+    and the run program's temporaries and code the size they were (the mesh
+    waits for a four-chip reading of the loop: PERF.md section 6)."""
+    from jax.sharding import Mesh
+
+    from stateright_tpu.models.paxos import paxos_model
+    from stateright_tpu.parallel.mesh import MeshTpuChecker
+    from stateright_tpu.parallel.partition import (
+        MESH_AXES,
+        StepPlacement,
+        replicated,
+    )
+
+    mesh = Mesh(np.asarray(v5e_2x2).reshape(1, 4), MESH_AXES)
+    cap, qcap, batch, cand = 1 << 14, 1 << 16, 64, 512
+    run_fn, avals = step_program(
+        cap=cap, qcap=qcap, batch=batch, cand=cand, place=StepPlacement(mesh),
+        model=paxos_model(2) if rows == "wide" else None)
+    placer = MeshTpuChecker.__new__(MeshTpuChecker)  # its rules, no engine
+    placer._mesh = mesh
+    placed = placer._place(avals)
+    mesh_run = jax.jit(run_fn, in_shardings=(placed,),
+                       out_shardings=(placed, replicated(mesh)))
+    avals = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        avals, placed)
+    text = compiled_for(None, mesh_run, avals).as_text()
+    assert (avals.q_rows.shape[1] > 1) == (rows == "wide")
+    assert "sr.append/while" not in text
+    movers = stage_movers(text, "sr.append")
+    assert {kind for kind, _ in movers} >= {
+        "gather", "scatter", "dynamic-update-slice"}
+    assert {n for kind, n in movers
+            if kind in ("gather", "scatter", "dynamic-update-slice")} == {cand}
 
 
 def test_the_parent_walk_reads_the_table_with_two_slices_a_link():

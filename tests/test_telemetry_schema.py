@@ -46,6 +46,8 @@ SCHEMA = {
             # the device call this sync closed: its while_loop's trip
             # count, and the lanes a step pops (dsteps * batch offered)
             "dsteps": int, "batch": int,
+            # that call's chunk writes of the queue append
+            "append_chunks": int,
         },
     ),
     "growth": (
