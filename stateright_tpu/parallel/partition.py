@@ -162,21 +162,19 @@ class StepPlacement:
     def append(self, q, rows, tail):
         """``q`` with ``rows`` written at ``[tail, tail + len(rows))``
         (``dynamic_update_slice(q, rows, tail)``'s value), every chip
-        holding all of ``rows`` to do so.  The 512 B payload rows go in by
-        row index: a chip writes those that fall in its own range.  A
-        NARROW column (one word a row: ``q_fp``, ``q_ebits``, ``q_depth``)
-        keeps the update slice, which the partitioner does by gathering
-        that column - 37 MB a ``u32`` plane at paxos-6, 0.4 ms over ICI -
-        where a scatter of the window's words costs the chip its price an
-        index (with all four buffers scattered a check read 27.9 s, with
-        the payload alone 22.1 s; PERF.md section 6, PR 51).  Either way
-        the column stays SHARDED in the carry."""
-        rows = self.whole(rows)
-        if q.ndim == 1:
-            return jax.lax.dynamic_update_slice(q, rows, (tail,))
+        holding all of ``rows`` to do so: by row index, payload and narrow
+        columns alike, so a chip writes the rows that fall in its own range
+        and no collective moves more than ``rows``.  An update slice at a
+        traced offset of a SHARDED buffer is done by gathering the buffer on
+        every chip; for a one-word column that was the cheaper spelling
+        while the append wrote one candidate-stack-wide window a step (one
+        gather of a column against a scatter's price an index over the whole
+        stack), and is not inside ``append_novel``'s loop, where the gather
+        would be paid a TRIP and the indices are a chunk's.  Either way the
+        buffer stays SHARDED in the carry."""
         at = tail + jax.lax.iota(tail.dtype, rows.shape[0])
         return q.at[at].set(
-            rows, indices_are_sorted=True, unique_indices=True,
+            self.whole(rows), indices_are_sorted=True, unique_indices=True,
             mode="promise_in_bounds",
         )
 

@@ -154,18 +154,25 @@ def _in_column_planes(rows):
     return planes.reshape(width, n).T
 
 
-def _chunk_rows(block, off, n: int):
+def _chunk_rows(block, off, n: int, width: int):
     """Rows ``[off, off + n)`` of ``block`` (``[cand, width]``, the gathered
     payload rows a ``while_loop`` slices), cut loose from the layout the
     loop's body wants them in: the slice is flattened behind a barrier - a
     one-dimensional array has ONE physical order - so whatever order the
-    body's update slice asks of the chunk stops there, and ``block`` enters
+    body's write asks of the chunk stops there, and ``block`` enters
     the loop as its gather laid it out, with no copy at the loop's edge.
     The rows are padded to the TPU tile's 128 lanes first: a row-major
     ``[n, 128]`` and its flattening are the same bytes, which a ``[n, 21]``
     and its flattening are not (a pass, and a megabyte of code a step
-    program at 4,096 rows)."""
-    width = block.shape[1]
+    program at 4,096 rows).
+
+    A ``block`` that was flattened WHOLE before the loop (``[cand x
+    width]``, the mesh's: ``append_novel``) is cut loose already: its chunk
+    is a slice of it, as it lies."""
+    if block.ndim == 1:
+        return jax.lax.dynamic_slice(
+            block, (off * width,), (n * width,)
+        ).reshape(n, width)
     lanes = -(-width // 128) * 128
     rows = jax.lax.dynamic_slice(block, (off, jnp.int32(0)), (n, width))
     flat = jax.lax.optimization_barrier(
@@ -206,23 +213,49 @@ def append_novel(bufs, tail0, sel, n_new, cands, qchunk: int, place=None):
     (``tests/test_table_layout.py`` holds the compiled step to that).
     One-word rows have no such relayout and go with the columns.
 
-    The mesh engine (``place``: ``partition.StepPlacement``) keeps the
-    window this loop replaced - ``len(sel)`` rows gathered and written by
-    row index a step, zero chunks counted: the loop on a mesh is the
-    chunk's write through ``place.append`` and nothing else, and waits for
-    a reading of the committed files on four chips (PERF.md section 6)."""
-    if place is not None:
-        return tuple(
-            place.append(q, c[sel], tail0) for q, c in zip(bufs, cands)
-        ), jnp.int32(0)
+    On a mesh (``place``: ``partition.StepPlacement``) the body is the
+    same and the trips are counted the same; ``place`` decides how the
+    gathered block is held and how a chunk is written.  The block is made
+    whole before the loop (``place.whole``): a chunk's rows may belong to
+    any chip's range of the queue, so every chip holds the ``len(sel)``
+    novel rows - one all-reduce a step, the window's own - and the loop
+    slices them with no collective a trip.  It is also flattened whole
+    there, behind the barrier, where one chip pads and flattens a chunk a
+    trip: a chip of the mesh runs the insert's loop with its table shard's
+    planes in the chip's fast memory and this loop with the narrow
+    columns' shards there, and a padded chunk's buffers beside them are
+    more than that memory holds - the compiler then leaves the TABLE's
+    planes out of it and every scatter of the insert pays (read off the
+    text compiled for the described 2x2 at the benchmark's shapes: the
+    memory space of the insert's scatters' operands; PERF.md section 6).
+    One pass over the block a step is the cheaper price, and the one-chip
+    engines cannot pay it for their code's size (``_chunk_rows``).  And a
+    chunk is written through ``place.append``, by row index at ``tail0 +
+    off``, where one chip writes an update slice: an update slice at a
+    traced offset of a sharded buffer makes the partitioner gather the
+    buffer whole.  The chunk goes to the scatter as ``_chunk_rows`` left
+    it, not through ``_in_column_planes``: a scatter by row index takes its
+    operand's layout, not its update's, so the queue's shard stays in
+    column planes without the pin (``tests/test_table_layout.py`` holds the
+    text compiled for the 2x2 to no copy of a shard inside a loop and none
+    the window's module lacked), and the pin would be a transposition a
+    trip for nothing."""
     last = jnp.int32(sel.shape[0] - qchunk)
     # a payload wider than a word: gathered here, sliced in the loop
     wide = [c.ndim == 2 and c.shape[1] > 1 for c in cands]
     srcs = [c[sel] if w else c for c, w in zip(cands, wide)]
+    if place is not None:
+        srcs = [
+            jax.lax.optimization_barrier(place.whole(s).reshape(-1)) if w else s
+            for s, w in zip(srcs, wide)
+        ]
 
     def written(q, src, is_wide, off, w_idx):
-        rows = _chunk_rows(src, off, qchunk) if is_wide else src[w_idx]
+        rows = (_chunk_rows(src, off, qchunk, q.shape[1]) if is_wide
+                else src[w_idx])
         at = tail0 + off
+        if place is not None:
+            return place.append(q, rows, at)
         if q.ndim == 2:
             rows = _in_column_planes(rows)
         return jax.lax.dynamic_update_slice(

@@ -17,10 +17,11 @@ Held here:
    ``[0, tail)`` of all four buffers, the counts, the discoveries and their
    paths equal - the same three ``cand`` shapes, an overflowed batch
    replayed after growth, ``.symmetry()``, POR's two appends a step, and
-   the four-virtual-device mesh against the one-device engine, one-word
-   rows and wide ones;
+   the four-virtual-device mesh (whose window is ``ref_place_append``'s)
+   against the one-device engine too: one-word rows and wide ones, a
+   ``cand`` the chunk does not divide, ``.symmetry()``, POR;
  - ``append_chunks`` (the ``step`` records' counter) against a host replay
-   of the same search;
+   of the same search, and the mesh's against the one-device engine's;
  - the one-device step program's compiled text: no gather and no update of
    the append wider than ``qchunk`` rows, but a wide payload's one gather.
 """
@@ -188,37 +189,58 @@ ENGINES = {
     # POR's cycle proviso appends twice a step, the second at tail + n_new
     "por": (lambda: _pc_paxos1().checker().por(),
             dict(capacity=1 << 15, batch=256), 250),
-    "mesh4": (lambda: TwoPhaseSys(3).checker().mesh(devices=4),
+}
+
+# the four-virtual-device mesh engine: (the ONE-device builder, spawn, unique)
+MESH4 = {
+    "mesh4": (lambda: TwoPhaseSys(3).checker(),
               dict(capacity=1 << 12, batch=64, cand=256,
                    queue_capacity=1 << 12), 288),
     # rows wider than a word: the payload gathered before the loop, on
     # every chip, and a chunk of it sliced a trip
-    "mesh4_wide": (lambda: paxos_model(1, 3).checker().mesh(devices=4),
+    "mesh4_wide": (lambda: paxos_model(1, 3).checker(),
                    dict(capacity=1 << 12, batch=64, cand=256,
                         queue_capacity=1 << 12), None),
+    # the last chunk restarts at ``cand - qchunk``: rows rewritten by index
+    "mesh4_cand_odd": (lambda: TwoPhaseSys(3).checker(),
+                       dict(capacity=1 << 12, batch=64, cand=100,
+                            queue_capacity=1 << 12), 288),
+    "mesh4_symmetry": (lambda: TwoPhaseSys(3).checker().symmetry(),
+                       dict(capacity=1 << 12, batch=64), None),
+    "mesh4_por": (lambda: _pc_paxos1().checker().por(),
+                  dict(capacity=1 << 15, batch=256), 250),
 }
+ENGINES.update({
+    case: (lambda one=one: one().mesh(devices=4), spawn, unique)
+    for case, (one, spawn, unique) in MESH4.items()
+})
 
-ONE_DEVICE = {"mesh4": lambda: TwoPhaseSys(3).checker(),
-              "mesh4_wide": lambda: paxos_model(1, 3).checker()}
+
+def _chunks(c):
+    return sum(r["append_chunks"] for r in c.flight_recorder.records("step"))
 
 
 @pytest.mark.parametrize("case", sorted(ENGINES))
 def test_an_engine_queues_what_the_window_queued(case, monkeypatch):
     builder, spawn, unique = ENGINES[case]
-    new = builder().spawn_tpu(sync=True, **spawn)
+    new = builder().telemetry().spawn_tpu(sync=True, **spawn)
     assert unique is None or new.unique_state_count() == unique
     with monkeypatch.context() as mp:
         mp.setattr(wavefront, "append_novel", ref_append_novel)
-        ref = builder().spawn_tpu(sync=True, **spawn)
+        ref = builder().telemetry().spawn_tpu(sync=True, **spawn)
     _same_search(new, ref)
     if case == "replay_after_growth":
         kinds = {status for status, _ in new.growth_events}
         assert {wavefront._STATUS_TABLE_FULL, wavefront._STATUS_CAND_FULL} <= kinds
         assert new.growth_events == ref.growth_events
-    if case in ONE_DEVICE:  # and the one-device engine's, rows and all
+    assert _chunks(ref) == 0 < _chunks(new)  # the window counts none
+    if case in MESH4:  # and the one-device engine's, rows and all
         assert new.n_devices == 4
-        one = ONE_DEVICE[case]().spawn_tpu(sync=True, **spawn)
+        one = MESH4[case][0]().telemetry().spawn_tpu(sync=True, **spawn)
         _same_search(new, one)
+        # the trips follow ``n_new`` on the mesh as on one device
+        assert _chunks(new) == _chunks(one)
+        assert new.device_steps() == one.device_steps()
 
 
 # -- the counter ------------------------------------------------------------------------

@@ -290,8 +290,8 @@ def test_no_collective_of_the_step_is_the_size_of_the_queue(small_step):
 def test_the_placement_leaves_no_equation_in_the_one_device_step():
     """``place=None`` builds the parent's program: no sharding constraint,
     the pop a dynamic slice and the append a dynamic update slice; with a
-    placement the same step pops the queue, and appends its payload rows,
-    by row index."""
+    placement the same step pops the queue, and appends to all four of its
+    buffers, by row index - inside the same loop over chunks."""
     tensor = TwoPhaseSys(3).tensor_model()
     props = list(TwoPhaseSys(3).properties())
     avals = carry_avals(tensor, len(props), 1 << 10, 1 << 8, 16, checked=False)
@@ -315,14 +315,15 @@ def test_the_placement_leaves_no_equation_in_the_one_device_step():
     assert alone.count("dynamic_slice") >= 4
     placed = primitives(StepPlacement(build_mesh(4)))
     assert placed.count("sharding_constraint") >= 10
-    # (and the one-device append's loop slices a chunk of ``sel`` a trip,
-    # where the mesh keeps the window: wavefront.append_novel)
-    assert placed.count("dynamic_slice") == alone.count("dynamic_slice") - 4 - 1
-    # the payload rows go in by index; the three narrow columns keep the
-    # update slice (the partitioner gathers a column: StepPlacement.append)
+    assert placed.count("while") == alone.count("while")  # the one body
+    # the pop's four windows by index; the chunk of ``sel`` sliced a trip
+    # stays (wavefront.append_novel)
+    assert placed.count("dynamic_slice") == alone.count("dynamic_slice") - 4
+    # all four buffers go in by index, a chunk a trip: no update slice of a
+    # sharded buffer is left (StepPlacement.append)
     assert (placed.count("dynamic_update_slice")
-            == alone.count("dynamic_update_slice") - 1)
-    assert placed.count("scatter") == alone.count("scatter") + 1
+            == alone.count("dynamic_update_slice") - 4)
+    assert placed.count("scatter") == alone.count("scatter") + 4
 
 
 @pytest.mark.parametrize("shape,want", [

@@ -14,8 +14,9 @@ bitcast on the TPU) and the chunked scatters touch it.  Pinned here:
    bit for bit on the table's contents, at the benchmark cells' lane shapes;
  - on the same compiled step: the append moves ``qchunk`` rows a trip,
    never the ``cand``-wide window but for a wide payload's one gather
-   before the loop; on the mesh step compiled for the described 2x2: the
-   window, as it was;
+   before the loop; on the mesh step compiled for the described 2x2 the
+   same loop, written by row index, with nothing of a queue column's or a
+   shard's size in the stage;
  - on a wide-rowed twin's compiled step: no copy of the payload queue, and
    every operation of a block's size under the stage its predecessor's
    module filed it under.
@@ -28,6 +29,7 @@ flat and bucket-major, and resumes; growth up the ladder keeps the work).
 import functools
 import re
 import sys
+from collections import Counter
 from pathlib import Path
 
 import numpy as np
@@ -389,16 +391,40 @@ def test_every_pass_over_the_candidate_block_carries_its_stages_name(
     assert all(name == "" for name in found["relayout"])
 
 
+# the window's module (commit 17a2c16: one ``cand``-wide window a step on the
+# mesh), compiled the same way at the same shapes: temporaries and generated
+# code a chip, and the whole-shard copies of the payload buffer
+MESH_WINDOW = {
+    "one_word": dict(temp=1_936_896, code=3_587_072, shard_copies=0),
+    "wide": dict(temp=3_098_624, code=4_704_768, shard_copies=3),
+}
+
+
 @pytest.mark.parametrize("rows", ["one_word", "wide"])
-def test_the_mesh_append_compiled_for_the_2x2_is_the_window(v5e_2x2, rows):
+def test_the_mesh_append_compiled_for_the_2x2_moves_a_chunk_a_trip(v5e_2x2, rows):
     """The mesh step (batch 64, cand 512; 2pc-3's one-word rows and
-    paxos-2's 22-word ones) compiled for the described four chips keeps the
-    window ``append_novel``'s loop replaced on one chip: no ``while`` under
-    ``sr.append``, every gather, scatter and update slice of the stage
-    ``cand`` rows - the payload by row index, a narrow column by an update
-    slice whose operand the partitioner gathers (``StepPlacement.append``) -
-    and the run program's temporaries and code the size they were (the mesh
-    waits for a four-chip reading of the loop: PERF.md section 6)."""
+    paxos-2's 22-word ones) compiled for the described four chips runs
+    ``append_novel``'s loop, the one chip's:
+
+     - ``sr.append/while/body`` is there, and inside it every gather and
+       every scatter of the stage is ``qchunk`` = 64 rows - four scatters by
+       row index, one a buffer, and NO update slice (an update slice of a
+       sharded buffer is an all-gather of it, a trip: ``StepPlacement.
+       append``) - and the one collective is the chunk's words made whole,
+       ``qchunk`` of them a plane;
+     - outside the loop the stage holds nothing for one-word rows, and for
+       wide ones the payload's one ``cand``-row gather (a plane each of a
+       ``u64``) and the all-reduce that makes it whole, ``cand x width``
+       words - the window's own two, by name;
+     - nothing of the stage moves a queue column or a shard of one, and the
+       payload buffer's shard is copied whole no oftener than under the
+       window (at this size: once as the argument, twice at the run loop's
+       edge, never inside a loop) with the chunk handed to the scatter as it
+       lies, no ``_in_column_planes``;
+     - the run program's temporaries stay within 256 KiB of the window's
+       (a chunk's buffers; at the benchmark's shapes they are 254 MB UNDER
+       it, PERF.md section 6) and its code within 64 KiB (the wide block's
+       flattening, before the loop)."""
     from jax.sharding import Mesh
 
     from stateright_tpu.models.paxos import paxos_model
@@ -408,6 +434,7 @@ def test_the_mesh_append_compiled_for_the_2x2_is_the_window(v5e_2x2, rows):
         StepPlacement,
         replicated,
     )
+    from stateright_tpu.telemetry.collectives import hlo_collectives
 
     mesh = Mesh(np.asarray(v5e_2x2).reshape(1, 4), MESH_AXES)
     cap, qcap, batch, cand = 1 << 14, 1 << 16, 64, 512
@@ -422,14 +449,32 @@ def test_the_mesh_append_compiled_for_the_2x2_is_the_window(v5e_2x2, rows):
     avals = jax.tree.map(
         lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
         avals, placed)
-    text = compiled_for(None, mesh_run, avals).as_text()
-    assert (avals.q_rows.shape[1] > 1) == (rows == "wide")
-    assert "sr.append/while" not in text
-    movers = stage_movers(text, "sr.append")
-    assert {kind for kind, _ in movers} >= {
-        "gather", "scatter", "dynamic-update-slice"}
-    assert {n for kind, n in movers
-            if kind in ("gather", "scatter", "dynamic-update-slice")} == {cand}
+    compiled = compiled_for(None, mesh_run, avals)
+    text = compiled.as_text()
+    qalloc, width = avals.q_rows.shape
+    assert (width > 1) == (rows == "wide")
+    assert "sr.append/while/body" in text
+    qchunk = batch
+    inside = Counter(stage_movers(text, "sr.append/while/body"))
+    assert inside[("scatter", qchunk)] == 4
+    assert {kind for kind, _ in inside} == {"gather", "scatter", "all-reduce"}
+    assert {n for _, n in inside} == {qchunk}
+    outside = Counter(stage_movers(text, "sr.append")) - inside
+    assert outside == (
+        {("gather", cand): 2, ("all-reduce", cand * width): 1}
+        if rows == "wide" else {})
+    assert cand * width < qalloc // 4  # so: nothing the size of a column's shard
+    if rows == "wide":  # the step's largest collective, and whose it is
+        largest = hlo_collectives(text)["largest"]
+        assert f"u32[{cand},{width}]" in largest["shape"]  # combined, here
+        assert largest["op"].endswith("/while/body/sr.append/gather")
+    window = MESH_WINDOW[rows]
+    shard_copies = whole_queue_copies(text, (qalloc // 4, width))
+    assert len(shard_copies) == window["shard_copies"]
+    assert not [line for line in shard_copies if "/while/body/" in line]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= window["temp"] + (256 << 10)
+    assert mem.generated_code_size_in_bytes <= window["code"] + (64 << 10)
 
 
 def test_the_parent_walk_reads_the_table_with_two_slices_a_link():

@@ -46,7 +46,8 @@ SCHEMA = {
             # the device call this sync closed: its while_loop's trip
             # count, and the lanes a step pops (dsteps * batch offered)
             "dsteps": int, "batch": int,
-            # that call's chunk writes of the queue append
+            # that call's chunk writes of the queue append (the mesh
+            # engine counts them as one device does: one loop, PR 54)
             "append_chunks": int,
         },
     ),
