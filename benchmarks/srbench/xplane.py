@@ -107,6 +107,19 @@ def is_container(name: str) -> bool:
     return base in CONTAINER_OPS
 
 
+def opcode(name: str) -> str:
+    """The HLO opcode of a device operation's event name: the word before
+    the operands of its whole HLO line (``%all-reduce.44 = u32[..]
+    all-reduce(..)`` -> ``all-reduce``; a fusion XLA NAMED after what it
+    holds is still a ``fusion``), else - a bare instruction name, as
+    XLA:CPU's thunks carry - the name without its number."""
+    head, sep, rest = name.partition(" = ")
+    m = _RESULT_AND_OPCODE.match(_LAYOUT.sub("", rest)) if sep else None
+    if m:
+        return m.group(2)
+    return head.lstrip("%").split(".")[0].strip()
+
+
 def self_times(events: Iterable[Event]) -> list:
     """``[(name, start, end, self_ns)]``: each event's duration minus the
     part its nested children cover.  Events nest when one starts inside

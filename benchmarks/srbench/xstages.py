@@ -24,7 +24,9 @@ its time is the SELF time of its operations (``xplane.self_times``, control-
 flow containers excluded), so the stages and ``unnamed`` add up to the
 device's busy time.  An idle gap (``xplane.gaps`` over the leaf operations)
 is charged to the innermost ``sr/*`` host span that covers it, the rest to
-``unspanned``.
+``unspanned``.  Of the same self times, those of the operations that cross
+between chips (``COLLECTIVES``, by HLO opcode) are summed once more as
+``collective_s``, whatever stage they are filed under.
 
 On the CPU (the rehearsal) XLA's operations run on host threads and carry
 ``hlo_op`` / ``program_id`` instead of a scope; the scope is then looked up
@@ -59,6 +61,10 @@ STAGES = ("sr.pop", "sr.props", "sr.expand", "sr.hash", "sr.insert",
           "sr.append", "sr.bookkeep", "sr.stats")
 UNNAMED = "unnamed"
 UNSPANNED = "unspanned"
+# what crosses between chips: the HLO opcodes GSPMD writes for it (the program
+# counts the same five in its ``mesh.program`` record, telemetry/collectives.py)
+COLLECTIVES = ("all-reduce", "all-gather", "all-to-all", "reduce-scatter",
+               "collective-permute")
 
 
 # -- the wire format ----------------------------------------------------------
@@ -254,6 +260,16 @@ def stage_of(scope: str) -> str:
     return UNNAMED
 
 
+def is_collective(name: str) -> bool:
+    """Whether a device operation's HLO opcode is one of ``COLLECTIVES``; an
+    asynchronous one's two halves (``all-reduce-start`` / ``-done``) both
+    are, each for its own time."""
+    code = xplane.opcode(name)
+    for half in ("-start", "-done"):
+        code = code.removesuffix(half)
+    return code in COLLECTIVES
+
+
 @functools.lru_cache(maxsize=2)
 def load(path: str, annotation: str = WINDOW_ANNOTATION) -> dict:
     """``{"devices": {plane: [(op id, start_ns, duration_ns)]}, "ops":
@@ -336,8 +352,11 @@ def reduce_stages(devices: dict, ops: dict, window: Optional[tuple] = None,
     ``bytes_accessed`` summed per stage over the executed operations
     (``stage_bytes``: XLA's estimate, not a measurement), the ``top``
     operations of each stage with their source (``stage_ops``:
-    ``[label, source, seconds]``) and the idle ``gaps`` of the busiest
-    chip (``[(start_ns, end_ns)]``, all of them)."""
+    ``[label, source, seconds]``), the idle ``gaps`` of the busiest
+    chip (``[(start_ns, end_ns)]``, all of them) and, of the same self
+    times, the collectives' (``is_collective``, whatever scope they carry):
+    ``collective_s`` and its split by the stage each is filed under
+    (``collective_stages``) - a part OF the stages, not beside them."""
     if not devices or not any(devices.values()):
         return {}
     if window is None:
@@ -350,6 +369,8 @@ def reduce_stages(devices: dict, ops: dict, window: Optional[tuple] = None,
     stage_ns: dict = {}
     stage_bytes: dict = {}
     op_ns: dict = {}
+    collective_ns: dict = {}
+    crosses = {op_id: is_collective(op["name"]) for op_id, op in ops.items()}
     busy, work_of = [], []
     for plane, events in sorted(devices.items()):
         work = []
@@ -362,6 +383,8 @@ def reduce_stages(devices: dict, ops: dict, window: Optional[tuple] = None,
             stage_ns[stage] = stage_ns.get(stage, 0.0) + self_ns
             stage_bytes[stage] = stage_bytes.get(stage, 0) + op["bytes"]
             op_ns[op_id] = op_ns.get(op_id, 0.0) + self_ns
+            if crosses[op_id]:
+                collective_ns[stage] = collective_ns.get(stage, 0.0) + self_ns
         busy.append(xplane.union_ns(work))
         work_of.append(work)
     busiest = max(range(chips), key=busy.__getitem__)
@@ -380,6 +403,8 @@ def reduce_stages(devices: dict, ops: dict, window: Optional[tuple] = None,
         "unnamed_pct": 100.0 * stage_ns.get(UNNAMED, 0.0) / self_ns if self_ns else 0.0,
         "stage_bytes": {k: v // chips for k, v in stage_bytes.items()},
         "stage_ops": stage_ops,
+        "collective_s": sum(collective_ns.values()) / chips / 1e9,
+        "collective_stages": {k: v / chips / 1e9 for k, v in collective_ns.items()},
         "gaps": xplane.gaps(work_of[busiest], window),
         "chips": chips,
     }
@@ -520,6 +545,11 @@ def report(out: dict) -> str:
         )
         for label, source, s in out["stage_ops"].get(stage, []):
             rows.append(f"xstages:       {s:12.6f} s  {label}  [{source}]")
+    if out["collective_stages"]:
+        rows.append(f"xstages: collectives {out['collective_s']:.6f} s of that self time, "
+                    "by the stage they are filed under: " + ", ".join(
+            f"{k} {v:.6f}" for k, v in sorted(out["collective_stages"].items(),
+                                              key=lambda kv: -kv[1])))
     rows.append("xstages: host spans (seconds inside, profiler's clock): " + ", ".join(
         f"{k} {v:.6f}" for k, v in sorted(out["span_s"].items(), key=lambda kv: -kv[1])))
     rows.append("xstages: idle seconds by innermost covering span: " + ", ".join(

@@ -12,8 +12,16 @@ for a new cell alone — and EVERY test under ``tests/benchmarks/`` that reads t
 (each ``test_*`` function whose one argument is the ``manifest`` fixture,
 found by that signature, so a test added later is held to this too) is run
 on the copy.  CPU-only, unit-cheap.
+
+"Appended, nothing moved" is said here once, for every entry (PR 55): what
+the manifest held at PR 54 - the names of its configurations, cells and
+metrics, in their order - is a PREFIX of what it holds.  No other test under
+``tests/benchmarks/`` holds an entry to a place or a list to a length, so a
+later PR brings its entries by appending them and edits nothing.
 """
 
+import glob
+import importlib
 import inspect
 import json
 import os
@@ -31,22 +39,55 @@ sys.path.insert(0, HERE)
 
 import test_benchmark_loops as loops  # noqa: E402
 import test_benchmark_own as own  # noqa: E402
-import test_benchmark_stages as stages  # noqa: E402
-import test_benchmark_sym as sym  # noqa: E402
-import test_benchmark_twin as twin  # noqa: E402
 from srbench import check as chk  # noqa: E402
 from srbench.manifest import Manifest  # noqa: E402
 
 CLOSED, COLD, CONFIG, READER = ("linreg2x2o-tiny", "linreg2x2o-cold", "linreg2x2o",
                                 "checks_in_window")
 BOUNDED, PREFIX = "twopc5-bounded-tiny", "twopc5-prefix"
+# every test file of the benchmark, one added later too - but this one and
+# those that take ``roomier`` from here and run the guard on themselves
+# (test_benchmark_linreg4o.py: importing them back would go round in a circle)
+MODULES = [importlib.import_module(os.path.basename(path)[:-3])
+           for path in sorted(glob.glob(os.path.join(HERE, "test_benchmark_*.py")))
+           if "from test_benchmark_room import" not in open(path).read()
+           and os.path.abspath(path) != os.path.abspath(__file__)]
 MANIFEST_READERS = sorted(
-    (fn for module in (loops, own, stages, sym, twin)
+    {fn for module in MODULES
      for name, fn in vars(module).items()
      if name.startswith("test_") and inspect.isfunction(fn)
-     and list(inspect.signature(fn).parameters) == ["manifest"]),
+     and list(inspect.signature(fn).parameters) == ["manifest"]},
     key=lambda fn: (fn.__module__, fn.__name__),
 )
+
+# what the manifest held at PR 54, in its order.  A later PR extends the
+# MANIFEST, never these lists.
+HELD_AT_PR54 = {
+    "configs": (
+        "paxos3", "twopc8", "linreg2x3o", "twopc13sym", "singlecopy4", "linreg4o",
+        "paxos2lossy", "twopc10", "paxos6", "paxos6x4"),
+    "workloads": (
+        "paxos3-presized", "twopc8-presized", "paxos3-defaults",
+        "linreg2x3o-presized", "linreg2x3o-cold", "twopc13sym-presized",
+        "singlecopy4-presized", "linreg4o-presized", "paxos2lossy-presized",
+        "twopc10-bounded", "paxos6-bounded", "paxos6x4-bounded"),
+    "end_to_end": ("check_s", "gen_rate", "peak_hbm", "setup_s"),
+    "per_layer": (
+        "engine_acquire_s", "cache_misses", "acquire_check_s",
+        "fingerprint_bridge_s", "host_syncs", "growth_s", "dispatch_s",
+        "device_wait_s", "device_idle_pct", "step_roofline", "reconstruct_s",
+        "stage_pop_s", "stage_props_s", "stage_expand_s", "stage_hash_s",
+        "stage_insert_s", "stage_append_s", "stage_unnamed_pct", "device_steps",
+        "batch_fill_pct", "grow_pull_s", "grow_rehash_s", "grow_push_s",
+        "reconstruct_pull_s", "reconstruct_parents_s", "reconstruct_replay_s",
+        "idle_unspanned_s", "grow_queue_s", "stage_expand_table_s",
+        "stage_expand_net_s", "stage_expand_history_s", "twin_expand_roofline",
+        "twin_compile_s", "twin_compile_check_s", "twin_table_bytes",
+        "stage_hash_roofline", "cand_fill_pct", "stage_props_lin_s",
+        "acquire_trace_s", "acquire_lower_s", "acquire_trace_check_s",
+        "acquire_lower_check_s", "acquire_retrieval_check_s",
+        "programs_loaded_check", "stage_expand_drop_s", "queue_fill_pct"),
+}
 
 
 @pytest.fixture(scope="module")
@@ -64,7 +105,8 @@ def roomier(tmp_path_factory):
     cfg.update({
         "client_count": 2,
         "reduced_from": {"client_count": {"source": 3, "here": 2, "why": "tiny"}},
-        "deployment": {"servers": 2, "clients": 2},
+        "deployment": {"servers": 2, "clients": 2,
+                       "properties": ["always linearizable", "sometimes value chosen"]},
         "assumed": {"device_twin": "compiled by actor_compiler"},
         "guarantees": ["exact unique-state count over the whole reachable space"],
     })
@@ -138,6 +180,16 @@ def test_the_copy_has_more_of_everything_and_is_sound(roomier):
             ] == [COLD]
 
 
+@pytest.mark.parametrize("key", sorted(HELD_AT_PR54))
+def test_what_the_manifest_held_is_a_prefix_of_what_it_holds(roomier, key):
+    """Appended, nothing moved - of the committed manifest and of the copy
+    with more in it alike."""
+    held = list(HELD_AT_PR54[key])
+    committed = Manifest(os.path.join(REPO, "BENCHMARK.json"), BENCH).doc
+    for doc in (committed, roomier[1].doc):
+        assert [m["name"] for m in doc[key]][:len(held)] == held
+
+
 def test_the_tests_that_read_the_manifest_are_found():
     names = {fn.__name__ for fn in MANIFEST_READERS}
     assert {"test_every_committed_workload_names_a_known_kind",
@@ -146,7 +198,13 @@ def test_the_tests_that_read_the_manifest_are_found():
             "test_manifest_has_exactly_the_contract_keys",
             "test_every_reader_file_repeats_its_manifest_entry",
             "test_config_files_state_source_cut_guarantees_and_pins",
-            "test_the_symmetric_cell_is_presized_for_the_pinned_space"} <= names
+            "test_the_symmetric_cell_is_presized_for_the_pinned_space",
+            # of every cell's own file, since PR 55
+            "test_the_readers_constants_are_the_manifest_entrys",
+            "test_the_cell_reports_what_the_issue_lists",
+            "test_each_of_the_seven_is_listed_for_the_cells_with_something_to_read"} <= names
+    assert {"test_benchmark_paxos6", "test_benchmark_paxos6x4",
+            "test_benchmark_readers55"} <= {fn.__module__ for fn in MANIFEST_READERS}
 
 
 @pytest.mark.parametrize("held", MANIFEST_READERS,
